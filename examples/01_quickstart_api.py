@@ -12,9 +12,6 @@ import sys
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from fedtorch_tpu.utils import honor_platform_env
-honor_platform_env()  # respect JAX_PLATFORMS=cpu for device-free runs
-
 import jax
 
 from fedtorch_tpu.algorithms import make_algorithm

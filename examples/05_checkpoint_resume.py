@@ -21,9 +21,6 @@ import tempfile
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from fedtorch_tpu.utils import honor_platform_env
-honor_platform_env()
-
 import jax
 import numpy as np
 

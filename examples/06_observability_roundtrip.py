@@ -61,6 +61,4 @@ def main():
 
 
 if __name__ == "__main__":
-    from fedtorch_tpu.utils import honor_platform_env
-    honor_platform_env()
     main()

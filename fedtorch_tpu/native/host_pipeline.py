@@ -1,33 +1,43 @@
 """ctypes bindings + background prefetcher for the native host pipeline.
 
-Auto-compiles ``pipeline.cpp`` with g++ on first use (cached next to the
-source); every entry point falls back to numpy when the toolchain or the
-library is unavailable, so the Python-only path always works.
+Auto-compiles ``pipeline.cpp`` with g++ on first use, into a file named
+after the source's content hash next to it — so a binary is only ever
+loaded for the source it was built from, whatever a copy did to the
+mtimes. Every entry point falls back to numpy when the toolchain or the
+library is unavailable (the failure is warned about once, and
+``native_available()`` reports which path runs); the gather/pad
+fallbacks are bitwise-identical, ``seeded_permutation`` draws a
+different stream.
 """
 from __future__ import annotations
 
 import contextlib
 import ctypes
 import fcntl
+import glob
+import hashlib
 import os
 import queue
 import subprocess
 import threading
 import time
+import warnings
 from typing import Optional
 
 import numpy as np
 
 _SRC = os.path.join(os.path.dirname(__file__), "pipeline.cpp")
-_LIB_PATH = os.path.join(os.path.dirname(__file__),
-                         "libfedtorch_host.so")
+_LIB_STEM = os.path.join(os.path.dirname(__file__), "libfedtorch_host")
 _lib = None
 _lib_tried = False
 
 
-def _lib_fresh() -> bool:
-    return (os.path.exists(_LIB_PATH)
-            and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC))
+def _lib_path() -> str:
+    """The library file for the CURRENT source: the content hash is in
+    the name, so freshness never rests on mtimes."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return f"{_LIB_STEM}.{digest}.so"
 
 
 def _build_library(run=subprocess.run) -> Optional[str]:
@@ -37,25 +47,36 @@ def _build_library(run=subprocess.run) -> Optional[str]:
     racing a worker, multi-process gloo tests), and a ``dlopen`` of a
     half-written .so aborts the process — so the compiler writes to a
     private temp path and the result lands via atomic ``os.replace``,
-    serialized by an exclusive per-path file lock. A process that waited
-    on the lock re-checks freshness and adopts the winner's build
-    instead of compiling twice. ``run`` is injectable for tests."""
-    lock_path = _LIB_PATH + ".lock"
-    tmp_path = f"{_LIB_PATH}.tmp.{os.getpid()}"
+    serialized by an exclusive file lock. A process that waited on the
+    lock adopts the winner's build instead of compiling twice, and the
+    builder removes the binaries of other source versions. ``run`` is
+    injectable for tests."""
+    lib_path = _lib_path()
+    lock_path = _LIB_STEM + ".so.lock"
+    tmp_path = f"{lib_path}.tmp.{os.getpid()}"
     try:
         with open(lock_path, "w") as lock_f:
             fcntl.flock(lock_f.fileno(), fcntl.LOCK_EX)
             try:
-                if _lib_fresh():
-                    return _LIB_PATH  # a racing builder finished first
+                if os.path.exists(lib_path):
+                    return lib_path  # a racing builder finished first
                 run(["g++", "-O3", "-shared", "-fPIC", "-o", tmp_path,
                      _SRC, "-lpthread"],
                     check=True, capture_output=True, timeout=120)
-                os.replace(tmp_path, _LIB_PATH)
-                return _LIB_PATH
+                os.replace(tmp_path, lib_path)
+                for stale in glob.glob(_LIB_STEM + "*.so"):
+                    if stale != lib_path:
+                        with contextlib.suppress(OSError):
+                            os.unlink(stale)
+                return lib_path
             finally:
                 fcntl.flock(lock_f.fileno(), fcntl.LOCK_UN)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        warnings.warn(
+            f"native host pipeline build failed ({e!r}"
+            f"{detail[-200:].decode(errors='replace')}); using the "
+            "numpy fallback", RuntimeWarning, stacklevel=2)
         return None
     finally:
         with contextlib.suppress(OSError):
@@ -84,7 +105,9 @@ def load_library():
     if _lib is not None or _lib_tried:
         return _lib
     _lib_tried = True
-    path = _LIB_PATH if _lib_fresh() else _build_library()
+    path = _lib_path()
+    if not os.path.exists(path):
+        path = _build_library()
     if path is None:
         return None
     try:

@@ -50,13 +50,9 @@ def init_multihost(cfg: MeshConfig, *,
     # pinned to cpu (reading the config flag does NOT initialize a
     # backend — calling jax.default_backend() here would, breaking
     # distributed.initialize's must-run-first contract).
-    platforms = (getattr(jax.config, "jax_platforms", None) or "").lower()
+    platforms = (jax.config.jax_platforms or "").lower()
     if "cpu" in platforms.split(","):
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # jax version without the knob
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     timeout_s = cfg.init_timeout_s if timeout_s is None else timeout_s
     backoff_s = cfg.init_backoff_s if backoff_s is None else backoff_s
     deadline = time.monotonic() + timeout_s

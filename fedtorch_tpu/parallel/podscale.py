@@ -49,11 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 # cap on the deterministic group count: bounds the unrolled level-2
 # add chain (and with it program size) while leaving every shard count
 # up to a 64-host pod a whole number of groups per shard
@@ -143,9 +138,9 @@ def cohort_hierarchical_sum(payloads, mesh: Mesh, shards: int):
                                       tiled=True)  # [G, P], global order
             return _left_deep(full)
 
-        summed = _shard_map(
+        summed = jax.shard_map(
             per_shard, mesh=mesh, in_specs=P(axis), out_specs=P(),
-            check_rep=False)(flat)
+            check_vma=False)(flat)
     else:
         summed = _left_deep(_group_partials(flat, groups))
 

@@ -16,7 +16,9 @@ from fedtorch_tpu.utils.compile_cache import (  # noqa: F401
 from fedtorch_tpu.utils.lock_sentinel import (  # noqa: F401
     LockOrderSentinel, active_sentinel,
 )
-from fedtorch_tpu.utils.platform import honor_platform_env  # noqa: F401
+from fedtorch_tpu.utils.platform import (  # noqa: F401
+    device_stamp, require_tpu,
+)
 from fedtorch_tpu.utils.tracing import (  # noqa: F401
     RecompilationSentinel, capture_round_trace, instrument_trace,
     trace_counts,

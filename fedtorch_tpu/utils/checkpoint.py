@@ -710,9 +710,9 @@ def maybe_resume(directory: Optional[str], server, clients,
                                clients, restored["clients"])
     # The returned state feeds straight into the round jit, which
     # DONATES its inputs. Host-numpy leaves must not meet donation:
-    # the jit's implicit numpy->Array conversion has been observed (cpu
-    # jaxlib 0.4.36) to hand XLA buffers whose backing memory is torn
-    # down with the host array — the first post-resume round then
+    # the jit's implicit numpy->Array conversion has been observed (on
+    # an earlier CPU jaxlib) to hand XLA buffers whose backing memory
+    # is torn down with the host array — the first post-resume round then
     # aggregates into recycled heap (bitwise-correct losses, garbage
     # server params, a heap-corruption abort at exit). Committing the
     # restored server to device arrays HERE makes resume hand back

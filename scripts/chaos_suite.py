@@ -63,9 +63,6 @@ def run_suite(rounds: int = 20, smoke: bool = False, tol_points: float = 5.0,
     (``sync_mode='async'``) under the :func:`straggler_heavy_fault`
     schedule — the ISSUE 6 convergence bar (async within ``tol_points``
     of sync while its commit program traces exactly once)."""
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import jax.numpy as jnp
 
@@ -239,9 +236,6 @@ def run_availability_matrix(rounds: int = 12, smoke: bool = False,
       dropouts: arrivals discarded + re-dispatched, commit sequence
       deterministic under replay.
     """
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import hashlib
 
     import jax
@@ -476,9 +470,6 @@ def run_privacy_matrix(rounds: int = 12, smoke: bool = False,
       finishes every round noise-free with a `degraded` intent.
       Neither wedges.
     """
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import hashlib
     import shutil
     import tempfile
@@ -757,9 +748,6 @@ def run_builder_matrix(rounds: int = 8, smoke: bool = False,
     the faulted per-round device program for the sync cells, the
     faulted resident commit program for the commit cell. Writes
     BUILDER_MATRIX.json (tpu_capture.sh ``builder-matrix`` step)."""
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import numpy as np
 
@@ -947,9 +935,6 @@ def run_attack_matrix(rounds: int = 20, smoke: bool = False,
     — byzantine corruption — while the mixture keeps the task
     non-trivial.
     """
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import numpy as np
 
@@ -1122,9 +1107,6 @@ def run_ledger_attack(rounds: int = 20, smoke: bool = False,
     the only record naming the adversaries)."""
     import tempfile
 
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import numpy as np
 
@@ -1280,9 +1262,6 @@ def run_host_fault_matrix(rounds: int = 12, smoke: bool = False,
     Injection is a pure hash of (seed, seam, check index), so the
     whole matrix is replayable; results land in HOST_CHAOS_AB.json.
     """
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import hashlib
     import tempfile
 
@@ -1541,7 +1520,12 @@ def run_kill_drill(rounds: int = 150, ckpt_root: str = None) -> dict:
     trajectory-identity half of this drill lives in
     tests/test_kill_drill.py; this entry checks the operator-facing
     lifecycle end to end (drain -> restartable exit -> relaunch ->
-    completion) against the production entry point."""
+    completion) against the production entry point.
+
+    The children are the chip-using processes: this parent must not
+    have touched a backend before it launches them (a process that has
+    holds the chip), so main() runs this drill BEFORE the in-process
+    suite, and the children inherit the parent's platform choice."""
     import signal
     import subprocess
     import tempfile
@@ -1559,12 +1543,10 @@ def run_kill_drill(rounds: int = 150, ckpt_root: str = None) -> dict:
            "--federated_sync_type", "local_step", "--local_step", "2",
            "--batch_size", "8", "--lr", "0.1", "--eval_freq", "1",
            "--debug", "false", "--run_dir", run_dir]
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     state = {"killed": False}
 
     def popen(c, **kw):
-        proc = subprocess.Popen(c, env=env, stdout=subprocess.DEVNULL,
+        proc = subprocess.Popen(c, stdout=subprocess.DEVNULL,
                                 stderr=subprocess.DEVNULL)
         if not state["killed"]:
             # watch checkpoint.json; SIGTERM once the run is mid-flight
@@ -1726,12 +1708,15 @@ def main():
                                    out_path=args.attack_out)
         print(json.dumps(report), flush=True)
         return
+    # first, while this process has not touched a backend: the drill's
+    # children need the chip
+    kill_report = run_kill_drill(rounds=60 if args.smoke else 150) \
+        if args.kill_drill else None
     report = run_suite(rounds=args.rounds, smoke=args.smoke,
                        tol_points=args.tol, seed=args.seed,
                        straggler_heavy=args.straggler_heavy)
-    if args.kill_drill:
-        report["kill_drill"] = run_kill_drill(
-            rounds=60 if args.smoke else 150)
+    if kill_report is not None:
+        report["kill_drill"] = kill_report
     print(json.dumps(report), flush=True)
 
 

@@ -1,4 +1,4 @@
-"""Sparse-MoE dispatch A/B off-chip (VERDICT r4 item #6, no-relay branch).
+"""Sparse-MoE dispatch A/B off-chip.
 
 Runs the SAME cases as ``scripts/moe_ab_bench.py`` (dense exact
 dispatch vs Switch sparse capacity dispatch at cf 1.0/1.25/2.0, full
@@ -91,9 +91,7 @@ def ep_mesh_ab():
 
 
 def main() -> int:
-    from fedtorch_tpu.utils import enable_compile_cache, \
-        honor_platform_env
-    honor_platform_env()
+    from fedtorch_tpu.utils import enable_compile_cache
     enable_compile_cache()
     import jax
     if jax.devices()[0].platform != "cpu":

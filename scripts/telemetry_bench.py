@@ -353,9 +353,6 @@ def main():
     ap.add_argument("--out", default=OUT)
     args = ap.parse_args()
 
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import jax
 
     from fedtorch_tpu.telemetry import Telemetry

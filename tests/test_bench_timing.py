@@ -1,6 +1,5 @@
-"""Pin the fetch-synced timer (scripts/bench_timing.py) — the relay
-workaround every micro-benchmark depends on (BASELINE_REPRO.md
-"timing-methodology finding"): sync() must materialize real bytes for
+"""Pin the fetch-synced timer (scripts/bench_timing.py) every
+micro-benchmark depends on: sync() must materialize real bytes for
 any result shape, and timeit() must return a sane per-call mean."""
 import importlib.util
 import os
@@ -9,9 +8,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-# load the script module without mutating sys.path (same pattern as
-# test_bench_capture.py): a path insert would shadow any test-session
-# import that collides with a scripts/ filename
+# load the script module without mutating sys.path: a path insert
+# would shadow any test-session import that collides with a scripts/
+# filename
 _spec = importlib.util.spec_from_file_location(
     "bench_timing", os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -60,8 +59,8 @@ class TestTimeit:
         assert len(calls) == 6  # warmup + iters
 
     def test_sync_each_mode_calls_and_drains(self):
-        """The opt-in per-iteration-sync cross-check mode (ADVICE
-        round-5): same call count, every iteration drained through a
+        """The opt-in per-iteration-sync cross-check mode: same call
+        count, every iteration drained through a
         fetch before the next dispatch."""
         calls = []
 

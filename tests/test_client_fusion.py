@@ -232,7 +232,7 @@ class TestSweepPlumbing:
     @pytest.mark.slow
     def test_mfu_sweep_runs_fused_config_on_cpu(self, tmp_path,
                                                 monkeypatch):
-        """The measurement path the next relay window will execute:
+        """The measurement path the on-chip sweep executes:
         run_config with client_fusion='fused' end-to-end on CPU,
         including the capture_round_trace profiler artifact."""
         import os

@@ -49,8 +49,13 @@ TRACE_NAMES = {
 
 
 def capture_for(trainer, tmp_path, **kw):
+    # stamped as the one part the peaks table lists, so the gauge
+    # ARITHMETIC is exercised; what a cpu stamp yields is pinned in
+    # test_no_mfu_without_a_published_peak
+    kw.setdefault("device_kind", "TPU v5 lite")
+    kw.setdefault("compute_dtype", "bfloat16")
     cap = ProgramCostCapture(
-        str(tmp_path), compute_dtype="float32",
+        str(tmp_path),
         arch="logistic_regression", batch_size=8,
         local_steps=trainer.local_steps, k_online=trainer.k_online,
         num_devices=int(trainer.mesh.devices.size), backend="cpu",
@@ -129,14 +134,31 @@ class TestProgramCostsSchema:
             "peak_source": "x", "programs": {"round": rec}})
         assert cost_summary(None)["flops"] is None
 
-    def test_peak_resolution(self, monkeypatch):
-        monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
-        assert resolve_peak_tflops("bfloat16") == (
-            197.0, "default:tpu_v5e:bfloat16")
-        assert resolve_peak_tflops("float32")[0] == 98.0
-        monkeypatch.setenv("BENCH_PEAK_TFLOPS", "123.5")
-        assert resolve_peak_tflops("float32") == (
-            123.5, "env:BENCH_PEAK_TFLOPS")
+    def test_peak_resolution(self):
+        # a table keyed by device_kind; anything it does not list —
+        # the CPU, an unknown part, a dtype with no published peak —
+        # has NO peak, never another part's number
+        assert resolve_peak_tflops("TPU v5 lite", "bfloat16") == (
+            197.0, "table:TPU v5 lite:bfloat16")
+        for kind, dtype in (("cpu", "bfloat16"), ("cpu", "float32"),
+                            ("TPU v99", "bfloat16"), (None, "bfloat16"),
+                            ("TPU v5 lite", "float32")):
+            peak, source = resolve_peak_tflops(kind, dtype)
+            assert peak is None and source.startswith("none:")
+
+    def test_no_mfu_without_a_published_peak(self, tmp_path):
+        trainer = make_trainer()
+        server, clients = trainer.init_state(jax.random.key(0))
+        programs, primary = trainer.lowered_cost_programs(server,
+                                                          clients)
+        cap = capture_for(trainer, tmp_path,
+                          device_kind=jax.devices()[0].device_kind)
+        doc = cap.capture(programs, primary=primary)
+        assert doc["peak_tflops_per_chip"] is None
+        gauges = cap.round_gauges(0.25)
+        assert not {"model_flops_utilization", "round_device_min_s",
+                    "round_host_frac"} & set(gauges)
+        assert gauges["hbm_program_peak_bytes"] > 0
 
     def test_shared_flops_probes(self):
         # the dedup target: the generic jit probe and the train-step
@@ -224,14 +246,13 @@ class TestCostCaptureHostOnly:
         n_dev = int(trainer.mesh.devices.size)
         got = cap.round_gauges(0.25)["model_flops_utilization"]
         assert got == pytest.approx(
-            flops / (0.25 * 98.0 * 1e12 * n_dev))
+            flops / (0.25 * 197.0 * 1e12 * n_dev))
         # gauges are empty before a successful capture
         assert capture_for(trainer, tmp_path).round_gauges(0.25) == {}
 
     def test_resume_adopts_existing_capture(self, tmp_path):
         # elastic restarts reuse the run dir: a second capture object
-        # adopts the recorded document instead of recompiling (resumed
-        # runs bypass the persistent compile cache)
+        # adopts the recorded document instead of recompiling
         trainer = make_trainer()
         server, clients = trainer.init_state(jax.random.key(0))
         programs, primary = trainer.lowered_cost_programs(server,
@@ -458,7 +479,8 @@ class TestEndToEndCapture:
 class TestCliRunDeviceGauges:
     def test_mini_run_emits_costs_and_gauges(self, tmp_path):
         """run_experiment writes program_costs.json and every metrics
-        row carries the measured-MFU + HBM gauges (schema-valid)."""
+        row carries the HBM gauges (schema-valid) — and, on the CPU,
+        no MFU: the device has no published peak."""
         from test_telemetry import _cli_cfg
 
         from fedtorch_tpu.cli import run_experiment
@@ -473,9 +495,10 @@ class TestCliRunDeviceGauges:
                                                    "metrics.jsonl"))
                 if "schema" not in r]
         assert len(rows) == 3
+        assert doc["peak_tflops_per_chip"] is None
         for r in rows:
             validate_metrics_row(r)
-            assert r["model_flops_utilization"] > 0
+            assert "model_flops_utilization" not in r
             assert r["hbm_program_peak_bytes"] > 0
             assert r["hbm_live_bytes"] > 0
 

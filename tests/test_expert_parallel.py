@@ -11,16 +11,6 @@ from fedtorch_tpu.models.transformer import (
 )
 from fedtorch_tpu.parallel.expert import ep_moe_apply
 
-# ep_moe_apply executes inside jax.shard_map; jax releases that only
-# expose jax.experimental.shard_map raise AttributeError before any
-# expert math runs — a version skip, not a red baseline. The module's
-# single-device MoE-layer tests and the divisibility check (which
-# raises before shard_map) stay un-marked.
-requires_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax does not expose the public jax.shard_map API "
-           "(only jax.experimental.shard_map); ep_moe_apply needs it")
-
 
 def _layer(E=8, d=16, B=2, T=12):
     layer = MoEMLP(num_experts=E)
@@ -183,7 +173,6 @@ class TestAuxLoss:
 
 
 class TestExpertParallel:
-    @requires_shard_map
     @pytest.mark.parametrize("n_ep", [1, 2, 4, 8])
     def test_matches_single_device(self, n_ep):
         layer, params, x = _layer(E=8)
@@ -193,7 +182,6 @@ class TestExpertParallel:
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                    atol=2e-5, rtol=2e-5)
 
-    @requires_shard_map
     @pytest.mark.parametrize("n_ep", [2, 8])
     def test_sparse_dispatch_matches_dense(self, n_ep):
         """EP sparse path (per-device token gather over the expert
@@ -205,7 +193,6 @@ class TestExpertParallel:
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                    atol=2e-5, rtol=2e-5)
 
-    @requires_shard_map
     def test_sparse_dispatch_matches_module_sparse_with_drops(self):
         """With a TIGHT capacity the EP sparse path must drop exactly
         the tokens the single-device sparse module drops."""

@@ -18,12 +18,14 @@ class TestBuildRace:
     atomic rename, serialized by a per-path file lock."""
 
     def _patch_paths(self, tmp_path, monkeypatch):
+        import pathlib
+
         import fedtorch_tpu.native.host_pipeline as hp
         src = tmp_path / "src.cpp"
         src.write_text("// fake source")
         monkeypatch.setattr(hp, "_SRC", str(src))
-        monkeypatch.setattr(hp, "_LIB_PATH", str(tmp_path / "lib.so"))
-        return hp, tmp_path / "lib.so"
+        monkeypatch.setattr(hp, "_LIB_STEM", str(tmp_path / "lib"))
+        return hp, pathlib.Path(hp._lib_path())
 
     def test_never_compiles_in_place_and_no_tmp_residue(
             self, tmp_path, monkeypatch):
@@ -40,8 +42,7 @@ class TestBuildRace:
         assert hp._build_library(run=fake_run) == str(lib)
         assert lib.read_bytes() == b"SO"
         assert len(outs) == 1
-        residue = [p for p in tmp_path.iterdir()
-                   if p.name.startswith("lib.so.tmp")]
+        residue = [p for p in tmp_path.iterdir() if ".tmp." in p.name]
         assert residue == []
 
     def test_concurrent_builders_compile_once(self, tmp_path,
@@ -75,15 +76,46 @@ class TestBuildRace:
         hp, lib = self._patch_paths(tmp_path, monkeypatch)
 
         def broken_run(cmd, **kw):
+            import subprocess
             with open(cmd[cmd.index("-o") + 1], "wb") as f:
                 f.write(b"PART")  # partial output before the failure
-            raise RuntimeError("compiler died")
+            raise subprocess.CalledProcessError(1, cmd, stderr=b"boom")
 
-        assert hp._build_library(run=broken_run) is None
+        # the numpy fallback is announced, not silent
+        with pytest.warns(RuntimeWarning, match="numpy fallback"):
+            assert hp._build_library(run=broken_run) is None
         assert not lib.exists()
-        residue = [p for p in tmp_path.iterdir()
-                   if p.name.startswith("lib.so.tmp")]
+        residue = [p for p in tmp_path.iterdir() if ".tmp." in p.name]
         assert residue == []
+
+    def test_freshness_follows_source_hash(self, tmp_path, monkeypatch):
+        """A binary is only ever adopted for the source it was built
+        from: the content hash is in the file name, so an edited
+        pipeline.cpp rebuilds whatever the mtimes say (a copied tree
+        does not preserve them), and the old binary is removed."""
+        import os
+        hp, lib_v1 = self._patch_paths(tmp_path, monkeypatch)
+        compiles = []
+
+        def fake_run(cmd, **kw):
+            compiles.append(cmd)
+            with open(cmd[cmd.index("-o") + 1], "wb") as f:
+                f.write(b"SO%d" % len(compiles))
+
+        assert hp._build_library(run=fake_run) == str(lib_v1)
+        # same source: adopted, not rebuilt — even with the binary's
+        # mtime far in the past
+        os.utime(lib_v1, (0, 0))
+        assert hp._build_library(run=fake_run) == str(lib_v1)
+        assert len(compiles) == 1
+        # edited source, binary NEWER than source: still rebuilt
+        (tmp_path / "src.cpp").write_text("// edited source")
+        os.utime(tmp_path / "src.cpp", (0, 0))
+        lib_v2 = hp._lib_path()
+        assert lib_v2 != str(lib_v1)
+        assert hp._build_library(run=fake_run) == lib_v2
+        assert len(compiles) == 2
+        assert not lib_v1.exists()  # stale version removed
 
 
 def test_seeded_perm_valid_and_deterministic():

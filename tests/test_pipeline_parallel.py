@@ -10,15 +10,6 @@ from jax.sharding import Mesh
 from fedtorch_tpu.models.transformer import TransformerLM
 from fedtorch_tpu.parallel.pipeline import pipeline_apply
 
-# the staged schedule executes inside jax.shard_map; jax releases that
-# only expose jax.experimental.shard_map raise AttributeError before
-# any pipeline math runs — a version skip, not a red baseline. The
-# argument-validation tests raise before shard_map and stay un-marked.
-requires_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax does not expose the public jax.shard_map API "
-           "(only jax.experimental.shard_map); pipeline_apply needs it")
-
 
 def _model_and_toks(layers=4, d_model=32, heads=4, seq=24, vocab=48,
                     batch=8):
@@ -29,7 +20,6 @@ def _model_and_toks(layers=4, d_model=32, heads=4, seq=24, vocab=48,
     return model, params, toks
 
 
-@requires_shard_map
 @pytest.mark.parametrize("n_pp,microbatches", [(1, 1), (2, 2), (4, 4),
                                                (4, 8), (2, 1)])
 def test_pipeline_matches_dense(n_pp, microbatches):
@@ -42,7 +32,6 @@ def test_pipeline_matches_dense(n_pp, microbatches):
                                atol=2e-5, rtol=2e-5)
 
 
-@requires_shard_map
 def test_eight_stage_single_block_each():
     model, params, toks = _model_and_toks(layers=8)
     mesh = Mesh(np.asarray(jax.devices()[:8]), ("pp",))
@@ -66,7 +55,6 @@ def test_rejects_indivisible_batch():
         pipeline_apply(model, params, toks, mesh, num_microbatches=4)
 
 
-@requires_shard_map
 def test_pipeline_moe_model():
     """pipeline_apply must thread num_experts into the rebuilt blocks:
     a MoE transformer pipelined over 4 stages equals its dense oracle."""

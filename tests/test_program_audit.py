@@ -120,8 +120,7 @@ class TestSeededText:
 
 class TestSeededLowerings:
     def test_injected_f64_cast_fires(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64():
             low = jax.jit(lambda x: x.astype(jnp.float64) * 2).lower(
                 jax.ShapeDtypeStruct((4,), jnp.float32))
             text = low.as_text()
@@ -197,7 +196,7 @@ class TestFullMatrix:
     def test_shipped_baseline_is_empty(self):
         fps, peaks = load_program_baseline()
         assert sum(fps.values()) == 0
-        # peaks may be pinned later by a relay capture; fingerprints
+        # peaks may be pinned later by a chip capture; fingerprints
         # must stay empty (findings are fixed, not accepted)
 
 

@@ -9,17 +9,6 @@ from fedtorch_tpu.parallel.sequence import (
     reference_attention, ring_attention, ulysses_attention,
 )
 
-# both strategies execute inside jax.shard_map; jax releases that only
-# expose jax.experimental.shard_map raise AttributeError before any
-# attention math runs. A version skip (not a red baseline) so real
-# regressions stay visible. The argument-validation tests below raise
-# BEFORE shard_map and stay un-marked.
-requires_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax does not expose the public jax.shard_map API "
-           "(only jax.experimental.shard_map); the sequence-parallel "
-           "strategies need it")
-
 
 def _mesh(n):
     return Mesh(np.asarray(jax.devices()[:n]), ("sp",))
@@ -31,7 +20,6 @@ def _qkv(b=2, s=32, h=4, d=16, seed=0):
     return tuple(jax.random.normal(k, shape) for k in ks)
 
 
-@requires_shard_map
 @pytest.mark.parametrize("n_shards", [1, 2, 8])
 def test_matches_dense_attention(n_shards):
     q, k, v = _qkv()
@@ -41,7 +29,6 @@ def test_matches_dense_attention(n_shards):
                                atol=2e-5, rtol=2e-5)
 
 
-@requires_shard_map
 @pytest.mark.parametrize("n_shards", [2, 8])
 def test_causal_matches_dense(n_shards):
     q, k, v = _qkv(seed=3)
@@ -51,7 +38,6 @@ def test_causal_matches_dense(n_shards):
                                atol=2e-5, rtol=2e-5)
 
 
-@requires_shard_map
 def test_long_sequence_sharded():
     """A sequence too big to be comfortable dense still runs sharded."""
     q, k, v = _qkv(b=1, s=1024, h=2, d=8, seed=5)
@@ -64,7 +50,6 @@ def test_long_sequence_sharded():
                                atol=2e-5, rtol=2e-5)
 
 
-@requires_shard_map
 def test_jit_compatible():
     mesh = _mesh(2)
     q, k, v = _qkv(s=16)
@@ -80,7 +65,6 @@ class TestRingFlashBlocks:
     pieces merged by logsumexp weighting (parallel/sequence.py
     _ring_flash_local)."""
 
-    @requires_shard_map
     @pytest.mark.parametrize("n_shards", [1, 2, 8])
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_dense_oracle(self, n_shards, causal):
@@ -91,7 +75,6 @@ class TestRingFlashBlocks:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    @requires_shard_map
     def test_matches_dense_block_impl(self):
         q, k, v = _qkv(s=64, seed=9)
         a = ring_attention(q, k, v, _mesh(4), causal=True,
@@ -101,7 +84,6 @@ class TestRingFlashBlocks:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-5, rtol=2e-5)
 
-    @requires_shard_map
     def test_gradients_match_oracle(self):
         """The lse joint VJP composes with the sharded merge: grads
         through the flash ring == grads through dense attention."""
@@ -119,7 +101,6 @@ class TestRingFlashBlocks:
         with pytest.raises(ValueError, match="block_impl"):
             ring_attention(q, k, v, _mesh(2), block_impl="sparse")
 
-    @requires_shard_map
     @pytest.mark.parametrize("strategy", ["ring", "ulysses"])
     def test_real_kernel_traces_under_shard_map_vma(self, strategy,
                                                     monkeypatch):
@@ -146,7 +127,6 @@ class TestUlysses:
     """All-to-all (head-parallel) strategy: must agree with dense AND
     with the ring strategy on identical inputs."""
 
-    @requires_shard_map
     @pytest.mark.parametrize("n_shards", [1, 2, 4])
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_dense(self, n_shards, causal):
@@ -156,7 +136,6 @@ class TestUlysses:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    @requires_shard_map
     def test_matches_ring(self):
         q, k, v = _qkv(b=1, s=64, h=8, d=8, seed=9)
         ring = ring_attention(q, k, v, _mesh(8), causal=True)
@@ -164,7 +143,6 @@ class TestUlysses:
         np.testing.assert_allclose(np.asarray(uly), np.asarray(ring),
                                    atol=2e-5, rtol=2e-5)
 
-    @requires_shard_map
     @pytest.mark.parametrize("causal", [False, True])
     def test_flash_local_matches_dense(self, causal):
         """block_impl='flash': the local full-sequence attention runs
@@ -176,7 +154,6 @@ class TestUlysses:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    @requires_shard_map
     def test_flash_local_gradients_match_oracle(self):
         """The flash custom VJP composed with the two all-to-alls under
         shard_map: gradients == dense attention's."""
@@ -199,7 +176,6 @@ class TestUlysses:
         with pytest.raises(ValueError, match="divisible"):
             ulysses_attention(q, k, v, _mesh(8))
 
-    @requires_shard_map
     def test_jit_compatible(self):
         mesh = _mesh(4)
         q, k, v = _qkv(s=16, h=4)
@@ -211,7 +187,6 @@ class TestUlysses:
             atol=2e-5, rtol=2e-5)
 
 
-@requires_shard_map
 def test_sequence_parallel_training_step():
     """Long-context TRAINING, not just forward: optimizer steps through
     long_context_apply (ring + flash blocks) on the 8-shard mesh track
@@ -259,7 +234,6 @@ def test_sequence_parallel_training_step():
     assert sp_losses[-1] < sp_losses[0]
 
 
-@requires_shard_map
 def test_long_context_apply_ulysses_flash_matches_dense():
     """block_impl='flash' under ulysses runs the LOCAL head-slice
     attention through the flash kernel — same logits."""
@@ -276,7 +250,6 @@ def test_long_context_apply_ulysses_flash_matches_dense():
                                atol=2e-5, rtol=2e-5)
 
 
-@requires_shard_map
 def test_long_context_apply_strategies_agree():
     """The transformer forward must be identical under both
     sequence-parallel strategies and the dense baseline."""
