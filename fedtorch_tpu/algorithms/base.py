@@ -203,17 +203,20 @@ class FedAlgorithm:
             loss = loss + self.extra_loss(p, server_params, client_aux)
             return loss, (logits, new_rnn)
 
-        (loss, (logits, new_rnn)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
-        grads = self.transform_grads(
-            grads, params=params, server_params=server_params,
-            client_aux=client_aux, server_aux=server_aux, lr=lr)
-        if model.has_noise_param:
-            # robust archs: gradient ASCENT on the adversarial input
-            # noise (federated/main.py:131-141)
-            grads = dict(grads)
-            grads["noise"] = -grads["noise"]
-        params, opt = optim.local_step(params, grads, opt, lr, cfg.optim)
+        with jax.named_scope("fed.forward_backward"):
+            (loss, (logits, new_rnn)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+            grads = self.transform_grads(
+                grads, params=params, server_params=server_params,
+                client_aux=client_aux, server_aux=server_aux, lr=lr)
+            if model.has_noise_param:
+                # robust archs: gradient ASCENT on the adversarial input
+                # noise (federated/main.py:131-141)
+                grads = dict(grads)
+                grads["noise"] = -grads["noise"]
+        with jax.named_scope("fed.opt_step"):
+            params, opt = optim.local_step(params, grads, opt, lr,
+                                           cfg.optim)
         acc = jnp.asarray(0.0) if model.is_regression \
             else accuracy(logits, by)
         return params, opt, client_aux, new_rnn, loss, acc

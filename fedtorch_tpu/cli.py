@@ -734,8 +734,11 @@ def run_experiment(cfg: ExperimentConfig,
     # metrics/events + host spans + machine-readable health, written to
     # the run dir. Host-only: every value it records is either a host
     # counter or comes from the loop's ONE batched scalar fetch below —
-    # zero added device syncs, traced programs untouched.
+    # zero added device syncs, traced programs untouched. Every span
+    # also opens a profiler annotation, so a profile taken of this
+    # process holds the host spans beside the device's operations.
     from fedtorch_tpu.telemetry import Telemetry
+    from fedtorch_tpu.utils.tracing import CompileSpans
     tel = Telemetry(
         ckpt_dir, level=cfg.telemetry.level,
         process_index=jax.process_index(),
@@ -748,8 +751,13 @@ def run_experiment(cfg: ExperimentConfig,
             "num_comms": cfg.federated.num_comms,
             "experiment": cfg.experiment,
         },
-        max_span_events=cfg.telemetry.max_span_events)
+        max_span_events=cfg.telemetry.max_span_events,
+        annotate=jax.profiler.TraceAnnotation)
     tel.install()
+    # JAX's own trace / lower / compile reports as spans, for every
+    # program of the process (utils/tracing.py)
+    compile_spans = CompileSpans(tel.spans).install() \
+        if tel.spans is not None else None
     tel.health_update("starting")
 
     # host-plane chaos + self-healing (docs/robustness.md "Host
@@ -776,6 +784,8 @@ def run_experiment(cfg: ExperimentConfig,
         if injector is not None:
             injector.uninstall()
         recovery.uninstall()
+        if compile_spans is not None:
+            compile_spans.close()
 
     # everything from data build through trainer/handler
     # construction can raise (dataset IO, the async/stream
@@ -813,19 +823,23 @@ def run_experiment(cfg: ExperimentConfig,
             return {"test_top1": float(res.top1), "rounds": len(history)}
 
         algorithm = make_algorithm(cfg)
-        if cfg.federated.sync_mode == "async":
-            # the async commit plane (docs/robustness.md "Asynchronous
-            # federation"): run_round executes one COMMIT and server.round
-            # counts commit versions, so the loop below — checkpointing,
-            # eval cadence, preemption drain, supervisor — runs unchanged
-            from fedtorch_tpu.async_plane import AsyncFederatedTrainer
-            trainer = AsyncFederatedTrainer(cfg, model, algorithm,
-                                            fed_data.train,
-                                            val_data=fed_data.val)
-        else:
-            trainer = FederatedTrainer(cfg, model, algorithm, fed_data.train,
-                                       val_data=fed_data.val)
-        server, clients = trainer.init_state(rng)
+        with tel.span("trainer.build"):
+            if cfg.federated.sync_mode == "async":
+                # the async commit plane (docs/robustness.md
+                # "Asynchronous federation"): run_round executes one
+                # COMMIT and server.round counts commit versions, so the
+                # loop below — checkpointing, eval cadence, preemption
+                # drain, supervisor — runs unchanged
+                from fedtorch_tpu.async_plane import AsyncFederatedTrainer
+                trainer = AsyncFederatedTrainer(cfg, model, algorithm,
+                                                fed_data.train,
+                                                val_data=fed_data.val)
+            else:
+                trainer = FederatedTrainer(cfg, model, algorithm,
+                                           fed_data.train,
+                                           val_data=fed_data.val)
+        with tel.span("state.init"):
+            server, clients = trainer.init_state(rng)
         server, clients, best_prec1, resumed = maybe_resume(
             cfg.checkpoint.resume, server, clients, cfg,
             cfg.checkpoint.checkpoint_index)
@@ -1027,10 +1041,12 @@ def run_experiment(cfg: ExperimentConfig,
             # the jitted round/commit program — what the 90%-non-MXU
             # attribution question is asked against
             with tel.span("round", round=r):
-                server, clients, metrics = run_round(server, clients)
+                with tel.span("round.dispatch", round=r):
+                    server, clients, metrics = run_round(server, clients)
                 if supervisor is None:
                     # the supervisor's health check already blocked
-                    jax.block_until_ready(server.params)
+                    with tel.span("round.wait", round=r):
+                        jax.block_until_ready(server.params)
             round_time = timer.stop("round")
             # ONE batched device->host fetch for everything this loop
             # logs (round_host_scalars) — per-scalar float() here would
@@ -1100,56 +1116,57 @@ def run_experiment(cfg: ExperimentConfig,
                         logger.log(f"cost capture: lowering failed "
                                    f"({e}); device gauges off")
 
-            if cfg.fault.chaos_enabled or cfg.fault.guard_updates:
-                if sc["dropped"] or sc["rejected"] or sc["clipped"] \
-                        or sc["stragglers"] or sc["byzantine"]:
-                    logger.log(
-                        f"Round {r}: faults — "
-                        f"dropped={sc['dropped']:.0f} "
-                        f"stragglers={sc['stragglers']:.0f} "
-                        f"rejected={sc['rejected']:.0f} "
-                        f"clipped={sc['clipped']:.0f} "
-                        f"byzantine={sc['byzantine']:.0f}")
-                if sc["byzantine"] and not byz_attack_seen:
-                    # one attack event per run, at the first observed
-                    # injection — monitors key on this, not on scanning
-                    # every row's counter
-                    byz_attack_seen = True
-                    tel.event("chaos.byzantine_attack", round=r,
-                              mode=cfg.fault.byzantine_mode,
-                              rate=cfg.fault.byzantine_rate,
-                              scale=cfg.fault.byzantine_scale,
-                              robust_agg=cfg.fault.robust_agg)
-                if supervisor is None and _all_rejected(sc):
-                    # renorm scale hit 0: every surviving update was
-                    # rejected (or every client crashed) — the server
-                    # held this round. With a supervisor the same
-                    # detection runs inside its health path.
-                    logger.log(f"Round {r}: guards rejected EVERY "
-                               "update — server held (renorm scale 0)")
-                    tel.event("guards.all_rejected", round=r,
-                              n_online=sc["n_online"],
-                              rejected=sc["rejected"],
-                              dropped=sc["dropped"])
+            with tel.span("round.record", round=r):
+                if cfg.fault.chaos_enabled or cfg.fault.guard_updates:
+                    if sc["dropped"] or sc["rejected"] or sc["clipped"] \
+                            or sc["stragglers"] or sc["byzantine"]:
+                        logger.log(
+                            f"Round {r}: faults — "
+                            f"dropped={sc['dropped']:.0f} "
+                            f"stragglers={sc['stragglers']:.0f} "
+                            f"rejected={sc['rejected']:.0f} "
+                            f"clipped={sc['clipped']:.0f} "
+                            f"byzantine={sc['byzantine']:.0f}")
+                    if sc["byzantine"] and not byz_attack_seen:
+                        # one attack event per run, at the first observed
+                        # injection — monitors key on this, not on scanning
+                        # every row's counter
+                        byz_attack_seen = True
+                        tel.event("chaos.byzantine_attack", round=r,
+                                  mode=cfg.fault.byzantine_mode,
+                                  rate=cfg.fault.byzantine_rate,
+                                  scale=cfg.fault.byzantine_scale,
+                                  robust_agg=cfg.fault.robust_agg)
+                    if supervisor is None and _all_rejected(sc):
+                        # renorm scale hit 0: every surviving update was
+                        # rejected (or every client crashed) — the server
+                        # held this round. With a supervisor the same
+                        # detection runs inside its health path.
+                        logger.log(f"Round {r}: guards rejected EVERY "
+                                   "update — server held (renorm scale 0)")
+                        tel.event("guards.all_rejected", round=r,
+                                  n_online=sc["n_online"],
+                                  rejected=sc["rejected"],
+                                  dropped=sc["dropped"])
 
-            if cfg.checkpoint.check_model_at_sync:
-                norms = jax.device_get(model_norms(server.params))
-                logger.log(f"Round {r}: server model l2="
-                           f"{float(norms['l2']):.4f} "
-                           f"max|w|={float(norms['max_abs']):.4f}")
-            if prev_params is not None:
-                tr = jax.device_get(
-                    aggregation_tracking(prev_params, server.params))
-                logger.log(f"Round {r}: aggregation cosine="
-                           f"{float(tr['cosine']):.6f} "
-                           f"distance={float(tr['distance']):.6f}")
+                if cfg.checkpoint.check_model_at_sync:
+                    norms = jax.device_get(model_norms(server.params))
+                    logger.log(f"Round {r}: server model l2="
+                               f"{float(norms['l2']):.4f} "
+                               f"max|w|={float(norms['max_abs']):.4f}")
+                if prev_params is not None:
+                    tr = jax.device_get(
+                        aggregation_tracking(prev_params, server.params))
+                    logger.log(f"Round {r}: aggregation cosine="
+                               f"{float(tr['cosine']):.6f} "
+                               f"distance={float(tr['distance']):.6f}")
 
-            n_online = max(sc["n_online"], 1.0)
-            epoch = sc["mean_epoch"]
-            logger.log_train(r, epoch, sc["loss_sum"] / n_online,
-                             sc["acc_sum"] / n_online, sc["lr"],
-                             comm_bytes=sc["comm_bytes"],
-                             round_time=round_time)
+                n_online = max(sc["n_online"], 1.0)
+                epoch = sc["mean_epoch"]
+                logger.log_train(r, epoch, sc["loss_sum"] / n_online,
+                                 sc["acc_sum"] / n_online, sc["lr"],
+                                 comm_bytes=sc["comm_bytes"],
+                                 round_time=round_time)
 
             eval_s = checkpoint_s = None
             if (r + 1) % cfg.train.eval_freq == 0:
@@ -1203,143 +1220,144 @@ def run_experiment(cfg: ExperimentConfig,
                                    summary["acc_mean"])
                 results["test_top1"] = top1
 
-            # one schema-versioned metrics row per round (async: per
-            # commit), populated from the already-fetched scalar dict
-            # plus host-only subsystem gauges — zero extra transfers
-            n_onl = max(sc["n_online"], 1.0)
-            row = {
-                "round": r, "round_s": round_time,
-                "loss": sc["loss_sum"] / n_onl,
-                "acc": sc["acc_sum"] / n_onl, "lr": sc["lr"],
-                "n_online": sc["n_online"],
-                "comm_bytes": sc["comm_bytes"],
-                "mean_epoch": sc["mean_epoch"], "fetch_s": fetch_s,
-                "dropped": sc["dropped"],
-                "stragglers": sc["stragglers"],
-                "rejected": sc["rejected"], "clipped": sc["clipped"],
-                "staleness": sc["staleness"],
-                "byzantine": sc["byzantine"],
-                "robust_selected": sc["robust_selected"],
-                "robust_trimmed": sc["robust_trimmed"],
-                # deployment-realism lifecycle counters — same fetch
-                "avail_dropped": sc["avail_dropped"],
-                "deadline_missed": sc["deadline_missed"],
-                "quorum_degraded": sc["quorum_degraded"],
-            }
-            if eval_s is not None:
-                row["eval_s"] = eval_s
-                # already host floats (the eval device_get above) —
-                # riding the row costs nothing extra
-                row["test_top1"] = top1
-                row["best_top1"] = best_prec1
-            if checkpoint_s is not None:
-                row["checkpoint_s"] = checkpoint_s
-            if "cohort_dispersion" in sc:
-                # the heterogeneity gauge (cohort_stats on) — already
-                # part of the batched scalar fetch
-                row["cohort_dispersion"] = sc["cohort_dispersion"]
-            if "dp_clipped_frac" in sc:
-                # privacy-plane gauges (DP armed) — same batched fetch
-                row["dp_clipped_frac"] = sc["dp_clipped_frac"]
-                row["dp_noise_sigma"] = sc["dp_noise_sigma"]
-            if accountant is not None:
-                # host-side accountant read: pure f64 math, no sync
-                row["dp_epsilon_spent"] = accountant.epsilon()
-            if led is not None:
-                # cohort norm quantiles + the per-client ledger fold
-                # (host numpy from the same fetch; O(k) update)
-                nq = led["norm_q"]
-                row.update({
-                    "cohort_norm_min": float(nq[0]),
-                    "cohort_norm_q25": float(nq[1]),
-                    "cohort_norm_med": float(nq[2]),
-                    "cohort_norm_q75": float(nq[3]),
-                    "cohort_norm_max": float(nq[4]),
-                })
-                ledger.update(r, led)
-                row.update(ledger.stats())
-            row.update(trainer.telemetry_gauges())
-            overlap_eff = overlap_tracker.observe(row)
-            if overlap_eff is not None:
-                # stream plane: the fraction of this round's producer
-                # gather+H2D wall hidden under device compute — the
-                # number ROADMAP item 1's STREAM_AB 1.15x gap needs
-                row["overlap_efficiency"] = overlap_eff
-            if cost_capture is not None:
-                # measured MFU + HBM watermark pair — empty until the
-                # capture above succeeded, host-side either way
-                row.update(cost_capture.round_gauges(round_time))
-            if async_ckpt is not None:
-                row.update(async_ckpt.stats())
-            if supervisor is not None:
-                row.update(sup_rollbacks=float(supervisor.stats.rollbacks),
-                           sup_retries=float(supervisor.stats.retries),
-                           sup_skipped=float(
-                               supervisor.stats.skipped_rounds),
-                           # skip-cause split (fault vs sub-quorum
-                           # abort) — docs/robustness.md "Deployment
-                           # realism"
-                           sup_skipped_fault=float(
-                               supervisor.stats.skipped_fault),
-                           sup_skipped_quorum=float(
-                               supervisor.stats.skipped_quorum))
-            # host-plane recovery gauges: retries/recoveries/degraded
-            # seams (and injected-fault count when a drill is armed) —
-            # host counters, zero extra device syncs
-            row.update(recovery.stats())
-            if injector is not None:
-                row.update(injector.stats())
-            tel.round_row(row)
-            if sc["quorum_degraded"] > 0:
-                # a sub-quorum round that committed its renormalized
-                # partial cohort (degrade action) or is about to be
-                # escalated (abort retries exhausted into a skip) —
-                # the per-round operator signal behind the 'degraded'
-                # health intent below
-                tel.event("lifecycle.quorum_degraded", round=r,
-                          n_online=sc["n_online"],
-                          avail_dropped=sc["avail_dropped"],
-                          deadline_missed=sc["deadline_missed"])
-            if anomaly is not None:
-                # observe-only EWMA z-score pass over the finished row
-                # (telemetry/anomaly.py): events + report fodder, no
-                # control flow
-                for a in anomaly.observe(row):
-                    tel.event("anomaly.detected", round=r, **a)
-            if cfg.telemetry.level == "debug" and (r + 1) % 25 == 0:
-                # debug cadence snapshot of the async staleness
-                # histogram: a hard-killed run (watchdog os._exit)
-                # keeps at most 25 commits of histogram, not all of it
-                hist = trainer.staleness_histogram()
-                if hist:
-                    tel.event("async.staleness_hist", round=r,
-                              snapshot="debug",
-                              hist={str(k): v
-                                    for k, v in sorted(hist.items())})
-            # health: r+1 rounds complete — same convention as
-            # checkpoint.json's "round", so monitors can compare the
-            # live counter against the last durable one. Intent
-            # reflects the host-plane recovery state: 'degraded' while
-            # any seam runs in degraded mode, 'recovering' on a round
-            # that absorbed a host-seam retry, 'running' otherwise —
-            # the run IS progressing in all three.
-            host_retries_now = recovery.total_retries()
-            quorum_streak = quorum_streak + 1 \
-                if sc["quorum_degraded"] > 0 else 0
-            if recovery.degraded or quorum_streak >= 3 or dp_degraded:
-                # host seam running degraded, OR the availability
-                # lifecycle committing sub-quorum cohorts for 3+
-                # consecutive rounds, OR the privacy budget exhausted
-                # into noise-free continuation — progressing, but an
-                # operator should look (docs/robustness.md)
-                intent = "degraded"
-            elif host_retries_now > host_retries_seen:
-                intent = "recovering"
-            else:
-                intent = "running"
-            host_retries_seen = host_retries_now
-            tel.health_update(intent, round_idx=r + 1,
-                              staleness=sc["staleness"])
+            with tel.span("round.record", round=r):
+                # one schema-versioned metrics row per round (async: per
+                # commit), populated from the already-fetched scalar dict
+                # plus host-only subsystem gauges — zero extra transfers
+                n_onl = max(sc["n_online"], 1.0)
+                row = {
+                    "round": r, "round_s": round_time,
+                    "loss": sc["loss_sum"] / n_onl,
+                    "acc": sc["acc_sum"] / n_onl, "lr": sc["lr"],
+                    "n_online": sc["n_online"],
+                    "comm_bytes": sc["comm_bytes"],
+                    "mean_epoch": sc["mean_epoch"], "fetch_s": fetch_s,
+                    "dropped": sc["dropped"],
+                    "stragglers": sc["stragglers"],
+                    "rejected": sc["rejected"], "clipped": sc["clipped"],
+                    "staleness": sc["staleness"],
+                    "byzantine": sc["byzantine"],
+                    "robust_selected": sc["robust_selected"],
+                    "robust_trimmed": sc["robust_trimmed"],
+                    # deployment-realism lifecycle counters — same fetch
+                    "avail_dropped": sc["avail_dropped"],
+                    "deadline_missed": sc["deadline_missed"],
+                    "quorum_degraded": sc["quorum_degraded"],
+                }
+                if eval_s is not None:
+                    row["eval_s"] = eval_s
+                    # already host floats (the eval device_get above) —
+                    # riding the row costs nothing extra
+                    row["test_top1"] = top1
+                    row["best_top1"] = best_prec1
+                if checkpoint_s is not None:
+                    row["checkpoint_s"] = checkpoint_s
+                if "cohort_dispersion" in sc:
+                    # the heterogeneity gauge (cohort_stats on) — already
+                    # part of the batched scalar fetch
+                    row["cohort_dispersion"] = sc["cohort_dispersion"]
+                if "dp_clipped_frac" in sc:
+                    # privacy-plane gauges (DP armed) — same batched fetch
+                    row["dp_clipped_frac"] = sc["dp_clipped_frac"]
+                    row["dp_noise_sigma"] = sc["dp_noise_sigma"]
+                if accountant is not None:
+                    # host-side accountant read: pure f64 math, no sync
+                    row["dp_epsilon_spent"] = accountant.epsilon()
+                if led is not None:
+                    # cohort norm quantiles + the per-client ledger fold
+                    # (host numpy from the same fetch; O(k) update)
+                    nq = led["norm_q"]
+                    row.update({
+                        "cohort_norm_min": float(nq[0]),
+                        "cohort_norm_q25": float(nq[1]),
+                        "cohort_norm_med": float(nq[2]),
+                        "cohort_norm_q75": float(nq[3]),
+                        "cohort_norm_max": float(nq[4]),
+                    })
+                    ledger.update(r, led)
+                    row.update(ledger.stats())
+                row.update(trainer.telemetry_gauges())
+                overlap_eff = overlap_tracker.observe(row)
+                if overlap_eff is not None:
+                    # stream plane: the fraction of this round's producer
+                    # gather+H2D wall hidden under device compute — the
+                    # number ROADMAP item 1's STREAM_AB 1.15x gap needs
+                    row["overlap_efficiency"] = overlap_eff
+                if cost_capture is not None:
+                    # measured MFU + HBM watermark pair — empty until the
+                    # capture above succeeded, host-side either way
+                    row.update(cost_capture.round_gauges(round_time))
+                if async_ckpt is not None:
+                    row.update(async_ckpt.stats())
+                if supervisor is not None:
+                    row.update(sup_rollbacks=float(supervisor.stats.rollbacks),
+                               sup_retries=float(supervisor.stats.retries),
+                               sup_skipped=float(
+                                   supervisor.stats.skipped_rounds),
+                               # skip-cause split (fault vs sub-quorum
+                               # abort) — docs/robustness.md "Deployment
+                               # realism"
+                               sup_skipped_fault=float(
+                                   supervisor.stats.skipped_fault),
+                               sup_skipped_quorum=float(
+                                   supervisor.stats.skipped_quorum))
+                # host-plane recovery gauges: retries/recoveries/degraded
+                # seams (and injected-fault count when a drill is armed) —
+                # host counters, zero extra device syncs
+                row.update(recovery.stats())
+                if injector is not None:
+                    row.update(injector.stats())
+                tel.round_row(row)
+                if sc["quorum_degraded"] > 0:
+                    # a sub-quorum round that committed its renormalized
+                    # partial cohort (degrade action) or is about to be
+                    # escalated (abort retries exhausted into a skip) —
+                    # the per-round operator signal behind the 'degraded'
+                    # health intent below
+                    tel.event("lifecycle.quorum_degraded", round=r,
+                              n_online=sc["n_online"],
+                              avail_dropped=sc["avail_dropped"],
+                              deadline_missed=sc["deadline_missed"])
+                if anomaly is not None:
+                    # observe-only EWMA z-score pass over the finished row
+                    # (telemetry/anomaly.py): events + report fodder, no
+                    # control flow
+                    for a in anomaly.observe(row):
+                        tel.event("anomaly.detected", round=r, **a)
+                if cfg.telemetry.level == "debug" and (r + 1) % 25 == 0:
+                    # debug cadence snapshot of the async staleness
+                    # histogram: a hard-killed run (watchdog os._exit)
+                    # keeps at most 25 commits of histogram, not all of it
+                    hist = trainer.staleness_histogram()
+                    if hist:
+                        tel.event("async.staleness_hist", round=r,
+                                  snapshot="debug",
+                                  hist={str(k): v
+                                        for k, v in sorted(hist.items())})
+                # health: r+1 rounds complete — same convention as
+                # checkpoint.json's "round", so monitors can compare the
+                # live counter against the last durable one. Intent
+                # reflects the host-plane recovery state: 'degraded' while
+                # any seam runs in degraded mode, 'recovering' on a round
+                # that absorbed a host-seam retry, 'running' otherwise —
+                # the run IS progressing in all three.
+                host_retries_now = recovery.total_retries()
+                quorum_streak = quorum_streak + 1 \
+                    if sc["quorum_degraded"] > 0 else 0
+                if recovery.degraded or quorum_streak >= 3 or dp_degraded:
+                    # host seam running degraded, OR the availability
+                    # lifecycle committing sub-quorum cohorts for 3+
+                    # consecutive rounds, OR the privacy budget exhausted
+                    # into noise-free continuation — progressing, but an
+                    # operator should look (docs/robustness.md)
+                    intent = "degraded"
+                elif host_retries_now > host_retries_seen:
+                    intent = "recovering"
+                else:
+                    intent = "running"
+                host_retries_seen = host_retries_now
+                tel.health_update(intent, round_idx=r + 1,
+                                  staleness=sc["staleness"])
 
             if round_callback is not None:
                 round_callback(r, trainer, server, clients, metrics)

@@ -12,6 +12,7 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
+from fedtorch_tpu import telemetry
 from fedtorch_tpu.config import ExperimentConfig
 from fedtorch_tpu.data.batching import (  # noqa: F401
     ClientData, epoch_permutation, growing_batch_schedule, sample_batch,
@@ -70,15 +71,18 @@ def choose_partitions(splits: DatasetSplits, cfg: ExperimentConfig,
 def build_federated_data(cfg: ExperimentConfig,
                          download: bool = False) -> FederatedData:
     num_clients = cfg.federated.num_clients
-    splits = get_dataset(cfg.data, num_clients, download=download,
-                         seq_len=cfg.model.rnn_seq_len)
-    parts = choose_partitions(splits, cfg, num_clients)
+    with telemetry.span("data.load"):
+        splits = get_dataset(cfg.data, num_clients, download=download,
+                             seq_len=cfg.model.rnn_seq_len)
+    with telemetry.span("data.partition"):
+        parts = choose_partitions(splits, cfg, num_clients)
+        if cfg.federated.personal:
+            parts, val_parts = train_val_split(
+                parts, cfg.data.val_fraction, seed=cfg.train.manual_seed)
 
-    val = None
-    if cfg.federated.personal:
-        parts, val_parts = train_val_split(parts, cfg.data.val_fraction,
-                                           seed=cfg.train.manual_seed)
-        val = stack_partitions(splits.train_x, splits.train_y, val_parts)
-    train = stack_partitions(splits.train_x, splits.train_y, parts)
+    with telemetry.span("data.layout"):
+        val = stack_partitions(splits.train_x, splits.train_y, val_parts) \
+            if cfg.federated.personal else None
+        train = stack_partitions(splits.train_x, splits.train_y, parts)
     return FederatedData(train=train, val=val, test_x=splits.test_x,
                          test_y=splits.test_y, num_clients=num_clients)

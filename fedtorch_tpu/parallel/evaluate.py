@@ -168,10 +168,12 @@ def _eval_run_fn(model: ModelDef):
             return carry, (jnp.sum(per * mb_f), jnp.sum(t1 * mb_f),
                            jnp.sum(t5 * mb_f), jnp.sum(mb_f))
 
-        _, (losses, t1s, t5s, ws) = jax.lax.scan(body, 0, (bx, by, bm))
-        total = jnp.maximum(jnp.sum(ws), 1e-8)
-        return EvalResult(jnp.sum(losses) / total,
-                          jnp.sum(t1s) / total, jnp.sum(t5s) / total)
+        with jax.named_scope("eval.forward"):
+            _, (losses, t1s, t5s, ws) = jax.lax.scan(body, 0,
+                                                     (bx, by, bm))
+            total = jnp.maximum(jnp.sum(ws), 1e-8)
+            return EvalResult(jnp.sum(losses) / total,
+                              jnp.sum(t1s) / total, jnp.sum(t5s) / total)
 
     return run
 

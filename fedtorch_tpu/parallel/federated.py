@@ -424,11 +424,18 @@ class FederatedTrainer:
             self.val_data = None
         else:
             self.host_store = None
-            self.data = shard_clients(
-                pad_client_axis(data, self.padded_clients), self.mesh)
-            self.val_data = shard_clients(
-                pad_client_axis(val_data, self.padded_clients),
-                self.mesh) if val_data is not None else None
+            with telemetry.span("data.h2d") as h2d:
+                self.data = shard_clients(
+                    pad_client_axis(data, self.padded_clients), self.mesh)
+                self.val_data = shard_clients(
+                    pad_client_axis(val_data, self.padded_clients),
+                    self.mesh) if val_data is not None else None
+                if h2d is not telemetry.NULL_SPAN:
+                    # a recorded span ends when the store is on the
+                    # device, not when the copy is dispatched; with no
+                    # recorder nothing waits and the copy overlaps the
+                    # state's build
+                    jax.block_until_ready((self.data, self.val_data))
         # lazily-started feed producer (stream plane only); see
         # _next_stream_feed / invalidate_stream
         self._stream: Optional[StreamFeedProducer] = None
@@ -512,44 +519,49 @@ class FederatedTrainer:
         :meth:`_round_core`, so the two planes cannot diverge."""
         alg = self.algorithm
         K, B, C = self.local_steps, self.batch_size, self.num_clients
-        rng_round = jax.random.fold_in(server.rng, server.round)
-        rng_sample, rng_train = jax.random.split(rng_round)
-
-        # participation hooks read the ALGORITHM aux (DRFA's lambda),
-        # not the norm_bound momentum wrap
-        part_aux = server.aux["alg"] \
-            if (self.robust_momentum or self.dp_on) else server.aux
-        idx = alg.participation(rng_sample, C, self.k_dispatch,
-                                server.round, part_aux)
-        if idx is None:
-            idx = participation_indices(rng_sample, C, self.k_dispatch,
-                                        server.round,
-                                        mode=self.participation_mode)
-        on_sizes = jnp.take(data.sizes, idx)
-        rngs = jax.random.split(rng_train, self.k_dispatch)
         batch_mode = self.gather_mode == "batch"
+        with jax.named_scope("fed.select"):
+            rng_round = jax.random.fold_in(server.rng, server.round)
+            rng_sample, rng_train = jax.random.split(rng_round)
 
-        if batch_mode:
-            # move only the touched rows: [k, K*B, ...]. round_row_plan
-            # (data/batching.py) is the SHARED batch-order definition —
-            # the host feed packer calls the same function, which is
-            # what makes the streaming plane's bitwise parity hold.
-            rows = jax.vmap(lambda r, s: round_row_plan(
-                r, s, data.x.shape[1], K * B))(rngs, on_sizes)
-            # pod-scale: pin the row plan REPLICATED. The seam's cohort
-            # sharding otherwise propagates backward through the gather
-            # into round_row_plan's argsort, and a cross-device
-            # partitioned sort is not bitwise-stable across shard
-            # counts — the one S-variant lowering in the whole program
-            # (no-op when podscale is disarmed)
-            rows = self._replicate_cohort(rows)
-            on_x = data.x[idx[:, None], rows]
-            on_y = data.y[idx[:, None], rows]
-        else:
-            # whole shards; rows are selected per step inside the vmap so
-            # nothing larger than the shard is ever materialized
-            on_x = jnp.take(data.x, idx, axis=0)
-            on_y = jnp.take(data.y, idx, axis=0)
+            # participation hooks read the ALGORITHM aux (DRFA's
+            # lambda), not the norm_bound momentum wrap
+            part_aux = server.aux["alg"] \
+                if (self.robust_momentum or self.dp_on) else server.aux
+            idx = alg.participation(rng_sample, C, self.k_dispatch,
+                                    server.round, part_aux)
+            if idx is None:
+                idx = participation_indices(
+                    rng_sample, C, self.k_dispatch, server.round,
+                    mode=self.participation_mode)
+            on_sizes = jnp.take(data.sizes, idx)
+            rngs = jax.random.split(rng_train, self.k_dispatch)
+            if batch_mode:
+                # move only the touched rows: [k, K*B, ...].
+                # round_row_plan (data/batching.py) is the SHARED
+                # batch-order definition — the host feed packer calls
+                # the same function, which is what makes the streaming
+                # plane's bitwise parity hold.
+                rows = jax.vmap(lambda r, s: round_row_plan(
+                    r, s, data.x.shape[1], K * B))(rngs, on_sizes)
+                # pod-scale: pin the row plan REPLICATED. The seam's
+                # cohort sharding otherwise propagates backward through
+                # the gather into round_row_plan's argsort, and a
+                # cross-device partitioned sort is not bitwise-stable
+                # across shard counts — the one S-variant lowering in
+                # the whole program (no-op when podscale is disarmed)
+                rows = self._replicate_cohort(rows)
+
+        with jax.named_scope("fed.gather"):
+            if batch_mode:
+                on_x = data.x[idx[:, None], rows]
+                on_y = data.y[idx[:, None], rows]
+            else:
+                # whole shards; rows are selected per step inside the
+                # vmap so nothing larger than the shard is ever
+                # materialized
+                on_x = jnp.take(data.x, idx, axis=0)
+                on_y = jnp.take(data.y, idx, axis=0)
 
         # the val stream makes its own shard-vs-rows decision: val shards
         # are typically much smaller than train shards, so K*B rows can
@@ -557,27 +569,30 @@ class FederatedTrainer:
         val_batch_mode = (batch_mode and val_data is not None
                           and K * B < val_data.x.shape[1])
         if val_data is not None:
-            on_vsizes = jnp.take(val_data.sizes, idx)
-            if val_batch_mode:
-                vrows = jax.vmap(lambda r, s: round_row_plan(
-                    r, s, val_data.x.shape[1], K * B,
-                    VAL_FOLD))(rngs, on_vsizes)
-                vrows = self._replicate_cohort(vrows)
+            with jax.named_scope("fed.select"):
+                on_vsizes = jnp.take(val_data.sizes, idx)
+                if val_batch_mode:
+                    vrows = jax.vmap(lambda r, s: round_row_plan(
+                        r, s, val_data.x.shape[1], K * B,
+                        VAL_FOLD))(rngs, on_vsizes)
+                    vrows = self._replicate_cohort(vrows)
+        with jax.named_scope("fed.gather"):
+            if val_data is None:
+                # unused placeholders keep the vmapped signature static
+                on_vx, on_vy = on_x[:, :1], on_y[:, :1]
+                on_vsizes = jnp.ones_like(on_sizes)
+            elif val_batch_mode:
                 on_vx = val_data.x[idx[:, None], vrows]
                 on_vy = val_data.y[idx[:, None], vrows]
             else:
                 on_vx = jnp.take(val_data.x, idx, axis=0)
                 on_vy = jnp.take(val_data.y, idx, axis=0)
-        else:
-            # unused placeholders keep the vmapped signature static
-            on_vx, on_vy = on_x[:, :1], on_y[:, :1]
-            on_vsizes = jnp.ones_like(on_sizes)
 
-        # the pre_round hook always sees each client's first B
-        # storage-order rows, independent of gather mode (so mode
-        # choice cannot change hook numerics, e.g. APFL's alpha)
-        pre_x = data.x[idx[:, None], jnp.arange(B)[None, :]]
-        pre_y = data.y[idx[:, None], jnp.arange(B)[None, :]]
+            # the pre_round hook always sees each client's first B
+            # storage-order rows, independent of gather mode (so mode
+            # choice cannot change hook numerics, e.g. APFL's alpha)
+            pre_x = data.x[idx[:, None], jnp.arange(B)[None, :]]
+            pre_y = data.y[idx[:, None], jnp.arange(B)[None, :]]
         return self._round_core(
             server, clients, idx, on_x, on_y, on_vx, on_vy, on_sizes,
             on_vsizes, pre_x, pre_y, rng_round, rngs,
@@ -593,13 +608,15 @@ class FederatedTrainer:
         discarded: the host already replayed participation from it), so
         dropout/augmentation/chaos streams line up and the trajectories
         match the device plane bitwise (tests/test_streaming.py)."""
-        rng_round = jax.random.fold_in(server.rng, server.round)
-        _rng_sample, rng_train = jax.random.split(rng_round)
-        rngs = jax.random.split(rng_train, self.k_dispatch)
+        with jax.named_scope("fed.select"):
+            rng_round = jax.random.fold_in(server.rng, server.round)
+            _rng_sample, rng_train = jax.random.split(rng_round)
+            rngs = jax.random.split(rng_train, self.k_dispatch)
         # no streamed val plane (gated in __init__): mirror the device
         # path's val_data-None placeholders exactly
-        on_vx, on_vy = feed.x[:, :1], feed.y[:, :1]
-        on_vsizes = jnp.ones_like(feed.sizes)
+        with jax.named_scope("fed.gather"):
+            on_vx, on_vy = feed.x[:, :1], feed.y[:, :1]
+            on_vsizes = jnp.ones_like(feed.sizes)
         return self._round_core(
             server, clients, feed.idx, feed.x, feed.y, on_vx, on_vy,
             feed.sizes, on_vsizes, feed.pre_x, feed.pre_y, rng_round,
@@ -697,61 +714,67 @@ class FederatedTrainer:
         # the online axis length: k_online for the sync planes, the
         # commit buffer size m for the async plane
         k = idx.shape[0]
-        num_online_eff = num_online_effective(idx)
-        weights = alg.client_weights(server.aux, idx, num_online_eff,
-                                     on_sizes)
-        if weight_scale is not None:
-            # staleness weighting (async_plane/staleness.py): composed
-            # INTO the aggregation weights, so the guard renormalization
-            # below redistributes exactly the composed weight
-            weights = weights * weight_scale
-        weights = self._replicate_cohort(weights)
+        with jax.named_scope("fed.select"):
+            num_online_eff = num_online_effective(idx)
+            weights = alg.client_weights(server.aux, idx, num_online_eff,
+                                         on_sizes)
+            if weight_scale is not None:
+                # staleness weighting (async_plane/staleness.py): composed
+                # INTO the aggregation weights, so the guard renormalization
+                # below redistributes exactly the composed weight
+                weights = weights * weight_scale
+            weights = self._replicate_cohort(weights)
 
-        # deterministic chaos schedule for this round (crash/straggler/
-        # poison masks over the online clients) — its own fold of the
-        # round key, so fault-free streams are untouched
-        flt = self.fault
-        if plan is None:
-            plan = draw_chaos_plan(
-                jax.random.fold_in(rng_round, flt.chaos_salt),
-                k, flt) if self.chaos_on else no_chaos_plan(k)
-        if flt.byzantine_rate > 0.0:
-            # the adversarial cohort is FIXED per run (server.rng is
-            # threaded unchanged through every round, so the fold is
-            # round-independent); the plan carries its online slice.
-            # Applies to caller-built plans too (the async commit).
-            cohort = byzantine_cohort_mask(
-                jax.random.fold_in(server.rng, BYZ_COHORT_FOLD),
-                C, flt.byzantine_rate)
-            plan = plan._replace(byzantine=jnp.take(cohort, idx))
+        with jax.named_scope("fed.guard"):
+            # deterministic chaos schedule for this round (crash/straggler/
+            # poison masks over the online clients) — its own fold of the
+            # round key, so fault-free streams are untouched
+            flt = self.fault
+            if plan is None:
+                plan = draw_chaos_plan(
+                    jax.random.fold_in(rng_round, flt.chaos_salt),
+                    k, flt) if self.chaos_on else no_chaos_plan(k)
+            if flt.byzantine_rate > 0.0:
+                # the adversarial cohort is FIXED per run (server.rng is
+                # threaded unchanged through every round, so the fold is
+                # round-independent); the plan carries its online slice.
+                # Applies to caller-built plans too (the async commit).
+                cohort = byzantine_cohort_mask(
+                    jax.random.fold_in(server.rng, BYZ_COHORT_FOLD),
+                    C, flt.byzantine_rate)
+                plan = plan._replace(byzantine=jnp.take(cohort, idx))
 
-        # deployment-realism round lifecycle (robustness/availability.py
-        # sync planes only — the async plane's arrivals come from its
-        # event scheduler): per-dispatched-client arrival delays and
-        # mid-round dropouts, the round closing on its first k_online
-        # arrivals. Static gating: disarmed traces the exact
-        # pre-availability program.
-        avail_ok = avail_drop = avail_miss = None
-        if self.avail_sync:
-            avail_ok, avail_drop, avail_miss = sync_lifecycle(
-                server.rng, rng_round, idx, server.round, flt,
-                self.k_online)
+            # deployment-realism round lifecycle (robustness/availability.py
+            # sync planes only — the async plane's arrivals come from its
+            # event scheduler): per-dispatched-client arrival delays and
+            # mid-round dropouts, the round closing on its first k_online
+            # arrivals. Static gating: disarmed traces the exact
+            # pre-availability program.
+            avail_ok = avail_drop = avail_miss = None
+            if self.avail_sync:
+                avail_ok, avail_drop, avail_miss = sync_lifecycle(
+                    server.rng, rng_round, idx, server.round, flt,
+                    self.k_online)
 
-        # gather online-client state (the per-round new_group)
-        take = lambda t: jax.tree.map(lambda x: jnp.take(x, idx, axis=0), t)
-        on_clients = self._shard_cohort(take(clients))
+        with jax.named_scope("fed.gather"):
+            # gather online-client state (the per-round new_group)
+            take = lambda t: jax.tree.map(
+                lambda x: jnp.take(x, idx, axis=0), t)
+            on_clients = self._shard_cohort(take(clients))
 
-        # cross-client pre-round hook (APFL adaptive alpha, apfl.py:119-123)
-        on_lrs = jax.vmap(lambda e: lr_at(self.schedule, e))(
-            on_clients.epoch)
-        on_aux0 = alg.pre_round(on_clients.aux, server=server, x=pre_x,
-                                y=pre_y, sizes=on_sizes, lr=on_lrs,
-                                rng=rng_round)
-        # round-start state, kept for crashed clients: fail-stop means
-        # everything after round start (incl. the pre_round aux write)
-        # is lost on the client
-        on_clients0 = on_clients
-        on_clients = on_clients._replace(aux=on_aux0)
+        with jax.named_scope("fed.pre_round"):
+            # cross-client pre-round hook (APFL adaptive alpha,
+            # apfl.py:119-123)
+            on_lrs = jax.vmap(lambda e: lr_at(self.schedule, e))(
+                on_clients.epoch)
+            on_aux0 = alg.pre_round(on_clients.aux, server=server, x=pre_x,
+                                    y=pre_y, sizes=on_sizes, lr=on_lrs,
+                                    rng=rng_round)
+            # round-start state, kept for crashed clients: fail-stop means
+            # everything after round start (incl. the pre_round aux write)
+            # is lost on the client
+            on_clients0 = on_clients
+            on_clients = on_clients._replace(aux=on_aux0)
 
         def client_round(cstate: ClientState, x, y, vx, vy, size, vsize,
                          weight, rng_c, bscale, base_p, base_a):
@@ -761,63 +784,64 @@ class FederatedTrainer:
             # base_p/base_a are THIS client's server snapshot — the live
             # server state on the sync planes (vmap in_axes=None), its
             # dispatch-time commit version on the async plane
-            nb = jnp.ceil(size / B)  # batches per local epoch
-            server_params = base_p
-            carry0 = model.init_carry(B)
+            with jax.named_scope("fed.local_steps"):
+                nb = jnp.ceil(size / B)  # batches per local epoch
+                server_params = base_p
+                carry0 = model.init_carry(B)
 
-            full_loss = None
-            if alg.needs_full_loss:
-                # qFFL: F_k = SUM of per-batch mean losses over the
-                # client's full data on the incoming server model
-                # (centered/main.py:62-72 accumulates loss.item() per
-                # batch — the sum scales with the client's batch count);
-                # shard mode is enforced so x IS the whole shard here
-                n_full = -(-x.shape[0] // B)
+                full_loss = None
+                if alg.needs_full_loss:
+                    # qFFL: F_k = SUM of per-batch mean losses over the
+                    # client's full data on the incoming server model
+                    # (centered/main.py:62-72 accumulates loss.item() per
+                    # batch — the sum scales with the client's batch count);
+                    # shard mode is enforced so x IS the whole shard here
+                    n_full = -(-x.shape[0] // B)
 
-                def floss(carry, i):
-                    frows = i * B + jnp.arange(B)
-                    m = (frows < size).astype(jnp.float32)
-                    xb, yb = x[frows % x.shape[0]], y[frows % x.shape[0]]
-                    if model.is_recurrent:
-                        logits, _ = model.apply(server_params, xb,
-                                                carry=carry0)
-                    else:
-                        logits = model.apply(server_params, xb)
-                    per = per_sample_loss(logits, yb, model.is_regression)
-                    batch_mean = jnp.sum(per * m) / jnp.maximum(
-                        jnp.sum(m), 1.0)
-                    has_real = (jnp.sum(m) > 0).astype(jnp.float32)
-                    return carry, batch_mean * has_real
+                    def floss(carry, i):
+                        frows = i * B + jnp.arange(B)
+                        m = (frows < size).astype(jnp.float32)
+                        xb, yb = x[frows % x.shape[0]], y[frows % x.shape[0]]
+                        if model.is_recurrent:
+                            logits, _ = model.apply(server_params, xb,
+                                                    carry=carry0)
+                        else:
+                            logits = model.apply(server_params, xb)
+                        per = per_sample_loss(logits, yb, model.is_regression)
+                        batch_mean = jnp.sum(per * m) / jnp.maximum(
+                            jnp.sum(m), 1.0)
+                        has_real = (jnp.sum(m) > 0).astype(jnp.float32)
+                        return carry, batch_mean * has_real
 
-                _, batch_means = jax.lax.scan(floss, 0, jnp.arange(n_full))
-                full_loss = jnp.sum(batch_means)
+                    _, batch_means = jax.lax.scan(floss, 0, jnp.arange(n_full))
+                    full_loss = jnp.sum(batch_means)
 
-            if not batch_mode:
-                perm = epoch_permutation(jax.random.fold_in(rng_c, 0),
-                                         size, x.shape[0])
-            if alg.needs_val_batch and not val_batch_mode:
-                vperm = epoch_permutation(jax.random.fold_in(rng_c,
-                                                             VAL_FOLD),
-                                          vsize, vx.shape[0])
+                if not batch_mode:
+                    perm = epoch_permutation(jax.random.fold_in(rng_c, 0),
+                                             size, x.shape[0])
+                if alg.needs_val_batch and not val_batch_mode:
+                    vperm = epoch_permutation(jax.random.fold_in(rng_c,
+                                                                 VAL_FOLD),
+                                              vsize, vx.shape[0])
 
-            # per-client early exit (is_sync_fed, flow_utils.py:33-40):
-            # in epoch-sync mode a client stops after ITS OWN epoch
-            # budget ceil(size/B)*E steps; the scan keeps running in
-            # lockstep but frozen clients' state and metrics don't move.
-            # The budget is ALSO every hook's effective local_steps (so
-            # scaffold/fedgate control updates divide by the steps the
-            # client actually took) and feeds step-indexed algorithm
-            # logic (PerFedMe's sync pull, DRFA's snapshot clamp).
-            step_budget = (nb.astype(jnp.int32)
-                           * self.cfg.federated.num_epochs_per_comm) \
-                if self.epoch_sync else jnp.asarray(K, jnp.int32)
-            if flt.straggler_rate > 0.0:
-                # straggler chaos: the client misses the round deadline
-                # after a fraction of ITS OWN budget (>= 1 step); rides
-                # the same freeze mask as epoch-sync early exit
-                step_budget = jnp.maximum(jnp.ceil(
-                    step_budget.astype(jnp.float32) * bscale), 1.0) \
-                    .astype(jnp.int32)
+                # per-client early exit (is_sync_fed, flow_utils.py:33-40):
+                # in epoch-sync mode a client stops after ITS OWN epoch
+                # budget ceil(size/B)*E steps; the scan keeps running in
+                # lockstep but frozen clients' state and metrics don't move.
+                # The budget is ALSO every hook's effective local_steps (so
+                # scaffold/fedgate control updates divide by the steps the
+                # client actually took) and feeds step-indexed algorithm
+                # logic (PerFedMe's sync pull, DRFA's snapshot clamp).
+                step_budget = (nb.astype(jnp.int32)
+                               * self.cfg.federated.num_epochs_per_comm) \
+                    if self.epoch_sync else jnp.asarray(K, jnp.int32)
+                if flt.straggler_rate > 0.0:
+                    # straggler chaos: the client misses the round deadline
+                    # after a fraction of ITS OWN budget (>= 1 step); rides
+                    # the same freeze mask as epoch-sync early exit
+                    step_budget = jnp.maximum(jnp.ceil(
+                        step_budget.astype(jnp.float32) * bscale), 1.0) \
+                        .astype(jnp.int32)
 
             def step(carry, k):
                 params, opt, aux, epoch, li, rnn_carry = carry
@@ -842,9 +866,10 @@ class FederatedTrainer:
                     # separate stream from drop_rng's fold(k+1): derive
                     # from a disjoint parent key (folds are uint32; K can
                     # never reach 2^31 steps) so the two cannot collide
-                    aug_parent = jax.random.fold_in(rng_c, 0x7FFFFFFF)
-                    bx = augment_image_batch(
-                        jax.random.fold_in(aug_parent, k), bx)
+                    with jax.named_scope("fed.augment"):
+                        aug_parent = jax.random.fold_in(rng_c, 0x7FFFFFFF)
+                        bx = augment_image_batch(
+                            jax.random.fold_in(aug_parent, k), bx)
                 drop_rng = jax.random.fold_in(rng_c, k + 1)
                 n_params, n_opt, n_aux, n_rnn, loss, acc = alg.local_step(
                     params=params, opt=opt, client_aux=aux,
@@ -862,26 +887,30 @@ class FederatedTrainer:
                         li + active.astype(li.dtype), n_rnn), \
                     (loss, acc, af)
 
-            init = (server_params, cstate.opt, cstate.aux, cstate.epoch,
-                    cstate.local_index, carry0)
-            (params, opt, aux, epoch, li, _), (losses, accs, act) = \
-                jax.lax.scan(step, init, jnp.arange(K),
-                             unroll=min(self.cfg.mesh.scan_unroll, K))
+            with jax.named_scope("fed.local_steps"):
+                init = (server_params, cstate.opt, cstate.aux,
+                        cstate.epoch, cstate.local_index, carry0)
+                (params, opt, aux, epoch, li, _), (losses, accs, act) = \
+                    jax.lax.scan(step, init, jnp.arange(K),
+                                 unroll=min(self.cfg.mesh.scan_unroll, K))
 
-            delta = tree_sub(server_params, params)
-            lr_end = lr_at(self.schedule, epoch)
-            payload, aux = alg.client_payload(
-                delta=delta, client_aux=aux, params=params,
-                server_params=server_params, server_aux=base_a,
-                lr=lr_end, local_steps=step_budget, weight=weight,
-                full_loss=full_loss)
+                delta = tree_sub(server_params, params)
+                lr_end = lr_at(self.schedule, epoch)
+            with jax.named_scope("fed.wire"):
+                payload, aux = alg.client_payload(
+                    delta=delta, client_aux=aux, params=params,
+                    server_params=server_params, server_aux=base_a,
+                    lr=lr_end, local_steps=step_budget, weight=weight,
+                    full_loss=full_loss)
             new_state = ClientState(params=params, opt=opt, aux=aux,
                                     epoch=epoch, local_index=li)
             # metrics over the steps the client actually took (frozen
             # early-exit steps contribute nothing)
-            n_act = jnp.maximum(jnp.sum(act), 1.0)
-            return payload, delta, new_state, (
-                jnp.sum(losses * act) / n_act, jnp.sum(accs * act) / n_act)
+            with jax.named_scope("fed.metrics"):
+                n_act = jnp.maximum(jnp.sum(act), 1.0)
+                return payload, delta, new_state, (
+                    jnp.sum(losses * act) / n_act,
+                    jnp.sum(accs * act) / n_act)
 
         if self.client_fusion == "fused":
             # same per-client math, one grouped conv per layer — the
@@ -915,82 +944,86 @@ class FederatedTrainer:
             (payloads, deltas, new_on_clients))
         losses, accs = self._replicate_cohort((losses, accs))
 
-        # wire-level adversaries and faults: the clients' local state
-        # stays sane (``deltas`` itself must stay clean: client_post
-        # consumes it for persistent aux updates like FedGATE's
-        # tracking variate); ``wire_deltas`` is what the guards judge —
-        # the corrupted view the server saw. The byzantine swap comes
-        # FIRST (an adversary crafts what it sends, then the wire
-        # format applies like any client's); nan poison last (a fried
-        # wire trumps whatever was on it).
-        wire_deltas = deltas
-        byz_count = jnp.zeros(())
-        if flt.byzantine_rate > 0.0:
-            byz_rng = jax.random.fold_in(
-                jax.random.fold_in(rng_round, flt.chaos_salt),
-                BYZ_NOISE_FOLD)
-            wire_deltas, payloads = apply_byzantine(
-                plan, wire_deltas, payloads, weights, byz_rng, flt)
-            # count uploads that actually REACH the server: a cohort
-            # member that also crash-chaosed never uploads, so its
-            # crafted payload is not an injected attack
-            byz_count = jnp.sum(plan.byzantine * plan.survive)
-        if flt.nan_inject_rate > 0.0:
-            wire_deltas = poison_tree(wire_deltas, plan.nan_inject)
+        with jax.named_scope("fed.guard"):
+            # wire-level adversaries and faults: the clients' local state
+            # stays sane (``deltas`` itself must stay clean: client_post
+            # consumes it for persistent aux updates like FedGATE's
+            # tracking variate); ``wire_deltas`` is what the guards judge —
+            # the corrupted view the server saw. The byzantine swap comes
+            # FIRST (an adversary crafts what it sends, then the wire
+            # format applies like any client's); nan poison last (a fried
+            # wire trumps whatever was on it).
+            wire_deltas = deltas
+            byz_count = jnp.zeros(())
+            if flt.byzantine_rate > 0.0:
+                byz_rng = jax.random.fold_in(
+                    jax.random.fold_in(rng_round, flt.chaos_salt),
+                    BYZ_NOISE_FOLD)
+                wire_deltas, payloads = apply_byzantine(
+                    plan, wire_deltas, payloads, weights, byz_rng, flt)
+                # count uploads that actually REACH the server: a cohort
+                # member that also crash-chaosed never uploads, so its
+                # crafted payload is not an injected attack
+                byz_count = jnp.sum(plan.byzantine * plan.survive)
+            if flt.nan_inject_rate > 0.0:
+                wire_deltas = poison_tree(wire_deltas, plan.nan_inject)
 
-        # uplink wire format on the stacked [k] payload axis (per-client
-        # quantization via the pallas client-grid kernel — outside the
-        # vmap, where pallas_call can actually run)
-        payloads = alg.payload_batch_transform(payloads)
-        if flt.nan_inject_rate > 0.0:
-            payloads = poison_tree(payloads, plan.nan_inject)
+        with jax.named_scope("fed.wire"):
+            # uplink wire format on the stacked [k] payload axis (per-client
+            # quantization via the pallas client-grid kernel — outside the
+            # vmap, where pallas_call can actually run)
+            payloads = alg.payload_batch_transform(payloads)
+        with jax.named_scope("fed.guard"):
+            if flt.nan_inject_rate > 0.0:
+                payloads = poison_tree(payloads, plan.nan_inject)
 
-        # server-side screening: crashed clients never arrive; with
-        # guards on, non-finite / norm-exploded deltas are rejected or
-        # clipped (guards.py). ``accept`` is the final aggregation mask
-        # and the surviving aggregation weight is renormalized so the
-        # server step keeps its fault-free magnitude.
-        rejected = clipped = jnp.zeros(())
-        # reporters this round: chaos survival AND (availability plane
-        # armed) arrival by the deadline — a dropout or late report
-        # never reaches the server, so it is excluded BEFORE the
-        # guards (it must not influence the median norm) and before
-        # the robust rule; its weight renormalizes away below exactly
-        # like a crashed client's.
-        survive = plan.survive if avail_ok is None \
-            else plan.survive * avail_ok.astype(jnp.float32)
-        if self.guard_on:
-            payloads, report = screen_payloads(wire_deltas, payloads,
-                                               survive, flt)
-            accept, rejected, clipped = (report.accept, report.rejected,
-                                         report.clipped)
-        elif self.chaos_on or self.avail_sync:
-            accept = survive
-            payloads = tree_where(accept, payloads,
-                                  tree_zeros_like(payloads))
-        else:
-            accept = None
-        if accept is not None:
-            # the accept mask feeds the renormalization sums below —
-            # replicated, its weighted reductions keep one association
-            accept = self._replicate_cohort(accept)
-        if self.avail_sync and flt.byzantine_rate > 0.0:
-            # recount attacks that actually reached the server: a
-            # cohort member that dropped out or missed the deadline
-            # never delivered its crafted upload
-            byz_count = jnp.sum(plan.byzantine * survive)
+            # server-side screening: crashed clients never arrive; with
+            # guards on, non-finite / norm-exploded deltas are rejected or
+            # clipped (guards.py). ``accept`` is the final aggregation mask
+            # and the surviving aggregation weight is renormalized so the
+            # server step keeps its fault-free magnitude.
+            rejected = clipped = jnp.zeros(())
+            # reporters this round: chaos survival AND (availability plane
+            # armed) arrival by the deadline — a dropout or late report
+            # never reaches the server, so it is excluded BEFORE the
+            # guards (it must not influence the median norm) and before
+            # the robust rule; its weight renormalizes away below exactly
+            # like a crashed client's.
+            survive = plan.survive if avail_ok is None \
+                else plan.survive * avail_ok.astype(jnp.float32)
+            if self.guard_on:
+                payloads, report = screen_payloads(wire_deltas, payloads,
+                                                   survive, flt)
+                accept, rejected, clipped = (report.accept, report.rejected,
+                                             report.clipped)
+            elif self.chaos_on or self.avail_sync:
+                accept = survive
+                payloads = tree_where(accept, payloads,
+                                      tree_zeros_like(payloads))
+            else:
+                accept = None
+            if accept is not None:
+                # the accept mask feeds the renormalization sums below —
+                # replicated, its weighted reductions keep one association
+                accept = self._replicate_cohort(accept)
+            if self.avail_sync and flt.byzantine_rate > 0.0:
+                # recount attacks that actually reached the server: a
+                # cohort member that dropped out or missed the deadline
+                # never delivered its crafted upload
+                byz_count = jnp.sum(plan.byzantine * survive)
 
-        # privacy plane, clip half (robustness/privacy.py): per-client
-        # L2 clip to dp_clip_norm BEFORE the robust rule sees the
-        # payloads — the clip bounds every client's sensitivity no
-        # matter what the rule (or the cohort statistics below) then
-        # does with them. Composition order (pinned, docs/robustness.md
-        # "Privacy plane"): accept mask -> DP clip -> robust rule
-        # (x staleness weights) -> DP noise on the released estimate.
-        dp_clipped_frac = None
-        if self.dp_on:
-            payloads, dp_clipped_frac = dp_clip_payloads(
-                payloads, weights, accept, self.dp_clip_norm)
+        with jax.named_scope("fed.guard"):
+            # privacy plane, clip half (robustness/privacy.py): per-client
+            # L2 clip to dp_clip_norm BEFORE the robust rule sees the
+            # payloads — the clip bounds every client's sensitivity no
+            # matter what the rule (or the cohort statistics below) then
+            # does with them. Composition order (pinned, docs/robustness.md
+            # "Privacy plane"): accept mask -> DP clip -> robust rule
+            # (x staleness weights) -> DP noise on the released estimate.
+            dp_clipped_frac = None
+            if self.dp_on:
+                payloads, dp_clipped_frac = dp_clip_payloads(
+                    payloads, weights, accept, self.dp_clip_norm)
 
         # the aggregation seam: either the plain weighted sum (the
         # pre-robust engine, kept verbatim so --robust_agg mean stays
@@ -1005,167 +1038,175 @@ class FederatedTrainer:
         # the default traces the exact pre-cohort program)
         cohort = None
         if self.robust_rule != "mean":
-            accept_f = accept if accept is not None else jnp.ones((k,))
-            payload_sum, new_robust_m, rreport = robust_aggregate(
-                self.robust_rule, payloads, weights, accept_f, flt,
-                momentum=robust_m, per_client=self.cohort_stats)
-            robust_selected = rreport.selected
-            robust_trimmed = rreport.trimmed
-            if self.cohort_stats:
-                # the rule's own evidence (krum scores, trim fractions,
-                # clip ratios) is the suspicion; the dispersion/norm
-                # gauges come from the shared cohort statistics
-                cs = cohort_statistics(payloads, weights, accept_f)
-                cohort = {"accept": accept_f, "sel": rreport.sel_mask,
-                          "susp": rreport.suspicion,
-                          "norm_q": cs.norm_q, "disp": cs.dispersion}
+            with jax.named_scope("fed.guard"):
+                accept_f = accept if accept is not None else jnp.ones((k,))
+                payload_sum, new_robust_m, rreport = robust_aggregate(
+                    self.robust_rule, payloads, weights, accept_f, flt,
+                    momentum=robust_m, per_client=self.cohort_stats)
+                robust_selected = rreport.selected
+                robust_trimmed = rreport.trimmed
+                if self.cohort_stats:
+                    # the rule's own evidence (krum scores, trim fractions,
+                    # clip ratios) is the suspicion; the dispersion/norm
+                    # gauges come from the shared cohort statistics
+                    cs = cohort_statistics(payloads, weights, accept_f)
+                    cohort = {"accept": accept_f, "sel": rreport.sel_mask,
+                              "susp": rreport.suspicion,
+                              "norm_q": cs.norm_q, "disp": cs.dispersion}
         else:
-            if self.podscale_armed:
-                # the pod-scale seam (parallel/podscale.py): the
-                # S-invariant grouped hierarchical sum with exactly
-                # ONE cross-shard all-reduce — robust masks, staleness
-                # weights and the DP stage compose on the reduced
-                # estimate unchanged. S == 1 runs the identical add
-                # chains with no collective (the bitwise twin).
-                payload_sum = cohort_hierarchical_sum(
-                    payloads, self.mesh, self.client_shards)
-                self._allreduce_bytes = cohort_allreduce_bytes(
-                    payloads, k)
+            with jax.named_scope("fed.aggregate"):
+                if self.podscale_armed:
+                    # the pod-scale seam (parallel/podscale.py): the
+                    # S-invariant grouped hierarchical sum with exactly
+                    # ONE cross-shard all-reduce — robust masks, staleness
+                    # weights and the DP stage compose on the reduced
+                    # estimate unchanged. S == 1 runs the identical add
+                    # chains with no collective (the bitwise twin).
+                    payload_sum = cohort_hierarchical_sum(
+                        payloads, self.mesh, self.client_shards)
+                    self._allreduce_bytes = cohort_allreduce_bytes(
+                        payloads, k)
+                else:
+                    payload_sum = jax.tree.map(
+                        lambda p: jnp.sum(p, axis=0), payloads)
+                if accept is not None:
+                    # rejected weight redistributed over survivors;
+                    # all-rejected rounds contribute a zero payload (server
+                    # holds). Staleness weights (weight_scale) are already
+                    # composed into ``weights``, so they renormalize with
+                    # it (guards.py).
+                    payload_sum = renormalize_accepted(payload_sum, weights,
+                                                       accept)
+                if self.cohort_stats:
+                    accept_f = accept if accept is not None \
+                        else jnp.ones((k,))
+                    cs = cohort_statistics(payloads, weights, accept_f)
+                    cand = accept_f * (weights > 0.0).astype(accept_f.dtype)
+                    cohort = {"accept": accept_f, "sel": cand,
+                              "susp": cs.suspicion,
+                              "norm_q": cs.norm_q, "disp": cs.dispersion}
+        with jax.named_scope("fed.aggregate"):
+            payload_sum = alg.aggregate_transform(payload_sum)
+
+        with jax.named_scope("fed.guard"):
+            # privacy plane, noise half: calibrated Gaussian noise on the
+            # RELEASED estimate — sigma = z * clip / cohort_k on the
+            # weighted mean (DP-FedAvg server noise), drawn from its own
+            # fold of the round key so every other stream is untouched.
+            # cohort_k is the round's real width: k_online on the sync
+            # planes (over-selection closes on k_online), the commit
+            # buffer size m on the async plane (base_params is only
+            # threaded by the commit dispatch).
+            dp_sigma_t = None
+            if self.dp_on:
+                dp_k = k if base_params is not None else self.k_online
+                dp_sigma = dp_noise_stddev(self.dp_noise_multiplier,
+                                           self.dp_clip_norm, dp_k)
+                payload_sum = dp_add_noise(payload_sum, rng_round, weights,
+                                           dp_sigma, dp_scale)
+                dp_sigma_t = (dp_sigma * dp_scale).astype(jnp.float32)
+
+        with jax.named_scope("fed.server_step"):
+            new_params, new_opt, new_saux = alg.server_update(
+                server.params, server.opt, server.aux, payload_sum,
+                online_idx=idx, num_online_eff=num_online_eff,
+                client_losses=losses)
+
+        with jax.named_scope("fed.scatter"):
+            # aux updates that need the aggregated payload (FedGATE); each
+            # client sees its own end-of-round local params, final LR, and
+            # EFFECTIVE step count (its epoch-sync budget, not the scan K)
+            if self.epoch_sync:
+                E = self.cfg.federated.num_epochs_per_comm
+                on_budgets = jnp.ceil(on_sizes / B).astype(jnp.int32) * E
             else:
-                payload_sum = jax.tree.map(
-                    lambda p: jnp.sum(p, axis=0), payloads)
-            if accept is not None:
-                # rejected weight redistributed over survivors;
-                # all-rejected rounds contribute a zero payload (server
-                # holds). Staleness weights (weight_scale) are already
-                # composed into ``weights``, so they renormalize with
-                # it (guards.py).
-                payload_sum = renormalize_accepted(payload_sum, weights,
-                                                   accept)
-            if self.cohort_stats:
-                accept_f = accept if accept is not None \
-                    else jnp.ones((k,))
-                cs = cohort_statistics(payloads, weights, accept_f)
-                cand = accept_f * (weights > 0.0).astype(accept_f.dtype)
-                cohort = {"accept": accept_f, "sel": cand,
-                          "susp": cs.suspicion,
-                          "norm_q": cs.norm_q, "disp": cs.dispersion}
-        payload_sum = alg.aggregate_transform(payload_sum)
+                on_budgets = jnp.full(on_sizes.shape, K, jnp.int32)
+            if flt.straggler_rate > 0.0:
+                # mirror the in-loop straggler cut so hooks see the steps
+                # the client actually took
+                on_budgets = jnp.maximum(jnp.ceil(
+                    on_budgets.astype(jnp.float32) * plan.budget_scale),
+                    1.0).astype(jnp.int32)
+            post_aux = jax.vmap(
+                lambda d, a, w, p, e, ks: alg.client_post(
+                    delta=d, client_aux=a, payload_sum=payload_sum,
+                    lr=lr_at(self.schedule, e), local_steps=ks,
+                    server_params=server.params, params=p, weight=w)
+            )(deltas, new_on_clients.aux, weights, new_on_clients.params,
+              new_on_clients.epoch, on_budgets)
+            new_on_clients = new_on_clients._replace(
+                aux=post_aux,
+                # clients leave the round holding the aggregated server model
+                # (model_server = deepcopy(model_client), fedavg.py:97)
+                params=jax.vmap(lambda _: new_params)(jnp.arange(k)))
+            # pod-scale: the broadcast params land cohort-sharded so the
+            # [C] scatter below stays a local write per shard group
+            new_on_clients = self._shard_cohort(new_on_clients)
 
-        # privacy plane, noise half: calibrated Gaussian noise on the
-        # RELEASED estimate — sigma = z * clip / cohort_k on the
-        # weighted mean (DP-FedAvg server noise), drawn from its own
-        # fold of the round key so every other stream is untouched.
-        # cohort_k is the round's real width: k_online on the sync
-        # planes (over-selection closes on k_online), the commit
-        # buffer size m on the async plane (base_params is only
-        # threaded by the commit dispatch).
-        dp_sigma_t = None
-        if self.dp_on:
-            dp_k = k if base_params is not None else self.k_online
-            dp_sigma = dp_noise_stddev(self.dp_noise_multiplier,
-                                       self.dp_clip_norm, dp_k)
-            payload_sum = dp_add_noise(payload_sum, rng_round, weights,
-                                       dp_sigma, dp_scale)
-            dp_sigma_t = (dp_sigma * dp_scale).astype(jnp.float32)
+            # crash chaos: a crashed client's round never happened on its
+            # side — state rolls back to round start, and it reports no
+            # metrics (it is not online this round)
+            online = jnp.ones((k,))
+            if flt.client_drop_rate > 0.0:
+                new_on_clients = tree_where(plan.survive, new_on_clients,
+                                            on_clients0)
+                online = plan.survive
+            if self.avail_sync:
+                # a mid-round dropout went offline before finishing: its
+                # local round never happened (fail-stop, like crash
+                # chaos). A deadline miss DID finish training — the client
+                # keeps its local state; only its upload was masked at the
+                # server. ``online`` counts reporters, so the logged
+                # loss/acc are what the server actually observed.
+                new_on_clients = tree_where(~avail_drop, new_on_clients,
+                                            on_clients0)
+                online = online * avail_ok.astype(jnp.float32)
 
-        new_params, new_opt, new_saux = alg.server_update(
-            server.params, server.opt, server.aux, payload_sum,
-            online_idx=idx, num_online_eff=num_online_eff,
-            client_losses=losses)
+            # scatter online client state back into the full [C] axis
+            scatter = lambda full, new: jax.tree.map(
+                lambda f, n: f.at[idx].set(n), full, new)
+            new_clients = scatter(clients, new_on_clients)
 
-        # aux updates that need the aggregated payload (FedGATE); each
-        # client sees its own end-of-round local params, final LR, and
-        # EFFECTIVE step count (its epoch-sync budget, not the scan K)
-        if self.epoch_sync:
-            E = self.cfg.federated.num_epochs_per_comm
-            on_budgets = jnp.ceil(on_sizes / B).astype(jnp.int32) * E
-        else:
-            on_budgets = jnp.full(on_sizes.shape, K, jnp.int32)
-        if flt.straggler_rate > 0.0:
-            # mirror the in-loop straggler cut so hooks see the steps
-            # the client actually took
-            on_budgets = jnp.maximum(jnp.ceil(
-                on_budgets.astype(jnp.float32) * plan.budget_scale),
-                1.0).astype(jnp.int32)
-        post_aux = jax.vmap(
-            lambda d, a, w, p, e, ks: alg.client_post(
-                delta=d, client_aux=a, payload_sum=payload_sum,
-                lr=lr_at(self.schedule, e), local_steps=ks,
-                server_params=server.params, params=p, weight=w)
-        )(deltas, new_on_clients.aux, weights, new_on_clients.params,
-          new_on_clients.epoch, on_budgets)
-        new_on_clients = new_on_clients._replace(
-            aux=post_aux,
-            # clients leave the round holding the aggregated server model
-            # (model_server = deepcopy(model_client), fedavg.py:97)
-            params=jax.vmap(lambda _: new_params)(jnp.arange(k)))
-        # pod-scale: the broadcast params land cohort-sharded so the
-        # [C] scatter below stays a local write per shard group
-        new_on_clients = self._shard_cohort(new_on_clients)
+        with jax.named_scope("fed.metrics"):
+            # per-client metric leaves: 'perm' keeps the legacy [C]
+            # scatter; 'sparse' — the million-client mode — emits the
+            # cohort-aligned [k] rows instead. Zero-filling three [C]
+            # vectors per round is the last O(C) term on the round's
+            # critical path (12 MB/round at C=10^6), and every consumer
+            # reduces by sum, which is identical in either layout because
+            # offline rows are zeroed; the cohort ids ride ``cohort_idx``
+            # when the per-client ledger needs them.
+            # lint: disable=FTL005 — participation_mode is a static config
+            if self.participation_mode == "sparse":
+                mask_full = online
+                loss_full = losses * online
+                acc_full = accs * online
+            else:
+                mask_full = jnp.zeros((C,)).at[idx].set(online)
+                loss_full = jnp.zeros((C,)).at[idx].set(losses * online)
+                acc_full = jnp.zeros((C,)).at[idx].set(accs * online)
+            comm_bytes = jnp.asarray(
+                tree_bytes(server.params) * k
+                * alg.payload_scale(), jnp.float32)
+            if flt.client_drop_rate > 0.0 or self.avail_sync:
+                # crashed / dropped-out / past-deadline uploads never hit
+                # the wire (the server closed the round without them)
+                comm_bytes = comm_bytes * jnp.sum(online) / k
 
-        # crash chaos: a crashed client's round never happened on its
-        # side — state rolls back to round start, and it reports no
-        # metrics (it is not online this round)
-        online = jnp.ones((k,))
-        if flt.client_drop_rate > 0.0:
-            new_on_clients = tree_where(plan.survive, new_on_clients,
-                                        on_clients0)
-            online = plan.survive
-        if self.avail_sync:
-            # a mid-round dropout went offline before finishing: its
-            # local round never happened (fail-stop, like crash
-            # chaos). A deadline miss DID finish training — the client
-            # keeps its local state; only its upload was masked at the
-            # server. ``online`` counts reporters, so the logged
-            # loss/acc are what the server actually observed.
-            new_on_clients = tree_where(~avail_drop, new_on_clients,
-                                        on_clients0)
-            online = online * avail_ok.astype(jnp.float32)
-
-        # scatter online client state back into the full [C] axis
-        scatter = lambda full, new: jax.tree.map(
-            lambda f, n: f.at[idx].set(n), full, new)
-        new_clients = scatter(clients, new_on_clients)
-
-        # per-client metric leaves: 'perm' keeps the legacy [C]
-        # scatter; 'sparse' — the million-client mode — emits the
-        # cohort-aligned [k] rows instead. Zero-filling three [C]
-        # vectors per round is the last O(C) term on the round's
-        # critical path (12 MB/round at C=10^6), and every consumer
-        # reduces by sum, which is identical in either layout because
-        # offline rows are zeroed; the cohort ids ride ``cohort_idx``
-        # when the per-client ledger needs them.
-        # lint: disable=FTL005 — participation_mode is a static config
-        if self.participation_mode == "sparse":
-            mask_full = online
-            loss_full = losses * online
-            acc_full = accs * online
-        else:
-            mask_full = jnp.zeros((C,)).at[idx].set(online)
-            loss_full = jnp.zeros((C,)).at[idx].set(losses * online)
-            acc_full = jnp.zeros((C,)).at[idx].set(accs * online)
-        comm_bytes = jnp.asarray(
-            tree_bytes(server.params) * k
-            * alg.payload_scale(), jnp.float32)
-        if flt.client_drop_rate > 0.0 or self.avail_sync:
-            # crashed / dropped-out / past-deadline uploads never hit
-            # the wire (the server closed the round without them)
-            comm_bytes = comm_bytes * jnp.sum(online) / k
-
-        new_server = ServerState(params=new_params, opt=new_opt,
-                                 aux=new_saux, round=server.round + 1,
-                                 rng=server.rng)
-        # second global phase (DRFA dual update): full data access on
-        # the resident plane; on the stream plane the feed carries the
-        # host-packed probe batches instead (``probe`` — the same
-        # fold_in(rng_round, 99) chain, O(k) device work)
-        if probe is not None:
-            new_server = alg.post_round_global_feed(
-                new_server, probe, jax.random.fold_in(rng_round, 99))
-        else:
-            new_server = alg.post_round_global(
-                new_server, data, jax.random.fold_in(rng_round, 99))
+        with jax.named_scope("fed.server_step"):
+            new_server = ServerState(params=new_params, opt=new_opt,
+                                     aux=new_saux, round=server.round + 1,
+                                     rng=server.rng)
+            # second global phase (DRFA dual update): full data access on
+            # the resident plane; on the stream plane the feed carries the
+            # host-packed probe batches instead (``probe`` — the same
+            # fold_in(rng_round, 99) chain, O(k) device work)
+            if probe is not None:
+                new_server = alg.post_round_global_feed(
+                    new_server, probe, jax.random.fold_in(rng_round, 99))
+            else:
+                new_server = alg.post_round_global(
+                    new_server, data, jax.random.fold_in(rng_round, 99))
         if self.robust_momentum:
             # re-wrap: the updated norm_bound center rides server.aux
             # through checkpoints and the async snapshot ring unchanged
@@ -1177,63 +1218,64 @@ class FederatedTrainer:
             # flips the HOST copy; the program passes it through)
             new_server = new_server._replace(aux={
                 "alg": new_server.aux, "dp_noise_scale": dp_scale})
-        # federation-plane cohort fields (telemetry.cohort_stats):
-        # per-online-client evidence + heterogeneity gauges. The
-        # staleness vector is the sync plane's zeros here; the commit
-        # program overwrites it with each job's real commit staleness
-        # (parallel/round_program.py:_commit_core).
-        cohort_fields = {}
-        if cohort is not None:
-            cohort_fields = dict(
-                cohort_idx=idx.astype(jnp.int32),
-                cohort_online=online * jnp.ones((k,)),
-                cohort_accept=cohort["accept"],
-                cohort_selected=cohort["sel"],
-                cohort_suspicion=cohort["susp"],
-                cohort_staleness=jnp.zeros((k,)),
-                cohort_norm_q=cohort["norm_q"],
-                cohort_dispersion=cohort["disp"])
-        # availability lifecycle counters + the in-jit quorum verdict
-        # (all ride RoundMetrics into the loop's one batched fetch).
-        # The round ALWAYS commits its renormalized partial cohort —
-        # sub-quorum degrades (counted, evented, health intent) or is
-        # escalated by the supervisor when avail_quorum_action='abort';
-        # the program itself never wedges (all-rejected => the
-        # renormalization scale hit 0 and the server held).
-        avail_fields = {}
-        chaos_dropped = k - jnp.sum(online)
-        if self.avail_sync:
-            # keep 'dropped' = chaos crashes only; the availability
-            # plane reports its own counters
-            chaos_dropped = jnp.sum(1.0 - plan.survive)
-            n_report = jnp.sum(accept)
-            q_flag = jnp.zeros(())
-            if flt.avail_quorum_frac > 0.0:
-                quorum = math.ceil(
-                    flt.avail_quorum_frac * self.k_online)
-                q_flag = (n_report < quorum).astype(jnp.float32)
-            avail_fields = dict(
-                avail_dropped=jnp.sum(avail_drop.astype(jnp.float32)),
-                deadline_missed=jnp.sum(avail_miss.astype(jnp.float32)),
-                quorum_degraded=q_flag)
-        # privacy-plane gauges (None = DP off: zero extra outputs)
-        dp_fields = {}
-        if self.dp_on:
-            dp_fields = dict(
-                dp_clipped_frac=dp_clipped_frac.astype(jnp.float32),
-                dp_noise_sigma=dp_sigma_t)
-        metrics = RoundMetrics(
-            train_loss=loss_full, train_acc=acc_full,
-            online_mask=mask_full, comm_bytes=comm_bytes,
-            dropped_clients=chaos_dropped,
-            straggler_clients=jnp.sum(
-                (plan.budget_scale < 1.0).astype(jnp.float32)),
-            rejected_updates=jnp.asarray(rejected, jnp.float32),
-            clipped_updates=jnp.asarray(clipped, jnp.float32),
-            byzantine_clients=jnp.asarray(byz_count, jnp.float32),
-            robust_selected=jnp.asarray(robust_selected, jnp.float32),
-            robust_trimmed=jnp.asarray(robust_trimmed, jnp.float32),
-            **avail_fields, **cohort_fields, **dp_fields)
+        with jax.named_scope("fed.metrics"):
+            # federation-plane cohort fields (telemetry.cohort_stats):
+            # per-online-client evidence + heterogeneity gauges. The
+            # staleness vector is the sync plane's zeros here; the commit
+            # program overwrites it with each job's real commit staleness
+            # (parallel/round_program.py:_commit_core).
+            cohort_fields = {}
+            if cohort is not None:
+                cohort_fields = dict(
+                    cohort_idx=idx.astype(jnp.int32),
+                    cohort_online=online * jnp.ones((k,)),
+                    cohort_accept=cohort["accept"],
+                    cohort_selected=cohort["sel"],
+                    cohort_suspicion=cohort["susp"],
+                    cohort_staleness=jnp.zeros((k,)),
+                    cohort_norm_q=cohort["norm_q"],
+                    cohort_dispersion=cohort["disp"])
+            # availability lifecycle counters + the in-jit quorum verdict
+            # (all ride RoundMetrics into the loop's one batched fetch).
+            # The round ALWAYS commits its renormalized partial cohort —
+            # sub-quorum degrades (counted, evented, health intent) or is
+            # escalated by the supervisor when avail_quorum_action='abort';
+            # the program itself never wedges (all-rejected => the
+            # renormalization scale hit 0 and the server held).
+            avail_fields = {}
+            chaos_dropped = k - jnp.sum(online)
+            if self.avail_sync:
+                # keep 'dropped' = chaos crashes only; the availability
+                # plane reports its own counters
+                chaos_dropped = jnp.sum(1.0 - plan.survive)
+                n_report = jnp.sum(accept)
+                q_flag = jnp.zeros(())
+                if flt.avail_quorum_frac > 0.0:
+                    quorum = math.ceil(
+                        flt.avail_quorum_frac * self.k_online)
+                    q_flag = (n_report < quorum).astype(jnp.float32)
+                avail_fields = dict(
+                    avail_dropped=jnp.sum(avail_drop.astype(jnp.float32)),
+                    deadline_missed=jnp.sum(avail_miss.astype(jnp.float32)),
+                    quorum_degraded=q_flag)
+            # privacy-plane gauges (None = DP off: zero extra outputs)
+            dp_fields = {}
+            if self.dp_on:
+                dp_fields = dict(
+                    dp_clipped_frac=dp_clipped_frac.astype(jnp.float32),
+                    dp_noise_sigma=dp_sigma_t)
+            metrics = RoundMetrics(
+                train_loss=loss_full, train_acc=acc_full,
+                online_mask=mask_full, comm_bytes=comm_bytes,
+                dropped_clients=chaos_dropped,
+                straggler_clients=jnp.sum(
+                    (plan.budget_scale < 1.0).astype(jnp.float32)),
+                rejected_updates=jnp.asarray(rejected, jnp.float32),
+                clipped_updates=jnp.asarray(clipped, jnp.float32),
+                byzantine_clients=jnp.asarray(byz_count, jnp.float32),
+                robust_selected=jnp.asarray(robust_selected, jnp.float32),
+                robust_trimmed=jnp.asarray(robust_trimmed, jnp.float32),
+                **avail_fields, **cohort_fields, **dp_fields)
         return new_server, new_clients, metrics
 
     # -- fused client round (cfg.mesh.client_fusion='fused') --------------
@@ -1255,23 +1297,24 @@ class FederatedTrainer:
         K, B, k = self.local_steps, self.batch_size, self.k_dispatch
         flt = self.fault
         server_params = server.params
-        nb = jnp.ceil(sizes / B)  # [k] batches per local epoch
+        with jax.named_scope("fed.local_steps"):
+            nb = jnp.ceil(sizes / B)  # [k] batches per local epoch
 
-        # lint: disable=FTL005 — batch_mode is a static Python bool
-        if not batch_mode:
-            perms = jax.vmap(
-                lambda r, s: epoch_permutation(
-                    jax.random.fold_in(r, 0), s, x.shape[1])
-            )(rngs, sizes)
+            # lint: disable=FTL005 — batch_mode is a static Python bool
+            if not batch_mode:
+                perms = jax.vmap(
+                    lambda r, s: epoch_permutation(
+                        jax.random.fold_in(r, 0), s, x.shape[1])
+                )(rngs, sizes)
 
-        # per-client effective step counts (see client_round)
-        step_budget = (nb.astype(jnp.int32)
-                       * cfg.federated.num_epochs_per_comm) \
-            if self.epoch_sync else jnp.full((k,), K, jnp.int32)
-        if flt.straggler_rate > 0.0:
-            step_budget = jnp.maximum(jnp.ceil(
-                step_budget.astype(jnp.float32) * budget_scale), 1.0) \
-                .astype(jnp.int32)
+            # per-client effective step counts (see client_round)
+            step_budget = (nb.astype(jnp.int32)
+                           * cfg.federated.num_epochs_per_comm) \
+                if self.epoch_sync else jnp.full((k,), K, jnp.int32)
+            if flt.straggler_rate > 0.0:
+                step_budget = jnp.maximum(jnp.ceil(
+                    step_budget.astype(jnp.float32) * budget_scale),
+                    1.0).astype(jnp.int32)
 
         fused = self.fused_module
         lrs_of = jax.vmap(lambda e: lr_at(self.schedule, e))
@@ -1291,9 +1334,10 @@ class FederatedTrainer:
             if self.augment:
                 # client_round's exact fold chain: disjoint parent
                 # 0x7FFFFFFF, then the step index
-                aug = jax.vmap(lambda r: jax.random.fold_in(
-                    jax.random.fold_in(r, 0x7FFFFFFF), kk))(rngs)
-                bx = jax.vmap(augment_image_batch)(aug, bx)
+                with jax.named_scope("fed.augment"):
+                    aug = jax.vmap(lambda r: jax.random.fold_in(
+                        jax.random.fold_in(r, 0x7FFFFFFF), kk))(rngs)
+                    bx = jax.vmap(augment_image_batch)(aug, bx)
 
             def loss_fn(p):
                 logits = fused.apply({"params": p}, bx, train=True)
@@ -1313,50 +1357,55 @@ class FederatedTrainer:
                 # vmapped value_and_grad
                 return jnp.sum(loss_k), (loss_k, logits)
 
-            (_, (loss_k, logits)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            grads = jax.vmap(
-                lambda g, pc, ac, l: alg.transform_grads(
-                    g, params=pc, server_params=server_params,
-                    client_aux=ac, server_aux=server.aux, lr=l)
-            )(grads, params, aux, lr)
-            n_params, n_opt = jax.vmap(
-                lambda pc, g, o, l: optim.local_step(pc, g, o, l,
-                                                     cfg.optim)
-            )(params, grads, opt, lr)
-            if self.mask_steps:
-                n_params = tree_where(active, n_params, params)
-                n_opt = tree_where(active, n_opt, opt)
+            with jax.named_scope("fed.forward_backward"):
+                (_, (loss_k, logits)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
+                grads = jax.vmap(
+                    lambda g, pc, ac, l: alg.transform_grads(
+                        g, params=pc, server_params=server_params,
+                        client_aux=ac, server_aux=server.aux, lr=l)
+                )(grads, params, aux, lr)
+            with jax.named_scope("fed.opt_step"):
+                n_params, n_opt = jax.vmap(
+                    lambda pc, g, o, l: optim.local_step(pc, g, o, l,
+                                                         cfg.optim)
+                )(params, grads, opt, lr)
+                if self.mask_steps:
+                    n_params = tree_where(active, n_params, params)
+                    n_opt = tree_where(active, n_opt, opt)
             af = active.astype(jnp.float32)
             acc_k = jax.vmap(accuracy)(logits, by)
             return (n_params, n_opt, aux, epoch + af / nb,
                     li + active.astype(li.dtype)), (loss_k, acc_k, af)
 
-        init = (tree_broadcast_clients(server_params, k),
-                on_clients.opt, on_clients.aux, on_clients.epoch,
-                on_clients.local_index)
-        (params, opt, aux, epoch, li), (losses, accs, act) = \
-            jax.lax.scan(step, init, jnp.arange(K),
-                         unroll=min(cfg.mesh.scan_unroll, K))
+        with jax.named_scope("fed.local_steps"):
+            init = (tree_broadcast_clients(server_params, k),
+                    on_clients.opt, on_clients.aux, on_clients.epoch,
+                    on_clients.local_index)
+            (params, opt, aux, epoch, li), (losses, accs, act) = \
+                jax.lax.scan(step, init, jnp.arange(K),
+                             unroll=min(cfg.mesh.scan_unroll, K))
 
-        # delta = server - params, leaf-broadcast over the stacked [k]
-        # axis (same helper as the vmap path so the convention cannot
-        # drift between the two strategies)
-        deltas = tree_sub(server_params, params)
-        lr_end = lrs_of(epoch)
-        payloads, aux = jax.vmap(
-            lambda d, a, pc, l, sb, w: alg.client_payload(
-                delta=d, client_aux=a, params=pc,
-                server_params=server_params, server_aux=server.aux,
-                lr=l, local_steps=sb, weight=w, full_loss=None)
-        )(deltas, aux, params, lr_end, step_budget, weights)
+            # delta = server - params, leaf-broadcast over the stacked
+            # [k] axis (same helper as the vmap path so the convention
+            # cannot drift between the two strategies)
+            deltas = tree_sub(server_params, params)
+            lr_end = lrs_of(epoch)
+        with jax.named_scope("fed.wire"):
+            payloads, aux = jax.vmap(
+                lambda d, a, pc, l, sb, w: alg.client_payload(
+                    delta=d, client_aux=a, params=pc,
+                    server_params=server_params, server_aux=server.aux,
+                    lr=l, local_steps=sb, weight=w, full_loss=None)
+            )(deltas, aux, params, lr_end, step_budget, weights)
         new_states = ClientState(params=params, opt=opt, aux=aux,
                                  epoch=epoch, local_index=li)
         # metrics over the steps each client actually took
-        n_act = jnp.maximum(jnp.sum(act, axis=0), 1.0)
-        return payloads, deltas, new_states, (
-            jnp.sum(losses * act, axis=0) / n_act,
-            jnp.sum(accs * act, axis=0) / n_act)
+        with jax.named_scope("fed.metrics"):
+            n_act = jnp.maximum(jnp.sum(act, axis=0), 1.0)
+            return payloads, deltas, new_states, (
+                jnp.sum(losses * act, axis=0) / n_act,
+                jnp.sum(accs * act, axis=0) / n_act)
 
     def _mean_epoch_dev(self, clients) -> jnp.ndarray:
         """Device-side mean training epoch over the REAL clients — the

@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 from fedtorch_tpu.telemetry.health import HealthFile, health_path
 from fedtorch_tpu.telemetry.metrics import JsonlWriter
@@ -60,7 +60,8 @@ class Telemetry:
     def __init__(self, run_dir: Optional[str], level: str = "default",
                  process_index: int = 0,
                  run_meta: Optional[Dict] = None,
-                 max_span_events: int = 200_000):
+                 max_span_events: int = 200_000,
+                 annotate: Optional[Callable] = None):
         if level not in LEVELS:
             raise ValueError(
                 f"telemetry level must be one of {LEVELS}, got {level!r}")
@@ -91,7 +92,8 @@ class Telemetry:
                 os.path.join(run_dir, "events.jsonl"), EVENTS_SCHEMA,
                 run_meta,
                 on_degrade=lambda _w: self._writer_degraded("events"))
-            self.spans = SpanRecorder(max_events=max_span_events)
+            self.spans = SpanRecorder(max_events=max_span_events,
+                                      annotate=annotate)
             self.trace_path = os.path.join(run_dir, "trace.json")
 
     # -- lifecycle ------------------------------------------------------

@@ -17,6 +17,13 @@ producer/writer threads record without locks) — sub-microsecond,
 measured end-to-end by ``scripts/telemetry_bench.py``. The buffer is
 bounded (``max_events``); past the cap new spans are counted as
 dropped instead of growing without bound on month-long runs.
+
+With an ``annotate`` hook (the CLI passes
+``jax.profiler.TraceAnnotation``; this package imports no jax) every
+span also opens an annotation of the same name and args, so that while
+a profile is being taken the host spans lie in the profiler's own file
+beside the device's operations, on one clock. With no profile running
+the annotation is a flag test.
 """
 from __future__ import annotations
 
@@ -24,27 +31,34 @@ import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 
 class _Span:
     """Reusable context manager for one span (allocation-light: one
     object per ``span()`` call, no closure)."""
 
-    __slots__ = ("_rec", "name", "args", "_t0")
+    __slots__ = ("_rec", "name", "args", "_t0", "_ann")
 
     def __init__(self, rec: "SpanRecorder", name: str, args: Optional[Dict]):
         self._rec = rec
         self.name = name
         self.args = args
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        annotate = self._rec.annotate
+        if annotate is not None:
+            self._ann = annotate(self.name, **(self.args or {}))
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         self._rec._record(self.name, self._t0, time.perf_counter_ns(),
                           self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 class _NullSpan:
@@ -72,9 +86,13 @@ class SpanRecorder:
     """
 
     def __init__(self, max_events: int = 200_000,
-                 pid: Optional[int] = None):
+                 pid: Optional[int] = None,
+                 annotate: Optional[Callable] = None):
         self.pid = pid if pid is not None else os.getpid()
         self.max_events = int(max_events)
+        # ``annotate(name, **args)`` -> context manager opened with
+        # every span (module docstring); None records spans alone
+        self.annotate = annotate
         self.origin_ns = time.perf_counter_ns()
         self.origin_unix = time.time()
         self.dropped = 0
@@ -98,6 +116,15 @@ class SpanRecorder:
         if tid not in self._names:
             self._names[tid] = threading.current_thread().name
         self._events.append((name, t0, t1, tid, args))
+
+    def span_at(self, name: str, start_unix: float, end_unix: float,
+                **args) -> None:
+        """A span that was timed elsewhere, on the wall clock (JAX's
+        compile reports, ``utils.tracing.CompileSpans``): moved onto
+        the recorder's clock through the origin both clocks share."""
+        t0 = self.origin_ns + int((start_unix - self.origin_unix) * 1e9)
+        t1 = self.origin_ns + int((end_unix - self.origin_unix) * 1e9)
+        self._record(name, t0, max(t1, t0), args or None)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker (``ph: "i"``) — used for correlating

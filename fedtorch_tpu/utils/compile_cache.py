@@ -37,6 +37,18 @@ def enable_compile_cache() -> str:
     # programs aren't worth the disk round-trip
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # an executable carries the scope names (``fed.*``) and source lines
+    # of the program it was compiled from, and a profile shows those.
+    # JAX's default key leaves them out, so a program would load what an
+    # older one compiled and its trace would read by stale stage names,
+    # or by none (seen on the chip: the scope-less parent's trace showed
+    # this tree's scopes, and this tree loading the parent's would have
+    # read no stage at all). With them in the key a profile is always
+    # this program's own; the first run after an edit that moves a line
+    # on the round program's call stack, or after a move of the
+    # checkout, compiles where the default would load (PERF.md,
+    # section 6, has what that costs).
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 

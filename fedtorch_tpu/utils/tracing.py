@@ -19,8 +19,8 @@ machinery depends on.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
+import time
 from collections import Counter
 from typing import Callable, Dict, List, Optional
 
@@ -102,14 +102,62 @@ class RecompilationSentinel:
                 f"{dict(self.counts) or '{}'}")
 
 
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Context manager: profile everything inside to ``log_dir``."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+# JAX's own compile reports (``jax.monitoring``; emitted by
+# ``jax/_src/dispatch.py:log_elapsed_time`` and ``compiler.py``) and the
+# span each becomes
+_COMPILE_SPAN_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class CompileSpans:
+    """Every trace, lowering and backend compile of the process as a
+    span of the run's recorder, from JAX's own reports: ``jax.trace``,
+    ``jax.lower`` and ``jax.compile`` (args ``fun``, at the start and
+    end JAX gives) and ``jax.cache_load`` (a persistent-cache read, so
+    a hit; JAX reports its duration, so the span ends where it is
+    reported).
+
+    Where :class:`RecompilationSentinel` counts Python-body traces of
+    the callables registered with :func:`instrument_trace`, this sees
+    every program, eager operations included. A ``jax.compile`` span
+    covers the cache lookup, so on a hit ``jax.cache_load`` lies
+    inside it. The CLI installs one with the telemetry and closes it
+    with it; ``recorder`` is a ``telemetry.SpanRecorder``."""
+
+    def __init__(self, recorder):
+        self._rec = recorder
+        self._installed = False
+
+    def install(self) -> "CompileSpans":
+        jax.monitoring.register_event_time_span_listener(self._on_span)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        self._installed = True
+        return self
+
+    def close(self) -> None:
+        """Take the listeners out again. Idempotent."""
+        if not self._installed:
+            return
+        self._installed = False
+        jax.monitoring.unregister_event_time_span_listener(self._on_span)
+        jax.monitoring.unregister_event_duration_listener(
+            self._on_duration)
+
+    def _on_span(self, event, start, end, **kw) -> None:
+        name = _COMPILE_SPAN_OF.get(event)
+        if name is not None:
+            self._rec.span_at(name, start, end,
+                              fun=str(kw.get("fun_name", "")))
+
+    def _on_duration(self, event, duration, **kw) -> None:
+        if event == _CACHE_LOAD_EVENT:
+            end = time.time()
+            self._rec.span_at("jax.cache_load", end - duration, end)
 
 
 def fetch_sync(out):
@@ -160,11 +208,6 @@ def capture_round_trace(log_dir: str, fn: Callable, *args):
         finally:
             jax.profiler.stop_trace()
     return out
-
-
-def annotate(name: str):
-    """Named sub-span inside a trace (shows up on the TB timeline)."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def device_memory_stats() -> dict:

@@ -55,6 +55,10 @@ class TestCompileCache:
         assert enable_compile_cache() == str(tmp_path)
         assert "jax_compilation_cache_dir" not in calls
         assert "jax_persistent_cache_min_compile_time_secs" in calls
+        # a cached executable is this program's own, scope names and
+        # all: what a profile of it shows is never another tree's
+        assert calls["jax_compilation_cache_include_metadata_in_key"] \
+            is True
 
     def test_unset_means_repo_jax_cache(self, monkeypatch):
         from fedtorch_tpu.utils import enable_compile_cache

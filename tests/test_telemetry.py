@@ -17,6 +17,7 @@ import json
 import os
 import signal
 import threading
+import warnings
 
 import jax
 import numpy as np
@@ -201,6 +202,64 @@ class TestSpanRecorder:
         assert any(e["ph"] == "M" and e["name"] == "thread_name"
                    for e in evs)
         assert doc["otherData"]["dropped_spans"] == 0
+
+    def test_annotate_hook_opens_with_every_span(self):
+        """The hook the CLI fills with ``jax.profiler.TraceAnnotation``
+        (this package imports no jax): called with the span's name and
+        args, entered before and left after the span is recorded."""
+        import contextlib
+        log = []
+
+        @contextlib.contextmanager
+        def annotate(name, **args):
+            log.append(("open", name, args))
+            yield
+            log.append(("close", name, args))
+
+        rec = SpanRecorder(annotate=annotate)
+        with rec.span("round", round=3):
+            with rec.span("round.wait"):
+                pass
+        assert log == [("open", "round", {"round": 3}),
+                       ("open", "round.wait", {}),
+                       ("close", "round.wait", {}),
+                       ("close", "round", {"round": 3})]
+        assert [e["name"] for e in rec.to_trace_events()
+                if e["ph"] == "X"] == ["round.wait", "round"]
+
+    def test_compile_listener_sees_a_fresh_jit_once(self):
+        """``utils.tracing.CompileSpans``: one ``jax.compile`` span with
+        ``fun`` for a fresh ``jit``, none for its second call, none
+        once closed — for any program, registered or not."""
+        import jax.numpy as jnp
+
+        from fedtorch_tpu.utils.tracing import CompileSpans
+
+        def fresh_program_for_compile_spans(x):
+            return jnp.tanh(x) * 3.0
+
+        def compiles(rec):
+            return [e for e in rec.to_trace_events()
+                    if e["name"] == "jax.compile" and "fresh_program"
+                    in e["args"]["fun"]]
+
+        rec = SpanRecorder()
+        spans = CompileSpans(rec).install()
+        try:
+            f = jax.jit(fresh_program_for_compile_spans)
+            f(jnp.ones((3,))).block_until_ready()
+            assert len(compiles(rec)) == 1
+            names = {e["name"] for e in rec.to_trace_events()}
+            assert {"jax.trace", "jax.lower", "jax.compile"} <= names
+            one = compiles(rec)[0]
+            assert one["dur"] > 0 and one["ts"] >= 0
+            f(jnp.ones((3,))).block_until_ready()
+            assert len(compiles(rec)) == 1
+        finally:
+            spans.close()
+        spans.close()                      # idempotent
+        f(jnp.ones((5,))).block_until_ready()   # a new shape compiles
+        assert len(compiles(rec)) == 1     # ... unseen: the listener left
 
     def test_buffer_bound_counts_drops(self):
         rec = SpanRecorder(max_events=2)
@@ -455,6 +514,106 @@ class TestRunDirAndReport:
         from fedtorch_tpu.cli import main
         assert main(["report", run_dir]) == 0
         assert "phase breakdown" in capsys.readouterr().out
+
+    def test_spans_lie_in_the_profile_on_its_own_clock(self, tmp_path):
+        """A launcher run under ``jax.profiler``: every recorder span
+        also opened a ``TraceAnnotation``, so the host plane of the
+        ``.xplane.pb`` holds as many ``round`` / ``scalar_fetch`` /
+        ``round.record`` events as ``trace.json``, in the same order
+        and with the round in their stats — the one clock the
+        benchmark's gap labels are read on."""
+        import glob
+
+        from jax.profiler import ProfileData, ProfileOptions
+
+        from fedtorch_tpu.cli import run_experiment
+        run_dir, prof_dir = str(tmp_path / "run"), str(tmp_path / "prof")
+        opts = ProfileOptions()
+        opts.python_tracer_level = 0     # annotations only: a small file
+        jax.profiler.start_trace(prof_dir, profiler_options=opts)
+        try:
+            run_experiment(_cli_cfg(run_dir, rounds=2))
+        finally:
+            jax.profiler.stop_trace()
+        names = ("round", "scalar_fetch", "round.record")
+        doc = json.load(open(os.path.join(run_dir, "trace.json")))
+        recorded = sorted(
+            (e["ts"], e["name"], e["args"]["round"])
+            for e in doc["traceEvents"]
+            if e.get("ph") == "X" and e["name"] in names)
+        prof = ProfileData.from_file(glob.glob(os.path.join(
+            prof_dir, "plugins", "profile", "*", "*.xplane.pb"))[0])
+        # jaxlib's iterator over an event's stats raises a
+        # DeprecationWarning when it is first made (nanobind: no
+        # ``__module__``); as an error it aborts the interpreter from
+        # inside the extension
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", DeprecationWarning)
+            annotated = sorted(
+                (ev.start_ns, ev.name, dict(ev.stats)["round"])
+                for plane in prof.planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events
+                if ev.name in names)
+        assert len(recorded) == 2 * 4    # two record spans a round
+        assert [r[1:] for r in recorded] == [a[1:] for a in annotated]
+        # one clock: the annotations' spacing is the recorder's
+        rec_gap = (recorded[-1][0] - recorded[0][0]) * 1e3      # us -> ns
+        ann_gap = annotated[-1][0] - annotated[0][0]
+        assert abs(rec_gap - ann_gap) < 5e6
+
+    def test_dispatch_and_wait_lie_inside_round(self, tmp_path):
+        from fedtorch_tpu.cli import run_experiment
+        run_dir = str(tmp_path / "run")
+        run_experiment(_cli_cfg(run_dir, rounds=2))
+        doc = json.load(open(os.path.join(run_dir, "trace.json")))
+        by_round = {}
+        for e in doc["traceEvents"]:
+            if e.get("ph") == "X" and e["name"] in (
+                    "round", "round.dispatch", "round.wait"):
+                by_round.setdefault(e["args"]["round"], {})[e["name"]] = (
+                    e["ts"], e["ts"] + e["dur"])
+        assert sorted(by_round) == [0, 1]
+        for spans in by_round.values():
+            r0, r1 = spans["round"]
+            d0, d1 = spans["round.dispatch"]
+            w0, w1 = spans["round.wait"]
+            assert r0 <= d0 <= d1 <= w0 <= w1 <= r1
+        # the set-up spans: the store's placement inside the trainer's
+        # build, the loader's parts inside data.build
+        spans = {e["name"]: (e["ts"], e["ts"] + e["dur"])
+                 for e in doc["traceEvents"] if e.get("ph") == "X"}
+        for child, parent in (("data.h2d", "trainer.build"),
+                              ("data.load", "data.build"),
+                              ("data.partition", "data.build"),
+                              ("data.layout", "data.build")):
+            assert spans[parent][0] <= spans[child][0] \
+                and spans[child][1] <= spans[parent][1], (child, parent)
+        assert "state.init" in spans
+
+    def test_compile_listeners_leave_with_the_telemetry(self, tmp_path):
+        from jax._src import monitoring
+
+        from fedtorch_tpu.cli import run_experiment
+        before = (len(monitoring.get_event_time_span_listeners()),
+                  len(monitoring.get_event_duration_listeners()),
+                  len(monitoring.get_event_listeners()))
+        run_dir = str(tmp_path / "run")
+        run_experiment(_cli_cfg(run_dir, rounds=2))
+        assert before == (
+            len(monitoring.get_event_time_span_listeners()),
+            len(monitoring.get_event_duration_listeners()),
+            len(monitoring.get_event_listeners()))
+        # the run's own programs were seen: round 0 compiled inside
+        # its ``round`` span, under the name JAX gives it
+        doc = json.load(open(os.path.join(run_dir, "trace.json")))
+        evs = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+        r0 = next(e for e in evs if e["name"] == "round"
+                  and e["args"]["round"] == 0)
+        inside = [e for e in evs if e["name"] == "jax.compile"
+                  and r0["ts"] <= e["ts"]
+                  and e["ts"] + e["dur"] <= r0["ts"] + r0["dur"]]
+        assert any("round_fn" in e["args"]["fun"] for e in inside)
 
     def test_byzantine_run_report_and_events(self, tmp_path):
         """ISSUE 9: an attacked run lands the one-shot

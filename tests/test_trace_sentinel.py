@@ -160,3 +160,115 @@ def test_run_rounds_scan_driver_traces_once():
     # the scan body inlines round_fn directly — the per-round jit
     # entry must not have been traced at all by the scan driver
     assert s.count(trainer.trace_name) == 0
+
+
+# -- stage scopes inside the round program ---------------------------------
+# The trace reducers (benchmark/harness/stage_reduce.py) and the
+# documents name these; a stage that loses its scope reads as unstaged
+# time on the chip, so the lowered program is held to the list here.
+# (fed.pre_round and fed.guard hold no operation in a fault-free FedAvg
+# or SCAFFOLD round; the two cases after the matrix arm them.)
+BASE_STAGES = ("fed.select", "fed.gather", "fed.local_steps",
+               "fed.augment", "fed.forward_backward", "fed.opt_step",
+               "fed.wire", "fed.aggregate", "fed.server_step",
+               "fed.scatter", "fed.metrics")
+
+
+def make_image_trainer(algorithm, fusion, plane, fault_kw=None):
+    """A CIFAR-shaped CNN trainer (the fused strategy needs a conv
+    arch), small enough that lowering takes a second or two."""
+    import numpy as np
+
+    from fedtorch_tpu.config import MeshConfig
+    from fedtorch_tpu.data.batching import stack_partitions
+
+    sizes = (24, 9, 17, 24)
+    cfg = ExperimentConfig(
+        data=DataConfig(dataset="cifar10", batch_size=6, augment=True,
+                        data_plane=plane),
+        federated=FederatedConfig(
+            federated=True, num_clients=len(sizes),
+            online_client_rate=0.5, algorithm=algorithm,
+            sync_type="local_step"),
+        model=ModelConfig(arch="cnn", conv_impl="conv", norm="bn"),
+        optim=OptimConfig(lr=0.05, in_momentum=True),
+        train=TrainConfig(local_step=2),
+        mesh=MeshConfig(num_devices=1, client_fusion=fusion),
+        fault=FaultConfig(**(fault_kw or {})),
+    ).finalize()
+    rng = np.random.RandomState(0)
+    feats = rng.randn(sum(sizes), 32, 32, 3).astype(np.float32)
+    labels = rng.randint(0, 10, sum(sizes))
+    off = np.concatenate([[0], np.cumsum(sizes)])
+    parts = [np.arange(off[i], off[i + 1]) for i in range(len(sizes))]
+    model = define_model(cfg, batch_size=cfg.data.batch_size)
+    return FederatedTrainer(cfg, model, make_algorithm(cfg),
+                            stack_partitions(feats, labels, parts))
+
+
+def lowered_round_text(trainer):
+    """The round program's StableHLO with its locations (where the
+    scopes live), lowered against abstract state: nothing executes."""
+    server, clients = jax.eval_shape(trainer.init_state,
+                                     jax.random.key(0))
+    try:
+        programs, primary = trainer.lowered_cost_programs(server, clients)
+        return programs[primary].as_text(debug_info=True)
+    finally:
+        trainer.invalidate_stream()
+
+
+@pytest.mark.parametrize("plane", ["device", "stream"])
+@pytest.mark.parametrize("fusion", ["vmap", "fused"])
+@pytest.mark.parametrize("algorithm", ["fedavg", "scaffold"])
+def test_round_program_carries_every_stage_name(algorithm, fusion, plane):
+    texts = []
+    for _ in range(2):   # one call site: locations are part of the text
+        trainer = make_image_trainer(algorithm, fusion, plane)
+        assert trainer.client_fusion == fusion
+        texts.append(lowered_round_text(trainer))
+    missing = [s for s in BASE_STAGES if s not in texts[0]]
+    assert not missing, f"stages without a scope in the program: {missing}"
+    # a scope is metadata of the program, never a function of the
+    # round or of the build: the same cell lowers to the same text
+    # (compared as a bool: a diff of two 300 KB texts takes minutes)
+    assert (texts[0] == texts[1]) is True
+
+
+def test_guard_stage_is_named_when_faults_are_armed():
+    trainer = make_image_trainer("fedavg", "vmap", "device", fault_kw=dict(
+        client_drop_rate=0.25, nan_inject_rate=0.25, guard_updates=True))
+    assert "fed.guard" in lowered_round_text(trainer)
+
+
+def test_pre_round_stage_is_named_when_the_algorithm_has_the_hook():
+    cfg = ExperimentConfig(
+        data=DataConfig(dataset="synthetic", synthetic_dim=10,
+                        batch_size=16),
+        federated=FederatedConfig(
+            federated=True, num_clients=8, online_client_rate=0.5,
+            algorithm="apfl", sync_type="local_step", personal=True,
+            adaptive_alpha=True),
+        model=ModelConfig(arch="logistic_regression"),
+        optim=OptimConfig(lr=0.05, weight_decay=0.0),
+        train=TrainConfig(local_step=3),
+    ).finalize()
+    data = build_federated_data(cfg)
+    trainer = FederatedTrainer(
+        cfg, define_model(cfg, batch_size=cfg.data.batch_size),
+        make_algorithm(cfg), data.train, val_data=data.val)
+    assert "fed.pre_round" in lowered_round_text(trainer)
+
+
+def test_eval_program_carries_its_stage_name():
+    import numpy as np
+
+    from fedtorch_tpu.parallel.evaluate import lowered_eval_program
+
+    trainer = make_trainer("fedavg")
+    server, _ = jax.eval_shape(trainer.init_state, jax.random.key(0))
+    x = np.zeros((20, 10), np.float32)
+    y = np.zeros((20,), np.int32)
+    text = lowered_eval_program(trainer.model, server.params, x,
+                                y).as_text(debug_info=True)
+    assert "eval.forward" in text
