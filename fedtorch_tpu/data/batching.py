@@ -130,6 +130,49 @@ def round_row_plan(rng_c: jax.Array, size: jnp.ndarray, n_max: int,
     return perm[jnp.arange(num_rows) % jnp.maximum(size, 1)]
 
 
+def gather_client_rows(stores, idx: jnp.ndarray, rows: jnp.ndarray):
+    """``store[idx[:, None], rows]`` for every ``[C, n_max, *sample]``
+    store of the pytree ``stores`` (``idx: [k]`` clients, ``rows:
+    [k, R]`` storage rows of each): the ``[k, R, *sample]`` rows, bit
+    for bit, at a cost proportional to the COHORT's shards and never
+    to ``C x n_max``.
+
+    The one-gather spelling makes the whole store the gather's operand,
+    and on the TPU that operand is what the compiler re-lays-out (the
+    device keeps ``n_max`` minor-most, a row gather wants it major) and
+    re-types (its bf16 propagation moves the model's input cast up
+    through every data movement, optimization barriers included, to the
+    program argument): a pass over all ``C x n_max`` samples each round.
+    So the rows are taken one cohort member at a time, in one loop for
+    all stores: take that client's shard off the leading axis, view it
+    flat ``[n_max, features]``, gather its R rows. One shard is
+    re-laid-out at a time, and floating stores move as raw bits
+    (same-width unsigned integers), which no precision pass re-types:
+    the cast lands on the ``[k, R]`` rows, where the model asked for it.
+
+    The shard is taken with a one-index gather, not a
+    ``dynamic_slice``: on one device they compile to the same slice,
+    but where the client axis is split over devices the partitioner
+    serves a gather from the shard's owner (one all-reduce of a shard)
+    and answers a dynamic slice by all-gathering the store."""
+    def client_rows(client):
+        c, r = client
+
+        def take(store):
+            flat = store[c[None]].reshape((store.shape[1], -1))
+            if not jnp.issubdtype(store.dtype, jnp.floating):
+                return flat[r]
+            bits = jnp.dtype(f"uint{8 * store.dtype.itemsize}")
+            return jax.lax.bitcast_convert_type(
+                jax.lax.bitcast_convert_type(flat, bits)[r], store.dtype)
+        return jax.tree.map(take, stores)
+
+    out = jax.lax.map(client_rows, (idx, rows))
+    return jax.tree.map(
+        lambda o, store: o.reshape(rows.shape + store.shape[2:]),
+        out, stores)
+
+
 def take_batch(data_x: jnp.ndarray, data_y: jnp.ndarray,
                perm: jnp.ndarray, size: jnp.ndarray,
                step_in_epoch: jnp.ndarray, batch_size: int):

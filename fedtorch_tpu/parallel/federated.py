@@ -76,8 +76,8 @@ from fedtorch_tpu.core.state import (
     tree_bytes, tree_sub, tree_where, tree_zeros_like,
 )
 from fedtorch_tpu.data.batching import (
-    VAL_FOLD, ClientData, epoch_permutation, pad_client_axis,
-    round_row_plan, take_batch,
+    VAL_FOLD, ClientData, epoch_permutation, gather_client_rows,
+    pad_client_axis, round_row_plan, take_batch,
 )
 from fedtorch_tpu.data.streaming import (
     HostClientStore, MmapClientStore, RoundFeed, StreamFeedProducer,
@@ -513,10 +513,14 @@ class FederatedTrainer:
                  data: ClientData, val_data: Optional[ClientData] = None):
         """Device-resident data plane: the full ``[C, n_max, ...]``
         store is a program input and the round's online rows are
-        gathered IN-program (gather_mode 'batch'/'shard'). The
-        streaming twin (:meth:`round_stream_fn`) receives the same
-        rows as a host-packed feed; both funnel into
-        :meth:`_round_core`, so the two planes cannot diverge."""
+        gathered IN-program. 'batch' takes them one cohort member at a
+        time (``gather_client_rows``: that client's shard off the
+        leading axis, its ``K*B`` rows from the shard's flat view), so
+        the program reads k shards and never the store; 'shard' takes
+        the k whole shards and selects rows per step. The streaming
+        twin (:meth:`round_stream_fn`) receives the same rows as a
+        host-packed feed; both funnel into :meth:`_round_core`, so the
+        two planes cannot diverge."""
         alg = self.algorithm
         K, B, C = self.local_steps, self.batch_size, self.num_clients
         batch_mode = self.gather_mode == "batch"
@@ -537,7 +541,8 @@ class FederatedTrainer:
             on_sizes = jnp.take(data.sizes, idx)
             rngs = jax.random.split(rng_train, self.k_dispatch)
             if batch_mode:
-                # move only the touched rows: [k, K*B, ...].
+                # the [k, K*B] storage rows the round trains on, which
+                # gather_client_rows then takes shard by shard.
                 # round_row_plan (data/batching.py) is the SHARED
                 # batch-order definition — the host feed packer calls
                 # the same function, which is what makes the streaming
@@ -554,8 +559,8 @@ class FederatedTrainer:
 
         with jax.named_scope("fed.gather"):
             if batch_mode:
-                on_x = data.x[idx[:, None], rows]
-                on_y = data.y[idx[:, None], rows]
+                on_x, on_y = gather_client_rows(
+                    (data.x, data.y), idx, rows)
             else:
                 # whole shards; rows are selected per step inside the
                 # vmap so nothing larger than the shard is ever
@@ -582,8 +587,8 @@ class FederatedTrainer:
                 on_vx, on_vy = on_x[:, :1], on_y[:, :1]
                 on_vsizes = jnp.ones_like(on_sizes)
             elif val_batch_mode:
-                on_vx = val_data.x[idx[:, None], vrows]
-                on_vy = val_data.y[idx[:, None], vrows]
+                on_vx, on_vy = gather_client_rows(
+                    (val_data.x, val_data.y), idx, vrows)
             else:
                 on_vx = jnp.take(val_data.x, idx, axis=0)
                 on_vy = jnp.take(val_data.y, idx, axis=0)
@@ -591,8 +596,9 @@ class FederatedTrainer:
             # the pre_round hook always sees each client's first B
             # storage-order rows, independent of gather mode (so mode
             # choice cannot change hook numerics, e.g. APFL's alpha)
-            pre_x = data.x[idx[:, None], jnp.arange(B)[None, :]]
-            pre_y = data.y[idx[:, None], jnp.arange(B)[None, :]]
+            pre_x, pre_y = gather_client_rows(
+                (data.x, data.y), idx,
+                jnp.broadcast_to(jnp.arange(B), idx.shape + (B,)))
         return self._round_core(
             server, clients, idx, on_x, on_y, on_vx, on_vy, on_sizes,
             on_vsizes, pre_x, pre_y, rng_round, rngs,

@@ -73,7 +73,7 @@ import jax
 import jax.numpy as jnp
 
 from fedtorch_tpu.algorithms.base import FedAlgorithm
-from fedtorch_tpu.data.batching import round_row_plan
+from fedtorch_tpu.data.batching import gather_client_rows, round_row_plan
 from fedtorch_tpu.parallel.fusion import fusion_supported
 
 # the three axes; tests and the chaos-suite matrix enumerate these so a
@@ -472,10 +472,11 @@ class RoundProgramBuilder:
                 on_sizes = jnp.take(data.sizes, idx)
                 rows = jax.vmap(lambda r, s: round_row_plan(
                     r, s, data.x.shape[1], K * B))(rngs, on_sizes)
-                on_x = data.x[idx[:, None], rows]
-                on_y = data.y[idx[:, None], rows]
-                pre_x = data.x[idx[:, None], jnp.arange(B)[None, :]]
-                pre_y = data.y[idx[:, None], jnp.arange(B)[None, :]]
+                on_x, on_y = gather_client_rows(
+                    (data.x, data.y), idx, rows)
+                pre_x, pre_y = gather_client_rows(
+                    (data.x, data.y), idx,
+                    jnp.broadcast_to(jnp.arange(B), idx.shape + (B,)))
                 return core(server, clients, jobs, on_x, on_y, pre_x,
                             pre_y, on_sizes, rngs, rng_round)
         else:

@@ -1,0 +1,88 @@
+"""What the TPU's compiler makes of the round program, compiled here for
+a described v5e chip (no chip attached, nothing runs).
+
+The CPU cannot show it: the TPU pipeline re-types and re-lays-out
+operands (its bf16 propagation hoists the model's input cast through
+every data movement up to the program argument; its layout assignment
+wants a gather's operand row-major) and the device keeps a
+``[C, n_max, 32, 32, 3]`` store with ``n_max`` minor-most. With the
+whole store as a gather's operand that was one cast-and-copy of all
+``C x n_max`` images every round (PERF.md section 6, PR 25).
+
+Keep TPU compiles in THIS file only, behind the fixture: one process at
+a time may load the TPU's library, and the workers of a parallel run
+each import every test file.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from fedtorch_tpu.algorithms import make_algorithm
+from fedtorch_tpu.config import (
+    DataConfig, ExperimentConfig, FederatedConfig, MeshConfig, ModelConfig,
+    OptimConfig, TrainConfig,
+)
+from fedtorch_tpu.data.batching import ClientData
+from fedtorch_tpu.models import define_model
+from fedtorch_tpu.parallel import FederatedTrainer
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_compiled_round_holds_no_operation_of_the_stores_size(one_chip):
+    """bf16 compute, flip-and-crop, 'batch' gather: no instruction of
+    the compiled program produces an array of the store's shape, in any
+    type or layout — the store passes through as the argument it is."""
+    C, n_max = 4, 256
+    cfg = ExperimentConfig(
+        data=DataConfig(dataset="cifar10", batch_size=6, augment=True),
+        federated=FederatedConfig(
+            federated=True, num_clients=C, online_client_rate=0.5,
+            algorithm="fedavg", sync_type="local_step"),
+        model=ModelConfig(arch="cnn", conv_impl="conv", norm="bn"),
+        optim=OptimConfig(lr=0.05),
+        train=TrainConfig(local_step=2),
+        mesh=MeshConfig(num_devices=1, compute_dtype="bfloat16"),
+    ).finalize()
+    # the trainer is built on a small store of the same rank; the
+    # program is compiled against the abstract one below
+    small = ClientData(x=np.zeros((C, 16, 32, 32, 3), np.float32),
+                       y=np.zeros((C, 16), np.int32),
+                       sizes=np.full((C,), 16, np.int32))
+    trainer = FederatedTrainer(
+        cfg, define_model(cfg, batch_size=cfg.data.batch_size),
+        make_algorithm(cfg), small)
+    assert trainer.gather_mode == "batch"
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    server, clients = jax.eval_shape(trainer.init_state, jax.random.key(0))
+    data = on_chip(ClientData(
+        x=jax.ShapeDtypeStruct((C, n_max, 32, 32, 3), jnp.float32),
+        y=jax.ShapeDtypeStruct((C, n_max), jnp.int32),
+        sizes=jax.ShapeDtypeStruct((C,), jnp.int32)))
+    text = jax.jit(trainer.round_fn).lower(
+        on_chip(server), on_chip(clients), data).compile().as_text()
+    made = re.compile(
+        rf"%[\w.\-]+ = \w+\[{C},{n_max},32,32,3\]\S* ([\w\-]+)\(")
+    passes_through = {"parameter", "get-tuple-element"}
+    store_sized = [line.strip()[:200] for line in text.splitlines()
+                   for m in [made.search(line)]
+                   if m and m.group(1) not in passes_through]
+    assert "fed.gather" in text   # the stage is there to be judged
+    assert not store_sized, "\n".join(store_sized)
