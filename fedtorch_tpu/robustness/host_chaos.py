@@ -23,7 +23,7 @@ and the bitwise-trajectory acceptance bar is meaningful.
 Like the telemetry hub, the injector is an installable active
 instance: library code calls the module-level helpers
 (:func:`maybe_raise`, :func:`maybe_raise_io`, :func:`maybe_delay`,
-:func:`maybe_truncate`), which no-op when nothing is installed. The
+:func:`torn_length`), which no-op when nothing is installed. The
 telemetry writers cannot import this package (they must stay
 jax-free), so :meth:`HostFaultInjector.install` registers the check
 hook with ``telemetry.faults`` instead.
@@ -181,10 +181,12 @@ def maybe_delay(seam: str) -> None:
         time.sleep(inj.delay_s)
 
 
-def maybe_truncate(seam: str, data: bytes) -> bytes:
-    """Torn-write seams: hand back a truncated payload that LANDS —
-    simulating a partial write the OS reported complete. The
-    checkpoint integrity frame exists to catch exactly this."""
-    if fire(seam) and len(data) > 1:
-        return data[:len(data) // 2]
-    return data
+def torn_length(seam: str, size: int) -> int:
+    """Torn-write seams: how many of a write's ``size`` bytes LAND —
+    the first half where the seam fires, simulating a partial write
+    the OS reported complete. The checkpoint integrity frame exists to
+    catch exactly this. (A length, not the bytes: the caller writes a
+    frame in parts and never holds it as one object.)"""
+    if fire(seam) and size > 1:
+        return size // 2
+    return size

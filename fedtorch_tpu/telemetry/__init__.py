@@ -62,9 +62,11 @@ from fedtorch_tpu.telemetry.spans import (  # noqa: F401
 )
 
 
-def span(name: str, **args):
+def span(name: str, /, **args):
     """Module-level span hook: records on the active run's recorder,
-    or returns the shared no-op context when telemetry is off."""
+    or returns the shared no-op context when telemetry is off. The
+    span's own name is positional-only, so ``name`` is free as an arg
+    (``checkpoint.file_write`` carries the file's)."""
     t = get_active()
     if t is None:
         return NULL_SPAN

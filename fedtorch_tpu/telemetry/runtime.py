@@ -144,7 +144,7 @@ class Telemetry:
               "repeated write failures", file=sys.stderr, flush=True)
 
     # -- recording ------------------------------------------------------
-    def span(self, name: str, **args):
+    def span(self, name: str, /, **args):
         if self.spans is None:
             return NULL_SPAN
         return self.spans.span(name, **args)

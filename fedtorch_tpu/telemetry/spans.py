@@ -54,6 +54,12 @@ class _Span:
         self._t0 = time.perf_counter_ns()
         return self
 
+    def note(self, **args) -> None:
+        """Args that are known only inside the span (an outcome),
+        recorded with it at exit. The profiler's annotation keeps the
+        args it was opened with."""
+        self.args = {**(self.args or {}), **args}
+
     def __exit__(self, *exc) -> None:
         self._rec._record(self.name, self._t0, time.perf_counter_ns(),
                           self.args)
@@ -68,6 +74,9 @@ class _NullSpan:
 
     def __enter__(self):
         return self
+
+    def note(self, **args) -> None:
+        return None
 
     def __exit__(self, *exc):
         return None
@@ -105,7 +114,7 @@ class SpanRecorder:
         self._names: Dict[int, str] = {}
 
     # -- recording ------------------------------------------------------
-    def span(self, name: str, **args) -> _Span:
+    def span(self, name: str, /, **args) -> _Span:
         return _Span(self, name, args or None)
 
     def _record(self, name, t0, t1, args) -> None:

@@ -9,8 +9,10 @@ and round counter — is serialized, so a resumed run continues exactly.
 
 * run-folder naming from hyperparams + timestamp
   (get_checkpoint_folder_name, checkpoint.py:12-45);
-* best-accuracy copy (``model_best``) and optional per-round keeps
-  (save_some_models, checkpoint.py:68-82);
+* best-accuracy copy (``model_best``: a hard link to the checkpoint
+  just written, a written copy where the file system has no links) and
+  optional per-round keeps, files of their own (save_some_models,
+  checkpoint.py:68-82);
 * resume with config-compatibility validation (same dataset/batch size,
   new num_epochs >= old — checkpoint.py:93-139).
 """
@@ -161,9 +163,15 @@ _CKPT_DIGEST_OFF = _CKPT_LEN_OFF + 8
 _CKPT_HEADER = _CKPT_DIGEST_OFF + 32
 
 
-def _frame_payload(payload: bytes) -> bytes:
+def _frame_header(payload: bytes) -> bytes:
     return (_CKPT_MAGIC + len(payload).to_bytes(8, "big")
-            + hashlib.sha256(payload).digest() + payload)
+            + hashlib.sha256(payload).digest())
+
+
+def _frame_payload(payload: bytes) -> bytes:
+    """The frame as one object. The writer never builds it (a second
+    copy of the payload): it writes header and payload as two parts."""
+    return _frame_header(payload) + payload
 
 
 def _frame_want_len(head: bytes) -> int:
@@ -191,11 +199,12 @@ def _unframe_payload(blob: bytes):
     return payload, None
 
 
-def _atomic_write(path: str, data: bytes) -> None:
+def _atomic_write(path: str, *parts: bytes) -> None:
     """tmp + fsync + rename so a crash (including power loss — without
     the fsync, delayed allocation could rename before the data blocks
     hit disk) never corrupts the previous checkpoint. The reference
-    overwrites in place (checkpoint.py:72).
+    overwrites in place (checkpoint.py:72). The file holds ``parts``
+    one after the other.
 
     Self-healing (docs/robustness.md "Host plane"): each write runs
     under the bounded 'ckpt.write' retry policy — a transient
@@ -211,11 +220,61 @@ def _atomic_write(path: str, data: bytes) -> None:
         host_chaos.maybe_raise_io("ckpt.write")
         tmp = path + ".tmp"
         with open(tmp, "wb") as f:
-            f.write(data)
+            for part in parts:
+                f.write(part)
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, path)
-    host_recovery.retry_io(attempt, "ckpt.write")
+    with telemetry.span("checkpoint.file_write",
+                        name=os.path.basename(path),
+                        bytes=sum(len(p) for p in parts)):
+        host_recovery.retry_io(attempt, "ckpt.write")
+
+
+def _write_frame(path: str, frame: Tuple[bytes, bytes]) -> None:
+    """One payload file of its own: ``frame`` = (header, payload),
+    written, fsynced and renamed. The 'ckpt.torn' drill seam truncates
+    individual payload writes (each file written here draws
+    independently) but lets the rename land — the torn frame the
+    integrity record exists to catch at resume/GC time."""
+    from fedtorch_tpu.robustness import host_chaos  # lazy: see above
+    header, payload = frame
+    lands = host_chaos.torn_length("ckpt.torn", len(header) + len(payload))
+    _atomic_write(path, header[:lands],
+                  memoryview(payload)[:max(lands - len(header), 0)])
+
+
+def _link_or_write(src: str, path: str, frame: Tuple[bytes, bytes]
+                   ) -> None:
+    """Give ``path`` the durable bytes of ``src``, the payload file
+    just written, fsynced and renamed in the same directory: a hard
+    link under a tmp name, renamed over ``path``. No checkpoint is ever
+    written in place (the next save renames a NEW inode over ``src``),
+    so ``path`` keeps these bytes for as long as it keeps its name.
+    Where the file system refuses the link (any ``OSError`` of
+    ``os.link``: EPERM, ENOTSUP, EMLINK or EXDEV on FUSE and
+    object-store mounts), ``path`` is written as a file of its own."""
+    from fedtorch_tpu.robustness import host_chaos, host_recovery
+
+    def attempt() -> bool:
+        host_chaos.maybe_raise_io("ckpt.write")
+        tmp = path + ".tmp"
+        try:  # a crashed writer's leftover would fail the link (EEXIST)
+            os.remove(tmp)
+        except FileNotFoundError:
+            pass
+        try:
+            os.link(src, tmp)
+        except OSError:
+            return False
+        os.replace(tmp, path)
+        return True
+    with telemetry.span("checkpoint.link",
+                        name=os.path.basename(path)) as sp:
+        linked = host_recovery.retry_io(attempt, "ckpt.write")
+        sp.note(fallback=not linked)
+    if not linked:
+        _write_frame(path, frame)
 
 
 _ROUND_KEEP_RE = re.compile(r"^checkpoint_r(\d+)\.ckpt$")
@@ -305,28 +364,31 @@ def _write_checkpoint(directory: str, host_state, meta: dict,
                       keep_last_n: int = 0) -> str:
     """Serialize + write an already-host-resident snapshot (the worker
     half of both the sync and async paths)."""
-    from fedtorch_tpu.robustness import host_chaos  # lazy: see above
     os.makedirs(directory, exist_ok=True)
     # framed payload: resume verifies the in-file length + digest BEFORE
     # trying to deserialize, so a torn/truncated/bit-rotted file is
     # detected cleanly instead of surfacing as an opaque msgpack error.
-    # The 'ckpt.torn' drill seam truncates individual payload writes
-    # (each file draws independently) but lets the rename land — the
-    # torn frame the integrity record exists to catch at resume/GC time
-    payload = _frame_payload(serialization.to_bytes(host_state))
+    # Serialized and hashed ONCE, however many names the save gets
+    with telemetry.span("checkpoint.serialize"):
+        payload = serialization.to_bytes(host_state)
+        frame = (_frame_header(payload), payload)
     path = os.path.join(directory, "checkpoint.ckpt")
-    _atomic_write(path, host_chaos.maybe_truncate("ckpt.torn", payload))
+    _write_frame(path, frame)
     meta_bytes = json.dumps(meta, default=str).encode()
     _atomic_write(os.path.join(directory, "checkpoint.json"), meta_bytes)
     if is_best:
-        _atomic_write(os.path.join(directory, "model_best.ckpt"),
-                      host_chaos.maybe_truncate("ckpt.torn", payload))
+        # the same durable bytes under a second name: a link, not a
+        # second write + fsync of the payload
+        _link_or_write(path, os.path.join(directory, "model_best.ckpt"),
+                       frame)
         _atomic_write(os.path.join(directory, "model_best.json"),
                       meta_bytes)
     if save_all or round_idx in save_some_rounds:
-        _atomic_write(
+        # the per-round keeps stay files of their own: maybe_resume
+        # falls back to them when the latest frame is torn
+        _write_frame(
             os.path.join(directory, f"checkpoint_r{round_idx}.ckpt"),
-            host_chaos.maybe_truncate("ckpt.torn", payload))
+            frame)
         collect_round_keeps(directory, keep_last_n)
     return path
 

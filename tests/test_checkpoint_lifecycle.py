@@ -3,12 +3,18 @@
 cases — a corrupt ``checkpoint.json`` beside a valid per-round keep,
 and the heavily-padded template graft (``num_clients`` < device
 count, the mesh-shape-independence contract the degraded-pod resume
-rides on)."""
+rides on). ISSUE 28: a save's payload goes to disk once —
+``model_best.ckpt`` is a hard link to the ``checkpoint.ckpt`` just
+written, the frame is written in two parts, the keeps stay files of
+their own."""
+import errno
 import json
 import os
 
 import jax
+import numpy as np
 import pytest
+from flax import serialization
 
 from fedtorch_tpu.algorithms import make_algorithm
 from fedtorch_tpu.config import (
@@ -21,6 +27,7 @@ from fedtorch_tpu.parallel import FederatedTrainer
 from fedtorch_tpu.utils import (
     init_checkpoint_dir, maybe_resume, save_checkpoint,
 )
+from fedtorch_tpu.utils import checkpoint as ckpt_mod
 from fedtorch_tpu.utils.checkpoint import collect_round_keeps
 
 
@@ -189,3 +196,256 @@ class TestResumeEdgeCases:
             jax.block_until_ready(s3.params)
             tail.append(repr(float(m.train_loss.sum())))
         assert tail == fingerprints[2:]
+
+
+# -- one payload write a save ------------------------------------------------
+def _read(d, name):
+    with open(os.path.join(d, name), "rb") as f:
+        return f.read()
+
+
+def _restored_round(blob, cfg, server, clients):
+    """Verify ``blob`` the way resume does (frame, then flax) and hand
+    back the round it holds."""
+    payload, bad = ckpt_mod._unframe_payload(blob)
+    assert bad is None, bad
+    template = {"server": ckpt_mod._unkey(server),
+                "clients": ckpt_mod._strip_padding(
+                    clients, cfg.federated.num_clients)}
+    return int(serialization.from_bytes(template, payload)["server"].round)
+
+
+def _spans(tel):
+    """The recorder's spans as (name, start, end, args)."""
+    return [(e["name"], e["ts"], e["ts"] + e["dur"], e.get("args") or {})
+            for e in tel.spans.to_trace_events() if e.get("ph") == "X"]
+
+
+@pytest.fixture
+def recorder(tmp_path):
+    from fedtorch_tpu.telemetry import Telemetry
+    tel = Telemetry(str(tmp_path / "telemetry")).install()
+    try:
+        yield tel
+    finally:
+        tel.close()
+
+
+class TestPayloadGoesToDiskOnce:
+    def test_best_is_a_link_to_the_latest_and_both_verify(
+            self, tmp_path, recorder):
+        d = str(tmp_path / "ck")
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        os.makedirs(d)
+        # a crashed writer's leftover must not refuse the link (EEXIST)
+        with open(os.path.join(d, "model_best.ckpt.tmp"), "wb") as f:
+            f.write(b"stale")
+        save_checkpoint(d, server, clients, cfg, 0.5, is_best=True)
+        latest, best = (os.path.join(d, n)
+                        for n in ("checkpoint.ckpt", "model_best.ckpt"))
+        assert os.path.samefile(latest, best)
+        assert os.stat(best).st_nlink == 2
+        for name in ("checkpoint.ckpt", "model_best.ckpt"):
+            assert _restored_round(_read(d, name), cfg, server,
+                                   clients) == 1
+        assert sorted(os.listdir(d)) == [
+            "checkpoint.ckpt", "checkpoint.json", "model_best.ckpt",
+            "model_best.json"]          # no .tmp left behind
+        assert _read(d, "model_best.json") == _read(d, "checkpoint.json")
+
+        # what the save recorded: one serialization, one payload file,
+        # the link; every sub-span inside ``checkpoint.write``
+        spans = _spans(recorder)
+        (_, w0, w1, wargs), = [s for s in spans
+                               if s[0] == "checkpoint.write"]
+        assert wargs == {"round": 1}
+        inner = [s for s in spans if s[0] in (
+            "checkpoint.serialize", "checkpoint.file_write",
+            "checkpoint.link")]
+        assert all(w0 <= s[1] and s[2] <= w1 for s in inner)
+        assert [s[0] for s in inner].count("checkpoint.serialize") == 1
+        size = os.path.getsize(latest)
+        assert [(s[3]["name"], s[3]["bytes"]) for s in inner
+                if s[0] == "checkpoint.file_write"] == [
+            ("checkpoint.ckpt", size),
+            ("checkpoint.json", len(_read(d, "checkpoint.json"))),
+            ("model_best.json", len(_read(d, "model_best.json")))]
+        assert [s[3] for s in inner if s[0] == "checkpoint.link"] == [
+            {"name": "model_best.ckpt", "fallback": False}]
+
+    def test_later_save_leaves_model_best_its_round_bitwise(
+            self, tmp_path):
+        """No checkpoint is written in place: the next save renames a
+        new inode over ``checkpoint.ckpt`` and ``model_best.ckpt``
+        keeps the old one."""
+        d = str(tmp_path)
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        save_checkpoint(d, server, clients, cfg, 0.5, is_best=True)
+        best_then = _read(d, "model_best.ckpt")
+        inode_then = os.stat(os.path.join(d, "model_best.ckpt")).st_ino
+        s2, c2, _ = trainer.run_round(server, clients)
+        save_checkpoint(d, s2, c2, cfg, 0.5, is_best=False)
+        latest, best = (os.path.join(d, n)
+                        for n in ("checkpoint.ckpt", "model_best.ckpt"))
+        assert not os.path.samefile(latest, best)
+        assert os.stat(best).st_ino == inode_then
+        assert os.stat(latest).st_ino != inode_then
+        assert os.stat(best).st_nlink == 1
+        assert _read(d, "model_best.ckpt") == best_then
+        assert _restored_round(best_then, cfg, s2, c2) == 1
+        assert _restored_round(_read(d, "checkpoint.ckpt"), cfg, s2,
+                               c2) == 2
+        # and a later best save moves the name to the new latest
+        save_checkpoint(d, s2, c2, cfg, 0.6, is_best=True)
+        assert os.path.samefile(latest, best)
+        assert _restored_round(_read(d, "model_best.ckpt"), cfg, s2,
+                               c2) == 2
+
+    def test_refused_link_falls_back_to_a_written_copy(
+            self, tmp_path, monkeypatch, recorder):
+        d = str(tmp_path / "ck")
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+
+        def refuse(src, dst, **kw):
+            raise OSError(errno.EPERM, "links not permitted here")
+
+        monkeypatch.setattr(os, "link", refuse)
+        save_checkpoint(d, server, clients, cfg, 0.5, is_best=True)
+        latest, best = (os.path.join(d, n)
+                        for n in ("checkpoint.ckpt", "model_best.ckpt"))
+        assert not os.path.samefile(latest, best)
+        assert _read(d, "model_best.ckpt") == _read(d, "checkpoint.ckpt")
+        assert _restored_round(_read(d, "model_best.ckpt"), cfg, server,
+                               clients) == 1
+        assert not os.path.exists(best + ".tmp")
+        spans = _spans(recorder)
+        assert [s[3] for s in spans if s[0] == "checkpoint.link"] == [
+            {"name": "model_best.ckpt", "fallback": True}]
+        assert [s[3]["name"] for s in spans
+                if s[0] == "checkpoint.file_write"] == [
+            "checkpoint.ckpt", "checkpoint.json", "model_best.ckpt",
+            "model_best.json"]
+
+    @pytest.mark.parametrize("torn", [False, True])
+    def test_file_bytes_are_the_frame_exactly(self, tmp_path, torn):
+        """Written in two parts, the file is byte for byte what the
+        one-object frame was — and its first half where the 'ckpt.torn'
+        drill fires (on the latest, hence on its link; the keep draws
+        for itself)."""
+        from fedtorch_tpu.robustness import host_chaos
+        d = str(tmp_path)
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        want = ckpt_mod._frame_payload(serialization.to_bytes(
+            ckpt_mod._snapshot(server, clients, cfg)))
+        assert want[:6] == b"FTCK1\x00" and len(want) > 46
+        inj = host_chaos.HostFaultInjector(
+            ("ckpt.torn",), rate=1.0, max_fires=1).install() \
+            if torn else None
+        try:
+            save_checkpoint(d, server, clients, cfg, 0.5, is_best=True,
+                            save_all=True)
+        finally:
+            if inj is not None:
+                inj.uninstall()
+        landed = want[:len(want) // 2] if torn else want
+        assert _read(d, "checkpoint.ckpt") == landed
+        assert _read(d, "model_best.ckpt") == landed
+        assert _read(d, "checkpoint_r1.ckpt") == want
+        if torn:    # the frame says so, and resume takes the keep
+            assert ckpt_mod._unframe_payload(landed)[1] is not None
+            s2, c2 = trainer.init_state(jax.random.key(0))
+            with pytest.warns(RuntimeWarning, match="per-round keep"):
+                s3, _, _, resumed = maybe_resume(d, s2, c2, cfg, None)
+            assert resumed and int(jax.device_get(s3.round)) == 1
+
+    def test_checkpoint_written_by_the_parents_code_resumes(
+            self, tmp_path):
+        """The on-disk format did not move: a file made the old way
+        (the frame as one object, one plain write) resumes."""
+        d = str(tmp_path)
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        with open(os.path.join(d, "checkpoint.ckpt"), "wb") as f:
+            f.write(ckpt_mod._frame_payload(serialization.to_bytes(
+                ckpt_mod._snapshot(server, clients, cfg))))
+        with open(os.path.join(d, "checkpoint.json"), "w") as f:
+            json.dump(ckpt_mod._meta_for(cfg, 1, 0.25), f, default=str)
+        s2, c2 = trainer.init_state(jax.random.key(0))
+        s3, c3, best, resumed = maybe_resume(d, s2, c2, cfg, None)
+        assert resumed and best == 0.25
+        assert int(jax.device_get(s3.round)) == 1
+        for got, want in zip(jax.tree.leaves(s3.params),
+                             jax.tree.leaves(server.params)):
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want))
+
+    def test_every_payload_inode_is_fsynced_once_before_its_rename(
+            self, tmp_path, monkeypatch):
+        d = str(tmp_path)
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        log = []
+        real_fsync, real_replace = os.fsync, os.replace
+
+        def fsync(fd):
+            log.append(("fsync", os.fstat(fd).st_ino))
+            return real_fsync(fd)
+
+        def replace(src, dst, **kw):
+            log.append(("publish", os.stat(src).st_ino,
+                        os.path.basename(dst)))
+            return real_replace(src, dst, **kw)
+
+        monkeypatch.setattr(os, "fsync", fsync)
+        monkeypatch.setattr(os, "replace", replace)
+        save_checkpoint(d, server, clients, cfg, 0.5, is_best=True,
+                        save_all=True)
+        published = [e for e in log if e[0] == "publish"]
+        assert [e[2] for e in published] == [
+            "checkpoint.ckpt", "checkpoint.json", "model_best.ckpt",
+            "model_best.json", "checkpoint_r1.ckpt"]
+        for i, e in enumerate(log):
+            if e[0] == "publish":   # durable before it gets its name
+                assert ("fsync", e[1]) in log[:i], e
+        payload_inodes = {e[1] for e in published
+                          if e[2].endswith(".ckpt")}
+        # three names, two inodes: the latest (and its link), the keep
+        assert len(payload_inodes) == 2
+        for ino in payload_inodes:
+            assert log.count(("fsync", ino)) == 1
+        assert sum(e[0] == "fsync" for e in log) == 4   # + two metas
+
+    def test_save_all_keeps_are_files_of_their_own(self, tmp_path):
+        """``maybe_resume`` falls back to the keeps when the latest
+        frame is torn: they share no inode with it."""
+        d = str(tmp_path)
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        save_checkpoint(d, server, clients, cfg, 0.5, is_best=True,
+                        save_all=True)
+        latest, best, keep = (os.path.join(d, n) for n in (
+            "checkpoint.ckpt", "model_best.ckpt", "checkpoint_r1.ckpt"))
+        assert os.path.samefile(latest, best)
+        assert not os.path.samefile(latest, keep)
+        assert os.stat(keep).st_nlink == 1
+        assert _read(d, "checkpoint_r1.ckpt") == _read(d,
+                                                       "checkpoint.ckpt")
+
+    def test_async_writer_links_too(self, tmp_path):
+        from fedtorch_tpu.utils.checkpoint import AsyncCheckpointer
+        d = str(tmp_path)
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        saver = AsyncCheckpointer()
+        try:
+            saver.save(d, server, clients, cfg, 0.5, is_best=True)
+            saver.wait()
+        finally:
+            saver.close()
+        assert saver.stats()["ckpt_writes"] == 1.0
+        assert os.path.samefile(os.path.join(d, "checkpoint.ckpt"),
+                                os.path.join(d, "model_best.ckpt"))

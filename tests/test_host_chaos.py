@@ -82,7 +82,7 @@ class TestInjector:
         assert host_chaos.get_active() is None
         host_chaos.maybe_raise("stream.gather")  # no raise
         host_chaos.maybe_raise_io("ckpt.write")
-        assert host_chaos.maybe_truncate("ckpt.torn", b"abcd") == b"abcd"
+        assert host_chaos.torn_length("ckpt.torn", 4) == 4
 
     def test_installed_helpers_raise_the_real_classes(self):
         inj = host_chaos.HostFaultInjector(
@@ -95,8 +95,7 @@ class TestInjector:
                 host_chaos.maybe_raise_io("ckpt.write")
             import errno
             assert ei.value.errno == errno.ENOSPC
-            torn = host_chaos.maybe_truncate("ckpt.torn", b"x" * 100)
-            assert torn == b"x" * 50
+            assert host_chaos.torn_length("ckpt.torn", 100) == 50
         finally:
             inj.uninstall()
 

@@ -491,7 +491,8 @@ class TestRunDirAndReport:
         span_names = {e["name"] for e in doc["traceEvents"]}
         assert {"round", "scalar_fetch", "eval",
                 "checkpoint.snapshot", "checkpoint.write",
-                "data.build"} <= span_names
+                "checkpoint.serialize", "checkpoint.file_write",
+                "checkpoint.link", "data.build"} <= span_names
 
         # pillar 3: health reached 'complete' at the final round
         h = read_health(run_dir)
