@@ -93,8 +93,8 @@ def write_cifar10_batches(root: str, seed: int, train_per_batch: int,
 
 def north_star_argv(size: dict, data_dir: str, run_dir: str) -> list:
     """README's north-star command (``-fs local_step`` pins K; ``-lg
-    0.1`` is bench.py's learning rate — the launcher's default of 1.0 is
-    the reference's, tuned for its MLPs)."""
+    0.1`` is the benchmark configuration's learning rate — the
+    launcher's default of 1.0 is the reference's, tuned for its MLPs)."""
     return ["-f", "-ft", "fedavg", "-d", "cifar10", "-a", size["arch"],
             "-n", str(size["clients"]), "-b", str(size["batch"]),
             "-c", str(size["rounds"]), "-fs", "local_step",

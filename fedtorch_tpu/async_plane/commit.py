@@ -251,7 +251,7 @@ class AsyncFederatedTrainer(FederatedTrainer):
         sched = AsyncSchedule(key_data, key_impl, start_commit=commit0,
                               **self._schedule_args())
         # visible to schedule_stats / commit_times consumers on this
-        # plane too (scripts/async_bench.py reads both); the producer
+        # plane too; the producer
         # thread owns the simulation, so counters may run up to the
         # prefetch depth AHEAD of the last consumed commit
         self._sched = sched
@@ -366,9 +366,8 @@ class AsyncFederatedTrainer(FederatedTrainer):
         """Stream gauges (when on that plane) plus the async commit
         plane's: buffer occupancy, scheduler dispatch/straggler/ring-
         clamp counters, and the commit rate in virtual time units
-        (commits so far / last commit's virtual clock — the quantity
-        ASYNC_AB.json compares against the sync round clock). All host
-        counters; zero device syncs."""
+        (commits so far / last commit's virtual clock, comparable with
+        the sync round clock). All host counters; zero device syncs."""
         out = super().telemetry_gauges()
         sched = self._sched
         if sched is None:

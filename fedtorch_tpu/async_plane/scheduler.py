@@ -321,9 +321,8 @@ def simulate_sync_round_times(key_data, key_impl, *, rounds: int,
                               jitter: float = 0.25) -> np.ndarray:
     """Virtual duration of each SYNC round under the same delay model:
     the server blocks on all k online clients, so a round costs the MAX
-    of its k dispatch delays — the straggler sets the round clock. The
-    async A/B (scripts/async_bench.py) compares this against
-    :attr:`AsyncSchedule.commit_times`."""
+    of its k dispatch delays — the straggler sets the round clock.
+    Comparable with :attr:`AsyncSchedule.commit_times`."""
     with _cpu_scope(_cpu_device()):
         key = jax.random.wrap_key_data(
             jnp.asarray(np.asarray(key_data)), impl=key_impl)

@@ -138,17 +138,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="transformer attention backend: 'flash' = fused "
                         "online-softmax pallas kernel on TPU (exact; "
                         "dense fallback off-TPU); 'auto' (default) "
-                        "picks flash only at sequence lengths where the "
-                        "on-chip A/B measured it winning (T >= 4096 — "
-                        "FLASH_TRAIN.json's T=2048 window regressed "
-                        "0.68x)")
-    p.add_argument("--conv_impl", default="auto",
-                   choices=("auto", "conv", "matmul"),
-                   help="conv-family lowering (resnet/wideresnet/"
-                        "densenet/cnn): 'matmul' = im2col + one batched "
-                        "matmul per layer (identical math; fills the "
-                        "MXU differently under per-client weights — "
-                        "see docs/performance.md)")
+                        "picks flash at sequence lengths T >= 4096 "
+                        "(ops/attention_dispatch.py)")
     # training scheme (parameters.py:118-141)
     p.add_argument("--stop_criteria", default="epoch")
     p.add_argument("--num_epochs", type=int, default=None)
@@ -436,9 +427,6 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=("float32", "bfloat16"),
                    help="matmul/conv compute dtype (params stay f32); "
                         "bfloat16 feeds the MXU at full rate")
-    p.add_argument("--scan_unroll", type=int, default=1,
-                   help=">1 unrolls the local-step scan so XLA can "
-                        "software-pipeline consecutive steps")
     p.add_argument("--remat", action="store_true",
                    help="per-block rematerialization for resnet/"
                         "transformer: ~1.33x FLOPs for depth-independent "
@@ -474,8 +462,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "versioned metrics.jsonl/events.jsonl, a "
                         "Perfetto-loadable trace.json of host spans, "
                         "and the atomically-replaced health.json to "
-                        "the run dir (measured <= 1% round overhead, "
-                        "TELEMETRY_AB.json; zero added device syncs); "
+                        "the run dir (zero added device syncs); "
                         "'debug' re-exports the trace every 25 rounds; "
                         "'off' disables everything "
                         "(docs/observability.md)")
@@ -580,8 +567,7 @@ def args_to_config(args) -> ExperimentConfig:
             moe_experts=args.moe_experts,
             moe_capacity_factor=args.moe_capacity_factor,
             moe_aux_weight=args.moe_aux_weight,
-            attention=args.attention,
-            conv_impl=args.conv_impl),
+            attention=args.attention),
         optim=OptimConfig(
             optimizer=args.optimizer, lr=args.lr,
             in_momentum=args.in_momentum,
@@ -634,7 +620,7 @@ def args_to_config(args) -> ExperimentConfig:
             coordinator_address=args.coordinator_address,
             num_processes=args.num_processes, process_id=args.process_id,
             compute_dtype=args.compute_dtype,
-            scan_unroll=args.scan_unroll, remat=args.remat,
+            remat=args.remat,
             client_fusion=args.client_fusion,
             client_shards=args.client_shards),
         telemetry=TelemetryConfig(
@@ -1280,8 +1266,7 @@ def run_experiment(cfg: ExperimentConfig,
                 overlap_eff = overlap_tracker.observe(row)
                 if overlap_eff is not None:
                     # stream plane: the fraction of this round's producer
-                    # gather+H2D wall hidden under device compute — the
-                    # number ROADMAP item 1's STREAM_AB 1.15x gap needs
+                    # gather+H2D wall hidden under device compute
                     row["overlap_efficiency"] = overlap_eff
                 if cost_capture is not None:
                     # measured MFU + HBM watermark pair — empty until the

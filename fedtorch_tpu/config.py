@@ -273,24 +273,11 @@ class ModelConfig:
     # §2.2; paper default 0.01). 0 disables; without it the top-1 gate
     # can collapse onto one expert.
     moe_aux_weight: float = 0.0
-    # resnet-family convolution lowering: 'conv' = XLA's native
-    # convolution; 'matmul' = im2col + one batched matmul per layer
-    # (identical params/math; fills the MXU differently under the
-    # federated engine's per-client weight axis — docs/performance.md
-    # "MFU roofline", measured by vmap_penalty_bench's conv_lowering).
-    # 'auto' (default) resolves per (arch, dataset) in define_model:
-    # matmul for the conv families on small-image datasets, where the
-    # round-5 XLA A/B measured 7.0-8.2x (CONV_AB_CPU.json) and the
-    # N-lane roofline predicts a larger MXU win; conv elsewhere (the
-    # kh*kw x patch-memory trade is prohibitive at 96px+ inputs).
-    conv_impl: str = "auto"
     # transformer attention backend: 'dense' (materialized scores),
     # 'flash' (fused online-softmax pallas kernel on TPU, O(block^2)
     # score memory; exact, dense fallback off-TPU), or 'auto'
-    # (default): per-sequence-length dispatch that picks flash only
-    # where the on-chip training A/B measured it winning outside the
-    # noise band (T >= 4096; FLASH_TRAIN.json read 0.68x at T=2048 —
-    # ops/attention_dispatch.py:resolve_attention)
+    # (default): per-sequence-length dispatch that picks flash at
+    # T >= 4096 (ops/attention_dispatch.py:resolve_attention)
     attention: str = "auto"
     pretrained: bool = False
     # 'robust_*' archs learn an adversarial input-noise parameter.
@@ -615,7 +602,7 @@ class TelemetryConfig:
     device-sync count at the loop's one batched fetch."""
     # 'off' = no files, every hook a no-op; 'default' = metrics.jsonl
     # + events.jsonl + health.json + host spans (trace.json exported at
-    # run end; measured <= 1% round overhead, TELEMETRY_AB.json);
+    # run end; what the hooks cost on the chip: PERF.md section 6, PR 24);
     # 'debug' additionally re-exports trace.json every 25 rounds so a
     # live Perfetto session can follow a long run.
     level: str = "default"
@@ -649,7 +636,7 @@ class TelemetryConfig:
     # num_clients <= budget the ledger keeps dense per-client numpy
     # counters; above it, count-min participation sketches plus a
     # bounded suspicion top-K — memory stays O(min(C, budget)) at
-    # C >= 10^6 (measured in TELEMETRY_AB.json's ledger_memory row).
+    # C >= 10^6 (telemetry/ledger.py:memory_bytes).
     ledger_sketch_budget: int = 65536
     # EWMA z-score threshold of the host-side anomaly detector
     # (telemetry/anomaly.py) over the metrics rows (loss, cohort
@@ -682,12 +669,6 @@ class MeshConfig:
     init_timeout_s: float = 300.0
     init_backoff_s: float = 1.0
     compute_dtype: str = "float32"  # 'bfloat16' for MXU-friendly matmuls
-    # Unroll factor for the local-step scan: >1 lets XLA software-
-    # pipeline consecutive local steps (more instruction-level overlap,
-    # bigger program). The data-dependent step order is preserved;
-    # results match the rolled scan to float tolerance (re-fusion of the
-    # unrolled body may shift last-ulp rounding).
-    scan_unroll: int = 1
     # Per-block rematerialization (jax.checkpoint) for resnet/transformer
     # archs: trade ~1.33x FLOPs for activation memory that scales with
     # one block instead of the depth — the standard TPU HBM lever for
@@ -706,12 +687,9 @@ class MeshConfig:
     #             resnet-cifar family + cnn with norm='bn' on a
     #             single-device mesh and base-local-step algorithms;
     #             requesting it elsewhere raises with the reason;
-    #   'auto'  — currently resolves to 'vmap': the fused lowering is
-    #             built and CPU-proven but its on-chip win is still
-    #             unmeasured (scripts/mfu_sweep.py fused configs;
-    #             ROADMAP Design 3), and this repo does not flip
-    #             defaults ahead of chip data — the conv_impl lesson
-    #             (docs/performance.md "Conv-lowering decision").
+    #   'auto'  — resolves to 'vmap'. PERF.md section 6 (PR 29) holds
+    #             the chip's one reading of 'fused'; ROADMAP Design 3
+    #             says what follows from it.
     client_fusion: str = "auto"
     # Pod-scale client-axis sharding (docs/performance.md "Pod-scale
     # round programs"): shard the k online clients of a round over
@@ -852,14 +830,6 @@ class ExperimentConfig:
                              f"expected one of {FEDERATED_ALGORITHMS}")
         if data.dataset not in DATASETS:
             raise ValueError(f"Unknown dataset {data.dataset!r}")
-        if self.mesh.scan_unroll < 1:
-            raise ValueError(
-                f"mesh.scan_unroll must be >= 1, got "
-                f"{self.mesh.scan_unroll}")
-        if self.model.conv_impl not in ("auto", "conv", "matmul"):
-            raise ValueError(
-                f"model.conv_impl must be 'auto', 'conv' or 'matmul', "
-                f"got {self.model.conv_impl!r}")
         if self.model.attention not in ("auto", "dense", "flash"):
             raise ValueError(
                 f"model.attention must be 'auto', 'dense' or 'flash', "

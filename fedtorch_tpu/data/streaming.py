@@ -904,7 +904,7 @@ class StreamFeedProducer:
         if self._cohort_rows is not None:
             # pod-scale packing: this host's cohort block width and
             # its cumulative local pack wall — the per-shard producer
-            # evidence PODSCALE_AB summarizes (docs/performance.md)
+            # evidence (docs/performance.md "Pod-scale round programs")
             lo, hi = self._cohort_rows
             out["stream_shard_rows"] = float(hi - lo)
             out["stream_shard_pack_s"] = self.shard_pack_s  # lint: disable=FTH003 — GIL-atomic monotone gauge; staleness is bounded by one round

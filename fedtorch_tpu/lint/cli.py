@@ -1,7 +1,7 @@
 """``python -m fedtorch_tpu.lint`` / ``fedtorch-tpu lint`` entry point.
 
 Runs the tracing-hazard analyzer over the default targets (the package
-plus ``scripts/`` and ``bench.py``), diffs against the checked-in
+plus ``scripts/`` and ``run_tpu.py``), diffs against the checked-in
 baseline, and exits non-zero only on NEW findings — the regression
 gate ``scripts/lint_suite.py`` and ``tests/test_lint_suite.py`` wrap.
 
@@ -25,8 +25,7 @@ lowers every legal round-program builder cell on the active backend
 and checks the HLO/jaxpr; needs jax). ``--registry-only`` skips the
 lowering half for jax-free lanes; ``--write-baseline`` under
 ``--audit`` re-pins ``lint/program_baseline.json``; ``--out FILE``
-writes the audit report document (the ``audit`` step of
-scripts/tpu_capture.sh).
+writes the audit report document.
 """
 from __future__ import annotations
 
@@ -45,8 +44,7 @@ from fedtorch_tpu.lint.rules import explain
 # "tools" is walked when a top-level tools/ dir exists (none today —
 # package tools live under fedtorch_tpu/tools, which the package walk
 # covers); listing it keeps a future top-level tools/ inside the gate
-DEFAULT_TARGETS = ("fedtorch_tpu", "scripts", "tools", "bench.py",
-                   "run_tpu.py")
+DEFAULT_TARGETS = ("fedtorch_tpu", "scripts", "tools", "run_tpu.py")
 DEFAULT_BASELINE = os.path.join(os.path.dirname(os.path.abspath(
     __file__)), "baseline.json")
 

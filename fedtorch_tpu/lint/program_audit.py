@@ -307,7 +307,7 @@ def _audit_config(source: str, dispatch: str, execution: str,
                 federated=True, num_clients=4, online_client_rate=0.5,
                 algorithm="fedavg", sync_type="local_step",
                 sync_mode=facts["sync_mode"]),
-            model=ModelConfig(arch="cnn", conv_impl="conv", norm="bn"),
+            model=ModelConfig(arch="cnn", norm="bn"),
             optim=OptimConfig(lr=0.05, in_momentum=True),
             train=TrainConfig(local_step=2),
             mesh=MeshConfig(num_devices=1,

@@ -53,51 +53,6 @@ def _sample_regression(dataset: str, batch: int, synthetic_dim: int):
     return jnp.zeros((batch, dim), jnp.float32)
 
 
-_CONV_FAMILIES = ("resnet", "wideresnet", "densenet", "cnn")
-
-
-def resolve_conv_impl(conv_impl: str, arch: str, dataset: str,
-                      backend: "str | None" = None) -> str:
-    """Resolve ``conv_impl='auto'`` per (backend, arch, dataset).
-
-    Both sides of the lowering A/B have now been measured on the same
-    compiled federated round program, and the two backends disagree:
-
-    - **TPU v5e (on-chip, round 5)**: grouped conv wins **5.06x** —
-      579.15 vs 114.4 local-steps/s on the north-star bench
-      (BENCH_CONVSIDE_AB.json vs BENCH_MATMULSIDE_AB.json, 2026-07-31).
-      The MXU roofline's predicted matmul win did NOT transfer: the
-      kh*kw x patch HBM traffic (9x activations for 3x3 convs)
-      dominates on-chip, where XLA's native conv emitter already
-      tiles well.
-    - **XLA CPU**: im2col batched matmul wins **7.0-8.2x** at batch
-      50/128 (CONV_AB_CPU.json, round 5).
-
-    So 'auto' keeps XLA's native convolution on accelerators and uses
-    the im2col matmul lowering only on the CPU backend for the
-    small-image conv families (<=64 px — above that the patch-memory
-    trade is prohibitive even on CPU: a 7x7 stem books 49x its
-    activations). ``backend=None`` reads the live
-    ``jax.default_backend()``; pass it explicitly to resolve for a
-    target platform other than the current one (bench.py resolves the
-    north-star capture identity with ``backend='tpu'``).
-    Decision table: docs/performance.md "Conv-lowering decision"."""
-    if conv_impl != "auto":
-        return conv_impl
-    if not arch.startswith(_CONV_FAMILIES):
-        return "conv"
-    if backend is None:
-        import jax
-        backend = jax.default_backend()
-    if backend != "cpu":
-        return "conv"
-    try:
-        h, w = image_shape(dataset)[:2]
-    except NotImplementedError:
-        return "conv"
-    return "matmul" if max(h, w) <= 64 else "conv"
-
-
 def define_fused_model(cfg: ExperimentConfig,
                        num_clients: int) -> "object | None":
     """Client-fused module for ``cfg.mesh.client_fusion='fused'``.
@@ -109,8 +64,7 @@ def define_fused_model(cfg: ExperimentConfig,
     grouped convolutions (models/common.py "client-fused layers"), or
     ``None`` when the (arch, dataset, norm) triple has no fused form —
     the engine's fusion gate (parallel/fusion.py) then keeps the vmap
-    strategy. Fusion is a different lowering of the SAME math, so the
-    ``conv_impl`` toggle does not apply to it."""
+    strategy."""
     arch, dataset, m = cfg.model.arch, cfg.data.dataset, cfg.model
     if arch.startswith("resnet"):
         return build_fused_resnet(arch, dataset, num_clients, m.norm,
@@ -140,36 +94,23 @@ def define_model(cfg: ExperimentConfig, batch_size: int = 2) -> ModelDef:
             "resnet*/wideresnet*/densenet*/transformer — the deep "
             "activation-heavy families); running without "
             "rematerialization", stacklevel=2)
-    if m.conv_impl not in ("conv", "auto") and not arch.startswith(
-            _CONV_FAMILIES):
-        import warnings
-        warnings.warn(
-            f"--conv_impl {m.conv_impl!r} has no effect for arch "
-            f"{arch!r} (implemented for the conv families: resnet*/"
-            "wideresnet*/densenet*/cnn); running with the native conv "
-            "lowering — an A/B against this arch would measure two "
-            "identical models", stacklevel=2)
-    conv_impl = resolve_conv_impl(m.conv_impl, arch, dataset)
     if arch.startswith("wideresnet"):
         module = build_wideresnet(arch, dataset, m.wideresnet_widen_factor,
                                   m.drop_rate, m.norm,
                                   dtype=cfg.mesh.compute_dtype,
-                                  remat=cfg.mesh.remat,
-                                  conv_impl=conv_impl)
+                                  remat=cfg.mesh.remat)
         return ModelDef(arch, module, _sample_image(dataset, batch_size))
     if arch.startswith("resnet"):
         module = build_resnet(arch, dataset, m.norm,
                               dtype=cfg.mesh.compute_dtype,
-                              remat=cfg.mesh.remat,
-                              conv_impl=conv_impl)
+                              remat=cfg.mesh.remat)
         return ModelDef(arch, module, _sample_image(dataset, batch_size))
     if arch.startswith("densenet"):
         module = build_densenet(arch, dataset, m.densenet_growth_rate,
                                 m.densenet_bc_mode, m.densenet_compression,
                                 m.drop_rate, m.norm,
                                 dtype=cfg.mesh.compute_dtype,
-                                remat=cfg.mesh.remat,
-                                conv_impl=conv_impl)
+                                remat=cfg.mesh.remat)
         return ModelDef(arch, module, _sample_image(dataset, batch_size))
     if arch == "logistic_regression":
         return ModelDef(arch, LogisticRegression(
@@ -213,8 +154,7 @@ def define_model(cfg: ExperimentConfig, batch_size: int = 2) -> ModelDef:
     if arch == "cnn":
         return ModelDef(arch,
                         CNN(dataset=dataset,
-                            dtype=cfg.mesh.compute_dtype,
-                            conv_impl=conv_impl),
+                            dtype=cfg.mesh.compute_dtype),
                         _sample_image(dataset, batch_size))
     if arch == "rnn":
         module = CharGRU(vocab_size=m.vocab_size,
@@ -234,9 +174,9 @@ def define_model(cfg: ExperimentConfig, batch_size: int = 2) -> ModelDef:
                 f"--moe_experts {m.moe_experts} with dense dispatch "
                 f"executes {m.moe_experts}x the expert-MLP FLOPs "
                 "(exactness-oracle mode). For training at scale set "
-                "--moe_capacity_factor 1.25: measured 8.6x fewer "
-                "executed FLOPs at E=16 with bounded token drop "
-                "(docs/performance.md 'Dispatch A/B', MOE_AB_CPU.json)",
+                "--moe_capacity_factor 1.25: E/cf times fewer "
+                "executed expert FLOPs with bounded token drop "
+                "(docs/performance.md 'Dispatch A/B')",
                 stacklevel=2)
         module = TransformerLM(vocab_size=m.vocab_size, d_model=d_model,
                                num_heads=num_heads,

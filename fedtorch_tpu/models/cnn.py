@@ -11,7 +11,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from fedtorch_tpu.models.common import (
-    FusedConv, FusedDense, conv_of, fused_max_pool, num_classes_of,
+    FusedConv, FusedDense, fused_max_pool, num_classes_of,
     pack_clients,
 )
 
@@ -19,20 +19,18 @@ from fedtorch_tpu.models.common import (
 class CNN(nn.Module):
     dataset: str
     dtype: str = "float32"
-    conv_impl: str = "conv"
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         dt = jnp.dtype(self.dtype)
-        # explicit Conv_N names = nn.Conv auto-names (see resnet.py)
-        Conv = conv_of(self.conv_impl)
+        # explicit Conv_N names pin the parameter tree (see resnet.py)
         x = x.astype(dt)
-        x = Conv(20, (5, 5), padding="VALID", dtype=dt, use_bias=True,
-                 name="Conv_0")(x)
+        x = nn.Conv(20, (5, 5), padding="VALID", dtype=dt, use_bias=True,
+                    name="Conv_0")(x)
         x = nn.relu(x)
         x = nn.max_pool(x, (2, 2), strides=(2, 2))
-        x = Conv(50, (5, 5), padding="VALID", dtype=dt, use_bias=True,
-                 name="Conv_1")(x)
+        x = nn.Conv(50, (5, 5), padding="VALID", dtype=dt, use_bias=True,
+                    name="Conv_1")(x)
         x = nn.relu(x)
         x = nn.max_pool(x, (2, 2), strides=(2, 2))
         x = x.reshape((x.shape[0], -1))

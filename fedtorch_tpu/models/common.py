@@ -114,82 +114,6 @@ def norm_f32(kind: str, x, dtype):
     return make_norm(kind)(x.astype(jnp.float32)).astype(dtype)
 
 
-class MatmulConv(nn.Module):
-    """Drop-in ``nn.Conv`` replacement computing the convolution as
-    im2col patches + ONE matmul ``[B·P, kh·kw·C] x [kh·kw·C, F]``.
-
-    Why: under the federated engine every online client has its own
-    weights, so the vmapped conv lowers to a ``batch_group_count=k``
-    grouped convolution; the matmul formulation instead becomes one
-    BATCHED matmul over the client axis — rows/columns the MXU tiles
-    directly (see docs/performance.md "MFU roofline" and the
-    ``conv_lowering`` section of scripts/vmap_penalty_bench.py for the
-    measured A/B). Selected per-model via ``conv_impl='matmul'``.
-
-    Parameter tree is IDENTICAL to ``nn.Conv`` (one ``kernel`` of shape
-    ``[kh, kw, cin, features]``, same initializer, f32 params with
-    compute in ``dtype``), so checkpoints are loadable across the
-    toggle. Supports the subset the conv zoo uses: NHWC input, integer
-    or pair padding, strides, optional bias.
-
-    Cost trade to keep in mind when reading the A/B: the materialized
-    patches are kh*kw x the activation size (9x for 3x3), so this
-    formulation buys MXU-friendly matmul tiling with extra HBM traffic
-    and activation memory — XLA may fuse the extraction, and ``remat``
-    keeps the backward from storing patches across layers, but whether
-    the tiling win beats the bandwidth cost is exactly what
-    MFU_SWEEP.json / VMAP_PENALTY.json's conv_lowering measure.
-    """
-    features: int
-    kernel_size: tuple
-    strides: tuple = (1, 1)
-    padding: "int | str | tuple" = 0
-    use_bias: bool = False
-    dtype: "str | jnp.dtype" = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        kh, kw = self.kernel_size
-        cin = x.shape[-1]
-        kernel = self.param(
-            "kernel",
-            nn.initializers.lecun_normal(in_axis=(0, 1, 2),
-                                         out_axis=3),
-            (kh, kw, cin, self.features))
-        dt = jnp.dtype(self.dtype)
-        pad = self.padding
-        if isinstance(pad, int):
-            pad = ((pad, pad), (pad, pad))
-        patches = jax.lax.conv_general_dilated_patches(
-            x.astype(dt), (kh, kw), tuple(self.strides), pad,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        B, H, W, _ = patches.shape
-        p = patches.reshape(B, H * W, cin * kh * kw)
-        # patches order features as [cin, kh, kw]; match the kernel
-        km = kernel.astype(dt).transpose(2, 0, 1, 3).reshape(
-            cin * kh * kw, self.features)
-        y = jnp.einsum("bpc,cf->bpf", p, km).reshape(
-            B, H, W, self.features)
-        if self.use_bias:
-            bias = self.param("bias", nn.initializers.zeros,
-                              (self.features,))
-            y = y + bias.astype(dt)
-        return y
-
-
-def conv_of(impl: str):
-    """Conv layer factory for a ``conv_impl`` setting: 'conv' is XLA's
-    native convolution (``nn.Conv``), 'matmul' the im2col formulation
-    above. Callers pass explicit ``name='Conv_N'`` so both impls
-    produce the same parameter tree."""
-    if impl == "conv":
-        return nn.Conv
-    if impl == "matmul":
-        return MatmulConv
-    raise ValueError(f"unknown conv_impl {impl!r} "
-                     "(expected 'conv' or 'matmul')")
-
-
 # -- client-fused layers (cfg.mesh.client_fusion='fused') -------------------
 #
 # The federated engine's per-client weights make the vmapped conv lower

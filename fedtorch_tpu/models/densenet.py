@@ -11,7 +11,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from fedtorch_tpu.models.common import (
-    conv_of, make_norm, norm_f32, num_classes_of,
+    make_norm, norm_f32, num_classes_of,
 )
 
 
@@ -21,23 +21,21 @@ class _DenseLayer(nn.Module):
     drop_rate: float = 0.0
     norm: str = "bn"
     dtype: str = "float32"
-    conv_impl: str = "conv"
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         dt = jnp.dtype(self.dtype)
         # explicit Conv_N names = nn.Conv auto-names (which depend on
-        # bc_mode: the 3x3 is Conv_1 after a bottleneck, Conv_0 alone),
-        # so the param tree is identical for either conv_impl
-        Conv = conv_of(self.conv_impl)
+        # bc_mode: the 3x3 is Conv_1 after a bottleneck, Conv_0 alone):
+        # they pin the parameter tree (see resnet.py)
         y = nn.relu(norm_f32(self.norm, x, dt))
         if self.bc_mode:
-            y = Conv(4 * self.growth_rate, (1, 1), use_bias=False,
-                     dtype=dt, name="Conv_0")(y)
+            y = nn.Conv(4 * self.growth_rate, (1, 1), use_bias=False,
+                        dtype=dt, name="Conv_0")(y)
             y = nn.relu(norm_f32(self.norm, y, dt))
-        y = Conv(self.growth_rate, (3, 3), padding=1, use_bias=False,
-                 dtype=dt,
-                 name="Conv_1" if self.bc_mode else "Conv_0")(y)
+        y = nn.Conv(self.growth_rate, (3, 3), padding=1, use_bias=False,
+                    dtype=dt,
+                    name="Conv_1" if self.bc_mode else "Conv_0")(y)
         y = nn.Dropout(rate=self.drop_rate, deterministic=not train)(y)
         return jnp.concatenate([x.astype(dt), y], axis=-1)
 
@@ -52,7 +50,6 @@ class DenseNet(nn.Module):
     norm: str = "bn"
     dtype: str = "float32"
     remat: bool = False  # per-layer jax.checkpoint (see resnet.py)
-    conv_impl: str = "conv"
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -64,23 +61,22 @@ class DenseNet(nn.Module):
         # explicit names keep the param tree identical across the toggle
         layer = nn.remat(_DenseLayer, static_argnums=(2,)) if self.remat \
             else _DenseLayer
-        Conv = conv_of(self.conv_impl)
-        x = Conv(ch, (3, 3), padding=1, use_bias=False, dtype=dt,
-                 name="Conv_0")(x.astype(dt))
+        x = nn.Conv(ch, (3, 3), padding=1, use_bias=False, dtype=dt,
+                    name="Conv_0")(x.astype(dt))
         li = 0
         for block in range(3):
             for _ in range(layers_per_block):
                 x = layer(growth_rate=self.growth_rate,
                           bc_mode=self.bc_mode,
                           drop_rate=self.drop_rate, norm=self.norm,
-                          dtype=self.dtype, conv_impl=self.conv_impl,
+                          dtype=self.dtype,
                           name=f"_DenseLayer_{li}")(x, train)
                 li += 1
             if block < 2:
                 out_ch = int(x.shape[-1] * self.compression)
                 x = nn.relu(norm_f32(self.norm, x, dt))
-                x = Conv(out_ch, (1, 1), use_bias=False, dtype=dt,
-                         name=f"Conv_{block + 1}")(x)
+                x = nn.Conv(out_ch, (1, 1), use_bias=False, dtype=dt,
+                            name=f"Conv_{block + 1}")(x)
                 x = nn.avg_pool(x, (2, 2), strides=(2, 2))
         x = nn.relu(make_norm(self.norm)(x.astype(jnp.float32)))
         x = x.mean(axis=(1, 2))
@@ -90,12 +86,11 @@ class DenseNet(nn.Module):
 def build_densenet(arch: str, dataset: str, growth_rate: int, bc_mode: bool,
                    compression: float, drop_rate: float,
                    norm: str = "bn", dtype: str = "float32",
-                   remat: bool = False,
-                   conv_impl: str = "conv") -> nn.Module:
+                   remat: bool = False) -> nn.Module:
     """arch string 'densenet<depth>' (factory densenet.py:200-208)."""
     depth = int(arch.replace("densenet", ""))
     return DenseNet(dataset=dataset, depth=depth, growth_rate=growth_rate,
                     bc_mode=bc_mode,
                     compression=compression if bc_mode else 1.0,
                     drop_rate=drop_rate, norm=norm, dtype=dtype,
-                    remat=remat, conv_impl=conv_impl)
+                    remat=remat)

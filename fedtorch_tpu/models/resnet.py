@@ -20,7 +20,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from fedtorch_tpu.models.common import (
-    FusedConv, FusedDense, conv_of, fused_norm_f32, norm_f32 as _norm32,
+    FusedConv, FusedDense, fused_norm_f32, norm_f32 as _norm32,
     num_classes_of, pack_clients,
 )
 
@@ -30,28 +30,26 @@ class BasicBlock(nn.Module):
     stride: int = 1
     norm: str = "bn"
     dtype: str = "float32"
-    conv_impl: str = "conv"
     expansion = 1
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         dt = jnp.dtype(self.dtype)
-        # explicit Conv_N names = nn.Conv's auto-names, so the param
-        # tree is identical for either conv_impl (checkpoints stay
-        # loadable across the toggle)
-        Conv = conv_of(self.conv_impl)
+        # explicit Conv_N names (= nn.Conv's auto-names) pin the
+        # parameter tree that checkpoints hold
+        # (tests/data/model_param_trees.json)
         residual = x
-        y = Conv(self.planes, (3, 3), strides=(self.stride, self.stride),
-                 padding=1, use_bias=False, dtype=dt, name="Conv_0")(x)
+        y = nn.Conv(self.planes, (3, 3), strides=(self.stride, self.stride),
+                    padding=1, use_bias=False, dtype=dt, name="Conv_0")(x)
         y = _norm32(self.norm, y, dt)
         y = nn.relu(y)
-        y = Conv(self.planes, (3, 3), padding=1, use_bias=False,
-                 dtype=dt, name="Conv_1")(y)
+        y = nn.Conv(self.planes, (3, 3), padding=1, use_bias=False,
+                    dtype=dt, name="Conv_1")(y)
         y = _norm32(self.norm, y, dt)
         if self.stride != 1 or x.shape[-1] != self.planes:
-            residual = Conv(self.planes, (1, 1),
-                            strides=(self.stride, self.stride),
-                            use_bias=False, dtype=dt, name="Conv_2")(x)
+            residual = nn.Conv(self.planes, (1, 1),
+                               strides=(self.stride, self.stride),
+                               use_bias=False, dtype=dt, name="Conv_2")(x)
             residual = _norm32(self.norm, residual, dt)
         return nn.relu(y + residual)
 
@@ -61,30 +59,28 @@ class Bottleneck(nn.Module):
     stride: int = 1
     norm: str = "bn"
     dtype: str = "float32"
-    conv_impl: str = "conv"
     expansion = 4
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         dt = jnp.dtype(self.dtype)
-        Conv = conv_of(self.conv_impl)  # explicit names: see BasicBlock
         residual = x
         out_planes = self.planes * self.expansion
-        y = Conv(self.planes, (1, 1), use_bias=False, dtype=dt,
-                 name="Conv_0")(x)
+        y = nn.Conv(self.planes, (1, 1), use_bias=False, dtype=dt,
+                    name="Conv_0")(x)
         y = _norm32(self.norm, y, dt)
         y = nn.relu(y)
-        y = Conv(self.planes, (3, 3), strides=(self.stride, self.stride),
-                 padding=1, use_bias=False, dtype=dt, name="Conv_1")(y)
+        y = nn.Conv(self.planes, (3, 3), strides=(self.stride, self.stride),
+                    padding=1, use_bias=False, dtype=dt, name="Conv_1")(y)
         y = _norm32(self.norm, y, dt)
         y = nn.relu(y)
-        y = Conv(out_planes, (1, 1), use_bias=False, dtype=dt,
-                 name="Conv_2")(y)
+        y = nn.Conv(out_planes, (1, 1), use_bias=False, dtype=dt,
+                    name="Conv_2")(y)
         y = _norm32(self.norm, y, dt)
         if self.stride != 1 or x.shape[-1] != out_planes:
-            residual = Conv(out_planes, (1, 1),
-                            strides=(self.stride, self.stride),
-                            use_bias=False, dtype=dt, name="Conv_3")(x)
+            residual = nn.Conv(out_planes, (1, 1),
+                               strides=(self.stride, self.stride),
+                               use_bias=False, dtype=dt, name="Conv_3")(x)
             residual = _norm32(self.norm, residual, dt)
         return nn.relu(y + residual)
 
@@ -100,7 +96,6 @@ class ResNetCifar(nn.Module):
     # instead of the depth. The HBM<->FLOPs trade SURVEY.md's TPU notes
     # call for; gradients are bitwise the same computation graph values.
     remat: bool = False
-    conv_impl: str = "conv"  # 'matmul' = im2col formulation (common.py)
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -115,9 +110,8 @@ class ResNetCifar(nn.Module):
         # across the toggle; remat wrappers auto-name differently)
         block = nn.remat(base, static_argnums=(2,)) if self.remat \
             else base  # train (arg 2, counting self) is static
-        x = conv_of(self.conv_impl)(
-            16, (3, 3), padding=1, use_bias=False, dtype=dt,
-            name="Conv_0")(x)
+        x = nn.Conv(16, (3, 3), padding=1, use_bias=False, dtype=dt,
+                    name="Conv_0")(x)
         x = _norm32(self.norm, x, dt)
         x = nn.relu(x)
         bi = 0
@@ -125,7 +119,7 @@ class ResNetCifar(nn.Module):
             for i in range(n_blocks):
                 stride = 2 if (stage > 0 and i == 0) else 1
                 x = block(planes=planes, stride=stride, norm=self.norm,
-                          dtype=self.dtype, conv_impl=self.conv_impl,
+                          dtype=self.dtype,
                           name=f"{base.__name__}_{bi}")(x, train)
                 bi += 1
         x = x.mean(axis=(1, 2))
@@ -140,7 +134,6 @@ class ResNetImageNet(nn.Module):
     norm: str = "bn"
     dtype: str = "float32"
     remat: bool = False  # see ResNetCifar.remat
-    conv_impl: str = "conv"
 
     _PARAMS = {
         18: (BasicBlock, (2, 2, 2, 2)),
@@ -158,9 +151,8 @@ class ResNetImageNet(nn.Module):
         # explicit names: identical param tree with remat on/off (above)
         block = nn.remat(base, static_argnums=(2,)) if self.remat \
             else base
-        x = conv_of(self.conv_impl)(
-            64, (7, 7), strides=(2, 2), padding=3, use_bias=False,
-            dtype=dt, name="Conv_0")(x)
+        x = nn.Conv(64, (7, 7), strides=(2, 2), padding=3, use_bias=False,
+                    dtype=dt, name="Conv_0")(x)
         x = _norm32(self.norm, x, dt)
         x = nn.relu(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
@@ -170,7 +162,7 @@ class ResNetImageNet(nn.Module):
             for i in range(n_blocks):
                 stride = 2 if (stage > 0 and i == 0) else 1
                 x = block(planes=planes, stride=stride, norm=self.norm,
-                          dtype=self.dtype, conv_impl=self.conv_impl,
+                          dtype=self.dtype,
                           name=f"{base.__name__}_{bi}")(x, train)
                 bi += 1
         x = x.mean(axis=(1, 2))
@@ -321,18 +313,15 @@ def build_fused_resnet(arch: str, dataset: str, num_clients: int,
 
 
 def build_resnet(arch: str, dataset: str, norm: str = "bn",
-                 dtype: str = "float32", remat: bool = False,
-                 conv_impl: str = "conv") -> nn.Module:
+                 dtype: str = "float32", remat: bool = False) -> nn.Module:
     """Factory matching resnet.py:260-274 arch-string parsing."""
     size = int(arch.replace("resnet", ""))
     if "cifar" in dataset or "svhn" in dataset \
             or "downsampled_imagenet" in dataset or dataset == "stl10":
         return ResNetCifar(dataset=dataset, size=size, norm=norm,
-                           dtype=dtype, remat=remat,
-                           conv_impl=conv_impl)
+                           dtype=dtype, remat=remat)
     if "imagenet" in dataset:
         return ResNetImageNet(dataset=dataset, size=size, norm=norm,
-                              dtype=dtype, remat=remat,
-                              conv_impl=conv_impl)
+                              dtype=dtype, remat=remat)
     raise NotImplementedError(
         f"resnet supports cifar/imagenet-family datasets, got {dataset!r}")

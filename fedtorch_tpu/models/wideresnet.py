@@ -10,7 +10,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from fedtorch_tpu.models.common import (
-    conv_of, make_norm, norm_f32, num_classes_of,
+    make_norm, norm_f32, num_classes_of,
 )
 
 
@@ -20,30 +20,27 @@ class _WideBasic(nn.Module):
     drop_rate: float = 0.0
     norm: str = "bn"
     dtype: str = "float32"
-    conv_impl: str = "conv"
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         dt = jnp.dtype(self.dtype)
-        # explicit Conv_N names = nn.Conv auto-names: identical param
-        # tree for either conv_impl (see resnet.py)
-        Conv = conv_of(self.conv_impl)
+        # explicit Conv_N names pin the parameter tree (see resnet.py)
         y = norm_f32(self.norm, x, dt)
         y = nn.relu(y)
         shortcut_src = y if (self.stride != 1
                              or x.shape[-1] != self.planes) else x
-        y = Conv(self.planes, (3, 3), strides=(self.stride, self.stride),
-                 padding=1, use_bias=False, dtype=dt, name="Conv_0")(y)
+        y = nn.Conv(self.planes, (3, 3), strides=(self.stride, self.stride),
+                    padding=1, use_bias=False, dtype=dt, name="Conv_0")(y)
         y = norm_f32(self.norm, y, dt)
         y = nn.relu(y)
         y = nn.Dropout(rate=self.drop_rate, deterministic=not train)(y)
-        y = Conv(self.planes, (3, 3), padding=1, use_bias=False,
-                 dtype=dt, name="Conv_1")(y)
+        y = nn.Conv(self.planes, (3, 3), padding=1, use_bias=False,
+                    dtype=dt, name="Conv_1")(y)
         if self.stride != 1 or x.shape[-1] != self.planes:
-            shortcut = Conv(self.planes, (1, 1),
-                            strides=(self.stride, self.stride),
-                            use_bias=False, dtype=dt,
-                            name="Conv_2")(shortcut_src)
+            shortcut = nn.Conv(self.planes, (1, 1),
+                               strides=(self.stride, self.stride),
+                               use_bias=False, dtype=dt,
+                               name="Conv_2")(shortcut_src)
         else:
             shortcut = x
         return y + shortcut.astype(dt)
@@ -57,7 +54,6 @@ class WideResNet(nn.Module):
     norm: str = "bn"
     dtype: str = "float32"
     remat: bool = False  # per-block jax.checkpoint (see resnet.py)
-    conv_impl: str = "conv"
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -69,16 +65,15 @@ class WideResNet(nn.Module):
         # explicit names keep the param tree identical across the toggle
         block = nn.remat(_WideBasic, static_argnums=(2,)) if self.remat \
             else _WideBasic
-        x = conv_of(self.conv_impl)(
-            16, (3, 3), padding=1, use_bias=False, dtype=dt,
-            name="Conv_0")(x.astype(dt))
+        x = nn.Conv(16, (3, 3), padding=1, use_bias=False, dtype=dt,
+                    name="Conv_0")(x.astype(dt))
         bi = 0
         for stage, planes in enumerate((16 * k, 32 * k, 64 * k)):
             for i in range(n):
                 stride = 2 if (stage > 0 and i == 0) else 1
                 x = block(planes=planes, stride=stride,
                           drop_rate=self.drop_rate, norm=self.norm,
-                          dtype=self.dtype, conv_impl=self.conv_impl,
+                          dtype=self.dtype,
                           name=f"_WideBasic_{bi}")(x, train)
                 bi += 1
         x = nn.relu(make_norm(self.norm)(x.astype(jnp.float32)))
@@ -88,11 +83,10 @@ class WideResNet(nn.Module):
 
 def build_wideresnet(arch: str, dataset: str, widen_factor: int,
                      drop_rate: float, norm: str = "bn",
-                     dtype: str = "float32", remat: bool = False,
-                     conv_impl: str = "conv") -> nn.Module:
+                     dtype: str = "float32",
+                     remat: bool = False) -> nn.Module:
     """arch string 'wideresnet<depth>' (factory wideresnet.py:135-144)."""
     depth = int(arch.replace("wideresnet", ""))
     return WideResNet(dataset=dataset, depth=depth,
                       widen_factor=widen_factor, drop_rate=drop_rate,
-                      norm=norm, dtype=dtype, remat=remat,
-                      conv_impl=conv_impl)
+                      norm=norm, dtype=dtype, remat=remat)

@@ -12,22 +12,19 @@ there.
 from __future__ import annotations
 
 # Shortest sequence length at which 'auto' attention dispatch picks the
-# flash kernel. From the 2026-07-31 on-chip training A/B at the tuned
-# block defaults (FLASH_TRAIN.json, TPU v5e; a hypothesis until the
-# ledger repeats it, ROADMAP Speed 5):
-# T=1024 1.12x, T=2048 0.68x (a REGRESSION — the dense path's [T, T]
-# scores still fit comfortably and the kernel's launch/tiling overhead
-# dominates), T=4096 1.77x (outside the noise band), T=8192 1.05x with
-# the dense score tensor already at 2.1 GB/layer. Flash is therefore
-# the default only where it measurably wins or where dense memory
-# becomes the binding constraint — T >= 4096.
+# flash kernel. Rests on a capture of 2026-07-31 on a v5e, before PR 1;
+# record removed in PR 29; not measured on today's code (ROADMAP Speed
+# 5). It read flash slower than dense at T=2048 (the dense [T, T] scores
+# still fit and the kernel's launch and tiling overhead dominates) and
+# faster from T=4096, where the dense score tensor also starts to bind
+# memory (2.1 GB a layer at T=8192).
 FLASH_MIN_SEQ_LEN = 4096
 
 
 def resolve_attention(mode: str, seq_len: int) -> str:
     """Resolve an attention mode ('auto'|'dense'|'flash') for a static
-    sequence length. 'auto' guards users from the measured T=2048
-    regression window (constant above); explicit modes pass through so
+    sequence length. 'auto' keeps short sequences on the dense path
+    (constant above); explicit modes pass through so
     A/Bs can pin either backend at any T."""
     if mode == "auto":
         return "flash" if seq_len >= FLASH_MIN_SEQ_LEN else "dense"

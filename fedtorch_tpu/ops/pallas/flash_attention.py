@@ -338,13 +338,10 @@ def _divisor_block(T: int, block: int) -> int:
 
 
 def _default_blocks(T: int):
-    """Default block shape by sequence length, from the 2026-07-31
-    v5e captures (FLASH_BLOCK_SWEEP.json, FLASH_TRAIN.json — hypotheses
-    until the ledger repeats them, ROADMAP Speed 5):
-
-    * T >= 4096 — (512, 512): 1.48x vs dense forward at T=8192.
-    * T <= 2048 — (128, 128): the training A/B at (256, 512) read
-      0.68x at T=2048 against 1.04x at 128x128.
+    """Default block shape by sequence length: (512, 512) from
+    T = 4096, (128, 128) below. Rests on a capture of 2026-07-31 on a
+    v5e, before PR 1; record removed in PR 29; not measured on today's
+    code (ROADMAP Speed 5).
 
     Both fit VMEM comfortably (<=1 MB score tile; _MAX_BLOCK_ELEMS).
     Note 'auto' attention dispatch routes T < 4096 to dense anyway

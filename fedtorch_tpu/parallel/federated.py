@@ -897,8 +897,7 @@ class FederatedTrainer:
                 init = (server_params, cstate.opt, cstate.aux,
                         cstate.epoch, cstate.local_index, carry0)
                 (params, opt, aux, epoch, li, _), (losses, accs, act) = \
-                    jax.lax.scan(step, init, jnp.arange(K),
-                                 unroll=min(self.cfg.mesh.scan_unroll, K))
+                    jax.lax.scan(step, init, jnp.arange(K))
 
                 delta = tree_sub(server_params, params)
                 lr_end = lr_at(self.schedule, epoch)
@@ -1389,8 +1388,7 @@ class FederatedTrainer:
                     on_clients.opt, on_clients.aux, on_clients.epoch,
                     on_clients.local_index)
             (params, opt, aux, epoch, li), (losses, accs, act) = \
-                jax.lax.scan(step, init, jnp.arange(K),
-                             unroll=min(cfg.mesh.scan_unroll, K))
+                jax.lax.scan(step, init, jnp.arange(K))
 
             # delta = server - params, leaf-broadcast over the stacked
             # [k] axis (same helper as the vmap path so the convention

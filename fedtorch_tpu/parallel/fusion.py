@@ -37,10 +37,8 @@ enumeration.
 ``resolve_client_fusion`` applies the config policy on top: 'vmap'
 and 'fused' are explicit pins ('fused' raises when unsupported —
 silent fallback would invalidate an A/B the user asked for); 'auto'
-currently resolves to 'vmap' because the fused lowering's on-chip win
-is unmeasured (scripts/mfu_sweep.py fused configs are armed) and
-defaults here follow chip data, not predictions — the conv_impl
-lesson (docs/performance.md "Conv-lowering decision").
+resolves to 'vmap' (PERF.md section 6, PR 29, holds the chip's one
+reading of 'fused'; ROADMAP Design 3 what follows from it).
 """
 from __future__ import annotations
 

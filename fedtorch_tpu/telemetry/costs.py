@@ -6,10 +6,7 @@ answers the device half's first question — "what does the compiled
 round actually cost" — straight from XLA's own accounting:
 ``Compiled.cost_analysis()`` (FLOPs, transcendentals, bytes accessed)
 and ``Compiled.memory_analysis()`` (argument/output/temp buffer sizes,
-whose sum is the program's peak device-memory watermark). One shared
-helper replaces the three ad-hoc copies that grew in
-``scripts/mfu_sweep.py``, ``scripts/moe_ab_bench.py`` and ``bench.py``,
-so every bench reports the same ``flops_source`` vocabulary.
+whose sum is the program's peak device-memory watermark).
 
 Contract (pinned in tests/test_device_observability.py):
 
@@ -42,14 +39,13 @@ from typing import Dict, Optional, Tuple
 
 PROGRAM_COSTS_SCHEMA = "fedtorch_tpu.program_costs/v1"
 
-# the flops_source vocabulary every consumer shares (MFU_SWEEP.json,
-# MOE_AB.json, bench.py records, program_costs.json)
+# the ``flops_source`` of a program_costs.json record
 FLOPS_XLA = "xla_cost_analysis"
-FLOPS_ANALYTIC = "analytic_resnet20"
 
-# bench.py's analytic accounting: resnet20-cifar forward = 40.8e6
-# MACs/image (stem 0.44M + 3 stages x ~13-14M + fc; the 41M figure in
-# the ResNet paper), training step ~= 3x forward, 2 FLOPs/MAC
+# analytic accounting behind program_costs.json's ``analytic`` block:
+# resnet20-cifar forward = 40.8e6 MACs/image (stem 0.44M + 3 stages x
+# ~13-14M + fc; the 41M figure in the ResNet paper), training step ~=
+# 3x forward, 2 FLOPs/MAC
 ANALYTIC_MACS_PER_IMAGE = {"resnet20": 40.8e6}
 _TRAIN_STEP_OVER_FWD = 3 * 2  # bwd ~= 2x fwd, 2 FLOPs per MAC
 
@@ -138,35 +134,6 @@ def lowered_cost(lowered) -> Dict[str, Optional[float]]:
         summary["error"] = f"{type(e).__name__}: {e}"[:200]
     summary["flops_source"] = FLOPS_XLA if summary.get("flops") else None
     return summary
-
-
-def program_flops(fn, *args, static_argnums=()) -> Optional[float]:
-    """FLOPs of ``jit(fn)(*args)`` from XLA cost analysis — the shared
-    probe behind every bench's ``flops_source='xla_cost_analysis'``
-    row. None when the backend reports no FLOPs for the program."""
-    import jax
-    lowered = jax.jit(fn, static_argnums=static_argnums).lower(*args)
-    return lowered_cost(lowered).get("flops")
-
-
-def train_step_flops(model, batch: int) -> Optional[float]:
-    """Per-local-step training FLOPs of ``model``'s compiled fwd+bwd
-    (softmax cross-entropy on the model's own sample input) — the
-    probe ``scripts/mfu_sweep.py`` and ``bench.py`` share so their MFU
-    numerators cannot drift. None on backends without cost analysis."""
-    import jax
-    import jax.numpy as jnp
-
-    from fedtorch_tpu.core.losses import softmax_cross_entropy
-
-    x = model.sample_input
-    y = jnp.zeros((batch,), jnp.int32)
-    params = model.init(jax.random.key(0))
-
-    def loss(p):
-        return softmax_cross_entropy(model.apply(p, x), y)
-
-    return program_flops(jax.grad(loss), params)
 
 
 # -- the program_costs.json document ------------------------------------
@@ -321,8 +288,7 @@ class ProgramCostCapture:
         """The analytic roofline for the active config: hand-derived
         per-image training FLOPs scaled to one round (k clients x K
         local steps x batch B) — the yardstick the XLA number is read
-        against (docs/performance.md 'Where the remaining headroom
-        is')."""
+        against (docs/performance.md 'MFU roofline')."""
         if self.arch is None:
             return None
         per_image = analytic_train_flops_per_image(self.arch)
@@ -439,8 +405,7 @@ class ProgramCostCapture:
         the round wall (multi-second rounds sample fresh every row),
         and at least every 25 rows regardless (the gauge is a
         watermark, not a per-round delta; the amortized worst case is
-        ~0.1 ms/row). Measured by the ``costs`` arm of
-        scripts/telemetry_bench.py."""
+        ~0.1 ms/row)."""
         due = (self._live_cache is None
                or self._rows_since_live >= self._LIVE_REFRESH_ROWS
                or (round_s > 0

@@ -8,10 +8,8 @@ device syncs, shared by the live round loop (``cli.run_experiment``),
 
 * :func:`overlap_efficiency` — the stream plane's missing number
   (ROADMAP item 1): what fraction of the producer's gather+H2D wall
-  actually hid under device compute this round. STREAM_AB still shows
-  stream 1.15x slower than device-resident at C=100; this gauge says
-  per-round whether the overlap is working or the producer is the
-  round clock.
+  actually hid under device compute this round: it says per round
+  whether the overlap is working or the producer is the round clock.
 * :func:`round_wall_decomposition` — the host/device split of the
   round wall (ROADMAP item 3): joins the per-round span walls the
   metrics rows carry with the captured program costs'

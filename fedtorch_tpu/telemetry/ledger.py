@@ -23,8 +23,7 @@ Memory contract — **O(min(C, sketch_budget)) at any population**:
   space-saving top-K (``budget // 16`` records) keeps EXACT per-client
   records for the highest-cumulative-suspicion clients — the clients an
   operator actually asks about. A C=10^6 population costs the same
-  bytes as the budget, measured in TELEMETRY_AB.json's
-  ``ledger_memory`` row.
+  bytes as the budget (:meth:`memory_bytes`).
 
 Per-round semantics for an online client (all O(k) numpy updates):
 ``participation`` += 1 (sampled/dispatched), ``online`` += survived
@@ -267,8 +266,7 @@ class ClientLedger:
         return len(self._top)
 
     def memory_bytes(self) -> int:
-        """Host bytes the ledger holds — the O(min(C, budget)) bound
-        TELEMETRY_AB.json measures at C=10^6."""
+        """Host bytes the ledger holds: O(min(C, budget))."""
         if self.mode == "dense":
             return int(sum(a.nbytes for a in self._dense.values()))
         # dict-of-dict records: ~7 floats + key + dict overhead; the

@@ -13,7 +13,7 @@
 
 Library code that cannot see the run's ``Telemetry`` object (the
 stream-feed producer thread, the async checkpoint writer, the
-supervisor, ``capture_round_trace``) records through the module-level
+supervisor) records through the module-level
 :func:`~fedtorch_tpu.telemetry.span` / ``event`` / ``instant``
 functions, which dispatch to the ACTIVE instance — installed by the
 CLI loop for the run's duration — and compile to a shared no-op when

@@ -1,20 +1,18 @@
 """Host-span tracing exported as Chrome trace-event JSON.
 
-The profiler (``utils.tracing.capture_round_trace``) attributes time
-*inside* one XLA program; what it cannot see is the host side of a
-round — schedule replay, feed gather, H2D dispatch, the dispatch gap
-between rounds, scalar fetch, eval, checkpoint IO. Those phases are
-exactly where ~90% of the north-star round's wall-time hides
-(docs/performance.md §headroom), and :class:`SpanRecorder` makes them
-visible facts: every instrumented host phase becomes a complete event
-(``ph: "X"``) in a ``trace.json`` loadable in Perfetto / chrome://
-tracing, with thread lanes for the CLI loop, the stream-feed producer,
-and the async checkpoint writer.
+The profiler (``jax.profiler``) attributes time *inside* one XLA
+program; what it cannot see is the host side of a round — schedule
+replay, feed gather, H2D dispatch, the dispatch gap between rounds,
+scalar fetch, eval, checkpoint IO. Those phases are where most of the
+loop's wall-time goes (PERF.md section 5), and :class:`SpanRecorder`
+makes them visible facts: every instrumented host phase becomes a
+complete event (``ph: "X"``) in a ``trace.json`` loadable in Perfetto /
+chrome://tracing, with thread lanes for the CLI loop, the stream-feed
+producer, and the async checkpoint writer.
 
 Overhead discipline: opening+closing a span is two
 ``time.perf_counter_ns`` calls and one ``list.append`` (GIL-atomic, so
-producer/writer threads record without locks) — sub-microsecond,
-measured end-to-end by ``scripts/telemetry_bench.py``. The buffer is
+producer/writer threads record without locks). The buffer is
 bounded (``max_events``); past the cap new spans are counted as
 dropped instead of growing without bound on month-long runs.
 

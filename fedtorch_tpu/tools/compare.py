@@ -1,12 +1,11 @@
 """``fedtorch-tpu compare A B``: noise-aware diff of two run dirs.
 
-The repo's dozens of A/B artifacts (STREAM_AB, ASYNC_AB, TELEMETRY_AB,
-BENCH_r0x) were compared by eyeball; this tool makes "did run B
-regress run A" a machine decision — FedScale's point that an FL
-benchmark is only as good as its cross-run evaluation harness (Lai et
-al. 2022). It diffs everything the telemetry records: round/commit
-rate and per-phase walls, comm volume, the accuracy trajectory (round-
-aligned, with a measured max gap for a tolerance gate to judge),
+This tool makes "did run B regress run A" a machine decision —
+FedScale's point that an FL benchmark is only as good as its cross-run
+evaluation harness (Lai et al. 2022). It diffs everything the
+telemetry records: round/commit rate and per-phase walls, comm volume,
+the accuracy trajectory (round-aligned, with a measured max gap for a
+tolerance gate to judge),
 MFU/HBM gauges, overlap efficiency, event counts, and the captured
 program costs (FLOPs, bytes accessed, peak-HBM watermark).
 

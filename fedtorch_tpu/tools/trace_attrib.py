@@ -2,14 +2,12 @@
 time into named op categories (device-side observability, pillar 2 of
 docs/observability.md "Device-side").
 
-``utils.tracing.capture_round_trace`` writes a Chrome-trace
+``jax.profiler.start_trace(dir)`` writes a Chrome-trace
 ``plugins/profile/<ts>/<host>.trace.json.gz`` under its capture dir.
-Through round 8 that artifact was raw material an operator had to read
-by hand in Perfetto — the ~90%-non-MXU headroom question (ROADMAP item
-3) stayed "unattributed". This tool turns any capture dir into an
-attribution table: every device op event — the events carrying XLA's
-``hlo_op``/``hlo_module`` args (the CPU backend's Eigen/TfrtCpuClient
-lanes emit them too, which is what makes this testable in tier-1), or
+This tool turns any capture dir into an attribution table: every
+device op event — the events carrying XLA's ``hlo_op``/``hlo_module``
+args (the CPU backend's Eigen/TfrtCpuClient lanes emit them too, which
+is what makes this testable in tier-1), or
 living on a ``/device:*`` "XLA Ops" lane (TPU/GPU) — is bucketed by
 HLO op name into the taxonomy below, nested events are self-time
 split, and the per-lane gap becomes the ``idle_gap`` category.

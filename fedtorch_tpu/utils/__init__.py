@@ -20,6 +20,5 @@ from fedtorch_tpu.utils.platform import (  # noqa: F401
     device_stamp, require_tpu,
 )
 from fedtorch_tpu.utils.tracing import (  # noqa: F401
-    RecompilationSentinel, capture_round_trace, instrument_trace,
-    trace_counts,
+    RecompilationSentinel, instrument_trace, trace_counts,
 )

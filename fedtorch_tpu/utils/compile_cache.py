@@ -1,10 +1,10 @@
 """Persistent XLA compilation cache.
 
-Every entry point — CLI runs, bench.py, chip_smoke.py, the comparison
-scripts — compiles the same federated round program, and a cold compile
-is a large part of a short run. JAX's persistent cache keys on (HLO,
-compile options, platform version, cache path), so a shared on-disk
-cache at a FIXED path turns repeat compiles into a load.
+Every entry point — CLI runs, the benchmark, chip_smoke.py, the
+comparison scripts — compiles the same federated round program, and a
+cold compile is a large part of a short run. JAX's persistent cache
+keys on (HLO, compile options, platform version, cache path), so a
+shared on-disk cache at a FIXED path turns repeat compiles into a load.
 
 The reference has no analog (eager torch does not compile); this is
 TPU-runtime scope.

@@ -2,7 +2,7 @@
 
 Every record a measurement entry point writes names the device it ran
 on, as JAX reports it. An entry point whose numbers only mean something
-on the accelerator (bench.py, chip_smoke.py, the scripts/ harnesses)
+on the accelerator (chip_smoke.py)
 calls :func:`require_tpu` before it compiles anything: with no TPU it
 exits non-zero instead of carrying on on the CPU.
 

@@ -169,8 +169,7 @@ def fetch_sync(out):
     any backend. (On the TPU v5e runtime ``jax.block_until_ready``
     waits as well — measured against a matmul chain's FLOPs floor,
     scripts/bench_timing.py.) ``scripts/bench_timing.py`` re-exports
-    this for the measurement scripts — one implementation, so the
-    rule cannot drift between the bench timers and the trace hook."""
+    this for ``scripts/compare_reference.py``."""
     import numpy as np
 
     leaf = jax.tree_util.tree_leaves(out)[0]
@@ -178,66 +177,17 @@ def fetch_sync(out):
     return np.asarray(leaf[(0,) * getattr(leaf, "ndim", 0)])
 
 
-def capture_round_trace(log_dir: str, fn: Callable, *args):
-    """Run ``fn(*args)`` under a ``jax.profiler`` trace written to
-    ``log_dir`` and return its result — the canonical on-chip capture
-    hook for the round program (scripts/mfu_sweep.py, the MFU_PROFILE
-    arm of scripts/tpu_capture.sh).
-
-    The result is drained INSIDE the trace window by
-    :func:`fetch_sync`: a trace stopped before the device stream
-    finishes records dispatch, not execution.
-
-    The written ``log_dir`` is a capture dir in the sense of
-    ``fedtorch_tpu.tools.trace_attrib`` / ``fedtorch-tpu report
-    --device``: the device-time category attribution runs directly on
-    it (docs/observability.md "Device-side")."""
-    import os
-
-    from fedtorch_tpu import telemetry
-
-    os.makedirs(log_dir, exist_ok=True)
-    # correlated host-span marker: the profiler window shows up on the
-    # telemetry timeline (trace.json) with the capture dir in its args,
-    # so an operator can line the XLA trace up against the host spans
-    with telemetry.span("profiler.capture", log_dir=log_dir):
-        jax.profiler.start_trace(log_dir)
-        try:
-            out = fn(*args)
-            fetch_sync(out)
-        finally:
-            jax.profiler.stop_trace()
-    return out
-
-
-def device_memory_stats() -> dict:
-    """Per-device live-memory summary (HBM pressure check)."""
-    stats = {}
-    for d in jax.devices():
-        try:
-            s = d.memory_stats()
-            if s:
-                stats[str(d)] = {
-                    "bytes_in_use": s.get("bytes_in_use"),
-                    "peak_bytes_in_use": s.get("peak_bytes_in_use"),
-                    "bytes_limit": s.get("bytes_limit"),
-                }
-        except Exception:
-            pass
-    return stats
-
-
 def live_buffer_summary() -> dict:
     """Live ``jax.Array`` accounting: total ADDRESSABLE bytes (each
     replicated copy counted — the buffers a device actually holds) and
     a per-(shape, dtype) breakdown.
 
-    ``device_memory_stats`` is allocator-dependent and returns nothing
-    on the CPU backend, so the streaming-residency contract ("the
-    device holds the double-buffered feed, not the client store" —
-    tests/test_streaming.py, scripts/stream_bench.py) is asserted
-    against THIS view, which works on every platform: what the program
-    still holds references to, shape by shape."""
+    A device's ``memory_stats()`` is allocator-dependent and returns
+    nothing on the CPU backend, so the streaming-residency contract
+    ("the device holds the double-buffered feed, not the client store",
+    tests/test_streaming.py) is asserted against THIS view, which works
+    on every platform: what the program still holds references to,
+    shape by shape."""
     by_shape: Dict[str, int] = {}
     total = 0
     for a in jax.live_arrays():

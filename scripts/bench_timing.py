@@ -1,17 +1,16 @@
-"""Fetch-synced device timing for the measurement scripts.
+"""Fetch-synced device timing for ``scripts/compare_reference.py``.
 
-Every micro-benchmark syncs by fetching one element of its final
-output: materializing result BYTES on the host waits for the in-order
-device stream on any backend. On the TPU v5e runtime
+A timed call syncs by fetching one element of its final output:
+materializing result BYTES on the host waits for the in-order device
+stream on any backend. On the TPU v5e runtime
 ``jax.block_until_ready`` waits too — measured 2026-09-26 (PR 21, jax
 0.9.0): a chain of sixteen 8192^3 bf16 matmuls blocked in 95.1 ms
 against a 197 TFLOP/s floor of 89.3 ms, and the fetch added 2 ms — so
-the two syncs are interchangeable there; the fetch stays because it is
-the one rule every script and the trace hook already share.
+the two syncs are interchangeable there; the fetch stays because it
+holds on any backend.
 
-The implementation lives in ``fedtorch_tpu.utils.tracing.fetch_sync``
-(one copy — the profiler trace hook drains through the same rule);
-this module stays the scripts-facing import surface.
+The implementation lives in ``fedtorch_tpu.utils.tracing.fetch_sync``;
+this module is the import surface of ``scripts/compare_reference.py``.
 """
 from __future__ import annotations
 

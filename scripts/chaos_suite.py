@@ -46,8 +46,7 @@ def straggler_heavy_fault() -> dict:
     tail. Under the SYNC planes these knobs cut straggler step budgets
     (the deadline model); under the async commit plane the SAME knobs
     draw the event scheduler's completion delays, so one preset drives
-    both sides of the async A/B (scripts/async_bench.py reuses it as
-    its chaos schedule). Returned as kwargs so importers can compose
+    both planes. Returned as kwargs so importers can compose
     it into a FaultConfig with guards/crashes of their own."""
     return {"straggler_rate": 0.4, "straggler_step_frac": 0.1}
 
@@ -747,7 +746,7 @@ def run_builder_matrix(rounds: int = 8, smoke: bool = False,
     and — the engine-wide bar — match its reference program BITWISE:
     the faulted per-round device program for the sync cells, the
     faulted resident commit program for the commit cell. Writes
-    BUILDER_MATRIX.json (tpu_capture.sh ``builder-matrix`` step)."""
+    BUILDER_MATRIX.json."""
     import jax
     import numpy as np
 

@@ -30,8 +30,7 @@ import subprocess
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RUFF_TARGETS = ("fedtorch_tpu", "scripts", "tests", "bench.py",
-                "run_tpu.py")
+RUFF_TARGETS = ("fedtorch_tpu", "scripts", "tests", "run_tpu.py")
 
 
 def run_ruff() -> int | None:

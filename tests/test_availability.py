@@ -141,9 +141,8 @@ class TestDefaultModelBitwise:
         np.testing.assert_array_equal(u_p, u_a[:, :2])
 
     def test_sync_round_simulation_unchanged(self):
-        """simulate_sync_round_times still draws the raw legacy chain
-        (it is the sync side of ASYNC_AB) — pinned against an inline
-        recomputation of round 0."""
+        """simulate_sync_round_times still draws the raw legacy chain:
+        pinned against an inline recomputation of round 0."""
         kd, impl = _key_state(3)
         times = simulate_sync_round_times(
             kd, impl, rounds=4, k_online=5, straggler_rate=0.4,

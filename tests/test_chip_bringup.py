@@ -98,13 +98,12 @@ class TestPlatformTest:
 
 
 # -- entry points refuse without a chip, before compiling -----------------
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
-def test_entry_point_refuses_on_cpu_before_compiling(script):
-    out = _run([script])
+def test_entry_point_refuses_on_cpu_before_compiling():
+    out = _run(["chip_smoke.py"])
     assert out.returncode != 0
     assert "needs a TPU" in out.stderr
     # no result line, and nothing was compiled on the way to refusing
-    assert '"ok"' not in out.stdout and '"metric"' not in out.stdout
+    assert '"ok"' not in out.stdout
     assert "Compiling" not in out.stderr
     assert "chip_smoke phase" not in out.stdout
 

@@ -13,12 +13,10 @@ execution"). These tests make its contract executable on CPU:
   params/opt/aux (incl. SCAFFOLD control variates, i.e. the payload
   pipeline end to end), epochs/counters and metrics — for resnet20 and
   cnn under FedAvg and SCAFFOLD, with epoch-sync freeze masks, chaos +
-  update guards, bf16, and both gather modes. Both sides pin
-  ``conv_impl='conv'``: against the native lowering the fused round
-  measured BITWISE-identical on XLA CPU; the tolerance below is ulp
-  slack for other XLA versions. (Against ``conv_impl='matmul'`` the
-  comparison would measure the im2col-vs-grouped float-program gap —
-  a different A/B, owned by tests/test_conv_impl.py.)
+  update guards, bf16, and both gather modes. Against the vmap
+  path's native convolution the fused round measured
+  BITWISE-identical on XLA CPU; the tolerance below is ulp slack for
+  other XLA versions.
 * the fusion gate: 'fused' raises with a reason where the equivalence
   could not hold; 'auto' stays on the vmap path (measured-default
   policy, docs/performance.md);
@@ -76,8 +74,7 @@ def make_cfg(fusion, arch="cnn", algo="fedavg", sync="local_step",
             federated=True, num_clients=num_clients,
             online_client_rate=0.5, algorithm=algo, sync_type=sync,
             num_epochs_per_comm=1),
-        # conv_impl pinned: same-lowering A/B (module docstring)
-        model=ModelConfig(arch=arch, conv_impl="conv", norm=norm),
+        model=ModelConfig(arch=arch, norm=norm),
         optim=OptimConfig(lr=0.05, in_momentum=True),
         train=TrainConfig(local_step=local_step),
         mesh=MeshConfig(num_devices=num_devices, client_fusion=fusion,
@@ -248,43 +245,3 @@ class TestFusedTraceSentinel:
             for _ in range(3):
                 server, clients, _ = t.run_round(server, clients)
         s.assert_traces(t.trace_name, expected=1)
-
-
-class TestSweepPlumbing:
-    @pytest.mark.slow
-    def test_mfu_sweep_runs_fused_config_on_cpu(self, tmp_path,
-                                                monkeypatch):
-        """The measurement path the on-chip sweep executes:
-        run_config with client_fusion='fused' end-to-end on CPU,
-        including the capture_round_trace profiler artifact."""
-        import os
-        import sys
-        monkeypatch.syspath_prepend(
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "scripts"))
-        monkeypatch.setenv("MFU_CLIENTS", "8")
-        for mod in ("mfu_sweep", "bench_timing"):
-            sys.modules.pop(mod, None)
-        import mfu_sweep
-        monkeypatch.setattr(mfu_sweep, "NUM_CLIENTS", 8)
-        monkeypatch.setattr(mfu_sweep, "LOCAL_STEPS", 2)
-        monkeypatch.setattr(mfu_sweep, "TIMED_ROUNDS", 1)
-        row = mfu_sweep.run_config(
-            "smoke-fused", batch=8, online_rate=0.25, arch="resnet8",
-            client_fusion="fused", num_devices=1,
-            profile_dir=str(tmp_path))
-        assert row["client_fusion"] == "fused"
-        assert row["local_steps_per_sec_per_chip"] > 0
-        # the profiler artifact exists (the hook the on-chip capture
-        # uses — the verdict notes no trace has ever been captured)
-        captured = [p for p in tmp_path.rglob("*") if p.is_file()]
-        assert captured, "capture_round_trace wrote no trace files"
-
-
-def test_capture_round_trace_returns_result(tmp_path):
-    out = jnp.asarray(0.0)
-    from fedtorch_tpu.utils import capture_round_trace
-    res = capture_round_trace(str(tmp_path),
-                             jax.jit(lambda x: x + 41.0), out)
-    assert float(res) == 41.0
-    assert [p for p in tmp_path.rglob("*") if p.is_file()]

@@ -21,14 +21,13 @@ import json
 import os
 
 import jax
-import numpy as np
 import pytest
 
 from fedtorch_tpu.telemetry import validate_metrics_row
 from fedtorch_tpu.telemetry.costs import (
     FLOPS_XLA, PROGRAM_COSTS_SCHEMA, ProgramCostCapture, cost_summary,
-    lowered_cost, program_flops, read_program_costs,
-    resolve_peak_tflops, train_step_flops, validate_program_costs,
+    lowered_cost, read_program_costs, resolve_peak_tflops,
+    validate_program_costs,
 )
 from fedtorch_tpu.tools import trace_attrib
 from fedtorch_tpu.utils.tracing import RecompilationSentinel
@@ -159,14 +158,6 @@ class TestProgramCostsSchema:
         assert not {"model_flops_utilization", "round_device_min_s",
                     "round_host_frac"} & set(gauges)
         assert gauges["hbm_program_peak_bytes"] > 0
-
-    def test_shared_flops_probes(self):
-        # the dedup target: the generic jit probe and the train-step
-        # probe both report positive FLOPs on the CPU backend
-        assert program_flops(lambda x: (x @ x).sum(),
-                             np.ones((16, 16), np.float32)) > 0
-        trainer = make_trainer()
-        assert train_step_flops(trainer.model, 8) > 0
 
 
 # -- host-only: trace-once + byte-identical HLO -----------------------------
@@ -434,13 +425,15 @@ class TestEndToEndCapture:
         """The acceptance bar: a real CPU-backend capture of the round
         program attributes >= 95% of device time into named
         categories, and ``fedtorch-tpu report --device`` renders it."""
-        from fedtorch_tpu.utils.tracing import capture_round_trace
         trainer = make_trainer()
         server, clients = trainer.init_state(jax.random.key(0))
         server, clients, _ = trainer.run_round(server, clients)  # warm
         cap_dir = str(tmp_path / "capture")
-        server, clients, _ = capture_round_trace(
-            cap_dir, trainer.run_round, server, clients)
+        with jax.profiler.trace(cap_dir):
+            server, clients, _ = trainer.run_round(server, clients)
+            # drained inside the window: a trace stopped earlier
+            # records dispatch, not execution
+            jax.block_until_ready(server.params)
         doc = trace_attrib.attribute(cap_dir)
         assert doc["device_events"] > 0
         assert doc["attributed_frac"] >= 0.95, doc

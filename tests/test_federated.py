@@ -221,27 +221,6 @@ class TestBatchedRounds:
         assert loss.shape == (2,) and np.all(np.isfinite(loss))
 
 
-class TestScanUnroll:
-    def test_unrolled_scan_matches_default(self):
-        """mesh.scan_unroll is a compile-time pipelining knob; the local
-        steps are data-dependent so unrolling must not change results."""
-        t1, _, _ = make_trainer(num_clients=4, rate=0.5, local_step=5)
-        t2, _, _ = make_trainer(num_clients=4, rate=0.5, local_step=5,
-                                mesh_kw={"scan_unroll": 5})
-        s1, c1 = t1.init_state(jax.random.key(3))
-        s2, c2 = t2.init_state(jax.random.key(3))
-        for _ in range(2):
-            s1, c1, _ = t1.run_round(s1, c1)
-            s2, c2, _ = t2.run_round(s2, c2)
-        for a, b in zip(jax.tree.leaves(s1.params),
-                        jax.tree.leaves(s2.params)):
-            # unrolling preserves the data-dependent step order, but XLA
-            # may fuse the unrolled body differently, so allow ulp-level
-            # slack rather than demanding bitwise identity
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=1e-6, rtol=1e-6)
-
-
 class TestMLPEngine:
     def test_mlp_round_runs(self):
         trainer, data, cfg = make_trainer(arch="mlp", num_clients=4,

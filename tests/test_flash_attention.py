@@ -2,7 +2,7 @@
 kernel semantics, custom-VJP gradients, and transformer integration.
 
 The real-TPU lowering of the same kernel is exercised by
-chip_smoke.py (and timed by scripts/pallas_tpu_check.py)."""
+chip_smoke.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -123,11 +123,10 @@ class TestBackendSelection:
         jax.jit(fwd).trace(q, k, v).lower(lowering_platforms=("tpu",))
 
     def test_default_blocks_follow_measured_winners(self):
-        """Block defaults, settled per ADVICE r5: the TRAINING A/B
-        (FLASH_TRAIN.json) regressed 0.68x at T=2048 on the sweep-
-        derived (256, 512), so T<=2048 keeps the previously-validated
-        (128, 128); the forward sweep's (512, 512) stands at T>=4096.
-        Explicit args override; divisor adjustment still applies."""
+        """Block defaults: (128, 128) up to T=2048, (512, 512) from
+        T=4096 (ops/pallas/flash_attention.py:_default_blocks has the
+        ground). Explicit args override; divisor adjustment still
+        applies."""
         import fedtorch_tpu.ops.pallas.flash_attention as fa
 
         assert fa._default_blocks(1024) == (128, 128)
@@ -291,10 +290,9 @@ class TestTransformerIntegration:
 
 
 class TestAutoDispatch:
-    """Sequence-length dispatch guard (ISSUE 3 satellite): 'auto' must
-    keep the measured T=2048 regression window (FLASH_TRAIN.json read
-    flash at 0.68x dense there) off the flash kernel, and flip to
-    flash exactly where the on-chip A/B measured the win."""
+    """Sequence-length dispatch guard: 'auto' must keep sequences
+    shorter than FLASH_MIN_SEQ_LEN off the flash kernel and flip to
+    flash exactly there."""
 
     def test_boundary(self):
         from fedtorch_tpu.ops.attention_dispatch import (

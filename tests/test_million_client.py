@@ -1,13 +1,8 @@
 """The million-client data plane (ISSUE 18): the ClientStore seam
 (zero-copy RAM store, manifest-described mmap store, chunked writer),
 O(k) 'sparse' participation (device draw + host RoundSchedule replay +
-async event scheduler), config/CLI surface for the new knobs, and the
-population-scaling bench smoke (scripts/stream_bench.py population arm
-→ MILLION_CLIENT_AB.json)."""
+async event scheduler) and the config/CLI surface for the new knobs."""
 import json
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +25,6 @@ from fedtorch_tpu.models import define_model
 from fedtorch_tpu.parallel import FederatedTrainer
 from fedtorch_tpu.parallel.federated import participation_indices
 from fedtorch_tpu.robustness import HostSeamError
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def make_cfg(plane="stream", store="ram", store_dir="",
@@ -390,40 +383,3 @@ def test_cli_flags_map_to_config(tmp_path):
     assert cfg.data.store == "mmap"
     assert cfg.data.store_dir == str(tmp_path)
     assert cfg.federated.participation_mode == "sparse"
-
-
-# -- the population-scaling bench (slow lane) --------------------------------
-@pytest.mark.slow
-def test_population_bench_smoke(tmp_path):
-    """The population arm of scripts/stream_bench.py must run end to
-    end on the CPU mesh (smoke sizes), prove mmap-vs-RAM bitwise
-    parity + residency split + zero retraces, and leave run dirs the
-    compare tool can read — so the on-chip capture (tpu_capture.sh
-    `population` step) is never its first execution."""
-    out = tmp_path / "MILLION_CLIENT_AB.json"
-    runs = tmp_path / "population_ab"
-    env = dict(os.environ, JAX_PLATFORMS="cpu", STREAM_BENCH_SMOKE="1",
-               STREAM_BENCH_POPULATION="1",
-               MILLION_CLIENT_AB_PATH=str(out),
-               POPULATION_RUNS_DIR=str(runs))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts",
-                                      "stream_bench.py")],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    report = json.loads(out.read_text())
-    assert report["parity_bitwise_mmap_vs_ram"] is True
-    assert report["residency_mapped_not_resident"] is True
-    assert report["zero_retraces"] is True
-    assert len(report["populations"]) >= 2
-    # the run dirs feed the gated compare (MILLION_CLIENT_COMPARE)
-    cmp_out = tmp_path / "cmp.json"
-    cproc = subprocess.run(
-        [sys.executable, "-m", "fedtorch_tpu.tools.compare",
-         str(runs / "a"), str(runs / "b"), "--out", str(cmp_out)],
-        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=120)
-    assert cproc.returncode == 0, cproc.stderr[-2000:]
-    blob = cmp_out.read_text()
-    assert "round_s_mean_steady" in blob
-    assert "stream_store_mapped_mb" in blob

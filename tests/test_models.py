@@ -1,5 +1,7 @@
 """Model zoo tests: shapes, param counts vs the torch reference, and
 jit/vmap usability of every architecture."""
+import json
+import os
 import sys
 import types
 
@@ -231,3 +233,47 @@ def test_bf16_gru_training_step_finite_and_f32_invariant():
 def test_unknown_arch_raises():
     with pytest.raises(ValueError):
         define_model(_cfg("transformerXL", "mnist"))
+
+
+# -- the conv zoo's parameter trees and lowering ------------------------
+# tests/data/model_param_trees.json was written from the parent of PR 29
+# (which removed the im2col lowering and its option): the same paths and
+# shapes mean a checkpoint written before loads after.
+_TREE_CASES = {
+    "resnet20": ("resnet20", "cifar10", {}),
+    "resnet56_bottleneck": ("resnet56", "cifar10", {}),
+    "resnet18_imagenet": ("resnet18", "imagenet", {}),
+    "wideresnet28": ("wideresnet28", "cifar10", {}),
+    "densenet40": ("densenet40", "cifar10", {}),
+    "densenet40_bc": ("densenet40", "cifar10",
+                      {"densenet_bc_mode": True}),
+    "cnn": ("cnn", "cifar10", {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TREE_CASES))
+def test_conv_zoo_param_tree_and_lowering(case):
+    from fedtorch_tpu.models import ModelDef, build_resnet
+
+    arch, dataset, kw = _TREE_CASES[case]
+    if dataset == "imagenet":
+        # no loader names this family: the module alone, small images
+        model = ModelDef(arch, build_resnet(arch, dataset),
+                         jnp.zeros((2, 64, 64, 3)))
+    else:
+        model = define_model(_cfg(arch, dataset, **kw))
+    with open(os.path.join(os.path.dirname(__file__), "data",
+                           "model_param_trees.json")) as f:
+        want = json.load(f)[case]
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    got = {"/".join(str(k.key) for k in path): list(leaf.shape)
+           for path, leaf in
+           jax.tree_util.tree_flatten_with_path(shapes)[0]}
+    assert got == want
+    # the one lowering, on the CPU as on the chip: a convolution for
+    # every 4-D kernel, a product for every dense one and no other
+    text = jax.jit(model.apply).lower(shapes, model.sample_input).as_text()
+    for op, rank in (("stablehlo.convolution", 4),
+                     ("stablehlo.dot_general", 2)):
+        assert text.count(op) == sum(
+            len(s) == rank for s in want.values()), op

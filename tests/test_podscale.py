@@ -309,7 +309,7 @@ def test_fused_multi_device_refusal_exact_message():
         federated=FederatedConfig(
             federated=True, num_clients=4, online_client_rate=0.5,
             algorithm="fedavg", sync_type="local_step"),
-        model=ModelConfig(arch="cnn", conv_impl="conv", norm="bn"),
+        model=ModelConfig(arch="cnn", norm="bn"),
         optim=OptimConfig(lr=0.05, in_momentum=True),
         train=TrainConfig(local_step=2),
         mesh=MeshConfig(client_fusion="fused"),  # all 8 devices

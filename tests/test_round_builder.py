@@ -59,8 +59,7 @@ def make_cfg(source, *, execution="vmap", sync_mode="sync",
     plane = "stream" if source == "feed" else "device"
     if execution == "fused":
         # the fused execution needs a fused module (cnn/bn) and a
-        # single-device mesh; conv_impl pinned for the same-lowering
-        # A/B contract (tests/test_client_fusion.py)
+        # single-device mesh
         return ExperimentConfig(
             data=DataConfig(dataset="cifar10", batch_size=6,
                             augment=False, data_plane=plane),
@@ -68,7 +67,7 @@ def make_cfg(source, *, execution="vmap", sync_mode="sync",
                 federated=True, num_clients=4, online_client_rate=0.5,
                 algorithm=algorithm, sync_type="local_step",
                 sync_mode=sync_mode, **fed_kw),
-            model=ModelConfig(arch="cnn", conv_impl="conv", norm="bn"),
+            model=ModelConfig(arch="cnn", norm="bn"),
             optim=OptimConfig(lr=0.05, in_momentum=True),
             train=TrainConfig(local_step=2),
             mesh=MeshConfig(num_devices=1, client_fusion=execution),

@@ -108,7 +108,7 @@ class TestRingFlashBlocks:
         declare their varying mesh axes; the kernel propagates the
         inputs' vma onto out_shape. Off-TPU the flash call falls back
         to the XLA oracle, so this combination first fired on the real
-        chip (round 5, SEQPAR_TPU_PROBE.json) — TRACING the real
+        chip — TRACING the real
         pallas path here (no execution) pins the check on CPU."""
         import fedtorch_tpu.ops.pallas.flash_attention as fa
         from fedtorch_tpu.parallel.sequence import ulysses_attention

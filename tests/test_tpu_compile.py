@@ -52,7 +52,7 @@ def test_compiled_round_holds_no_operation_of_the_stores_size(one_chip):
         federated=FederatedConfig(
             federated=True, num_clients=C, online_client_rate=0.5,
             algorithm="fedavg", sync_type="local_step"),
-        model=ModelConfig(arch="cnn", conv_impl="conv", norm="bn"),
+        model=ModelConfig(arch="cnn", norm="bn"),
         optim=OptimConfig(lr=0.05),
         train=TrainConfig(local_step=2),
         mesh=MeshConfig(num_devices=1, compute_dtype="bfloat16"),

@@ -190,7 +190,7 @@ def make_image_trainer(algorithm, fusion, plane, fault_kw=None):
             federated=True, num_clients=len(sizes),
             online_client_rate=0.5, algorithm=algorithm,
             sync_type="local_step"),
-        model=ModelConfig(arch="cnn", conv_impl="conv", norm="bn"),
+        model=ModelConfig(arch="cnn", norm="bn"),
         optim=OptimConfig(lr=0.05, in_momentum=True),
         train=TrainConfig(local_step=2),
         mesh=MeshConfig(num_devices=1, client_fusion=fusion),

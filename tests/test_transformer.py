@@ -135,8 +135,7 @@ def test_long_context_ring_matches_dense():
 
 def test_large_e_dense_dispatch_warns():
     """E>=8 with dense dispatch is oracle mode at Ex the FLOPs; the
-    factory nudges toward the measured sparse recommendation
-    (MOE_AB_CPU.json: 8.6x executed-FLOPs ratio at E=16)."""
+    factory nudges toward sparse dispatch."""
     import warnings
 
     from fedtorch_tpu.config import (
