@@ -31,6 +31,7 @@ import numpy as np
 from typing import Optional, Tuple
 
 import jax
+import msgpack
 from flax import serialization
 
 from fedtorch_tpu import telemetry
@@ -163,15 +164,123 @@ _CKPT_DIGEST_OFF = _CKPT_LEN_OFF + 8
 _CKPT_HEADER = _CKPT_DIGEST_OFF + 32
 
 
-def _frame_header(payload: bytes) -> bytes:
-    return (_CKPT_MAGIC + len(payload).to_bytes(8, "big")
-            + hashlib.sha256(payload).digest())
+def _bin_header(n: int) -> bytes:
+    """msgpack's header of a ``bin`` of ``n`` bytes (bin 8 / 16 / 32,
+    the narrowest that holds ``n``, as its packer chooses)."""
+    if n <= 0xff:
+        return b"\xc4" + n.to_bytes(1, "big")
+    if n <= 0xffff:
+        return b"\xc5" + n.to_bytes(2, "big")
+    return b"\xc6" + n.to_bytes(4, "big")
+
+
+def _ext_header(code: int, n: int) -> bytes:
+    """msgpack's header of an ext of type ``code`` and ``n`` data
+    bytes: fixext for 1, 2, 4, 8 and 16, else ext 8 / 16 / 32."""
+    fix = {1: b"\xd4", 2: b"\xd5", 4: b"\xd6", 8: b"\xd7", 16: b"\xd8"}
+    if n in fix:
+        head = fix[n]
+    elif n <= 0xff:
+        head = b"\xc7" + n.to_bytes(1, "big")
+    elif n <= 0xffff:
+        head = b"\xc8" + n.to_bytes(2, "big")
+    else:
+        head = b"\xc9" + n.to_bytes(4, "big")
+    return head + code.to_bytes(1, "big")
+
+
+def _plain_array(x) -> bool:
+    """A leaf whose C-order bytes can go into the file as they lie in
+    memory: an array of plain items, inside msgpack's limit. (The
+    zero-size leaf has no memory to view and packs in a few bytes.)"""
+    return (isinstance(x, np.ndarray) and not x.dtype.hasobject
+            and x.dtype.fields is None
+            and 0 < x.nbytes <= serialization.MAX_CHUNK_SIZE)
+
+
+def _payload_pieces(host_state) -> Tuple[list, dict]:
+    """The msgpack payload of ``host_state`` as a list of buffers whose
+    concatenation is byte for byte ``serialization.to_bytes(host_state)``
+    — never built as one object, where flax copies every leaf three
+    times (``tobytes``, the inner ``packb``, the outer ``packb``'s
+    growing buffer). A ``_plain_array`` leaf contributes its thirty-odd
+    bytes of msgpack text (ext header, the array of shape, dtype name
+    and bin header) and then memory that is already there, as a
+    ``uint8`` memoryview (bfloat16 and the other extension dtypes
+    refuse the buffer protocol under their own type): ITS OWN where it
+    is C-contiguous, else one C-order copy of it. The second case is
+    the chip's: a TPU keeps the per-client state with the client axis
+    minor-most and ``device_get`` hands the host the same strides, so
+    the file's C-order bytes exist nowhere until something lays them
+    out. Every other value goes through flax's own packer, chunked
+    where flax chunks. The views of the first kind borrow the snapshot:
+    it must outlive the write, and does (the caller, or the async
+    worker's job, holds it).
+
+    Returns (pieces, counts) of leaf bytes by the way they took:
+    ``borrowed_bytes`` views of the snapshot's own memory,
+    ``relaid_bytes`` copied once into C order, ``copied_bytes`` packed
+    by flax's packer (the tree's map headers and keys, and an array's
+    header, count as none of them)."""
+    # the two packers flax's msgpack_serialize and _ndarray_to_bytes use
+    outer = msgpack.Packer(default=serialization._msgpack_ext_pack,
+                           strict_types=True)
+    inner = msgpack.Packer(use_bin_type=True)
+    ndarray_code = int(serialization._MsgpackExtType.ndarray)
+    pieces: list = []
+    text = bytearray()          # small bytes since the last view
+    counts = {"borrowed_bytes": 0, "relaid_bytes": 0, "copied_bytes": 0}
+
+    def walk(node):
+        if type(node) is dict:
+            text.extend(outer.pack_map_header(len(node)))
+            for key, value in node.items():
+                text.extend(outer.pack(key))
+                walk(value)
+            return
+        if isinstance(node, jax.Array):     # as _np_convert_in_place
+            node = np.array(node)
+        if not _plain_array(node):
+            if isinstance(node, np.ndarray) \
+                    and node.nbytes > serialization.MAX_CHUNK_SIZE:
+                node = serialization._chunk(node)
+            packed = outer.pack(node)
+            counts["copied_bytes"] += len(packed)
+            text.extend(packed)
+            return
+        if node.flags.c_contiguous:
+            counts["borrowed_bytes"] += node.nbytes
+        else:
+            node = np.ascontiguousarray(node)
+            counts["relaid_bytes"] += node.nbytes
+        head = (inner.pack_array_header(3) + inner.pack(node.shape)
+                + inner.pack(node.dtype.name) + _bin_header(node.nbytes))
+        text.extend(_ext_header(ndarray_code, len(head) + node.nbytes))
+        text.extend(head)
+        pieces.append(bytes(text))
+        text.clear()
+        pieces.append(memoryview(node.reshape(-1).view(np.uint8)))
+
+    walk(serialization.to_state_dict(host_state))
+    if text:
+        pieces.append(bytes(text))
+    return pieces, counts
+
+
+def _frame_header(pieces) -> bytes:
+    """The frame's header for the payload that ``pieces`` (buffers, in
+    order) make up: its length, and its sha256 fed piece by piece."""
+    digest = hashlib.sha256()
+    for piece in pieces:
+        digest.update(piece)
+    return (_CKPT_MAGIC + sum(len(p) for p in pieces).to_bytes(8, "big")
+            + digest.digest())
 
 
 def _frame_payload(payload: bytes) -> bytes:
-    """The frame as one object. The writer never builds it (a second
-    copy of the payload): it writes header and payload as two parts."""
-    return _frame_header(payload) + payload
+    """The frame as one object. The writer never builds it (nor the
+    payload): it writes the header and the payload's pieces in turn."""
+    return _frame_header((payload,)) + payload
 
 
 def _frame_want_len(head: bytes) -> int:
@@ -199,12 +308,12 @@ def _unframe_payload(blob: bytes):
     return payload, None
 
 
-def _atomic_write(path: str, *parts: bytes) -> None:
+def _atomic_write(path: str, *parts) -> None:
     """tmp + fsync + rename so a crash (including power loss — without
     the fsync, delayed allocation could rename before the data blocks
     hit disk) never corrupts the previous checkpoint. The reference
     overwrites in place (checkpoint.py:72). The file holds ``parts``
-    one after the other.
+    (bytes or memoryviews) one after the other.
 
     Self-healing (docs/robustness.md "Host plane"): each write runs
     under the bounded 'ckpt.write' retry policy — a transient
@@ -231,21 +340,27 @@ def _atomic_write(path: str, *parts: bytes) -> None:
         host_recovery.retry_io(attempt, "ckpt.write")
 
 
-def _write_frame(path: str, frame: Tuple[bytes, bytes]) -> None:
-    """One payload file of its own: ``frame`` = (header, payload),
-    written, fsynced and renamed. The 'ckpt.torn' drill seam truncates
-    individual payload writes (each file written here draws
-    independently) but lets the rename land — the torn frame the
-    integrity record exists to catch at resume/GC time."""
+def _write_frame(path: str, frame: list) -> None:
+    """One payload file of its own: ``frame`` = the header and the
+    payload's pieces, written in turn, fsynced and renamed. The
+    'ckpt.torn' drill seam truncates individual payload writes (each
+    file written here draws independently) but lets the rename land —
+    the torn frame the integrity record exists to catch at resume/GC
+    time. The cut falls where it falls in the list: whole pieces
+    before it, a slice of the piece it is in."""
     from fedtorch_tpu.robustness import host_chaos  # lazy: see above
-    header, payload = frame
-    lands = host_chaos.torn_length("ckpt.torn", len(header) + len(payload))
-    _atomic_write(path, header[:lands],
-                  memoryview(payload)[:max(lands - len(header), 0)])
+    lands = host_chaos.torn_length("ckpt.torn",
+                                   sum(len(p) for p in frame))
+    parts = []
+    for piece in frame:
+        if lands <= 0:
+            break
+        parts.append(piece[:lands])
+        lands -= len(piece)
+    _atomic_write(path, *parts)
 
 
-def _link_or_write(src: str, path: str, frame: Tuple[bytes, bytes]
-                   ) -> None:
+def _link_or_write(src: str, path: str, frame: list) -> None:
     """Give ``path`` the durable bytes of ``src``, the payload file
     just written, fsynced and renamed in the same directory: a hard
     link under a tmp name, renamed over ``path``. No checkpoint is ever
@@ -368,10 +483,13 @@ def _write_checkpoint(directory: str, host_state, meta: dict,
     # framed payload: resume verifies the in-file length + digest BEFORE
     # trying to deserialize, so a torn/truncated/bit-rotted file is
     # detected cleanly instead of surfacing as an opaque msgpack error.
-    # Serialized and hashed ONCE, however many names the save gets
-    with telemetry.span("checkpoint.serialize"):
-        payload = serialization.to_bytes(host_state)
-        frame = (_frame_header(payload), payload)
+    # Serialized and hashed ONCE, however many names the save gets: a
+    # list, so a retried write, a refused link's copy and the keep walk
+    # the same pieces again
+    with telemetry.span("checkpoint.serialize") as sp:
+        pieces, counts = _payload_pieces(host_state)
+        frame = [_frame_header(pieces)] + pieces
+        sp.note(pieces=len(pieces), **counts)
     path = os.path.join(directory, "checkpoint.ckpt")
     _write_frame(path, frame)
     meta_bytes = json.dumps(meta, default=str).encode()

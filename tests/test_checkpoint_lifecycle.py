@@ -6,7 +6,9 @@ count, the mesh-shape-independence contract the degraded-pod resume
 rides on). ISSUE 28: a save's payload goes to disk once —
 ``model_best.ckpt`` is a hard link to the ``checkpoint.ckpt`` just
 written, the frame is written in two parts, the keeps stay files of
-their own."""
+their own. ISSUE 30: the payload is never built — a list of pieces that
+borrow the snapshot's arrays, byte for byte what flax ``to_bytes``
+gives, hashed and written piece by piece."""
 import errno
 import json
 import os
@@ -449,3 +451,223 @@ class TestPayloadGoesToDiskOnce:
         assert saver.stats()["ckpt_writes"] == 1.0
         assert os.path.samefile(os.path.join(d, "checkpoint.ckpt"),
                                 os.path.join(d, "model_best.ckpt"))
+
+
+# -- the payload as pieces that borrow the snapshot --------------------------
+def _round_state():
+    """The snapshot a save serializes: ServerState + ClientState of the
+    small model after one round, numpy leaves that own their data."""
+    cfg, trainer, server, clients = make_experiment()
+    server, clients, _ = trainer.run_round(server, clients)
+    return ckpt_mod._snapshot(server, clients, cfg)
+
+
+def _client_minor(x):
+    """``x`` as a TPU's ``device_get`` hands the per-client state over:
+    the same values, the client axis minor-most in memory."""
+    if x.ndim < 2:
+        return x
+    return np.moveaxis(np.ascontiguousarray(np.moveaxis(x, 0, -1)), -1, 0)
+
+
+def _round_state_as_the_chip_lays_it_out():
+    state = _round_state()
+    return {"server": state["server"],
+            "clients": jax.tree.map(_client_minor, state["clients"])}
+
+
+def _device_state():
+    """The same tree before the snapshot: jax arrays, which flax
+    converts (and so does the walk)."""
+    cfg, trainer, server, clients = make_experiment()
+    return {"server": ckpt_mod._unkey(server), "clients": clients}
+
+
+def _mixed_leaves():
+    """Every kind of leaf the walk has to tell apart, sizes on both
+    sides of msgpack's 8/16/32-bit headers and on its fixext lengths."""
+    import ml_dtypes
+    big = np.random.default_rng(0).standard_normal(
+        (70, 40, 8)).astype(np.float32)
+    tree = {
+        "float32": big, "bfloat16": big[:3].astype(ml_dtypes.bfloat16),
+        "int32": np.arange(7, dtype=np.int32),
+        "key_data": np.asarray(jax.random.key_data(jax.random.key(3))),
+        "zero_d": np.array(3, np.int32),
+        "zero_size": np.zeros((0, 4), np.float32),
+        "non_contiguous": big[:, ::2, :],
+        "fortran": np.asfortranarray(big[0]),
+        "numpy_scalar": np.float32(2.5),
+        "int": 7, "float": 0.5, "str": "abc", "none": None, "bool": True,
+        "complex": 1 + 2j,
+        "nested": {"tuple": (np.ones(3), [np.zeros(2, np.int8)]),
+                   "empty": {}},
+        "bin16": np.ones(300, np.uint8), "bin32": np.ones(70000, np.uint8),
+    }
+    for n in range(1, 24):      # ext lengths 1..: the fixext widths
+        tree[f"int8_{n}"] = np.zeros(n, np.int8)
+        tree[f"col_{n}"] = np.zeros((n, 1, 1), np.uint16)
+    return tree
+
+
+class TestPayloadIsNeverBuilt:
+    @pytest.mark.parametrize("make,chunk", [
+        (_round_state, None),
+        (_round_state_as_the_chip_lays_it_out, None),
+        (_device_state, None),
+        (_mixed_leaves, None), (_mixed_leaves, 1000)],
+        ids=["round_state", "round_state_client_minor", "device_state",
+             "mixed_leaves", "mixed_leaves_chunked"])
+    def test_pieces_join_to_flax_to_bytes(self, monkeypatch, make, chunk):
+        if chunk is not None:   # leaves over it flax splits into chunks
+            monkeypatch.setattr(serialization, "MAX_CHUNK_SIZE", chunk)
+        tree = make()
+        want = serialization.to_bytes(tree)
+        pieces, counts = ckpt_mod._payload_pieces(tree)
+        assert b"".join(pieces) == want
+        views = [p for p in pieces if isinstance(p, memoryview)]
+        assert counts["borrowed_bytes"] + counts["relaid_bytes"] \
+            == sum(len(v) for v in views)
+        assert sum(counts.values()) <= len(want)
+        assert ckpt_mod._frame_header(pieces) \
+            == ckpt_mod._frame_payload(want)[:ckpt_mod._CKPT_HEADER]
+        if chunk is not None:
+            assert all(len(v) <= chunk for v in views)
+            assert counts["copied_bytes"] > 70 * 40 * 8 * 4
+
+    def test_borrowed_pieces_are_views_of_the_snapshot(self):
+        state = _round_state()
+        leaves = jax.tree.leaves(state)
+        assert all(isinstance(x, np.ndarray) and x.size for x in leaves)
+        pieces, counts = ckpt_mod._payload_pieces(state)
+        views = [np.frombuffer(p, np.uint8) for p in pieces
+                 if isinstance(p, memoryview)]
+        # every leaf of the round state is borrowed, in flax's order,
+        # none copied; the rest of the payload is msgpack text
+        assert len(views) == len(leaves)
+        assert counts == {
+            "borrowed_bytes": sum(x.nbytes for x in leaves),
+            "relaid_bytes": 0, "copied_bytes": 0}
+        by_address = {x.__array_interface__["data"][0]: x
+                      for x in leaves}
+        for v in views:
+            leaf = by_address[v.__array_interface__["data"][0]]
+            assert np.shares_memory(v, leaf) and v.nbytes == leaf.nbytes
+        text = sum(len(p) for p in pieces
+                   if not isinstance(p, memoryview))
+        assert text < 64 * len(leaves) + 256
+
+    def test_strided_leaves_are_laid_out_once_not_packed_by_flax(self):
+        """The chip's snapshot: the client state's memory is client-
+        minor, so its C-order bytes are made by ONE copy each (counted
+        as relaid, views of the copies) and flax's packer packs none."""
+        state = _round_state_as_the_chip_lays_it_out()
+        strided = [x for x in jax.tree.leaves(state["clients"])
+                   if not x.flags.c_contiguous]
+        assert strided
+        pieces, counts = ckpt_mod._payload_pieces(state)
+        assert counts["copied_bytes"] == 0
+        assert counts["relaid_bytes"] == sum(x.nbytes for x in strided)
+        assert counts["borrowed_bytes"] == sum(
+            x.nbytes for x in jax.tree.leaves(state)
+            if x.flags.c_contiguous)
+        views = [np.frombuffer(p, np.uint8) for p in pieces
+                 if isinstance(p, memoryview)]
+        assert len(views) == len(jax.tree.leaves(state))
+        for x in strided:
+            assert not any(np.shares_memory(v, x) for v in views)
+
+    def test_save_then_resume_is_bitwise(self, tmp_path, recorder):
+        d = str(tmp_path / "ck")
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        want = jax.device_get((ckpt_mod._unkey(server), clients))
+        save_checkpoint(d, server, clients, cfg, 0.75, is_best=False)
+        s2, c2 = trainer.init_state(jax.random.key(1))
+        s3, c3, best, resumed = maybe_resume(d, s2, c2, cfg, None)
+        assert resumed and best == 0.75
+        got = jax.device_get((ckpt_mod._unkey(s3), c3))
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+        # the span says the mechanism engaged, and on what
+        (_, _, _, args), = [s for s in _spans(recorder)
+                            if s[0] == "checkpoint.serialize"]
+        assert args["copied_bytes"] == 0 and args["pieces"] > 2
+        assert args["relaid_bytes"] == 0
+        assert 0 < args["borrowed_bytes"] < os.path.getsize(
+            os.path.join(d, "checkpoint.ckpt"))
+
+    @pytest.mark.parametrize("where", ["inside_the_header",
+                                       "at_a_piece_boundary",
+                                       "inside_a_borrowed_piece"])
+    def test_torn_cut_anywhere_in_the_list_is_refused(
+            self, tmp_path, monkeypatch, where):
+        """The drill cuts a list of pieces: the file is the frame's
+        first ``lands`` bytes wherever the cut falls, resume refuses
+        it and the previous keep restores."""
+        from fedtorch_tpu.robustness import host_chaos
+        d = str(tmp_path)
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        save_checkpoint(d, server, clients, cfg, 0.5, is_best=False,
+                        save_all=True)
+        server, clients, _ = trainer.run_round(server, clients)
+        snapshot = ckpt_mod._snapshot(server, clients, cfg)
+        pieces, _ = ckpt_mod._payload_pieces(snapshot)
+        assert isinstance(pieces[1], memoryview) and len(pieces[1]) > 1
+        boundary = ckpt_mod._CKPT_HEADER + len(pieces[0])
+        lands = {"inside_the_header": 20,
+                 "at_a_piece_boundary": boundary,
+                 "inside_a_borrowed_piece":
+                     boundary + len(pieces[1]) // 2}[where]
+        want = ckpt_mod._frame_payload(serialization.to_bytes(snapshot))
+        assert lands < len(want)
+        monkeypatch.setattr(host_chaos, "torn_length",
+                            lambda seam, size: lands)
+        save_checkpoint(d, server, clients, cfg, 0.5, is_best=False)
+        monkeypatch.undo()
+        landed = _read(d, "checkpoint.ckpt")
+        assert landed == want[:lands]
+        assert ckpt_mod._unframe_payload(landed)[1] is not None
+        assert not ckpt_mod.frame_quick_ok(
+            os.path.join(d, "checkpoint.ckpt"))
+        s2, c2 = trainer.init_state(jax.random.key(0))
+        with pytest.warns(RuntimeWarning, match="per-round keep"):
+            s3, _, _, resumed = maybe_resume(d, s2, c2, cfg, None)
+        assert resumed and int(jax.device_get(s3.round)) == 1
+
+    def test_retried_write_attempt_writes_the_full_file(
+            self, tmp_path, monkeypatch):
+        """An attempt that failed AFTER it had walked the pieces (its
+        fsync) is retried under 'ckpt.write', and the retry walks the
+        same list again: the whole frame lands, not an exhausted
+        iterator's nothing."""
+        from fedtorch_tpu.robustness import host_recovery
+        d = str(tmp_path)
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        want = ckpt_mod._frame_payload(serialization.to_bytes(
+            ckpt_mod._snapshot(server, clients, cfg)))
+        real_fsync, failed = os.fsync, []
+
+        def fsync(fd):
+            if not failed:
+                failed.append(os.fstat(fd).st_size)
+                raise OSError(errno.EIO, "injected: fsync failed")
+            return real_fsync(fd)
+
+        monkeypatch.setattr(os, "fsync", fsync)
+        ledger = host_recovery.HostRecovery(
+            sleep_fn=lambda s: None).install()
+        try:
+            save_checkpoint(d, server, clients, cfg, 0.5, is_best=True,
+                            save_all=True)
+        finally:
+            ledger.uninstall()
+        assert failed == [len(want)]    # the first attempt wrote it all
+        assert ledger.stats()["host_retries"] == 1
+        for name in ("checkpoint.ckpt", "model_best.ckpt",
+                     "checkpoint_r1.ckpt"):
+            assert _read(d, name) == want
