@@ -52,6 +52,7 @@ def main(argv=None) -> int:
              "bf16_accum": dict(accum_cast=_ops.bf16_round_trip)}
     sound, controls = [], {c: [] for c in args.controls.split(",") if c}
     cell = runner.load_cell(args.workload)
+    model = runner.load_by_name("reference", cell["config_file"]["arch"])
     ints = lambda text: [int(s) for s in text.split(",") if s]
     for seed in ints(args.seeds) + ints(args.sound_seeds):
         res = runner.run_cell(args.workload, seed, args.seconds, False,
@@ -64,10 +65,10 @@ def main(argv=None) -> int:
                 "memory_peak_bytes": res["device"]["memory_peak_bytes"]}
         sound.append(chk["numbers"])
         for name in controls if seed in ints(args.seeds) else ():
-            ctrl = correct.run_reference(
-                chk["case"], cell["config_file"]["arch"],
+            ctrl = correct.as_program(correct.reference_rounds(
+                chk["case"], model.loss,
                 cell["traffic_file"]["algorithm"], chk["hp"],
-                **hooks[name])
+                **hooks[name]))
             nums = correct.compare(chk["case"], ctrl, chk["ref"])
             controls[name].append(nums)
             line[name] = nums

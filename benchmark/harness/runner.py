@@ -165,12 +165,12 @@ def device_info() -> dict:
             "count": len(d)}
 
 
-def memory_peak_bytes() -> int:
+def device_memory(stat: str) -> int:
+    """``stat`` of the allocator of the fullest device."""
     import jax
 
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in jax.devices()]
-    return int(max(peaks)) if peaks else 0
+    return int(max((d.memory_stats() or {}).get(stat, 0)
+                   for d in jax.devices()))
 
 
 # -- the loop's callback -------------------------------------------------
@@ -179,7 +179,8 @@ class Loop:
     """State of one run, filled by the launcher's ``round_callback``.
 
     Rounds 0-2 are the rounds ``correct`` follows: the server's
-    parameters are copied to the host after each. The first whole
+    parameters are copied to the host after the first and the last
+    (``compare`` reads no other). The first whole
     cycles that hold ``WARM_ROUNDS`` rounds are set-up; the window is
     the whole cycles after them; at the callback that closes it the
     loop reads the device's memory peak and asks the launcher for its
@@ -205,6 +206,7 @@ class Loop:
         self.stamps: Dict[int, float] = {}
         self.lost = 0.0     # seconds spent inside this callback so far
         self.params_after: Dict[int, dict] = {}
+        self.copy_s = 0.0   # of ``lost``: the parameters' copies to the host
         self.trainer = None
         self.open_round: Optional[int] = None     # last warm-up round
         self.close_round: Optional[int] = None
@@ -228,17 +230,26 @@ class Loop:
         finally:
             self.lost += time.perf_counter() - t_in
 
+    def _copy(self, params):
+        """The server's parameters on the host, for ``correct``."""
+        import jax
+
+        t0 = time.perf_counter()
+        out = jax.device_get(params)
+        self.copy_s += time.perf_counter() - t0
+        return out
+
     def _on_round(self, r, trainer, server, clients) -> None:
         import jax
 
         if self.close_round is not None:
             if r == self.close_round + 1:
                 # the drain's extra round: the late round of ``correct``
-                self.late["after"] = jax.device_get(server.params)
+                self.late["after"] = self._copy(server.params)
             return
         self.trainer = trainer
-        if r < self.CHECK_ROUNDS:
-            self.params_after[r] = jax.device_get(server.params)
+        if r in (0, self.CHECK_ROUNDS - 1):
+            self.params_after[r] = self._copy(server.params)
         if r == self.warm - 1:
             self.open_round = r
             self.setup_s = time.time() - self.t_start
@@ -272,13 +283,13 @@ class Loop:
             self._tracing = False
             self.trace_rounds = (self.trace_rounds[0], r)
         self.close_round = r
-        self.peak_bytes = memory_peak_bytes()
+        self.peak_bytes = device_memory("peak_bytes_in_use")
         self._sentinel.__exit__(None, None, None)
         self.traces_in_window = dict(self._sentinel.counts)
         self.late = {"round": r + 1,
                      "dtype_mismatch": correct_mod.dtype_mismatch(
                          (server.params, clients), self.stated),
-                     "before": jax.device_get(server.params),
+                     "before": self._copy(server.params),
                      "state": self._client_state(server, clients,
                                                  r + 1)}
         os.kill(os.getpid(), signal.SIGUSR1)   # the documented drain
@@ -394,9 +405,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         "samples_per_round": per_round, "trace": None,
         "device": device_info(),
     }
+    loop_rate = window.samples_per_s_chip(
+        len(wrows), per_round, window_s, cell["chips"])
     end_to_end = {
-        "samples_per_s_chip": window.samples_per_s_chip(
-            len(wrows), per_round, window_s, cell["chips"]),
         "round_s_p50": statistics.median(round_s) if round_s else None,
         "peak_hbm_gib": loop.peak_bytes / GIB,
         "setup_s": loop.setup_s,
@@ -408,7 +419,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             round_s, n=4)] if len(round_s) > 1 else round_s)
         + "; cycle walls " + str([round(c, 3) for c in window.cycle_walls(
             loop.stamps, loop.open_round, last, loop.eval_freq)]))
-    log("benchmark: window checkpoint_s "
+    log(f"benchmark: window {loop_rate:.1f} samples/s/chip, checkpoint_s "
         + str([round(r["checkpoint_s"], 2) for r in wrows
                if "checkpoint_s" in r][:12])
         + " eval_s " + str([round(r["eval_s"], 2) for r in wrows
@@ -433,12 +444,26 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                    for m in metrics_for(cell, "end_to_end")
                    if end_to_end[m["name"]] is not None}
 
-    # the reference runs once the launcher has returned and its state
-    # is dropped: the memory peak above stays the program's
+    # the reference runs once the launcher has returned and nothing of
+    # the program is left on the device (its state, and the trainer
+    # with its store once the reference's inputs are rebuilt): the
+    # memory peak above stays the program's
     trainer, loop.trainer = loop.trainer, None
-    verdict = correct_mod.check(cell, cfg, trainer, loop.params_after,
-                                loop.late, rows)
-    log("benchmark: reference done")
+    t0 = time.perf_counter()
+    case = correct_mod.gather_case(cfg, trainer, loop.late,
+                                   loop.CHECK_ROUNDS)
+    inputs_s = time.perf_counter() - t0
+    del trainer, results
+    log(f"benchmark: {device_memory('bytes_in_use') / GIB:.3f} GiB in use "
+        "on the device as the reference starts")
+    copies = len(loop.params_after) + 2
+    verdict = correct_mod.check(cell, cfg, case, loop.params_after,
+                                loop.late, rows, keep=keep_check)
+    log(f"benchmark: correct cost: the callback's {copies} copies of "
+        f"the parameters {loop.copy_s:.2f}s, the reference's inputs "
+        f"{inputs_s:.2f}s, its {loop.CHECK_ROUNDS + 1} rounds "
+        f"{verdict['seconds']['reference']:.2f}s, the comparison "
+        f"{verdict['seconds']['compare']:.2f}s")
     for line in verdict["lines"]:
         print("benchmark: correct: " + line, flush=True)
     for d in (run_dir, trace_dir):
@@ -452,6 +477,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     out["workload"] = workload
     out["window"] = {"rounds": len(wrows), "seconds": window_s,
                      "first_round": first, "last_round": last}
+    # each number compared beside its limit: the result's last key and
+    # the run's last lines on standard error
+    out["compared"] = verdict["compared"]
+    for name, c in out["compared"].items():
+        sys.stderr.write(f"benchmark: compared {name} = {c['value']} "
+                         f"limit {c['limit']}\n")
+    sys.stderr.flush()
     return out
 
 

@@ -32,6 +32,12 @@ def test_throughput_counts_all_work_over_all_time():
     # 30 rounds of 10 clients x 10 steps x 50 images in 24 s on 1 chip
     assert w.samples_per_s_chip(30, 5000, 24.0, 1) == pytest.approx(6250.0)
     assert w.samples_per_s_chip(30, 5000, 24.0, 4) == pytest.approx(1562.5)
+    # the per-layer reader takes the same from a run's context
+    from benchmark.layer_metrics import loop_samples_per_s_chip as reader
+    ctx = {"rows": [{}] * 30, "samples_per_round": 5000,
+           "window": {"seconds": 24.0}, "cell": {"chips": 4}}
+    assert reader.read(ctx) == pytest.approx(1562.5)
+    assert reader.read(dict(ctx, rows=[])) is None
 
 
 def test_train_iterations_and_cycles_from_the_callback_stamps():
