@@ -23,6 +23,7 @@ from fedtorch_tpu.config import ExperimentConfig
 from fedtorch_tpu.core import optim
 from fedtorch_tpu.core.losses import accuracy  # noqa: F401 (hook use)
 from fedtorch_tpu.core.state import tree_scale
+from fedtorch_tpu.models.common import is_token_model
 
 
 def num_online_effective(online_idx: jnp.ndarray) -> jnp.ndarray:
@@ -183,6 +184,25 @@ class FedAlgorithm:
 
         Returns (params, opt, client_aux, rnn_carry, loss, acc)."""
         model, criterion, cfg = self.model, self.criterion, self.cfg
+
+        if is_token_model(model):
+            # a token model makes its target from the batch itself (the
+            # next token); a row's label takes no part in the loss
+            def token_loss_fn(p):
+                loss, acc = model.token_loss(p, bx, train=True, rng=rng)
+                return loss + self.extra_loss(p, server_params,
+                                              client_aux), acc
+
+            with jax.named_scope("fed.forward_backward"):
+                (loss, acc), grads = jax.value_and_grad(
+                    token_loss_fn, has_aux=True)(params)
+                grads = self.transform_grads(
+                    grads, params=params, server_params=server_params,
+                    client_aux=client_aux, server_aux=server_aux, lr=lr)
+            with jax.named_scope("fed.opt_step"):
+                params, opt = optim.local_step(params, grads, opt, lr,
+                                               cfg.optim)
+            return params, opt, client_aux, rnn_carry, loss, acc
 
         moe_w = cfg.model.moe_aux_weight
 

@@ -133,6 +133,13 @@ def build_parser() -> argparse.ArgumentParser:
                    help="Switch load-balance aux-loss weight (0.01 in "
                         "the paper); 0 disables and the gate can "
                         "collapse onto one expert")
+    p.add_argument("--model_spec", default=None,
+                   help="arch hybrid_lm: a JSON file with the keys of "
+                        "a public config.json (hidden_size, "
+                        "intermediate_size, num_hidden_layers, "
+                        "layer_types, num_attention_heads, linear_*, "
+                        "vocab_size, rms_norm_eps) the model's shape "
+                        "is read from")
     p.add_argument("--attention", default="auto",
                    choices=("auto", "dense", "flash"),
                    help="transformer attention backend: 'flash' = fused "
@@ -432,7 +439,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "transformer: ~1.33x FLOPs for depth-independent "
                         "activation memory")
     p.add_argument("--client_fusion", default="auto",
-                   choices=("auto", "vmap", "fused"),
+                   choices=("auto", "vmap", "fused", "sequential"),
                    help="client-axis execution strategy for the round "
                         "program's model compute: 'fused' packs the k "
                         "online clients into one feature_group_count=k "
@@ -567,7 +574,8 @@ def args_to_config(args) -> ExperimentConfig:
             moe_experts=args.moe_experts,
             moe_capacity_factor=args.moe_capacity_factor,
             moe_aux_weight=args.moe_aux_weight,
-            attention=args.attention),
+            attention=args.attention,
+            spec_file=args.model_spec),
         optim=OptimConfig(
             optimizer=args.optimizer, lr=args.lr,
             in_momentum=args.in_momentum,

@@ -97,7 +97,7 @@ FEDERATED_ALGORITHMS = (
 DATASETS = (
     "cifar10", "cifar100", "mnist", "fashion_mnist", "emnist", "emnist_full",
     "synthetic", "shakespeare", "adult", "epsilon", "MSD", "higgs", "rcv1",
-    "stl10",
+    "stl10", "tokens",
 )
 
 
@@ -279,6 +279,10 @@ class ModelConfig:
     # (default): per-sequence-length dispatch that picks flash at
     # T >= 4096 (ops/attention_dispatch.py:resolve_attention)
     attention: str = "auto"
+    # arch 'hybrid_lm' only: a JSON file with the keys of a public
+    # config.json (hidden_size, layer_types, linear_*, vocab_size, ...)
+    # that the model's shape is read from (models/hybrid_lm.py)
+    spec_file: Optional[str] = None
     pretrained: bool = False
     # 'robust_*' archs learn an adversarial input-noise parameter.
     robust_noise_ascent_lr: float = 0.1
@@ -834,9 +838,11 @@ class ExperimentConfig:
             raise ValueError(
                 f"model.attention must be 'auto', 'dense' or 'flash', "
                 f"got {self.model.attention!r}")
-        if self.mesh.client_fusion not in ("auto", "vmap", "fused"):
+        if self.mesh.client_fusion not in ("auto", "vmap", "fused",
+                                           "sequential"):
             raise ValueError(
-                f"mesh.client_fusion must be 'auto', 'vmap' or 'fused', "
+                f"mesh.client_fusion must be 'auto', 'vmap', 'fused' or "
+                f"'sequential', "
                 f"got {self.mesh.client_fusion!r}")
         cs = self.mesh.client_shards
         if cs < 0 or cs > 64 or (cs > 0 and cs & (cs - 1)):

@@ -175,8 +175,18 @@ def adam_server_step(params, direction, state: AdamState, scale,
 
 # -- Dispatch ---------------------------------------------------------------
 
-def init_opt_state(params, cfg: OptimConfig):
+def init_opt_state(params, cfg: OptimConfig, lean: bool = False):
+    """``lean`` (the sequential execution, parallel/federated.py)
+    allocates no SGD buffer whose momentum is off: such a buffer is
+    carried through every step and never read."""
     if cfg.optimizer == "sgd":
+        if lean:
+            zeros = lambda on: jax.tree.map(jnp.zeros_like, params) \
+                if on else ()
+            return SGDState(
+                in_buf=zeros(cfg.in_momentum and cfg.in_momentum_factor),
+                out_buf=zeros(cfg.out_momentum
+                              and cfg.out_momentum_factor))
         return init_sgd(params)
     if cfg.optimizer in ("adam", "adamw"):
         return init_adam(params)

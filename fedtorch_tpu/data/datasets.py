@@ -307,6 +307,35 @@ def load_shakespeare(data_dir: str, seq_len: int = 50,
                          client_partitions=parts)
 
 
+# -- token rows --------------------------------------------------------------
+
+def load_tokens(data_dir: str) -> DatasetSplits:
+    """Fixed-length rows of token ids, one client's after another's:
+    ``<data_dir>/tokens/train.npz`` with ``x`` (int32 ``[rows,
+    seq_len]``) and ``client`` (the client each row belongs to, sorted),
+    and ``test.npz`` with ``x``. ``y`` is one number a row (the row's
+    client; 0 for test rows) and takes no part in the loss: a token
+    model makes its next-token target from ``x``
+    (models/hybrid_lm.py). No packing, segment ids or document masks."""
+    base = os.path.join(data_dir, "tokens")
+    train_p = os.path.join(base, "train.npz")
+    if not os.path.exists(train_p):
+        raise _missing("tokens", train_p)
+    with np.load(train_p) as f:
+        x = np.asarray(f["x"], np.int32)
+        client = np.asarray(f["client"], np.int32)
+    if np.any(np.diff(client) < 0):
+        raise ValueError(f"{train_p}: rows must be sorted by client")
+    with np.load(os.path.join(base, "test.npz")) as f:
+        test_x = np.asarray(f["x"], np.int32)
+    bounds = np.searchsorted(client, np.arange(int(client.max()) + 2))
+    parts = [np.arange(bounds[i], bounds[i + 1])
+             for i in range(len(bounds) - 1)]
+    return DatasetSplits(x, client, test_x,
+                         np.zeros(len(test_x), np.int32),
+                         client_partitions=parts)
+
+
 # -- LibSVM datasets --------------------------------------------------------
 
 _LIBSVM_FILES = {
@@ -542,6 +571,8 @@ def get_dataset(cfg: DataConfig, num_clients: int,
                            allow_train_as_test=cfg.allow_train_as_test)
     if name == "shakespeare":
         return load_shakespeare(root, seq_len=seq_len, download=download)
+    if name == "tokens":
+        return load_tokens(root)
     if name in _LIBSVM_FILES:
         return load_libsvm(name, root, download)
     if name == "adult":

@@ -326,7 +326,10 @@ def _audit_config(source: str, dispatch: str, execution: str,
         model=ModelConfig(arch="logistic_regression"),
         optim=OptimConfig(lr=0.3, weight_decay=0.0),
         train=TrainConfig(local_step=2),
-        mesh=MeshConfig(client_fusion=facts["client_fusion"],
+        # the sequential fold is one device's
+        mesh=MeshConfig(num_devices=1 if execution == "sequential"
+                        else None,
+                        client_fusion=facts["client_fusion"],
                         compute_dtype=compute_dtype,
                         client_shards=facts["client_shards"]),
     ).finalize()

@@ -31,7 +31,7 @@ from fedtorch_tpu.models.wideresnet import WideResNet, build_wideresnet
 MODEL_NAMES = (
     "logistic_regression", "robust_logistic_regression", "least_square",
     "robust_least_square", "mlp", "robust_mlp", "cnn", "rnn",
-    "transformer",
+    "transformer", "hybrid_lm",
     # prefix families:
     "resnet*", "wideresnet*", "densenet*",
 )
@@ -85,6 +85,17 @@ def define_model(cfg: ExperimentConfig, batch_size: int = 2) -> ModelDef:
     arch = cfg.model.arch
     dataset = cfg.data.dataset
     m = cfg.model
+    if arch == "hybrid_lm":
+        # the shape comes from a file with the public config.json's
+        # keys, not from the RNN's fields
+        from fedtorch_tpu.models.hybrid_lm import HybridLM, load_spec
+        if not m.spec_file:
+            raise ValueError(
+                "arch 'hybrid_lm' reads its shape from a file: pass "
+                "--model_spec <config.json>")
+        return HybridLM(arch, load_spec(m.spec_file),
+                        dtype=cfg.mesh.compute_dtype,
+                        attention=m.attention, remat=cfg.mesh.remat)
     if cfg.mesh.remat and not (
             arch.startswith(("resnet", "wideresnet", "densenet"))
             or arch == "transformer"):

@@ -283,6 +283,13 @@ class _GN(nn.Module):
         return nn.GroupNorm(num_groups=max(groups, 1))(x)
 
 
+def is_token_model(model) -> bool:
+    """A model that makes its target from the batch itself (the next
+    token, models/hybrid_lm.py): its loss is ``model.token_loss(params,
+    x)`` and a row's label takes no part in it."""
+    return getattr(model, "token_loss", None) is not None
+
+
 class ModelDef(NamedTuple):
     """A model as pure functions — replaces the reference's nn.Module
     objects held by each Client (nodes/nodes.py:43-62).

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedtorch_tpu.core.losses import make_criterion, topk_accuracy
-from fedtorch_tpu.models.common import ModelDef
+from fedtorch_tpu.models.common import ModelDef, is_token_model
 from fedtorch_tpu.utils.tracing import instrument_trace
 
 
@@ -115,13 +115,20 @@ def evaluate(model: ModelDef, params, x: np.ndarray, y: np.ndarray,
     scanning over batches on device with padding masks. Robust archs get
     the adversarial noise-ascent prelude (eval.py:59-68) unless
     ``robust_ascent=False``."""
+    token = is_token_model(model)
+    if token:
+        # a row is thousands of tokens: a few rows a step
+        batch_size = model.eval_batch
     bx, by, bm = _pad_batches(np.asarray(x), np.asarray(y), batch_size)
     bx, by, bm = jnp.asarray(bx), jnp.asarray(by), jnp.asarray(bm)
     if model.has_noise_param and robust_ascent:
         # pad/upload once; the ascent shares the same device batches
         params = _ascent_on_batches(model, params, bx, by, bm)
 
-    key = (model.module, model.is_regression, model.is_recurrent)
+    # a token model is a tuple of hashables, dtype and backend among
+    # them: the whole of it is the key
+    key = model if token else \
+        (model.module, model.is_regression, model.is_recurrent)
     if key not in _EVAL_CACHE:
         # params is the live server model, reused every round —
         # donation would be unsafe here
@@ -142,6 +149,10 @@ def _eval_run_fn(model: ModelDef):
                     params, xb, carry=model.init_carry(xb.shape[0]))
             else:
                 logits = model.apply(params, xb)
+            if is_token_model(model):
+                # next-token loss and top-k: position t's logits
+                # against token t + 1 of the same row
+                logits, yb = logits[:, :-1], xb[:, 1:]
             if logits.ndim == 3:
                 # sequence model ([B, T, V] logits, [B, T] targets):
                 # per-token statistics over the flattened time axis
@@ -185,6 +196,8 @@ def lowered_eval_program(model: ModelDef, params, x: np.ndarray,
     is identical) against abstract padded-batch inputs: the ``eval``
     entry of ``program_costs.json`` (telemetry.costs). Lowering
     executes nothing on device."""
+    if is_token_model(model):
+        batch_size = model.eval_batch
     bx, by, bm = _pad_batches(np.asarray(x), np.asarray(y), batch_size)
     sds = jax.ShapeDtypeStruct
     return jax.jit(_eval_run_fn(model)).lower(

@@ -82,7 +82,7 @@ from fedtorch_tpu.data.batching import (
 from fedtorch_tpu.data.streaming import (
     HostClientStore, MmapClientStore, RoundFeed, StreamFeedProducer,
 )
-from fedtorch_tpu.models.common import ModelDef
+from fedtorch_tpu.models.common import ModelDef, is_token_model
 from fedtorch_tpu.ops.augment import augment_image_batch
 from fedtorch_tpu.parallel.fusion import resolve_client_fusion
 from fedtorch_tpu.parallel.round_program import (
@@ -344,6 +344,12 @@ class FederatedTrainer:
         # reference's cifar transform, prepare_data.py:29-35);
         # ClientData x is [clients, N, H, W, C] for image datasets
         self.augment = bool(cfg.data.augment) and data.x.ndim == 5
+        # a token model trains on rows of token ids ([C, n, T] store):
+        # what a round trains, for the row's ``tokens_trained`` counter
+        self.tokens_per_round = (
+            self.k_online * self.local_steps
+            * self.batch_size * int(data.x.shape[2])
+            if is_token_model(model) and data.x.ndim == 3 else 0)
 
         num_epochs = cfg.train.num_epochs or 1
         self.schedule: LRSchedule = compile_schedule(
@@ -473,15 +479,29 @@ class FederatedTrainer:
     def init_state(self, rng: jax.Array) -> Tuple[ServerState, ClientState]:
         rng, init_rng = jax.random.split(rng)
         params = self.model.init(init_rng)
+        sequential = self.client_fusion == "sequential"
         server = ServerState(
             params=params,
-            opt=optim.init_opt_state(params, self.cfg.optim),
+            opt=optim.init_opt_state(params, self.cfg.optim,
+                                     lean=sequential),
             aux=self.algorithm.init_server_aux(params, self.num_clients),
             round=jnp.zeros((), jnp.int32),
             rng=rng)
         # client states cover the PADDED axis so they shard evenly; the
         # padding tail is dead weight that is never gathered by idx
         C = self.padded_clients
+        if sequential:
+            # every client starts its round from the server's
+            # parameters and nothing reads its own back, so the state
+            # holds no [C]-leading parameter-sized leaf: the counters
+            # alone (validate_cell refused what needs more)
+            clients = ClientState(
+                params=(), opt=optim.init_opt_state((), self.cfg.optim,
+                                                    lean=True),
+                aux=(), epoch=jnp.zeros((C,)),
+                local_index=jnp.zeros((C,), jnp.int32))
+            return replicate(server, self.mesh), \
+                shard_clients(clients, self.mesh)
 
         def one_client(_):
             return ClientState(
@@ -684,6 +704,12 @@ class FederatedTrainer:
         ``plan`` substitutes a caller-built chaos plan (async stragglers
         are arrival DELAYS, not step cuts). All four default to None,
         which traces exactly the synchronous program."""
+        if self.client_fusion == "sequential":
+            # validate_cell refused the commit seam, chaos, guards,
+            # robust rules, DP, val streams and shard gathers by name
+            return self._round_core_sequential(
+                server, clients, idx, on_x, on_y, on_sizes, rng_round,
+                rngs, data=data, probe=probe)
         # norm_bound robust aggregation carries its server momentum in
         # server.aux ({'alg': ..., 'norm_bound_m': ...}); every
         # algorithm hook below reads the unwrapped ALG aux. The async
@@ -1283,6 +1309,125 @@ class FederatedTrainer:
                 **avail_fields, **cohort_fields, **dp_fields)
         return new_server, new_clients, metrics
 
+    # -- sequential round (cfg.mesh.client_fusion='sequential') -----------
+    def _round_core_sequential(self, server: ServerState,
+                               clients: ClientState, idx, on_x, on_y,
+                               on_sizes, rng_round, rngs, *, data=None,
+                               probe=None):
+        """``_round_core`` for a model too large to stack: the cohort's
+        clients run ONE AFTER ANOTHER, each from the server's
+        parameters through its K steps, and each result is folded into
+        a running weighted sum (``fed.fold``) and let go of. Three
+        parameter-sized trees are live at the peak (the server's, the
+        running client's, the sum) where the vmapped round holds
+        2k + 1 and the state C more. The cohort draw, row plan, keys,
+        weights, local step, payload and server step are the ones the
+        vmapped round uses (same hooks, same order), so the two agree
+        to the rounding of the sum's order
+        (tests/test_sequential_round.py)."""
+        cfg, model, alg = self.cfg, self.model, self.algorithm
+        K, B, C = self.local_steps, self.batch_size, self.num_clients
+        k = idx.shape[0]
+        with jax.named_scope("fed.select"):
+            num_online_eff = num_online_effective(idx)
+            weights = alg.client_weights(server.aux, idx, num_online_eff,
+                                         on_sizes)
+        with jax.named_scope("fed.gather"):
+            on_epoch = jnp.take(clients.epoch, idx)
+            on_li = jnp.take(clients.local_index, idx)
+        # no buffer where momentum is off (validate_cell refused local
+        # momentum): nothing parameter-sized rides the step's carry
+        opt0 = optim.init_opt_state((), cfg.optim, lean=True)
+        carry0 = model.init_carry(B)
+        budget = jnp.asarray(K, jnp.int32)
+
+        def one_client(total, member):
+            x, y, size, weight, rng_c, epoch0, li0 = member
+            nb = jnp.ceil(size / B)
+
+            def step(carry, s):
+                params, epoch, li = carry
+                lr = lr_at(self.schedule, epoch)
+                bx = jax.lax.dynamic_slice_in_dim(x, s * B, B)
+                by = jax.lax.dynamic_slice_in_dim(y, s * B, B)
+                if self.augment:
+                    with jax.named_scope("fed.augment"):
+                        aug_parent = jax.random.fold_in(rng_c, 0x7FFFFFFF)
+                        bx = augment_image_batch(
+                            jax.random.fold_in(aug_parent, s), bx)
+                n_params, _, _, _, loss, acc = alg.local_step(
+                    params=params, opt=opt0, client_aux=(),
+                    rnn_carry=carry0, server_params=server.params,
+                    server_aux=server.aux, bx=bx, by=by, bval_x=None,
+                    bval_y=None, lr=lr,
+                    rng=jax.random.fold_in(rng_c, s + 1), step_idx=s,
+                    local_index=li, step_budget=budget)
+                return (n_params, epoch + 1.0 / nb, li + 1), (loss, acc)
+
+            with jax.named_scope("fed.local_steps"):
+                (params, epoch, li), (losses, accs) = jax.lax.scan(
+                    step, (server.params, epoch0, li0), jnp.arange(K))
+                with jax.named_scope("fed.fold"):
+                    payload, _ = alg.client_payload(
+                        delta=tree_sub(server.params, params),
+                        client_aux=(), params=params,
+                        server_params=server.params,
+                        server_aux=server.aux,
+                        lr=lr_at(self.schedule, epoch),
+                        local_steps=budget, weight=weight,
+                        full_loss=None)
+                    total = jax.tree.map(jnp.add, total, payload)
+            return total, (epoch, li, jnp.mean(losses), jnp.mean(accs))
+
+        with jax.named_scope("fed.local_steps"):
+            with jax.named_scope("fed.fold"):
+                zero = tree_zeros_like(server.params)
+            payload_sum, (epochs, lis, losses, accs) = jax.lax.scan(
+                one_client, zero,
+                (on_x, on_y, on_sizes, weights, rngs, on_epoch, on_li))
+        with jax.named_scope("fed.aggregate"):
+            payload_sum = alg.aggregate_transform(payload_sum)
+        with jax.named_scope("fed.server_step"):
+            new_params, new_opt, new_saux = alg.server_update(
+                server.params, server.opt, server.aux, payload_sum,
+                online_idx=idx, num_online_eff=num_online_eff,
+                client_losses=losses)
+        with jax.named_scope("fed.scatter"):
+            new_clients = clients._replace(
+                epoch=clients.epoch.at[idx].set(epochs),
+                local_index=clients.local_index.at[idx].set(lis))
+        with jax.named_scope("fed.metrics"):
+            # lint: disable=FTL005 — participation_mode is static config
+            if self.participation_mode == "sparse":
+                mask_full, loss_full, acc_full = jnp.ones((k,)), losses, \
+                    accs
+            else:
+                mask_full = jnp.zeros((C,)).at[idx].set(1.0)
+                loss_full = jnp.zeros((C,)).at[idx].set(losses)
+                acc_full = jnp.zeros((C,)).at[idx].set(accs)
+            none = jnp.zeros((), jnp.float32)   # no fault layer here
+            metrics = RoundMetrics(
+                train_loss=loss_full, train_acc=acc_full,
+                online_mask=mask_full,
+                comm_bytes=jnp.asarray(
+                    tree_bytes(server.params) * k * alg.payload_scale(),
+                    jnp.float32),
+                dropped_clients=none, straggler_clients=none,
+                rejected_updates=none, clipped_updates=none,
+                byzantine_clients=none, robust_selected=none,
+                robust_trimmed=none)
+        with jax.named_scope("fed.server_step"):
+            new_server = ServerState(params=new_params, opt=new_opt,
+                                     aux=new_saux, round=server.round + 1,
+                                     rng=server.rng)
+            if probe is not None:
+                new_server = alg.post_round_global_feed(
+                    new_server, probe, jax.random.fold_in(rng_round, 99))
+            else:
+                new_server = alg.post_round_global(
+                    new_server, data, jax.random.fold_in(rng_round, 99))
+        return new_server, new_clients, metrics
+
     # -- fused client round (cfg.mesh.client_fusion='fused') --------------
     def _fused_client_round(self, server, on_clients, x, y, sizes,
                             weights, rngs, budget_scale, batch_mode):
@@ -1581,6 +1726,8 @@ class FederatedTrainer:
         zero-extra-device-syncs by construction. Subclasses extend
         (the async plane adds its scheduler counters)."""
         out = {}
+        if self.tokens_per_round:
+            out["tokens_trained"] = float(self.tokens_per_round)
         ss = self.stream_stats()
         if ss is not None:
             out.update(ss)

@@ -87,13 +87,19 @@ def fusion_supported(cfg: ExperimentConfig, model: ModelDef,
 def resolve_client_fusion(cfg: ExperimentConfig, model: ModelDef,
                           algorithm: FedAlgorithm, mesh_devices: int,
                           k_online: int) -> Tuple[str, Optional[object]]:
-    """Resolve ``cfg.mesh.client_fusion`` -> ('vmap'|'fused', module).
+    """Resolve ``cfg.mesh.client_fusion`` ->
+    ('vmap'|'fused'|'sequential', module).
 
     'fused' raises when unsupported; 'auto' resolves to 'vmap' until
     the on-chip fused A/B lands (module docstring)."""
     mode = cfg.mesh.client_fusion
     if mode == "vmap" or mode == "auto":
         return "vmap", None
+    if mode == "sequential":
+        # the cohort one client after another into a running fold
+        # (FederatedTrainer._round_core_sequential); what it cannot
+        # serve is refused by round_program.validate_cell
+        return "sequential", None
     fused, why = fusion_supported(cfg, model, algorithm, mesh_devices,
                                   k_online)
     if fused is None:

@@ -18,8 +18,12 @@ refused multi-device) is composed here from three independent choices:
   scan family, with per-job stale bases threaded through the commit
   seam of ``_round_core``);
 * **client execution** — ``'vmap'`` (per-client model compute under
-  ``vmap``) or ``'fused'`` (one ``feature_group_count=k`` grouped conv
-  per layer — ``parallel/fusion.py``).
+  ``vmap``), ``'fused'`` (one ``feature_group_count=k`` grouped conv
+  per layer — ``parallel/fusion.py``) or ``'sequential'`` (the cohort
+  one client after another into a running weighted sum, no per-client
+  copy of the parameters at rest —
+  ``FederatedTrainer._round_core_sequential``; what needs the stacked
+  cohort is refused by :func:`_sequential_refusal`).
 
 Every cell funnels into the SAME ``FederatedTrainer._round_core``, so
 the robust-aggregation seam, chaos/guard masks, staleness weights and
@@ -80,7 +84,13 @@ from fedtorch_tpu.parallel.fusion import fusion_supported
 # new axis value can never be silently absent from the coverage matrix
 SOURCES = ("resident", "feed")
 DISPATCHES = ("round", "scan", "commit")
-EXECUTIONS = ("vmap", "fused")
+EXECUTIONS = ("vmap", "fused", "sequential")
+
+# algorithms the sequential execution serves: their client hooks are
+# the base's (a weighted delta as payload, no per-client aux read
+# back), so a client's result can be folded into the running sum and
+# let go of
+SEQUENTIAL_ALGORITHMS = ("fedavg", "fedprox", "fedadam")
 
 # algorithms wired for stale-snapshot commits (the commit dispatch):
 # their hooks read only the per-job base params/aux the snapshot ring
@@ -326,10 +336,73 @@ def illegal_reason(source: str, dispatch: str, execution: str, *, cfg,
         if fused is None:
             return f"mesh.client_fusion='fused' is unsupported: {why}"
 
+    if execution == "sequential":
+        why = _sequential_refusal(cfg, algorithm, model, dispatch,
+                                  mesh_devices, gather_mode, has_val)
+        if why is not None:
+            return ("mesh.client_fusion='sequential' runs the cohort "
+                    "one client after another into a running weighted "
+                    "sum and keeps no per-client copy of the "
+                    f"parameters: {why}")
+
     # -- gather-mode precondition shared by every cell -------------------
     if gather_mode == "batch" and algorithm.needs_full_loss:
         return (f"{algorithm.name} requires gather_mode='shard' "
                 "(it evaluates the full local dataset each round)")
+    return None
+
+
+def _sequential_refusal(cfg, algorithm, model, dispatch: str,
+                        mesh_devices: int, gather_mode: str,
+                        has_val: bool):
+    """Why the sequential execution cannot serve this configuration,
+    or None. Everything refused here needs the STACKED cohort (a rule
+    over all k updates at once, per-client state read back next round)
+    or a second program shape this execution does not trace."""
+    fed, flt = cfg.federated, cfg.fault
+    alg_name = cfg.effective_algorithm
+    if alg_name not in SEQUENTIAL_ALGORITHMS:
+        return (f"algorithm {alg_name!r} keeps per-client state or a "
+                "structured payload that has no fold yet (SCAFFOLD's "
+                "and FedGATE's variates, the personalized models, "
+                "qFFL's and AFL's cohort-global losses, DRFA's dual "
+                f"phase); supported: {', '.join(SEQUENTIAL_ALGORITHMS)}")
+    if flt.robust_agg != "mean":
+        return (f"robust_agg={flt.robust_agg!r} ranks or trims the k "
+                "stacked updates against each other; only the "
+                "weighted 'mean' is a fold")
+    if flt.chaos_enabled or flt.guard_updates or flt.avail_armed \
+            or flt.byzantine_rate > 0.0:
+        return ("chaos, update guards, byzantine adversaries and the "
+                "availability lifecycle screen and renormalize the "
+                "stacked cohort")
+    if flt.dp_armed:
+        return "the DP stage clips each of the stacked payloads"
+    if cfg.telemetry.cohort_stats:
+        return "telemetry.cohort_stats reads all k updates at once"
+    if fed.quantized or fed.compressed:
+        return ("the uplink wire format is applied to the stacked "
+                "[k] payloads")
+    if dispatch == "commit":
+        return ("buffered commits train each job against its own "
+                "stale snapshot from the ring")
+    if fed.sync_type == "epoch":
+        return ("epoch sync freezes clients by a mask over the "
+                "lockstep cohort; use --federated_sync_type local_step")
+    if cfg.optim.optimizer != "sgd" or (cfg.optim.in_momentum
+                                        and cfg.optim.in_momentum_factor):
+        return ("a local optimizer with buffers (momentum, Adam) is "
+                "per-client parameter-sized state")
+    if has_val or algorithm.needs_val_batch or fed.personal:
+        return "per-client validation splits are not threaded"
+    if gather_mode == "shard" or algorithm.needs_full_loss:
+        return "whole-shard gathers are not threaded ('batch' only)"
+    if model.is_recurrent:
+        return "a recurrent carry is not threaded"
+    if mesh_devices > 1 or int(getattr(cfg.mesh, "client_shards", 0)
+                               or 0) > 0:
+        return (f"the fold is one device's (mesh has {mesh_devices} "
+                "devices; client_shards must be 0)")
     return None
 
 

@@ -74,6 +74,9 @@ METRICS_OPTIONAL = {
     # eval results (eval rounds only; host floats from the eval fetch)
     "test_top1": "server-model test top-1 this eval",
     "best_top1": "best test top-1 so far",
+    # token models (models/hybrid_lm.py): a host counter from shapes
+    "tokens_trained": "tokens this round trained on (k clients x K "
+                      "steps x B rows x the rows' length)",
     # stream plane (trainer.stream_stats)
     "stream_depth": "prefetched feeds ready at fetch time",
     "stream_wait_s": "consumer wall blocked on the feed queue (total)",

@@ -1,0 +1,23 @@
+"""The harness that judges a 10^9-parameter cell, in the lane the
+driver runs: ``correct.compare``'s one walk over the leaves against the
+whole-tree arithmetic it replaced, and the FedAvg reference's
+three-tree round against the one it replaced. The cases live with the
+benchmark (``benchmark/tests``, which no lane of the driver collects)
+and are imported here, not copied: CPU only, seconds."""
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark.tests.test_compare import (  # noqa: E402,F401
+    test_no_whole_tree_in_float64,
+    test_one_round_followed_reads_the_same_tree_twice,
+    test_one_walk_gives_the_whole_tree_numbers,
+    test_trees_are_let_go_of_as_the_rounds_come,
+)
+from benchmark.tests.test_fedavg_reference import (  # noqa: E402,F401
+    test_at_most_three_trees_on_the_device,
+    test_round_is_bit_identical_to_the_one_it_replaced,
+)

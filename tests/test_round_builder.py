@@ -48,6 +48,8 @@ CELLS = list(iter_cells())
 ILLEGAL = {
     ("resident", "commit", "fused"),
     ("feed", "commit", "fused"),
+    ("resident", "commit", "sequential"),
+    ("feed", "commit", "sequential"),
 }
 
 CHAOS = {"client_drop_rate": 0.3, "straggler_rate": 0.3,
@@ -84,7 +86,9 @@ def make_cfg(source, *, execution="vmap", sync_mode="sync",
         model=ModelConfig(arch="logistic_regression"),
         optim=OptimConfig(lr=0.3, weight_decay=0.0),
         train=TrainConfig(local_step=3),
-        mesh=MeshConfig(client_fusion=execution),
+        # the sequential fold is one device's
+        mesh=MeshConfig(num_devices=1 if execution == "sequential"
+                        else None, client_fusion=execution),
         fault=FaultConfig(**(fault_kw or {})),
     ).finalize()
 
@@ -305,6 +309,29 @@ def test_refusal_snapshot_feed_commit_fused():
     assert str(err.value) == (
         "round-program cell (feed x commit x fused) is "
         "unsupported here: " + _COMMIT_FUSED_REASON)
+
+
+_COMMIT_SEQUENTIAL_REASON = (
+    "mesh.client_fusion='sequential' runs the cohort one client after "
+    "another into a running weighted sum and keeps no per-client copy "
+    "of the parameters: buffered commits train each job against its "
+    "own stale snapshot from the ring")
+
+
+def test_refusal_snapshot_resident_commit_sequential():
+    with pytest.raises(ValueError) as err:
+        _validate("resident", "commit", "sequential", "async")
+    assert str(err.value) == (
+        "round-program cell (resident x commit x sequential) is "
+        "unsupported here: " + _COMMIT_SEQUENTIAL_REASON)
+
+
+def test_refusal_snapshot_feed_commit_sequential():
+    with pytest.raises(ValueError) as err:
+        _validate("feed", "commit", "sequential", "async")
+    assert str(err.value) == (
+        "round-program cell (feed x commit x sequential) is "
+        "unsupported here: " + _COMMIT_SEQUENTIAL_REASON)
 
 
 def test_refusal_snapshot_scan_under_async():
