@@ -1043,12 +1043,13 @@ def run_experiment(cfg: ExperimentConfig,
                         jax.block_until_ready(server.params)
             round_time = timer.stop("round")
             # ONE batched device->host fetch for everything this loop
-            # logs (round_host_scalars) — per-scalar float() here would
-            # serialize a transfer per metric per round (lint FTL001).
-            # The ledger's per-client cohort vectors ride the SAME
-            # device_get when cohort_stats is on. A supervised healthy
-            # round already fetched the scalar dict for its health
-            # check: reuse it (only the [k] cohort vectors transfer).
+            # logs (round_host_fetch: one compiled program, one array)
+            # — per-scalar float() here would serialize a transfer per
+            # metric per round (lint FTL001). The ledger's per-client
+            # cohort vectors ride the SAME device_get when cohort_stats
+            # is on. A supervised healthy round already fetched the
+            # scalar dict for its health check: reuse it (only the [k]
+            # cohort vectors transfer).
             led_dev = trainer.cohort_fetch_dev(metrics) \
                 if ledger is not None else None
             led = None
@@ -1060,15 +1061,8 @@ def run_experiment(cfg: ExperimentConfig,
                     led = jax.device_get(led_dev)
             else:
                 with tel.span("scalar_fetch", round=r):
-                    if led_dev is None:
-                        sc = trainer.round_host_scalars(clients,
-                                                        metrics)
-                    else:
-                        sc_dev, led = jax.device_get(
-                            (trainer.round_scalars_dev(clients,
-                                                       metrics),
-                             led_dev))
-                        sc = {k: float(v) for k, v in sc_dev.items()}
+                    sc, led = trainer.round_host_fetch(
+                        clients, metrics, led_dev)
             fetch_s = time.perf_counter() - fetch_t0
             timer.add_comm(num_bytes=sc["comm_bytes"])
             # the scalar fetch blocked on the round's results: the

@@ -73,6 +73,11 @@ _HOST_RESULT_CALLS = {
 _HOST_RESULT_NAMES = {"isinstance", "issubclass", "len", "getattr",
                       "hasattr", "type", "repr", "str", "callable"}
 
+# methods whose RESULT is host values whatever they are handed: the
+# trainer's sanctioned batched fetches, one jax.device_get inside each
+# (FederatedTrainer.round_host_fetch / round_host_scalars)
+_HOST_RESULT_METHODS = {"round_host_fetch", "round_host_scalars"}
+
 # device-returning jax namespaces (callable prefixes)
 _DEVICE_CALL_PREFIXES = (
     "jax.numpy.", "jax.lax.", "jax.nn.", "jax.random.", "jax.scipy.",
@@ -489,6 +494,9 @@ class ModuleAnalysis:
                 return False  # dtype predicates / sanctioned transfer
             if isinstance(node.func, ast.Name) and \
                     node.func.id in _HOST_RESULT_NAMES:
+                return False
+            if isinstance(node.func, ast.Attribute) and \
+                    node.func.attr in _HOST_RESULT_METHODS:
                 return False
             if canon and (canon.startswith(_DEVICE_CALL_PREFIXES)
                           or canon == "jax.numpy"):

@@ -114,6 +114,55 @@ from fedtorch_tpu.robustness.privacy import (
 )
 from fedtorch_tpu.utils.tracing import instrument_trace
 
+# RoundMetrics' scalar leaves that ride the round's one fetch
+# (FederatedTrainer.round_host_fetch), as (the key the host loop logs
+# it under, the field). A field whose leaf is None has no key: absent,
+# not 0. Keys and the program's values both come from this one table.
+_SCALAR_FIELDS = (
+    ("comm_bytes", "comm_bytes"),
+    ("dropped", "dropped_clients"),
+    ("stragglers", "straggler_clients"),
+    ("rejected", "rejected_updates"),
+    ("clipped", "clipped_updates"),
+    # async commit plane: mean snapshot staleness this commit consumed
+    # (0.0 on the sync planes)
+    ("staleness", "staleness_mean"),
+    # byzantine adversary + robust aggregation counters (0 when off)
+    ("byzantine", "byzantine_clients"),
+    ("robust_selected", "robust_selected"),
+    ("robust_trimmed", "robust_trimmed"),
+    # deployment-realism lifecycle counters (0 when the availability
+    # plane is disarmed); the supervisor reads quorum_degraded from
+    # here for the avail_quorum_action='abort' escalation
+    ("avail_dropped", "avail_dropped"),
+    ("deadline_missed", "deadline_missed"),
+    ("quorum_degraded", "quorum_degraded"),
+    # the heterogeneity gauge (telemetry.cohort_stats); None when off
+    ("cohort_dispersion", "cohort_dispersion"),
+    # privacy-plane gauges (fault.dp_noise_multiplier > 0): clip
+    # saturation + applied noise stddev; None when DP is off
+    ("dp_clipped_frac", "dp_clipped_frac"),
+    ("dp_noise_sigma", "dp_noise_sigma"),
+)
+# what the program computes itself, ahead of the table's leaves
+_COMPUTED_SCALARS = ("mean_epoch", "lr", "n_online", "loss_sum",
+                     "acc_sum")
+
+
+# the per-client cohort vectors (cohort_fetch_dev's, for the ledger):
+# the scalar program reads none of them, so it is not handed them
+_NO_COHORT_VECTORS = dict.fromkeys(
+    ("cohort_idx", "cohort_online", "cohort_accept", "cohort_selected",
+     "cohort_suspicion", "cohort_staleness", "cohort_norm_q"))
+
+
+def _scalar_leaves(metrics: RoundMetrics) -> dict:
+    """key -> leaf of the table's fields that are present, in the
+    table's order."""
+    leaves = ((key, getattr(metrics, field))
+              for key, field in _SCALAR_FIELDS)
+    return {key: leaf for key, leaf in leaves if leaf is not None}
+
 
 def _sparse_participation(rng: jax.Array, num_clients: int,
                           k: int) -> jnp.ndarray:
@@ -469,9 +518,27 @@ class FederatedTrainer:
             donate_argnums=(0, 1)) if self.data_plane == "stream" \
             else None
         self._rounds_jit: dict = {}  # num_rounds -> jitted scan driver
+        # the round's log scalars as ONE program handing over ONE
+        # array (round_host_fetch): built here, traced at the first
+        # fetch and once more for each set of metrics leaves it meets
+        # (cohort stats / DP gauges armed); the result replicated, so
+        # every process can fetch it
+        self.scalars_trace_name = "federated.round_scalars"
+        # lint: disable=FTL004 — the epochs and metrics handed in live on
+        self._scalars_jit = jax.jit(
+            instrument_trace(self.scalars_trace_name,
+                             self._round_scalars),
+            out_shardings=replicated_sharding(self.mesh))
+        # the schedule enters that program as an ARGUMENT, placed on
+        # the mesh once: closed over, its arrays are constants XLA
+        # folds (a division by a constant becomes a product with its
+        # reciprocal) and the logged lr leaves lr_at's eager value by
+        # one place in the last (tests/test_round_scalars.py)
+        self._schedule_dev = jax.device_put(
+            self.schedule, replicated_sharding(self.mesh))
         # preemption stop-flag plumbing (robustness/preemption.py):
         # attach_stop_signal folds a cross-host-agreed stop flag into
-        # round_scalars_dev; nothing here touches the round program
+        # round_host_fetch; nothing here touches the round program
         self._stop_signal: Optional[Callable[[], bool]] = None
         self._stop_reduce = None  # lazily-jitted cross-process max
 
@@ -1561,8 +1628,12 @@ class FederatedTrainer:
         one sanctioned reduction over client state: the padded tail
         (pad_client_axis) never advances, so naive means are biased by
         real/padded. Single definition shared by every consumer
-        (mean_client_epoch, round_host_scalars, the LocalSGD loop)."""
-        return jnp.mean(clients.epoch[:self.num_clients])
+        (mean_client_epoch, the round's scalar program, the LocalSGD
+        loop)."""
+        return self._mean_epoch(clients.epoch)
+
+    def _mean_epoch(self, epoch) -> jnp.ndarray:
+        return jnp.mean(epoch[:self.num_clients])
 
     def mean_client_epoch(self, clients) -> float:
         return float(jax.device_get(self._mean_epoch_dev(clients)))
@@ -1571,7 +1642,7 @@ class FederatedTrainer:
     def attach_stop_signal(self, fn: Callable[[], bool]) -> None:
         """Register a zero-arg host callable (e.g.
         ``PreemptionHandler.stop_requested``) polled once per round.
-        Its value is folded into :meth:`round_scalars_dev` as the
+        Its value is folded into :meth:`round_host_fetch` as the
         ``"stop"`` entry — on multi-host meshes as a cross-process max
         reduction, so every process agrees on the stop round (a host
         that exits while its peers enter the next round's collective
@@ -1579,13 +1650,14 @@ class FederatedTrainer:
         fetch means the agreement costs no extra transfer."""
         self._stop_signal = fn
 
-    def stop_flag_dev(self, local_stop: bool) -> jnp.ndarray:
-        """Device scalar = max of ``local_stop`` over all processes
-        (1.0 if ANY host wants to stop). Single-process meshes skip
-        the collective entirely."""
+    def stop_flag_dev(self, local_stop: bool):
+        """Scalar = max of ``local_stop`` over all processes (1.0 if
+        ANY host wants to stop), on the device. A single process skips
+        the collective and the device entirely: its flag is the host
+        value, which ``jax.device_get`` passes through."""
         flag = np.float32(1.0 if local_stop else 0.0)
         if jax.process_count() == 1:
-            return jnp.asarray(flag)
+            return flag
         sh = client_sharding(self.mesh)
         n = int(self.mesh.devices.size)
         local_rows = sum(1 for d in self.mesh.devices.flat
@@ -1631,63 +1703,73 @@ class FederatedTrainer:
             aux = {"alg": aux, "ring": ring}
         return server._replace(aux=aux)
 
-    def round_scalars_dev(self, clients, metrics) -> dict:
-        """DEVICE-side dict of everything the host round loop logs —
-        no transfer here, so callers (the CLI loop, the round
-        supervisor) can extend it and pay ONE ``device_get`` total.
-        With a stop signal attached (:meth:`attach_stop_signal`) the
-        dict also carries the SPMD-agreed ``"stop"`` flag."""
-        mean_epoch = self._mean_epoch_dev(clients)
-        out = {
-            "mean_epoch": mean_epoch,
-            # the logged LR is a jnp computation over the schedule
-            # arrays — evaluate it on device and ride the same fetch
-            "lr": lr_at(self.schedule, mean_epoch),
-            "n_online": jnp.sum(metrics.online_mask),
-            "loss_sum": jnp.sum(metrics.train_loss),
-            "acc_sum": jnp.sum(metrics.train_acc),
-            "comm_bytes": metrics.comm_bytes,
-            "dropped": metrics.dropped_clients,
-            "stragglers": metrics.straggler_clients,
-            "rejected": metrics.rejected_updates,
-            "clipped": metrics.clipped_updates,
-            # async commit plane: mean snapshot staleness this commit
-            # consumed (0.0 on the sync planes) — riding the same fetch
-            "staleness": metrics.staleness_mean,
-            # byzantine adversary + robust aggregation counters (0 when
-            # off) — same single batched fetch
-            "byzantine": metrics.byzantine_clients,
-            "robust_selected": metrics.robust_selected,
-            "robust_trimmed": metrics.robust_trimmed,
-            # deployment-realism lifecycle counters (0 when the
-            # availability plane is disarmed) — same single fetch; the
-            # supervisor reads quorum_degraded from here for the
-            # avail_quorum_action='abort' escalation
-            "avail_dropped": metrics.avail_dropped,
-            "deadline_missed": metrics.deadline_missed,
-            "quorum_degraded": metrics.quorum_degraded,
-        }
-        if metrics.cohort_dispersion is not None:
-            # the heterogeneity gauge (telemetry.cohort_stats) rides
-            # the same fetch; absent — not 0 — when stats are off
-            out["cohort_dispersion"] = metrics.cohort_dispersion
-        if metrics.dp_clipped_frac is not None:
-            # privacy-plane gauges (fault.dp_noise_multiplier > 0):
-            # clip saturation + applied noise stddev, same fetch;
-            # absent — not 0 — when DP is off
-            out["dp_clipped_frac"] = metrics.dp_clipped_frac
-            out["dp_noise_sigma"] = metrics.dp_noise_sigma
-        if self._stop_signal is not None:
-            out["stop"] = self.stop_flag_dev(bool(self._stop_signal()))
-        return out
+    def _round_scalars(self, schedule: LRSchedule, epoch,
+                       metrics: RoundMetrics):
+        """Body of the round's scalar program
+        (``self.scalars_trace_name``; under ``jax.jit`` only, which
+        specialises on the leaves ``metrics`` holds):
+        ``_COMPUTED_SCALARS`` then ``_scalar_leaves(metrics)``, as ONE
+        float32 array, so one copy brings them to the host. Nothing is
+        cast: a leaf of another dtype is refused when the program is
+        traced."""
+        leaves = _scalar_leaves(metrics)
+        if self.padded_clients != self.num_clients:
+            # a padded axis is cut on a replicated copy and summed in
+            # index order on every device: what eager _mean_epoch_dev
+            # does (a slice whose length does not divide over the mesh
+            # comes back replicated); summed shard by shard, the mean
+            # differs from that in the last place
+            epoch = jax.lax.with_sharding_constraint(
+                epoch, replicated_sharding(self.mesh))
+        mean_epoch = self._mean_epoch(epoch)
+        values = (mean_epoch,
+                  # the logged LR is a jnp computation over the
+                  # schedule's arrays
+                  lr_at(schedule, mean_epoch),
+                  jnp.sum(metrics.online_mask),
+                  jnp.sum(metrics.train_loss),
+                  jnp.sum(metrics.train_acc), *leaves.values())
+        for key, v in zip(_COMPUTED_SCALARS + tuple(leaves), values):
+            if jnp.result_type(v) != jnp.float32 or jnp.ndim(v):
+                raise TypeError(
+                    f"round scalar {key!r} is {jnp.result_type(v)}"
+                    f"{list(jnp.shape(v))}, not a float32 scalar: "
+                    "the packed fetch casts nothing")
+        return jnp.stack(values)
+
+    def round_host_fetch(self, clients, metrics, extra=None) -> tuple:
+        """``(scalars, extra on the host)``: everything the host round
+        loop logs, as Python floats by key, and whatever further
+        device values the caller wants with them (the ledger's cohort
+        vectors, the supervisor's finite flag), in ONE batched
+        ``device_get``. The scalars are ONE compiled program's ONE
+        array: the program is handed ``clients.epoch`` and the
+        metrics' scalar leaves, never the client-state tree (a
+        program's dispatch costs by the leaves it is handed). With a
+        stop signal attached (:meth:`attach_stop_signal`) the dict
+        also carries the SPMD-agreed ``"stop"`` flag: a host value on
+        one process, the cross-process max in the same fetch on
+        several."""
+        packed = self._scalars_jit(
+            self._schedule_dev, clients.epoch,
+            metrics._replace(**_NO_COHORT_VECTORS))
+        stop = None if self._stop_signal is None else \
+            self.stop_flag_dev(bool(self._stop_signal()))
+        values, stop, extra = jax.device_get((packed, stop, extra))
+        scalars = dict(zip(
+            _COMPUTED_SCALARS + tuple(_scalar_leaves(metrics)),
+            values.tolist()))
+        if stop is not None:
+            scalars["stop"] = float(stop)
+        return scalars, extra
 
     def cohort_fetch_dev(self, metrics) -> Optional[dict]:
         """Device-side per-client cohort vectors for the ledger
         (telemetry/ledger.py): online ids, survive/accept/selection
         masks, the robust rule's suspicion, per-job staleness, and the
         [5] update-norm quantiles. None when ``cohort_stats`` is off.
-        The CLI loop batches this dict into the SAME ``device_get`` as
-        :meth:`round_scalars_dev`, so the per-round device-sync count
+        The CLI loop hands this dict to :meth:`round_host_fetch` as its
+        ``extra``, so the per-round device-sync count
         stays at the one fetch (docs/observability.md "Federation
         plane")."""
         if metrics.cohort_idx is None:
@@ -1707,8 +1789,7 @@ class FederatedTrainer:
         ``device_get`` — the per-round alternative to a pile of
         ``float(...)`` calls that each block on a separate transfer
         (fedtorch_tpu.lint FTL001; docs/static_analysis.md)."""
-        return {k: float(v) for k, v in jax.device_get(
-            self.round_scalars_dev(clients, metrics)).items()}
+        return self.round_host_fetch(clients, metrics)[0]
 
     # -- telemetry gauges (fedtorch_tpu.telemetry) ------------------------
     def stream_stats(self) -> Optional[dict]:

@@ -15,7 +15,7 @@ the signal into a *clean drain*:
    collective), so the local flag is folded into the per-round scalar
    fetch as a tiny cross-host max-reduce
    (``FederatedTrainer.attach_stop_signal`` /
-   ``round_scalars_dev["stop"]``) — every process sees the same value
+   ``round_host_fetch``'s ``"stop"``) — every process sees the same value
    on the same round, at no extra transfer.
 3. The loop drains the :class:`~fedtorch_tpu.utils.AsyncCheckpointer`,
    writes a final checkpoint, and exits with the restartable code

@@ -167,10 +167,10 @@ class RoundSupervisor:
         transfer per scalar (lint FTL001). The fetched scalars are
         kept on ``self.last_scalars`` so the host round loop reuses
         them instead of paying a second transfer."""
-        dev = self.trainer.round_scalars_dev(clients, metrics)
-        dev["finite"] = model_norms(server.params)["all_finite"]
-        dev["round"] = server.round
-        h = {k: float(v) for k, v in jax.device_get(dev).items()}
+        h, (finite, rnd) = self.trainer.round_host_fetch(
+            clients, metrics,
+            (model_norms(server.params)["all_finite"], server.round))
+        h["finite"], h["round"] = float(finite), float(rnd)
         self.last_scalars = h
         n = h["n_online"]
         return {"finite": bool(h["finite"]), "n": n,

@@ -55,6 +55,13 @@ if ckpt_dir:
 loss = float(metrics.train_loss.sum()) / 10.0
 epoch = trainer.mean_client_epoch(clients)
 assert loss == loss and epoch > 0, (loss, epoch)
+# so is the round's one fetch (one program, one replicated array), and
+# the stop flag one process raises reaches both as the max
+trainer.attach_stop_signal(lambda: pid == 1)
+sc = trainer.round_host_scalars(clients, metrics)
+assert sc["stop"] == 1.0, sc
+assert sc["mean_epoch"] == epoch and sc["loss_sum"] / 10.0 == loss, sc
+assert sc["n_online"] == 10.0 and sc["lr"] > 0.0, sc
 print(f"MULTIHOST_OK pid={pid} loss={loss:.6f} epoch={epoch:.3f}",
       flush=True)
 jax.distributed.shutdown()

@@ -7,6 +7,8 @@ numpy at setup time, and key reuse after an intervening fold_in.
 """
 import textwrap
 
+import pytest
+
 from fedtorch_tpu.lint import analyze_source
 from fedtorch_tpu.lint.findings import (
     diff_against_baseline, load_baseline, save_baseline,
@@ -78,6 +80,25 @@ def test_ftl001_negative_host_values():
         return n + float(host["m"])
     """
     assert hits(src, "FTL001") == []
+
+
+@pytest.mark.parametrize("method", ["round_host_fetch",
+                                    "round_host_scalars"])
+def test_ftl001_negative_trainer_host_fetch(method):
+    """The trainer's batched fetches hand back host values whatever
+    device state they are handed; the same call under another name is
+    still a device value."""
+    src = f"""\
+    import jax.numpy as jnp
+
+    def loop(trainer, state):
+        clients = jnp.zeros((4,)) + state
+        got = trainer.{method}(clients, None)
+        return float(got[0])
+    """
+    assert hits(src, "FTL001") == []
+    assert hits(src.replace(method, "round_dev"), "FTL001") \
+        == [("FTL001", 6)]
 
 
 def test_ftl001_inside_jit_is_flagged():
