@@ -170,7 +170,8 @@ class FedAlgorithm:
 
     def local_step(self, *, params, opt, client_aux, rnn_carry,
                    server_params, server_aux, bx, by, bval_x, bval_y, lr,
-                   rng, step_idx, local_index, step_budget=None):
+                   rng, step_idx, local_index, step_budget=None,
+                   with_parts: bool = False):
         """One local training step (the hot loop body,
         federated/main.py:83-155). The base implements the standard
         inference -> backward -> per-algorithm grad correction ->
@@ -182,19 +183,24 @@ class FedAlgorithm:
         engine, so step-indexed logic (sync pulls, snapshots) must
         anchor on the budget, not the scan length.
 
-        Returns (params, opt, client_aux, rnn_carry, loss, acc)."""
+        Returns (params, opt, client_aux, rnn_carry, loss, acc) and,
+        ``with_parts``, seventh the parts a token model's loss reports
+        beside its value (``token_loss_parts``: a dict of float32
+        arrays, empty for a single-pass model). An override that does
+        not know the option refuses it by its signature."""
         model, criterion, cfg = self.model, self.criterion, self.cfg
 
         if is_token_model(model):
             # a token model makes its target from the batch itself (the
             # next token); a row's label takes no part in the loss
             def token_loss_fn(p):
-                loss, acc = model.token_loss(p, bx, train=True, rng=rng)
+                loss, acc, parts = model.token_loss_parts(
+                    p, bx, train=True, rng=rng)
                 return loss + self.extra_loss(p, server_params,
-                                              client_aux), acc
+                                              client_aux), (acc, parts)
 
             with jax.named_scope("fed.forward_backward"):
-                (loss, acc), grads = jax.value_and_grad(
+                (loss, (acc, parts)), grads = jax.value_and_grad(
                     token_loss_fn, has_aux=True)(params)
                 grads = self.transform_grads(
                     grads, params=params, server_params=server_params,
@@ -202,7 +208,8 @@ class FedAlgorithm:
             with jax.named_scope("fed.opt_step"):
                 params, opt = optim.local_step(params, grads, opt, lr,
                                                cfg.optim)
-            return params, opt, client_aux, rnn_carry, loss, acc
+            out = (params, opt, client_aux, rnn_carry, loss, acc)
+            return out + (parts,) if with_parts else out
 
         moe_w = cfg.model.moe_aux_weight
 
@@ -239,7 +246,8 @@ class FedAlgorithm:
                                            cfg.optim)
         acc = jnp.asarray(0.0) if model.is_regression \
             else accuracy(logits, by)
-        return params, opt, client_aux, new_rnn, loss, acc
+        out = (params, opt, client_aux, new_rnn, loss, acc)
+        return out + ({},) if with_parts else out
 
     # -- aggregation -----------------------------------------------------
     def client_weights(self, server_aux, online_idx, num_online_eff,

@@ -137,9 +137,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="arch hybrid_lm: a JSON file with the keys of "
                         "a public config.json (hidden_size, "
                         "intermediate_size, num_hidden_layers, "
-                        "layer_types, num_attention_heads, linear_*, "
-                        "vocab_size, rms_norm_eps) the model's shape "
-                        "is read from")
+                        "layer_types, num_attention_heads, "
+                        "vocab_size, rms_norm_eps; linear_* where a "
+                        "layer is a linear_attention one; optional "
+                        "model_type, rope_theta, total_ut_steps) the "
+                        "model's shape is read from")
     p.add_argument("--attention", default="auto",
                    choices=("auto", "dense", "flash"),
                    help="transformer attention backend: 'flash' = fused "
@@ -1248,6 +1250,10 @@ def run_experiment(cfg: ExperimentConfig,
                     # privacy-plane gauges (DP armed) — same batched fetch
                     row["dp_clipped_frac"] = sc["dp_clipped_frac"]
                     row["dp_noise_sigma"] = sc["dp_noise_sigma"]
+                if "lm_exit_mass_last" in sc:
+                    # a looped token model's exit gauges — same fetch
+                    row["lm_exit_mass_last"] = sc["lm_exit_mass_last"]
+                    row["lm_exit_entropy"] = sc["lm_exit_entropy"]
                 if accountant is not None:
                     # host-side accountant read: pure f64 math, no sync
                     row["dp_epsilon_spent"] = accountant.epsilon()

@@ -280,8 +280,9 @@ class ModelConfig:
     # T >= 4096 (ops/attention_dispatch.py:resolve_attention)
     attention: str = "auto"
     # arch 'hybrid_lm' only: a JSON file with the keys of a public
-    # config.json (hidden_size, layer_types, linear_*, vocab_size, ...)
-    # that the model's shape is read from (models/hybrid_lm.py)
+    # config.json (hidden_size, layer_types, vocab_size, ...; optional
+    # model_type, rope_theta, total_ut_steps) that the model's shape is
+    # read from (models/hybrid_lm.py)
     spec_file: Optional[str] = None
     pretrained: bool = False
     # 'robust_*' archs learn an adversarial input-noise parameter.

@@ -103,6 +103,12 @@ class RoundMetrics(NamedTuple):
     dp_clipped_frac: Any = None    # scalar [0,1] — accepted clients clipped
     dp_noise_sigma: Any = None     # scalar — applied noise stddev
     #                                (sigma * noise_scale; 0 after degrade)
+    # a looped token model's exit gauges (models/hybrid_lm.py
+    # ``exit_objective``), means over the round's clients and steps,
+    # from the sequential execution. None for every other model: zero
+    # leaves, the round program as it was.
+    lm_exit_mass_last: Any = None  # scalar — exit mass on the last pass
+    lm_exit_entropy: Any = None    # scalar — entropy of the exit law
 
 
 def tree_where(pred, on_true, on_false):

@@ -1,36 +1,64 @@
 """A causal language model whose shape is read from a file: the keys
 of a public ``config.json`` (``hidden_size``, ``intermediate_size``,
 ``num_hidden_layers``, ``layer_types``, ``num_attention_heads``,
-``linear_*``, ``vocab_size``, ``rms_norm_eps``), as the Olmo-Hybrid
-family states them. ``layer_types`` names each layer's mixer:
+``vocab_size``, ``rms_norm_eps``; ``linear_*`` where a layer is a
+``linear_attention`` one), as the Olmo-Hybrid and the Ouro families
+state them. ``layer_types`` names each layer's mixer:
 
 * ``linear_attention`` — the gated delta rule (``ops/delta_rule.py``)
   behind a causal depthwise convolution, with an output gate;
 * ``full_attention`` — causal softmax attention through
   ``ops/attention_dispatch.py`` (flash from 4096 tokens on).
 
-Every layer ends in a SwiGLU MLP; norms sit on each sublayer's OUTPUT
-before the residual add (the Olmo 2/3 placement), the head is untied,
-nothing has a bias. ``benchmark/reference/olmo_hybrid.py`` writes the
-same equations out in plain float32 and lists what the public config
-leaves open.
+Every layer ends in a SwiGLU MLP, the head is untied, nothing has a
+bias. Three optional keys choose the rest:
+
+* ``model_type`` — the family's block. ``ouro``: sandwich norms (an
+  RMSNorm on each sublayer's input and one on its output, four scales
+  a layer) and no QK-norm; anything else: norms on each sublayer's
+  OUTPUT alone and an RMSNorm over the whole projected q and k (the
+  Olmo 2/3 placement);
+* ``rope_theta`` (top level, or under ``rope_parameters``) — rotary
+  position embedding on q and k of the full-attention layers
+  (rotate-half, positions ``0..T-1``, angles in float32); absent or
+  null: none;
+* ``total_ut_steps`` (default 1) — the stack of layers, closed by the
+  final norm, runs that many times with the same parameters, each
+  pass fed the one before. After every pass come the head and an exit
+  gate (one ``hidden_size -> 1`` linear layer with a bias, shared by
+  the passes), and the training loss is the exit-weighted objective of
+  Zhu et al. 2025 ("Scaling Latent Reasoning via Looped Language
+  Models", stage I) with the weight ``exit_entropy_beta`` (default
+  0.05) on the exit distribution's entropy: :func:`exit_objective`.
+  Evaluation reads the last pass. More than one pass is refused for a
+  ``model_type`` whose looped form is not written here.
+
+``benchmark/reference/olmo_hybrid.py`` and ``benchmark/reference/ouro.py``
+write the same equations out in plain float32 and list what the public
+configs leave open.
 
 Pure functions over a nested dict of float32 parameters; every layer
-is a subtree of its own (``layer_<i>``: no stacked scan, so a
-gradient is consumed leaf by leaf). Products take bfloat16 operands
-where the launcher's ``compute_dtype`` says so and accumulate in
-float32; the residual stream, norms, softmax, decays, the convolution
-and the loss are float32. With ``remat`` each layer runs under ``jax.checkpoint``.
+is a subtree of its own (``layer_<i>``: no stacked scan over layers, so
+a single-pass model's gradient is consumed leaf by leaf; a looped
+layer's gradient is a sum over the passes, which the scan over passes
+accumulates). Products take bfloat16 operands where the launcher's
+``compute_dtype`` says so and accumulate in float32; the residual
+stream, norms, rotary angles, softmax, decays, the convolution, the
+exit gate and the loss are float32. With ``remat`` each layer runs
+under ``jax.checkpoint``.
 
 Scopes for the device trace: ``lm.delta_rule``, ``lm.attention``,
-``lm.mlp``, ``lm.head``.
+``lm.mlp``, ``lm.head``; in a looped model also ``lm.loop`` (the scan
+over passes: what it holds outside ``lm.attention`` and ``lm.mlp`` are
+the projections, norms and rotary embedding) and ``lm.exit`` (the
+heads, per-exit losses, gate and mixture; ``lm.head`` inside it).
 """
 from __future__ import annotations
 
 import functools
 import json
 import math
-from typing import Any, NamedTuple, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +68,15 @@ from fedtorch_tpu.ops.delta_rule import chunk_gated_delta_rule
 
 LAYER_KINDS = ("linear_attention", "full_attention")
 INIT_STD = 0.02
+# ``model_type`` values whose block differs from the Olmo placement
+SANDWICH_BLOCKS = ("ouro",)
+# the file's keys: asked of every file, and of one with a
+# ``linear_attention`` layer
+PLAIN_KEYS = ("vocab_size", "hidden_size", "intermediate_size",
+              "layer_types", "num_attention_heads", "rms_norm_eps")
+LINEAR_KEYS = ("linear_num_key_heads", "linear_num_value_heads",
+               "linear_key_head_dim", "linear_value_head_dim",
+               "linear_conv_kernel_dim")
 
 
 class HybridSpec(NamedTuple):
@@ -56,16 +93,25 @@ class HybridSpec(NamedTuple):
     linear_value_head_dim: int
     linear_conv_kernel_dim: int
     rms_norm_eps: float
+    total_ut_steps: int = 1
+    rope_theta: Optional[float] = None
+    sandwich: bool = False      # the block: sandwich norms, no QK-norm
+    exit_entropy_beta: float = 0.05
+
+    @property
+    def looped(self) -> bool:
+        return self.total_ut_steps > 1
 
 
 def load_spec(path: str) -> HybridSpec:
     """Read a specification file. Keys beyond the public config's are
     ignored (a benchmark configuration's file carries its launcher
     flags beside them); ``layer_types`` is cut to
-    ``num_hidden_layers``."""
+    ``num_hidden_layers``; the ``linear_*`` keys are asked for only
+    where a layer of the cut is a ``linear_attention`` one."""
     with open(path) as f:
         doc = json.load(f)
-    missing = [k for k in HybridSpec._fields + ("num_hidden_layers",)
+    missing = [k for k in PLAIN_KEYS + ("num_hidden_layers",)
                if k not in doc]
     if missing:
         raise ValueError(f"model specification {path!r} lacks {missing}")
@@ -75,9 +121,14 @@ def load_spec(path: str) -> HybridSpec:
         raise ValueError(
             f"model specification {path!r}: layer_types must name "
             f"num_hidden_layers layers, each one of {LAYER_KINDS}")
+    linear = "linear_attention" in kinds
+    missing = [k for k in LINEAR_KEYS if linear and k not in doc]
+    if missing:
+        raise ValueError(f"model specification {path!r} lacks {missing}")
     if doc.get("num_key_value_heads", doc["num_attention_heads"]) \
             != doc["num_attention_heads"] \
-            or doc["linear_num_value_heads"] != doc["linear_num_key_heads"]:
+            or doc.get("linear_num_value_heads") \
+            != doc.get("linear_num_key_heads"):
         raise ValueError(
             f"model specification {path!r}: grouped key/value heads are "
             "not supported (as many key and value heads as query heads)")
@@ -85,8 +136,31 @@ def load_spec(path: str) -> HybridSpec:
         raise ValueError(
             f"model specification {path!r}: tied embeddings and "
             "attention biases are not supported")
-    return HybridSpec(**{k: (kinds if k == "layer_types" else doc[k])
-                         for k in HybridSpec._fields})
+    heads = doc["num_attention_heads"]
+    if doc.get("head_dim", doc["hidden_size"] // heads) * heads \
+            != doc["hidden_size"]:
+        raise ValueError(
+            f"model specification {path!r}: head_dim must be "
+            "hidden_size / num_attention_heads")
+    steps = int(doc.get("total_ut_steps", 1))
+    sandwich = doc.get("model_type") in SANDWICH_BLOCKS
+    if steps < 1 or (steps > 1 and not sandwich):
+        raise ValueError(
+            f"model specification {path!r}: total_ut_steps {steps} with "
+            f"model_type {doc.get('model_type')!r}: a looped stack is "
+            f"written for {SANDWICH_BLOCKS} only")
+    if doc.get("rope_scaling"):
+        raise ValueError(
+            f"model specification {path!r}: rope_scaling is not supported")
+    theta = doc.get("rope_theta",
+                    (doc.get("rope_parameters") or {}).get("rope_theta"))
+    fields = dict({k: doc[k] for k in PLAIN_KEYS}, layer_types=kinds,
+                  **{k: doc.get(k, 0) for k in LINEAR_KEYS})
+    return HybridSpec(
+        **fields, total_ut_steps=steps,
+        rope_theta=None if theta is None else float(theta),
+        sandwich=sandwich,
+        exit_entropy_beta=float(doc.get("exit_entropy_beta", 0.05)))
 
 
 def _linear_shapes(s: HybridSpec) -> dict:
@@ -101,29 +175,37 @@ def _linear_shapes(s: HybridSpec) -> dict:
 
 def _full_shapes(s: HybridSpec) -> dict:
     d = s.hidden_size
-    return {"wq": (d, d), "wk": (d, d), "wv": (d, d), "wo": (d, d),
-            "q_norm": (d,), "k_norm": (d,)}
+    proj = {"wq": (d, d), "wk": (d, d), "wv": (d, d), "wo": (d, d)}
+    # lint: disable=FTL005 — the block is a flag of the spec
+    return proj if s.sandwich else dict(proj, q_norm=(d,), k_norm=(d,))
 
 
 def param_shapes(s: HybridSpec) -> dict:
     d, f = s.hidden_size, s.intermediate_size
     tree = {"embed": (s.vocab_size, d), "final_norm": (d,),
             "head": (d, s.vocab_size)}
+    # lint: disable=FTL005 — a looped model or not, by the spec
+    if s.looped:
+        tree["exit_gate"] = {"w": (d,), "b": (1,)}
     for i, kind in enumerate(s.layer_types):
         tree[f"layer_{i}"] = {
             "mixer": _linear_shapes(s) if kind == "linear_attention"
             else _full_shapes(s),
             "mixer_norm": (d,), "mlp_norm": (d,),
             "mlp": {"gate": (d, f), "up": (d, f), "down": (f, d)}}
+        # lint: disable=FTL005 — the block is a flag of the spec
+        if s.sandwich:
+            tree[f"layer_{i}"].update(mixer_in_norm=(d,), mlp_in_norm=(d,))
     return tree
 
 
 def init_params(spec: HybridSpec, rng) -> Any:
     """Seeded float32 parameters: matrices normal(0, 0.02), norm
-    scales 1, the convolution uniform(+-1/sqrt(taps)), decay rates
-    ``exp(a_log)`` spread over 1..16 and time steps
-    ``softplus(dt_bias)`` log-spread over 0.001..0.1 across the heads
-    (the delta-rule family's own initialisation)."""
+    scales 1, the exit gate's bias 0, the convolution
+    uniform(+-1/sqrt(taps)), decay rates ``exp(a_log)`` spread over
+    1..16 and time steps ``softplus(dt_bias)`` log-spread over
+    0.001..0.1 across the heads (the delta-rule family's own
+    initialisation)."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
         param_shapes(spec), is_leaf=lambda x: isinstance(x, tuple))
     out = []
@@ -132,6 +214,8 @@ def init_params(spec: HybridSpec, rng) -> Any:
         key = jax.random.fold_in(rng, i)
         if name.endswith("norm"):
             leaf = jnp.ones(shape, jnp.float32)
+        elif name == "b":
+            leaf = jnp.zeros(shape, jnp.float32)
         elif name == "a_log":
             leaf = jnp.log(jnp.linspace(1.0, 16.0, shape[0]))
         elif name == "dt_bias":
@@ -181,6 +265,25 @@ def _dot(x, w, dt):
                       preferred_element_type=jnp.float32)
 
 
+def rotary_tables(positions, head_dim: int, theta: float):
+    """(cos, sin), each ``[T, head_dim]`` float32, of the rotate-half
+    rotary embedding at ``positions`` [T]: pair ``i`` of a head
+    (elements ``i`` and ``i + head_dim / 2``) turns by
+    ``position * theta ** (-2 i / head_dim)``."""
+    inv = jnp.exp(jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                  * (-math.log(theta) / head_dim))
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _rotate(x, rope):
+    """``x`` [B, T, H, hd] float32 turned by ``rope``'s angles."""
+    cos, sin = (t[None, :, None, :] for t in rope)
+    a, b = jnp.split(x, 2, axis=-1)
+    return x * cos + jnp.concatenate([-b, a], axis=-1) * sin
+
+
 def _linear_attention(p, x, s: HybridSpec, dt):
     B, T, _ = x.shape
     h, dk, dv = (s.linear_num_key_heads, s.linear_key_head_dim,
@@ -204,14 +307,27 @@ def _linear_attention(p, x, s: HybridSpec, dt):
     return _dot((o * gate).reshape(B, T, h * dv), p["wo"], dt)
 
 
-def _full_attention(p, x, s: HybridSpec, dt, attention: str):
+def _full_attention(p, x, s: HybridSpec, dt, attention: str, rope=None):
     B, T, d = x.shape
     h = s.num_attention_heads
     hd = d // h
-    q = _rms_norm(_dot(x, p["wq"], dt), p["q_norm"], s.rms_norm_eps)
-    k = _rms_norm(_dot(x, p["wk"], dt), p["k_norm"], s.rms_norm_eps)
-    v = _dot(x, p["wv"], dt)
-    q, k, v = (t.astype(dt).reshape(B, T, h, hd) for t in (q, k, v))
+
+    def project(name, norm):
+        t = _dot(x, p[name], dt)
+        # lint: disable=FTL005 — the block is a flag of the spec
+        if norm and not s.sandwich:
+            t = _rms_norm(t, p[norm], s.rms_norm_eps)
+        return t
+
+    def heads(t, turn):
+        # lint: disable=FTL005 — a model with or without rotary embedding
+        if turn and rope is not None:
+            return _rotate(t.reshape(B, T, h, hd), rope).astype(dt)
+        return t.astype(dt).reshape(B, T, h, hd)
+
+    q, k = project("wq", "q_norm"), project("wk", "k_norm")
+    v = project("wv", None)
+    q, k, v = heads(q, True), heads(k, True), heads(v, False)
     with jax.named_scope("lm.attention"):
         # lint: disable=FTL005 — a static mode string and a static length
         if resolve_attention(attention, T) == "flash":
@@ -237,28 +353,75 @@ def _mlp(p, x, dt):
                     * _dot(x, p["up"], dt), p["down"], dt)
 
 
-def _layer(p, x, kind: str, s: HybridSpec, dt, attention: str):
-    # lint: disable=FTL005 — the layer's kind is a string of the spec
-    if kind == "linear_attention":
-        mixed = _linear_attention(p["mixer"], x, s, dt)
-    else:
-        mixed = _full_attention(p["mixer"], x, s, dt, attention)
+def _layer(p, x, kind: str, s: HybridSpec, dt, attention: str, rope=None):
+    eps = s.rms_norm_eps
+
+    def mixer(u):
+        # lint: disable=FTL005 — the layer's kind is a string of the spec
+        if kind == "linear_attention":
+            return _linear_attention(p["mixer"], u, s, dt)
+        return _full_attention(p["mixer"], u, s, dt, attention, rope)
+
     # the residual stream stays float32: a sublayer's normed output is
     # of unit size beside an embedding of 0.02, and bfloat16's spacing
     # near 1 would round a tenth of the embedding away
-    x = x + _rms_norm(mixed, p["mixer_norm"], s.rms_norm_eps)
-    return x + _rms_norm(_mlp(p["mlp"], x, dt), p["mlp_norm"],
-                         s.rms_norm_eps)
+    # lint: disable=FTL005 — the block is a flag of the spec
+    if s.sandwich:
+        x = x + _rms_norm(mixer(_rms_norm(x, p["mixer_in_norm"], eps)),
+                          p["mixer_norm"], eps)
+        return x + _rms_norm(
+            _mlp(p["mlp"], _rms_norm(x, p["mlp_in_norm"], eps), dt),
+            p["mlp_norm"], eps)
+    x = x + _rms_norm(mixer(x), p["mixer_norm"], eps)
+    return x + _rms_norm(_mlp(p["mlp"], x, dt), p["mlp_norm"], eps)
+
+
+def _rope(s: HybridSpec, T: int):
+    """The rotary tables of a ``T``-token row, or None without
+    ``rope_theta``."""
+    if s.rope_theta is None:
+        return None
+    return rotary_tables(jnp.arange(T),
+                         s.hidden_size // s.num_attention_heads,
+                         s.rope_theta)
+
+
+def _stack(params, h, s: HybridSpec, dt, attention: str, remat: bool,
+           rope):
+    """One pass over the layers: ``[B, T, D] -> [B, T, D]``."""
+    for i, kind in enumerate(s.layer_types):
+        fn = lambda p, h, kind=kind: _layer(p, h, kind, s, dt, attention,
+                                            rope)
+        # lint: disable=FTL005 — remat is a static flag of the launcher
+        h = (jax.checkpoint(fn) if remat else fn)(params[f"layer_{i}"], h)
+    return h
 
 
 def hidden_states(params, x, s: HybridSpec, dt, attention: str,
                   remat: bool):
-    """Token ids ``[B, T]`` -> the last layer's output ``[B, T, D]``."""
-    h = params["embed"][x]
-    for i, kind in enumerate(s.layer_types):
-        fn = lambda p, h, kind=kind: _layer(p, h, kind, s, dt, attention)
-        h = (jax.checkpoint(fn) if remat else fn)(params[f"layer_{i}"], h)
-    return h
+    """Token ids ``[B, T]`` -> the last layer's output ``[B, T, D]``
+    of a single-pass model (the final norm is ``logits_of``'s)."""
+    return _stack(params, params["embed"][x], s, dt, attention, remat,
+                  _rope(s, x.shape[1]))
+
+
+def looped_states(params, x, s: HybridSpec, dt, attention: str,
+                  remat: bool):
+    """Token ids ``[B, T]`` -> every pass's output after the final
+    norm, ``[R, B, T, D]``: one traced body of the layers, scanned
+    ``total_ut_steps`` times with the parameters closed over, so a
+    layer's gradient comes out as the sum over the passes."""
+    rope = _rope(s, x.shape[1])
+
+    def one_pass(h, _):
+        h = _rms_norm(_stack(params, h, s, dt, attention, remat, rope),
+                      params["final_norm"], s.rms_norm_eps)
+        return h, h
+
+    with jax.named_scope("lm.loop"):
+        _, hs = jax.lax.scan(one_pass, params["embed"][x], None,
+                             length=s.total_ut_steps)
+    return hs
 
 
 def logits_of(params, h, s: HybridSpec, dt):
@@ -276,6 +439,52 @@ def next_token_stats(logits, x):
     nll = -jnp.take_along_axis(logp, nxt[..., None], axis=-1)[..., 0]
     hit = (jnp.argmax(logits, axis=-1) == nxt).astype(jnp.float32)
     return nll, hit
+
+
+def exit_log_distribution(z):
+    """Log of the exit distribution over the ``R`` passes from the
+    gate's pre-activations ``z`` [R, ...]: with ``lambda_t =
+    sigmoid(z_t)``, ``q_t = lambda_t prod_{j<t} (1 - lambda_j)`` for
+    ``t < R`` and ``q_R = prod_{j<R} (1 - lambda_j)`` (the last
+    pass takes what is left; its own gate value is not read)."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(z[:1]), stay], axis=0)
+    return before + jnp.concatenate(
+        [jax.nn.log_sigmoid(z[:-1]), jnp.zeros_like(z[:1])], axis=0)
+
+
+def exit_objective(nll, z, beta: float):
+    """The looped model's training loss from the per-exit next-token
+    losses ``nll`` and gate pre-activations ``z``, both [R, B, T']:
+    per position ``sum_t q_t nll_t - beta H(q)``, ``H`` the entropy of
+    the exit distribution, mean over positions. Returns (loss,
+    {"exit_ce": [R] mean loss of each exit, "exit_mass": [R] mean
+    ``q_t``, "exit_entropy": mean ``H(q)``})."""
+    log_q = exit_log_distribution(z)
+    q = jnp.exp(log_q)
+    entropy = -jnp.sum(q * log_q, axis=0)
+    loss = jnp.mean(jnp.sum(q * nll, axis=0) - beta * entropy)
+    return loss, {"exit_ce": jnp.mean(nll, axis=(1, 2)),
+                  "exit_mass": jnp.mean(q, axis=(1, 2)),
+                  "exit_entropy": jnp.mean(entropy)}
+
+
+def exit_stats(params, hs, x, dt):
+    """The heads of the passes, one at a time: ``hs`` [R, B, T, D]
+    (each pass's normed output) -> (next-token loss, top-1 hit), each
+    [R, B, T - 1]. The body is checkpointed, so one exit's float32
+    logits live at a time, forward and backward."""
+    @jax.checkpoint
+    def one_exit(h):
+        with jax.named_scope("lm.head"):
+            return next_token_stats(_dot(h, params["head"], dt), x)
+    return jax.lax.map(one_exit, hs)
+
+
+def exit_gate(params, hs):
+    """The gate's pre-activations ``[R, B, T]``, float32 throughout."""
+    gate = params["exit_gate"]
+    return jnp.sum(hs * gate["w"], axis=-1) + gate["b"]
 
 
 class HybridLM(NamedTuple):
@@ -297,26 +506,53 @@ class HybridLM(NamedTuple):
     def spec(self) -> HybridSpec:
         return self.module
 
+    @property
+    def ut_steps(self) -> int:
+        """Passes a token makes through the stack of layers."""
+        return self.module.total_ut_steps
+
     def init(self, rng):
         return _jitted_init(self.module)(rng)
 
-    def apply(self, params, x, train: bool = False, rng=None, carry=None):
+    def _states(self, params, x):
         dt = jnp.dtype(self.dtype)
-        h = hidden_states(params, x, self.module, dt, self.attention,
+        states = looped_states if self.module.looped else hidden_states
+        return dt, states(params, x, self.module, dt, self.attention,
                           self.remat)
+
+    def apply(self, params, x, train: bool = False, rng=None, carry=None):
+        """Logits ``[B, T, V]``; of the last pass in a looped model."""
+        dt, h = self._states(params, x)
+        # lint: disable=FTL005 — a looped model or not, by the spec
+        if self.module.looped:
+            with jax.named_scope("lm.exit"), jax.named_scope("lm.head"):
+                return _dot(h[-1], params["head"], dt)
         with jax.named_scope("lm.head"):
             return logits_of(params, h, self.module, dt)
 
-    def token_loss(self, params, x, train: bool = False, rng=None):
-        """(mean next-token cross-entropy, top-1) over the B x (T - 1)
-        positions of ``x`` that have a next token."""
-        dt = jnp.dtype(self.dtype)
-        h = hidden_states(params, x, self.module, dt, self.attention,
-                          self.remat)
+    def token_loss_parts(self, params, x, train: bool = False, rng=None):
+        """(loss, top-1, parts) over the B x (T - 1) positions of ``x``
+        that have a next token. A single-pass model: the mean
+        next-token cross-entropy, no parts. A looped model:
+        :func:`exit_objective` over its passes, the top-1 of the last
+        pass, and the objective's parts."""
+        dt, h = self._states(params, x)
+        # lint: disable=FTL005 — a looped model or not, by the spec
+        if self.module.looped:
+            with jax.named_scope("lm.exit"):
+                nll, hit = exit_stats(params, h, x, dt)
+                loss, parts = exit_objective(
+                    nll, exit_gate(params, h)[..., :-1],
+                    self.module.exit_entropy_beta)
+                return loss, jnp.mean(hit[-1]), parts
         with jax.named_scope("lm.head"):
             nll, hit = next_token_stats(
                 logits_of(params, h, self.module, dt), x)
-            return jnp.mean(nll), jnp.mean(hit)
+            return jnp.mean(nll), jnp.mean(hit), {}
+
+    def token_loss(self, params, x, train: bool = False, rng=None):
+        """(loss, top-1) of :meth:`token_loss_parts`."""
+        return self.token_loss_parts(params, x, train, rng)[:2]
 
     def init_carry(self, batch_size: int):
         return None

@@ -12,12 +12,19 @@ there.
 from __future__ import annotations
 
 # Shortest sequence length at which 'auto' attention dispatch picks the
-# flash kernel. Rests on a capture of 2026-07-31 on a v5e, before PR 1;
-# record removed in PR 29; not measured on today's code (ROADMAP Speed
-# 5). It read flash slower than dense at T=2048 (the dense [T, T] scores
-# still fit and the kernel's launch and tiling overhead dominates) and
-# faster from T=4096, where the dense score tensor also starts to bind
-# memory (2.1 GB a layer at T=8192).
+# flash kernel. Set from a capture of 2026-07-31 on a v5e, before PR 1
+# (record removed in PR 29): flash slower than dense at T=2048 (the
+# dense [T, T] scores still fit and the kernel's launch and tiling
+# overhead dominates) and faster from T=4096, where the dense score
+# tensor also starts to bind memory (2.1 GB a layer at T=8192).
+# Read on today's code by PR 33 (PERF.md section 5; one chip, forward,
+# recomputation and backward of a layer call, 30 heads of 128): the
+# flash path at T=4096 21.5 ms a call, 9.1 % of causal attention's
+# roofline (the Mosaic forward and its chunked float32 XLA backward);
+# the dense path at T=2048 6.7 ms a call, 7.3 %. Neither length ran
+# both paths, so the constant stays where the capture put it. The
+# looped cell of PR 35 (16 heads of 128, T=1024) reads the dense path
+# 32 times a step: its seconds are in PERF.md section 5.
 FLASH_MIN_SEQ_LEN = 4096
 
 

@@ -143,6 +143,9 @@ _SCALAR_FIELDS = (
     # saturation + applied noise stddev; None when DP is off
     ("dp_clipped_frac", "dp_clipped_frac"),
     ("dp_noise_sigma", "dp_noise_sigma"),
+    # a looped token model's exit gauges; None for every other model
+    ("lm_exit_mass_last", "lm_exit_mass_last"),
+    ("lm_exit_entropy", "lm_exit_entropy"),
 )
 # what the program computes itself, ahead of the table's leaves
 _COMPUTED_SCALARS = ("mean_epoch", "lr", "n_online", "loss_sum",
@@ -399,6 +402,10 @@ class FederatedTrainer:
             self.k_online * self.local_steps
             * self.batch_size * int(data.x.shape[2])
             if is_token_model(model) and data.x.ndim == 3 else 0)
+        # passes a token makes through a looped model's layers, for
+        # the row's ``ut_steps`` counter (1: not looped, no counter)
+        self.ut_steps = int(getattr(model, "ut_steps", 1)) \
+            if self.tokens_per_round else 1
 
         num_epochs = cfg.train.num_epochs or 1
         self.schedule: LRSchedule = compile_schedule(
@@ -1407,6 +1414,9 @@ class FederatedTrainer:
         opt0 = optim.init_opt_state((), cfg.optim, lean=True)
         carry0 = model.init_carry(B)
         budget = jnp.asarray(K, jnp.int32)
+        # a looped model's loss reports its exits' parts: asked for
+        # here alone, so that every other model's step is as it was
+        with_parts = {"with_parts": True} if self.ut_steps > 1 else {}
 
         def one_client(total, member):
             x, y, size, weight, rng_c, epoch0, li0 = member
@@ -1422,18 +1432,20 @@ class FederatedTrainer:
                         aug_parent = jax.random.fold_in(rng_c, 0x7FFFFFFF)
                         bx = augment_image_batch(
                             jax.random.fold_in(aug_parent, s), bx)
-                n_params, _, _, _, loss, acc = alg.local_step(
+                n_params, _, _, _, loss, acc, *parts = alg.local_step(
                     params=params, opt=opt0, client_aux=(),
                     rnn_carry=carry0, server_params=server.params,
                     server_aux=server.aux, bx=bx, by=by, bval_x=None,
                     bval_y=None, lr=lr,
                     rng=jax.random.fold_in(rng_c, s + 1), step_idx=s,
-                    local_index=li, step_budget=budget)
-                return (n_params, epoch + 1.0 / nb, li + 1), (loss, acc)
+                    local_index=li, step_budget=budget, **with_parts)
+                return (n_params, epoch + 1.0 / nb, li + 1), \
+                    (loss, acc, *parts)
 
             with jax.named_scope("fed.local_steps"):
-                (params, epoch, li), (losses, accs) = jax.lax.scan(
-                    step, (server.params, epoch0, li0), jnp.arange(K))
+                (params, epoch, li), (losses, accs, *parts) = \
+                    jax.lax.scan(step, (server.params, epoch0, li0),
+                                 jnp.arange(K))
                 with jax.named_scope("fed.fold"):
                     payload, _ = alg.client_payload(
                         delta=tree_sub(server.params, params),
@@ -1444,12 +1456,13 @@ class FederatedTrainer:
                         local_steps=budget, weight=weight,
                         full_loss=None)
                     total = jax.tree.map(jnp.add, total, payload)
-            return total, (epoch, li, jnp.mean(losses), jnp.mean(accs))
+            return total, (epoch, li, jnp.mean(losses), jnp.mean(accs),
+                           *parts)
 
         with jax.named_scope("fed.local_steps"):
             with jax.named_scope("fed.fold"):
                 zero = tree_zeros_like(server.params)
-            payload_sum, (epochs, lis, losses, accs) = jax.lax.scan(
+            payload_sum, (epochs, lis, losses, accs, *parts) = jax.lax.scan(
                 one_client, zero,
                 (on_x, on_y, on_sizes, weights, rngs, on_epoch, on_li))
         with jax.named_scope("fed.aggregate"):
@@ -1483,6 +1496,13 @@ class FederatedTrainer:
                 rejected_updates=none, clipped_updates=none,
                 byzantine_clients=none, robust_selected=none,
                 robust_trimmed=none)
+            if parts:
+                # [k, K, R] exit masses, [k, K] entropies: means over
+                # the round's clients and steps
+                metrics = metrics._replace(
+                    lm_exit_mass_last=jnp.mean(
+                        parts[0]["exit_mass"][..., -1]),
+                    lm_exit_entropy=jnp.mean(parts[0]["exit_entropy"]))
         with jax.named_scope("fed.server_step"):
             new_server = ServerState(params=new_params, opt=new_opt,
                                      aux=new_saux, round=server.round + 1,
@@ -1809,6 +1829,8 @@ class FederatedTrainer:
         out = {}
         if self.tokens_per_round:
             out["tokens_trained"] = float(self.tokens_per_round)
+        if self.ut_steps > 1:
+            out["ut_steps"] = float(self.ut_steps)
         ss = self.stream_stats()
         if ss is not None:
             out.update(ss)

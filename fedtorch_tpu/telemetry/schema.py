@@ -77,6 +77,12 @@ METRICS_OPTIONAL = {
     # token models (models/hybrid_lm.py): a host counter from shapes
     "tokens_trained": "tokens this round trained on (k clients x K "
                       "steps x B rows x the rows' length)",
+    "ut_steps": "passes a token makes through a looped model's layers "
+                "(total_ut_steps of the model's file; absent at 1)",
+    "lm_exit_mass_last": "looped token model: mean mass the exit "
+                         "distribution puts on the last pass",
+    "lm_exit_entropy": "looped token model: mean entropy of the exit "
+                       "distribution over the passes (nats)",
     # stream plane (trainer.stream_stats)
     "stream_depth": "prefetched feeds ready at fetch time",
     "stream_wait_s": "consumer wall blocked on the feed queue (total)",
