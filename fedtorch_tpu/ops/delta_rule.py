@@ -29,19 +29,33 @@ solved, not multiplied out: the powers of its strict part grow like
 binomials where keys repeat under ``beta`` near 2, while the solve
 stays as well conditioned as the recurrence.
 
+The solve is this module's own (``unit_lower_inverse``): forward
+substitution row by row, 64 dependent steps, each one multiply-and-sum
+over all 960 chunks of a call at once, with its backward rule written
+out (``-strict_lower(X^T G X^T)``) so that JAX does not differentiate
+through the steps. ``jax.scipy``'s ``solve_triangular`` is the same
+substitution on the chip, but XLA:TPU's generic inverter of diagonal
+blocks runs it one block after another: 2.9 ms a call at the model's
+shapes against 0.37 ms (PERF.md section 6, PR 36, which also read the
+blocked forms there: diagonal blocks of 16 or 32 rows substituted and
+merged by products cost more on the chip than they save, and lose a
+factor of four of accuracy where keys repeat).
+
 Decays only ever appear as ``exp`` of a difference ``c_i - c_j`` with
 ``i >= j`` (masked before the ``exp``), so a decay near 0 underflows
-to 0 and never overflows. Differentiable by JAX's own rules; a Pallas
-kernel is a later PR's, to be read against
-``benchmark/flops/olmo_hybrid.py:delta_rule_flops``.
+to 0 and never overflows. What the chip's trace leaves after the
+inverse is the two scans over chunks (forward: three fusions and a
+copy a trip; backward: eighteen fusions and two copies a trip) and
+the batched products around them; a fused kernel for those is read
+against ``benchmark/flops/olmo_hybrid.py:delta_rule_flops``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.scipy.linalg import solve_triangular
 
 CHUNK = 64
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _chunks(x, n: int, c: int):
@@ -49,6 +63,47 @@ def _chunks(x, n: int, c: int):
     b, _, h = x.shape[:3]
     x = x.reshape((b, n, c, h) + x.shape[3:])
     return jnp.moveaxis(x, 3, 1)
+
+
+@jax.custom_vjp
+def unit_lower_inverse(l):
+    """``(I + l)^-1`` for strictly lower triangular ``l`` of
+    ``[..., n, n]`` float32 (what lies on or above the diagonal is
+    not read), by forward substitution, ``X[r] = e_r - l[r, :r]
+    X[:r]``: ``n`` dependent steps, each one multiply-and-sum over
+    every block of the batch at once. The blocks lie on the minor axis
+    meanwhile (``[n, n, N]``): there a step is whole vector
+    operations, whatever ``n``. Its own backward rule
+    (``-strict_lower(X^T G X^T)``, two products at the highest
+    precision) keeps JAX from differentiating through the steps."""
+    with jax.named_scope("delta.inverse"):
+        shape, n = l.shape, l.shape[-1]
+        lt = jnp.moveaxis(jnp.tril(l, -1).reshape(-1, n, n), 0, -1)
+        eye = jnp.eye(n, dtype=l.dtype)
+
+        def step(r, x):
+            # rows r and below of x are still 0: the sum is over :r
+            row = eye[r][:, None] - jnp.sum(lt[r][:, None, :] * x, axis=0)
+            return x.at[r].set(row)
+
+        x = jax.lax.fori_loop(0, n, step, jnp.zeros_like(lt))
+        return jnp.moveaxis(x, -1, 0).reshape(shape)
+
+
+def _inverse_fwd(l):
+    x = unit_lower_inverse(l)
+    return x, x
+
+
+def _inverse_bwd(x, g):
+    with jax.named_scope("delta.inverse"):
+        xt = jnp.swapaxes(x, -1, -2)
+        bar = jnp.matmul(jnp.matmul(xt, g, precision=HIGHEST), xt,
+                         precision=HIGHEST)
+        return (-jnp.tril(bar, -1),)
+
+
+unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
@@ -88,9 +143,7 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     gam = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
     kb = kc.astype(f32) * bc[..., None]
     a = jnp.where(strict, mm("bhnid,bhnjd->bhnij", kb, kc) * gam, 0.0)
-    eye = jnp.eye(chunk, dtype=f32)
-    inv = solve_triangular(a + eye, jnp.broadcast_to(eye, a.shape),
-                           lower=True, unit_diagonal=True)
+    inv = unit_lower_inverse(a)
     ec = jnp.exp(c)[..., None]
     w = mm("bhnij,bhnjd->bhnid", inv, kb * ec)
     u = mm("bhnij,bhnjd->bhnid", inv, vc.astype(f32) * bc[..., None])

@@ -1,7 +1,8 @@
 """The harness that judges a 10^9-parameter cell, in the lane the
 driver runs: ``correct.compare``'s one walk over the leaves against the
 whole-tree arithmetic it replaced, and the FedAvg reference's
-three-tree round against the one it replaced. The cases live with the
+three-tree round against the one it replaced; and ``tag_reduce``'s
+seconds of a named part of a model scope. The cases live with the
 benchmark (``benchmark/tests``, which no lane of the driver collects)
 and are imported here, not copied: CPU only, seconds."""
 import os
@@ -20,4 +21,9 @@ from benchmark.tests.test_compare import (  # noqa: E402,F401
 from benchmark.tests.test_fedavg_reference import (  # noqa: E402,F401
     test_at_most_three_trees_on_the_device,
     test_round_is_bit_identical_to_the_one_it_replaced,
+)
+from benchmark.tests.test_tag_reduce import (  # noqa: E402,F401
+    test_none_where_no_operation_holds_the_tag,
+    test_reader_returns_none_without_a_trace_or_a_profile,
+    test_seconds_of_the_operations_that_hold_the_tag,
 )

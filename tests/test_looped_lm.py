@@ -398,15 +398,18 @@ def test_launcher_runs_saves_and_resumes(files, tmp_path):
 # -- the single-pass model's program --------------------------------------------
 
 OLMO_ROUND_SHA256 = \
-    "0d16f9ff886caf0d8bb826b5c6be700bca0180be09b8f642cfe9bb589da836ce"
+    "e54e9767a690fcbbe1037b2c3936b9ca84a545151e237032699669eeace23f57"
 
 
 def test_the_olmo_cells_lowered_round_is_unchanged(tmp_path):
     """``olmo_hybrid_7b_l4.fedavg_k2_e10``'s round program at the
     cell's own flags and widths (nothing allocated: abstract state, a
     store of 4 rows a client), lowered on the CPU: the text's digest
-    as the parent of PR 35 gave it. A change to the shared model file
-    that moves one operation of the single-pass path moves this."""
+    as PR 36 left it (which moved it on purpose: the delta rule's
+    triangular inverse; PR 35 had left its parent's,
+    ``0d16f9ff...da836ce``, in place). A change to the shared model
+    file that moves one operation of the single-pass path moves
+    this."""
     from benchmark.harness import runner
     from fedtorch_tpu.algorithms import make_algorithm
     from fedtorch_tpu.cli import args_to_config, build_parser
@@ -426,4 +429,5 @@ def test_the_olmo_cells_lowered_round_is_unchanged(tmp_path):
     server, clients = jax.eval_shape(t.init_state, jax.random.key(0))
     text = jax.jit(t.round_fn, donate_argnums=(0, 1)).lower(
         server, clients, t.data, None).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == OLMO_ROUND_SHA256
+    digest = hashlib.sha256(text.encode()).hexdigest()
+    assert digest == OLMO_ROUND_SHA256, digest

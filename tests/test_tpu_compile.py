@@ -86,3 +86,28 @@ def test_compiled_round_holds_no_operation_of_the_stores_size(one_chip):
                    if m and m.group(1) not in passes_through]
     assert "fed.gather" in text   # the stage is there to be judged
     assert not store_sized, "\n".join(store_sized)
+
+
+def test_delta_rule_gradient_holds_no_triangular_solve(one_chip):
+    """The chunk's unit lower-triangular inverse is the module's own
+    batched substitution (``ops/delta_rule.py:unit_lower_inverse``) in
+    the forward pass and its own two products in the backward pass: at
+    the olmo cell's shapes the compiled gradient holds neither XLA's
+    generic inverter of diagonal blocks (2.6 ms a call on the chip,
+    PERF.md section 6, PR 36) nor a ``triangular-solve``."""
+    from fedtorch_tpu.ops.delta_rule import chunk_gated_delta_rule
+
+    B, T, H, dk, dv = 1, 2048, 30, 96, 192
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    args = (on_chip((B, T, H, dk), jnp.bfloat16),
+            on_chip((B, T, H, dk), jnp.bfloat16),
+            on_chip((B, T, H, dv), jnp.bfloat16),
+            on_chip((B, T, H), jnp.float32),
+            on_chip((B, T, H), jnp.float32))
+    loss = lambda *a: jnp.sum(chunk_gated_delta_rule(*a))
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        *args).compile().as_text()
+    assert "delta.inverse" in text     # the inverse is there to be judged
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert not re.search(r"\btriangular-solve\(", text)
