@@ -708,9 +708,10 @@ def run_experiment(cfg: ExperimentConfig,
     from fedtorch_tpu.data import build_federated_data
     from fedtorch_tpu.models import define_model
     from fedtorch_tpu.parallel import (
-        FederatedTrainer, build_local_sgd, evaluate, evaluate_personal,
+        FederatedTrainer, build_local_sgd, evaluate_personal,
         init_multihost,
     )
+    from fedtorch_tpu.parallel.evaluate import evaluate_to_host
     from fedtorch_tpu.utils import (
         PhaseTimer, RunLogger, aggregation_tracking, init_checkpoint_dir,
         maybe_resume, model_norms, save_checkpoint,
@@ -807,9 +808,8 @@ def run_experiment(cfg: ExperimentConfig,
                 splits_y = np.asarray(fed_data.train.y).reshape(-1)
                 trainer = build_local_sgd(cfg, model, splits_x, splits_y)
                 server, clients, history = trainer.fit(rng)
-                res = jax.device_get(evaluate(model, server.params,
-                                              fed_data.test_x,
-                                              fed_data.test_y))
+                res = evaluate_to_host(model, server.params,
+                                       fed_data.test_x, fed_data.test_y)
                 logger.log_val(len(history), "test", float(res.loss),
                                float(res.top1), float(res.top5))
                 tel.health_update("complete", round_idx=len(history))
@@ -1083,7 +1083,7 @@ def run_experiment(cfg: ExperimentConfig,
                 # adopt the run dir's existing capture instead of
                 # lowering the twins again); a failure turns the
                 # device gauges off, never the run
-                with tel.span("cost_capture", round=r):
+                with tel.span("cost_capture"):
                     try:
                         programs, primary = \
                             trainer.lowered_cost_programs(
@@ -1161,11 +1161,11 @@ def run_experiment(cfg: ExperimentConfig,
             eval_s = checkpoint_s = None
             if (r + 1) % cfg.train.eval_freq == 0:
                 timer.start("eval")
-                with tel.span("eval", round=r):
+                with tel.span("eval", round=r).rss():
                     # one transfer for the whole EvalResult pytree
-                    res = jax.device_get(evaluate(
+                    res = evaluate_to_host(
                         model, server.params, fed_data.test_x,
-                        fed_data.test_y))
+                        fed_data.test_y)
                 eval_s = timer.stop("eval")
                 top1 = float(res.top1)
                 is_best = top1 > best_prec1
@@ -1187,7 +1187,7 @@ def run_experiment(cfg: ExperimentConfig,
                     # of the resume contract)
                     accountant.save(ckpt_dir)
                 timer.start("checkpoint")
-                with tel.span("checkpoint", round=r):
+                with tel.span("checkpoint", round=r).rss():
                     saver(ckpt_dir, server, clients, cfg, best_prec1,
                           is_best,
                           save_all=cfg.checkpoint.save_all_models,

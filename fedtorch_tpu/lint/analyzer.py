@@ -71,7 +71,10 @@ _HOST_RESULT_CALLS = {
     "jax.device_get", "jax.eval_shape", "jax.typeof",
 }
 _HOST_RESULT_NAMES = {"isinstance", "issubclass", "len", "getattr",
-                      "hasattr", "type", "repr", "str", "callable"}
+                      "hasattr", "type", "repr", "str", "callable",
+                      # parallel.evaluate: the evaluation's ONE
+                      # jax.device_get is inside it
+                      "evaluate_to_host"}
 
 # methods whose RESULT is host values whatever they are handed: the
 # trainer's sanctioned batched fetches, one jax.device_get inside each

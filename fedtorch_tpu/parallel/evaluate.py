@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fedtorch_tpu import telemetry
 from fedtorch_tpu.core.losses import make_criterion, topk_accuracy
 from fedtorch_tpu.models.common import ModelDef, is_token_model
 from fedtorch_tpu.utils.tracing import instrument_trace
@@ -119,22 +120,39 @@ def evaluate(model: ModelDef, params, x: np.ndarray, y: np.ndarray,
     if token:
         # a row is thousands of tokens: a few rows a step
         batch_size = model.eval_batch
-    bx, by, bm = _pad_batches(np.asarray(x), np.asarray(y), batch_size)
-    bx, by, bm = jnp.asarray(bx), jnp.asarray(by), jnp.asarray(bm)
-    if model.has_noise_param and robust_ascent:
-        # pad/upload once; the ascent shares the same device batches
-        params = _ascent_on_batches(model, params, bx, by, bm)
+    # the call's host half under spans of its own: the padded copy of
+    # the whole test set and its upload are fresh buffers of the set's
+    # size every call
+    with telemetry.span("eval.batches") as sp:
+        bx, by, bm = _pad_batches(np.asarray(x), np.asarray(y), batch_size)
+        nbytes = bx.nbytes + by.nbytes + bm.nbytes
+        sp.note(bytes=nbytes, rows=len(x), pad_rows=bm.size - len(x))
+    with telemetry.span("eval.h2d", bytes=nbytes):
+        bx, by, bm = jnp.asarray(bx), jnp.asarray(by), jnp.asarray(bm)
+    with telemetry.span("eval.dispatch"):
+        if model.has_noise_param and robust_ascent:
+            # pad/upload once; the ascent shares the same device batches
+            params = _ascent_on_batches(model, params, bx, by, bm)
 
-    # a token model is a tuple of hashables, dtype and backend among
-    # them: the whole of it is the key
-    key = model if token else \
-        (model.module, model.is_regression, model.is_recurrent)
-    if key not in _EVAL_CACHE:
-        # params is the live server model, reused every round —
-        # donation would be unsafe here
-        _EVAL_CACHE[key] = jax.jit(
-            instrument_trace("evaluate.run", _eval_run_fn(model)))
-    return _EVAL_CACHE[key](params, bx, by, bm)
+        # a token model is a tuple of hashables, dtype and backend among
+        # them: the whole of it is the key
+        key = model if token else \
+            (model.module, model.is_regression, model.is_recurrent)
+        if key not in _EVAL_CACHE:
+            # params is the live server model, reused every round —
+            # donation would be unsafe here
+            _EVAL_CACHE[key] = jax.jit(
+                instrument_trace("evaluate.run", _eval_run_fn(model)))
+        return _EVAL_CACHE[key](params, bx, by, bm)
+
+
+def evaluate_to_host(model: ModelDef, params, x: np.ndarray,
+                     y: np.ndarray) -> EvalResult:
+    """:func:`evaluate` and the ONE transfer of its result to the host
+    (span ``eval.fetch``: the wait for the device is in here)."""
+    res = evaluate(model, params, x, y)
+    with telemetry.span("eval.fetch"):
+        return jax.device_get(res)
 
 
 def _eval_run_fn(model: ModelDef):

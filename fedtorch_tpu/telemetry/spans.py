@@ -16,6 +16,11 @@ producer/writer threads record without locks). The buffer is
 bounded (``max_events``); past the cap new spans are counted as
 dropped instead of growing without bound on month-long runs.
 
+A span that asks (``span(...).rss()``: the loop's ``eval`` and
+``checkpoint``, twice a cycle) also carries the process's resident set
+at enter and at exit, read outside its own clock reads; a span that
+does not ask runs the code it always ran.
+
 With an ``annotate`` hook (the CLI passes
 ``jax.profiler.TraceAnnotation``; this package imports no jax) every
 span also opens an annotation of the same name and args, so that while
@@ -30,6 +35,21 @@ import os
 import threading
 import time
 from typing import Callable, Dict, List, Optional
+
+_PROC_STATUS = "/proc/self/status"
+
+
+def _vm_rss(arg: str) -> Dict[str, int]:
+    """``{arg: bytes}``: the ``VmRSS`` line of ``/proc/self/status``
+    (kB there); empty where there is no such file or no such line."""
+    try:
+        with open(_PROC_STATUS, "rb") as f:
+            for line in f:
+                if line.startswith(b"VmRSS:"):
+                    return {arg: int(line.split()[1]) * 1024}
+    except OSError:
+        pass
+    return {}
 
 
 class _Span:
@@ -58,9 +78,36 @@ class _Span:
         args it was opened with."""
         self.args = {**(self.args or {}), **args}
 
+    def rss(self) -> "_Span":
+        """The same span, asked for the process's resident set: args
+        ``vm_rss_enter`` and ``vm_rss_exit`` in bytes, recorded at exit
+        as :meth:`note` args are (their difference is what the span's
+        work left resident: buffers found again read 0, fresh ones
+        their size). Absent off Linux. Call it before entering."""
+        return _RssSpan(self._rec, self.name, self.args)
+
     def __exit__(self, *exc) -> None:
         self._rec._record(self.name, self._t0, time.perf_counter_ns(),
                           self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+class _RssSpan(_Span):
+    """What :meth:`_Span.rss` hands back. Both reads of the file lie
+    outside the span's two clock reads, so its duration is that of the
+    work alone."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_RssSpan":
+        self.note(**_vm_rss("vm_rss_enter"))
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        self._rec._record(self.name, self._t0, t1,
+                          {**self.args, **_vm_rss("vm_rss_exit")})
         if self._ann is not None:
             self._ann.__exit__(*exc)
 
@@ -75,6 +122,9 @@ class _NullSpan:
 
     def note(self, **args) -> None:
         return None
+
+    def rss(self) -> "_NullSpan":
+        return self
 
     def __exit__(self, *exc):
         return None

@@ -19,6 +19,7 @@ and round counter — is serialized, so a resumed run continues exactly.
 from __future__ import annotations
 
 import atexit
+import contextlib
 import dataclasses
 import hashlib
 import json
@@ -132,11 +133,11 @@ def _snapshot(server, clients, cfg: ExperimentConfig):
     (shard_clients), so a plain device_get on one process would touch
     non-addressable shards; the cross-host allgather materializes the
     global value on every process. It is a COLLECTIVE — every process
-    must call _snapshot even though only process 0 writes."""
-    state = {"server": _unkey(server),
-             "clients": _strip_padding(clients,
-                                       cfg.federated.num_clients)}
+    must call _snapshot even though only process 0 writes.
 
+    The one part of a save that needs the device's state still, hence
+    the floor of a save taken off the loop: it runs under its own span,
+    ``checkpoint.snapshot``, for both savers."""
     def to_host(x):
         if isinstance(x, jax.Array) and not x.is_fully_addressable:
             # sharded across processes (the client axis): collective
@@ -145,8 +146,16 @@ def _snapshot(server, clients, cfg: ExperimentConfig):
             return multihost_utils.process_allgather(x, tiled=True)
         return jax.device_get(x)
 
-    return jax.tree.map(_owning_host_copy,
-                        jax.tree.map(to_host, state))
+    with telemetry.span("checkpoint.snapshot") as sp:
+        state = {"server": _unkey(server),
+                 "clients": _strip_padding(clients,
+                                           cfg.federated.num_clients)}
+        fetched = jax.tree.leaves(jax.tree.map(to_host, state))
+        owned = [_owning_host_copy(x) for x in fetched]
+        sp.note(bytes=sum(x.nbytes for x in owned), leaves=len(owned),
+                owned_copy_bytes=sum(
+                    o.nbytes for x, o in zip(fetched, owned) if o is not x))
+    return jax.tree.unflatten(jax.tree.structure(state), owned)
 
 
 # self-describing checkpoint framing: magic + payload length + sha256
@@ -221,7 +230,8 @@ def _payload_pieces(host_state) -> Tuple[list, dict]:
     ``borrowed_bytes`` views of the snapshot's own memory,
     ``relaid_bytes`` copied once into C order, ``copied_bytes`` packed
     by flax's packer (the tree's map headers and keys, and an array's
-    header, count as none of them)."""
+    header, count as none of them); and ``relaid_s``, the seconds
+    inside those C-order copies (two clock reads a strided leaf)."""
     # the two packers flax's msgpack_serialize and _ndarray_to_bytes use
     outer = msgpack.Packer(default=serialization._msgpack_ext_pack,
                            strict_types=True)
@@ -229,7 +239,8 @@ def _payload_pieces(host_state) -> Tuple[list, dict]:
     ndarray_code = int(serialization._MsgpackExtType.ndarray)
     pieces: list = []
     text = bytearray()          # small bytes since the last view
-    counts = {"borrowed_bytes": 0, "relaid_bytes": 0, "copied_bytes": 0}
+    counts = {"borrowed_bytes": 0, "relaid_bytes": 0, "copied_bytes": 0,
+              "relaid_s": 0.0}
 
     def walk(node):
         if type(node) is dict:
@@ -251,7 +262,9 @@ def _payload_pieces(host_state) -> Tuple[list, dict]:
         if node.flags.c_contiguous:
             counts["borrowed_bytes"] += node.nbytes
         else:
+            t0 = time.perf_counter()
             node = np.ascontiguousarray(node)
+            counts["relaid_s"] += time.perf_counter() - t0
             counts["relaid_bytes"] += node.nbytes
         head = (inner.pack_array_header(3) + inner.pack(node.shape)
                 + inner.pack(node.dtype.name) + _bin_header(node.nbytes))
@@ -324,20 +337,31 @@ def _atomic_write(path: str, *parts) -> None:
     # package chain, so a module-level robustness import here would
     # be circular
     from fedtorch_tpu.robustness import host_chaos, host_recovery
+    attempts = 0
 
     def attempt():
-        host_chaos.maybe_raise_io("ckpt.write")
+        # one set of sub-spans an attempt: a retried write shows as two
+        nonlocal attempts
+        attempts += 1
         tmp = path + ".tmp"
-        with open(tmp, "wb") as f:
-            for part in parts:
-                f.write(part)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, path)
+        with contextlib.ExitStack() as closing:
+            with telemetry.span("checkpoint.file_write.data"):
+                host_chaos.maybe_raise_io("ckpt.write")
+                f = closing.enter_context(open(tmp, "wb"))
+                for part in parts:
+                    f.write(part)
+                f.flush()
+            with telemetry.span("checkpoint.file_write.fsync"):
+                os.fsync(f.fileno())
+        with telemetry.span("checkpoint.file_write.rename"):
+            os.replace(tmp, path)
     with telemetry.span("checkpoint.file_write",
                         name=os.path.basename(path),
-                        bytes=sum(len(p) for p in parts)):
-        host_recovery.retry_io(attempt, "ckpt.write")
+                        bytes=sum(len(p) for p in parts)) as sp:
+        try:
+            host_recovery.retry_io(attempt, "ckpt.write")
+        finally:
+            sp.note(attempts=attempts)
 
 
 def _write_frame(path: str, frame: list) -> None:
@@ -487,8 +511,12 @@ def _write_checkpoint(directory: str, host_state, meta: dict,
     # list, so a retried write, a refused link's copy and the keep walk
     # the same pieces again
     with telemetry.span("checkpoint.serialize") as sp:
-        pieces, counts = _payload_pieces(host_state)
-        frame = [_frame_header(pieces)] + pieces
+        with telemetry.span("checkpoint.layout") as layout:
+            pieces, counts = _payload_pieces(host_state)
+            layout.note(relaid_s=counts.pop("relaid_s"), **counts)
+        with telemetry.span("checkpoint.digest", bytes=sum(
+                len(p) for p in pieces)):
+            frame = [_frame_header(pieces)] + pieces
         sp.note(pieces=len(pieces), **counts)
     path = os.path.join(directory, "checkpoint.ckpt")
     _write_frame(path, frame)
@@ -507,7 +535,8 @@ def _write_checkpoint(directory: str, host_state, meta: dict,
         _write_frame(
             os.path.join(directory, f"checkpoint_r{round_idx}.ckpt"),
             frame)
-        collect_round_keeps(directory, keep_last_n)
+        with telemetry.span("checkpoint.gc"):
+            collect_round_keeps(directory, keep_last_n)
     return path
 
 
@@ -541,8 +570,7 @@ def save_checkpoint(directory: str, server, clients,
     variant. Every process participates in the snapshot (it is a
     collective on multi-host); only process 0 touches the disk."""
     path = os.path.join(directory, "checkpoint.ckpt")
-    with telemetry.span("checkpoint.snapshot"):
-        host_state = _snapshot(server, clients, cfg)
+    host_state = _snapshot(server, clients, cfg)
     if not _is_writer_process():
         return path
     round_idx = int(server.round)
@@ -672,8 +700,7 @@ class AsyncCheckpointer:
              save_some_rounds: Tuple[int, ...] = ()) -> None:
         # the snapshot is a COLLECTIVE on multi-host — all processes
         # take it FIRST; only process 0 writes
-        with telemetry.span("checkpoint.snapshot"):
-            host_state = _snapshot(server, clients, cfg)
+        host_state = _snapshot(server, clients, cfg)
         if not _is_writer_process():
             return
         round_idx = int(server.round)
@@ -695,8 +722,7 @@ class AsyncCheckpointer:
             from fedtorch_tpu.robustness import host_recovery
             t0 = time.perf_counter()
             try:
-                with telemetry.span("checkpoint.write", round=round_idx,
-                                    degraded=True):
+                with telemetry.span("checkpoint.write", round=round_idx):
                     # the whole write under the seam retry: dir
                     # creation can fail with the same transient
                     # OSErrors the atomic writes can, and exhaustion

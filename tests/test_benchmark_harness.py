@@ -5,8 +5,11 @@ three-tree round against the one it replaced; and ``tag_reduce``'s
 seconds of a named part of a model scope. The cases live with the
 benchmark (``benchmark/tests``, which no lane of the driver collects)
 and are imported here, not copied: CPU only, seconds."""
+import gc
 import os
 import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
@@ -27,3 +30,11 @@ from benchmark.tests.test_tag_reduce import (  # noqa: E402,F401
     test_reader_returns_none_without_a_trace_or_a_profile,
     test_seconds_of_the_operations_that_hold_the_tag,
 )
+
+
+@pytest.fixture(autouse=True)
+def _no_garbage_from_the_test_before():
+    """Several of these cases count what is live on the device or on
+    the host: an earlier test's uncollected cycles, freed in the middle
+    of one, would read as negative bytes."""
+    gc.collect()

@@ -528,6 +528,7 @@ class TestPayloadIsNeverBuilt:
         views = [p for p in pieces if isinstance(p, memoryview)]
         assert counts["borrowed_bytes"] + counts["relaid_bytes"] \
             == sum(len(v) for v in views)
+        assert counts.pop("relaid_s") >= 0.0    # seconds, not bytes
         assert sum(counts.values()) <= len(want)
         assert ckpt_mod._frame_header(pieces) \
             == ckpt_mod._frame_payload(want)[:ckpt_mod._CKPT_HEADER]
@@ -547,7 +548,7 @@ class TestPayloadIsNeverBuilt:
         assert len(views) == len(leaves)
         assert counts == {
             "borrowed_bytes": sum(x.nbytes for x in leaves),
-            "relaid_bytes": 0, "copied_bytes": 0}
+            "relaid_bytes": 0, "copied_bytes": 0, "relaid_s": 0.0}
         by_address = {x.__array_interface__["data"][0]: x
                       for x in leaves}
         for v in views:
@@ -671,3 +672,170 @@ class TestPayloadIsNeverBuilt:
         for name in ("checkpoint.ckpt", "model_best.ckpt",
                      "checkpoint_r1.ckpt"):
             assert _read(d, name) == want
+
+
+# -- the save's span tree (ISSUE 37) ------------------------------------------
+# the spans under which a save does its work; the rest only hold them
+UNSPANNED_GAP_US = 20_000
+LEAF_SPANS = ("checkpoint.snapshot", "checkpoint.layout",
+              "checkpoint.digest", "checkpoint.file_write.data",
+              "checkpoint.file_write.fsync", "checkpoint.file_write.rename",
+              "checkpoint.link", "checkpoint.gc")
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def _named(spans, name):
+    return [s for s in spans if s[0] == name]
+
+
+def _check_save_tree(spans, d, files):
+    """One best save with a keep: ``files`` in the order they are
+    written; nesting and args of every span below ``checkpoint.write``
+    and of the snapshot."""
+    (snap,), (write,), (ser,), (layout,), (digest,), (link,), (gc,) = (
+        _named(spans, n) for n in (
+            "checkpoint.snapshot", "checkpoint.write",
+            "checkpoint.serialize", "checkpoint.layout",
+            "checkpoint.digest", "checkpoint.link", "checkpoint.gc"))
+    payload = os.path.getsize(os.path.join(d, "checkpoint.ckpt")) \
+        - ckpt_mod._CKPT_HEADER
+    # the snapshot: before the write, with what it holds
+    assert snap[2] <= write[1]
+    assert set(snap[3]) == {"bytes", "leaves", "owned_copy_bytes"}
+    assert 0 < snap[3]["bytes"] < payload and snap[3]["leaves"] > 2
+    # on the CPU device_get hands back views: every byte is copied
+    assert snap[3]["owned_copy_bytes"] == snap[3]["bytes"]
+    # serialize = layout then digest, its own args as they were
+    assert _inside(ser, write)
+    assert _inside(layout, ser) and _inside(digest, ser)
+    assert layout[2] <= digest[1]
+    assert set(ser[3]) == {"pieces", "borrowed_bytes", "relaid_bytes",
+                           "copied_bytes"}
+    assert set(layout[3]) == (set(ser[3]) - {"pieces"}) | {"relaid_s"}
+    assert layout[3]["borrowed_bytes"] == snap[3]["bytes"]
+    assert layout[3]["relaid_s"] == 0.0     # nothing strided on the CPU
+    assert digest[3] == {"bytes": payload}
+    # one file_write a file, each one attempt of data, fsync, rename
+    writes = _named(spans, "checkpoint.file_write")
+    assert [w[3]["name"] for w in writes] == files
+    for w in writes:
+        assert _inside(w, write) and w[3]["attempts"] == 1
+        assert set(w[3]) == {"name", "bytes", "attempts"}
+        parts = [s for s in spans if s[0].startswith(
+            "checkpoint.file_write.") and _inside(s, w)]
+        assert [p[0] for p in parts] == [
+            "checkpoint.file_write.data", "checkpoint.file_write.fsync",
+            "checkpoint.file_write.rename"]
+        assert all(p[3] == {} for p in parts)
+        assert parts[0][2] <= parts[1][1] and parts[1][2] <= parts[2][1]
+    assert len(_named(spans, "checkpoint.file_write.data")) == len(files)
+    assert _inside(link, write) and link[3] == {
+        "name": "model_best.ckpt", "fallback": False}
+    assert _inside(gc, write) and gc[3] == {}
+    assert writes[-1][2] <= gc[1]
+
+
+SAVE_FILES = ["checkpoint.ckpt", "checkpoint.json", "model_best.json",
+              "checkpoint_r1.ckpt"]
+
+
+class TestSaveSpanTree:
+    def test_synchronous_save(self, tmp_path, recorder):
+        d = str(tmp_path / "ck")
+        cfg, trainer, server, clients = make_experiment(
+            ckpt_kw={"keep_last_n": 2})
+        server, clients, _ = trainer.run_round(server, clients)
+        save_checkpoint(d, server, clients, cfg, 0.5, is_best=True,
+                        save_all=True)
+        spans = _spans(recorder)
+        _check_save_tree(spans, d, SAVE_FILES)
+        assert len({e["tid"] for e in recorder.spans.to_trace_events()
+                    if e["name"].startswith("checkpoint")}) == 1
+
+    def test_async_save_gets_the_same_tree_on_its_lane(
+            self, tmp_path, recorder):
+        from fedtorch_tpu.utils.checkpoint import AsyncCheckpointer
+        d = str(tmp_path / "ck")
+        cfg, trainer, server, clients = make_experiment(
+            ckpt_kw={"keep_last_n": 2})
+        server, clients, _ = trainer.run_round(server, clients)
+        saver = AsyncCheckpointer()
+        try:
+            saver.save(d, server, clients, cfg, 0.5, is_best=True,
+                       save_all=True)
+            saver.wait()
+        finally:
+            saver.close()
+        _check_save_tree(_spans(recorder), d, SAVE_FILES)
+        lanes = {}
+        for e in recorder.spans.to_trace_events():
+            if e.get("ph") == "X" and e["name"].startswith("checkpoint"):
+                lanes.setdefault(e["tid"], set()).add(e["name"])
+        # the snapshot on the caller's thread, the rest on the writer's
+        assert sorted(lanes.values(), key=len)[0] == {"checkpoint.snapshot"}
+        assert len(lanes) == 2
+
+    def test_retried_write_shows_as_two_attempts(self, tmp_path, recorder):
+        """The ``ckpt.write`` drill fails the payload file's first
+        attempt: two ``checkpoint.file_write.data`` under ONE
+        ``checkpoint.file_write`` whose ``attempts`` is 2, and one
+        fsync and rename, the retry's."""
+        from fedtorch_tpu.robustness import host_chaos, host_recovery
+        d = str(tmp_path / "ck")
+        cfg, trainer, server, clients = make_experiment()
+        server, clients, _ = trainer.run_round(server, clients)
+        ledger = host_recovery.HostRecovery(
+            sleep_fn=lambda s: None).install()
+        inj = host_chaos.HostFaultInjector(
+            ("ckpt.write",), rate=1.0, max_fires=1).install()
+        try:
+            save_checkpoint(d, server, clients, cfg, 0.5, is_best=False)
+        finally:
+            inj.uninstall()
+            ledger.uninstall()
+        assert ledger.stats()["host_retries"] == 1
+        spans = _spans(recorder)
+        first, meta = _named(spans, "checkpoint.file_write")
+        assert first[3]["name"] == "checkpoint.ckpt"
+        assert first[3]["attempts"] == 2 and meta[3]["attempts"] == 1
+        assert [s[0] for s in spans if s[0].startswith(
+            "checkpoint.file_write.") and _inside(s, first)] == [
+                "checkpoint.file_write.data", "checkpoint.file_write.data",
+                "checkpoint.file_write.fsync",
+                "checkpoint.file_write.rename"]
+        assert ckpt_mod.frame_quick_ok(os.path.join(d, "checkpoint.ckpt"))
+
+    def test_leaf_spans_tile_the_loops_checkpoint_span(
+            self, tmp_path, recorder):
+        """What the save does outside its leaf spans (the meta's JSON,
+        directory and drill bookkeeping, the reads of the resident
+        set) is a few milliseconds whatever the state's size: every
+        leaf lies inside the loop's ``checkpoint`` span, none overlaps
+        the next, and the time between them stays under a fixed cap, so
+        work moved out from under its span shows."""
+        d = str(tmp_path / "ck")
+        cfg, trainer, server, clients = make_experiment(
+            ckpt_kw={"keep_last_n": 2})
+        server, clients, _ = trainer.run_round(server, clients)
+        # as the launcher's loop opens it
+        with recorder.span("checkpoint", round=0).rss():
+            save_checkpoint(d, server, clients, cfg, 0.5, is_best=True,
+                            save_all=True)
+        spans = _spans(recorder)
+        (whole,) = _named(spans, "checkpoint")
+        leaves = sorted((s for s in spans if s[0] in LEAF_SPANS),
+                        key=lambda s: s[1])
+        assert {s[0] for s in leaves} == set(LEAF_SPANS)
+        edges = [whole[1]] + [t for s in leaves for t in s[1:3]] \
+            + [whole[2]]
+        assert edges == sorted(edges)
+        gaps = [b - a for a, b in zip(edges[::2], edges[1::2])]
+        # microseconds: the widest gap (the meta's JSON), and all of them
+        assert max(gaps) < UNSPANNED_GAP_US
+        assert sum(gaps) < 3 * UNSPANNED_GAP_US
+        assert set(whole[3]) == {"round"} | (
+            {"vm_rss_enter", "vm_rss_exit"}
+            if os.path.exists("/proc/self/status") else set())

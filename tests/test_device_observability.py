@@ -504,3 +504,45 @@ class TestCliRunDeviceGauges:
                                 extra=("--telemetry", "off")))
         assert not os.path.exists(
             os.path.join(run_dir, "program_costs.json"))
+
+
+class TestSaveAndEvalSpansInTheProfile:
+    def test_new_span_names_lie_in_the_host_plane(self, tmp_path):
+        """ISSUE 37's spans go through the one recorder, so its
+        ``annotate`` hook puts each in the profile's host plane, on the
+        device trace's clock, as often as ``trace.json`` holds it."""
+        import glob
+        import warnings
+
+        from jax.profiler import ProfileData, ProfileOptions
+        from test_telemetry import _cli_cfg
+
+        from fedtorch_tpu.cli import run_experiment
+        names = {"eval.batches", "eval.h2d", "eval.dispatch", "eval.fetch",
+                 "checkpoint.layout", "checkpoint.digest",
+                 "checkpoint.file_write.data",
+                 "checkpoint.file_write.fsync",
+                 "checkpoint.file_write.rename", "checkpoint.snapshot"}
+        run_dir, prof_dir = str(tmp_path / "run"), str(tmp_path / "prof")
+        opts = ProfileOptions()
+        opts.python_tracer_level = 0     # annotations only: a small file
+        jax.profiler.start_trace(prof_dir, profiler_options=opts)
+        try:
+            run_experiment(_cli_cfg(run_dir, rounds=2))
+        finally:
+            jax.profiler.stop_trace()
+        doc = json.load(open(os.path.join(run_dir, "trace.json")))
+        recorded = sorted(e["name"] for e in doc["traceEvents"]
+                          if e.get("ph") == "X" and e["name"] in names)
+        prof = ProfileData.from_file(glob.glob(os.path.join(
+            prof_dir, "plugins", "profile", "*", "*.xplane.pb"))[0])
+        # see test_telemetry: jaxlib's stats iterator warns when made
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", DeprecationWarning)
+            annotated = sorted(
+                ev.name for plane in prof.planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events
+                if ev.name in names)
+        assert set(recorded) == names
+        assert annotated == recorded

@@ -189,6 +189,16 @@ def scheme_fixture(scheme):
     return trainer, clients, metrics, edges
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _let_go_of_the_cached_trainers():
+    """The cache above would keep four trainers' device stores alive
+    for the rest of the worker's session, where a later file counts
+    what is live on the device (test_streaming's residency case failed
+    whenever the two files shared a worker)."""
+    yield
+    scheme_fixture.cache_clear()
+
+
 @pytest.mark.parametrize("where", ["before", "on", "past"])
 @pytest.mark.parametrize("scheme", sorted(SCHEMES))
 def test_program_lr_is_lr_at(scheme, where):

@@ -261,6 +261,62 @@ class TestSpanRecorder:
         f(jnp.ones((5,))).block_until_ready()   # a new shape compiles
         assert len(compiles(rec)) == 1     # ... unseen: the listener left
 
+    def test_a_span_that_asks_carries_the_resident_set(self):
+        """``span(...).rss()``: ``vm_rss_enter`` and ``vm_rss_exit``
+        in bytes as args at exit beside the span's own and its notes; a
+        span that does not ask records none, whatever ran inside it."""
+        import numpy as np
+        rec = SpanRecorder()
+        with rec.span("quiet", round=1):
+            np.ones(1 << 22, np.uint8)
+        with rec.span("asks", round=2).rss() as sp:
+            # 64 MiB of fresh memory, every page touched, and held
+            held = np.ones(1 << 26, np.uint8)
+            sp.note(bytes=held.nbytes)
+        quiet, asks = (e for e in rec.to_trace_events() if e["ph"] == "X")
+        assert quiet["args"] == {"round": 1}
+        assert asks["args"]["round"] == 2
+        assert asks["args"]["bytes"] == 1 << 26
+        if os.path.exists("/proc/self/status"):
+            assert set(asks["args"]) == {"round", "bytes", "vm_rss_enter",
+                                         "vm_rss_exit"}
+            assert type(asks["args"]["vm_rss_exit"]) is int
+            assert asks["args"]["vm_rss_enter"] > 1 << 20   # bytes, not kB
+            # what the span's work left resident (the rest of the
+            # process may give a little back meanwhile)
+            grown = asks["args"]["vm_rss_exit"] - asks["args"]["vm_rss_enter"]
+            assert grown >= 1 << 25
+        json.dumps(asks)       # rides trace.json as every other arg
+
+    def test_the_resident_set_is_simply_absent_off_linux(self, monkeypatch):
+        """No ``/proc/self/status``, or one with no ``VmRSS`` line: the
+        span records its own args and no ``vm_*`` one, and does not
+        fail."""
+        from fedtorch_tpu.telemetry import spans as spans_mod
+        rec = SpanRecorder()
+        monkeypatch.setattr(spans_mod, "_PROC_STATUS",
+                            "/nonexistent/status")
+        with rec.span("eval", round=3).rss():
+            pass
+        with rec.span("checkpoint").rss() as sp:
+            sp.note(ok=True)
+        monkeypatch.setattr(spans_mod, "_PROC_STATUS", os.devnull)
+        with rec.span("eval").rss():
+            pass
+        assert [e.get("args") for e in rec.to_trace_events()
+                if e["ph"] == "X"] == [{"round": 3}, {"ok": True}, None]
+
+    def test_the_resident_set_costs_nothing_where_nobody_asks(self):
+        """The disabled path is the one shared no-op, asked or not, and
+        a recorded span that does not ask is the plain class (two clock
+        reads and an append)."""
+        from fedtorch_tpu.telemetry import spans as spans_mod
+        assert telemetry.NULL_SPAN.rss() is telemetry.NULL_SPAN
+        assert telemetry.span("eval").rss() is telemetry.NULL_SPAN
+        rec = SpanRecorder()
+        assert type(rec.span("round")) is spans_mod._Span
+        assert type(rec.span("round").rss()) is spans_mod._RssSpan
+
     def test_buffer_bound_counts_drops(self):
         rec = SpanRecorder(max_events=2)
         for _ in range(5):
@@ -291,6 +347,63 @@ class TestSpanRecorder:
     def test_bad_level_rejected(self, tmp_path):
         with pytest.raises(ValueError, match="level"):
             Telemetry(str(tmp_path), level="verbose")
+
+
+# -- the evaluation's spans (library code: the module-level hook) -----------
+class TestEvaluateSpans:
+    @staticmethod
+    def _case():
+        from fedtorch_tpu.models import define_model
+        rng = np.random.default_rng(0)
+        cfg = ExperimentConfig(
+            data=DataConfig(dataset="synthetic", synthetic_dim=10,
+                            batch_size=8),
+            model=ModelConfig(arch="logistic_regression")).finalize()
+        model = define_model(cfg, batch_size=8)
+        params = model.init(jax.random.key(0))
+        x = rng.normal(size=(300, 10)).astype(np.float32)
+        y = rng.integers(0, 2, size=300).astype(np.int32)
+        return model, params, x, y
+
+    def test_four_spans_with_a_run_installed(self, tmp_path):
+        from fedtorch_tpu.parallel.evaluate import evaluate_to_host
+        model, params, x, y = self._case()
+        with Telemetry(str(tmp_path)) as tel:
+            with tel.span("eval", round=0).rss():
+                res = evaluate_to_host(model, params, x, y)
+            spans = [e for e in tel.spans.to_trace_events()
+                     if e["ph"] == "X"]
+        assert isinstance(res.top1, np.ndarray)     # on the host
+        assert [e["name"] for e in spans] == [
+            "eval.batches", "eval.h2d", "eval.dispatch", "eval.fetch",
+            "eval"]
+        batches, h2d, dispatch, fetch, whole = spans
+        # 300 rows padded to two batches of 256: float32 features,
+        # int32 labels, float64 mask
+        want = 512 * (10 * 4 + 4 + 8)
+        assert batches["args"] == {"bytes": want, "rows": 300,
+                                   "pad_rows": 212}
+        assert h2d["args"] == {"bytes": want}
+        assert "args" not in dispatch and "args" not in fetch
+        edges = [t for e in spans[:4] for t in (e["ts"], e["ts"] + e["dur"])]
+        assert edges == sorted(edges)               # one after the other
+        assert whole["ts"] <= edges[0] \
+            and edges[-1] <= whole["ts"] + whole["dur"]
+        assert whole["args"]["round"] == 0
+        assert set(whole["args"]) <= {"round", "vm_rss_enter",
+                                      "vm_rss_exit"}
+
+    def test_no_run_installed_records_nothing_and_agrees(self, tmp_path):
+        from fedtorch_tpu.parallel.evaluate import (
+            evaluate, evaluate_to_host,
+        )
+        model, params, x, y = self._case()
+        assert telemetry.get_active() is None
+        bare = evaluate_to_host(model, params, x, y)
+        with Telemetry(str(tmp_path)):
+            spanned = jax.device_get(evaluate(model, params, x, y))
+        for a, b in zip(bare, spanned):
+            np.testing.assert_array_equal(a, b)
 
 
 # -- health.json -------------------------------------------------------------
@@ -591,6 +704,41 @@ class TestRunDirAndReport:
             assert spans[parent][0] <= spans[child][0] \
                 and spans[child][1] <= spans[parent][1], (child, parent)
         assert "state.init" in spans
+
+    def test_the_loops_eval_and_checkpoint_carry_the_resident_set(
+            self, tmp_path):
+        """A launcher run: the loop's ``eval`` and ``checkpoint`` carry
+        ``vm_rss_enter`` and ``vm_rss_exit``, the evaluation's four
+        children lie inside ``eval`` and the save's tree inside
+        ``checkpoint``; the spans that do not ask carry none."""
+        from fedtorch_tpu.cli import run_experiment
+        run_dir = str(tmp_path / "run")
+        run_experiment(_cli_cfg(run_dir, rounds=2))
+        doc = json.load(open(os.path.join(run_dir, "trace.json")))
+        spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+        named = lambda n: [e for e in spans if e["name"] == n]
+        for name in ("round", "round.dispatch", "round.wait",
+                     "scalar_fetch", "round.record"):
+            # one a round (two for ``round.record``)
+            assert len(named(name)) == (4 if name == "round.record" else 2)
+            assert all(set(e["args"]) == {"round"} for e in named(name))
+        inside = lambda c, p: p["ts"] <= c["ts"] and \
+            c["ts"] + c["dur"] <= p["ts"] + p["dur"]
+        for parent, children in (
+                ("eval", ("eval.batches", "eval.h2d", "eval.dispatch",
+                          "eval.fetch")),
+                ("checkpoint", (
+                    "checkpoint.snapshot", "checkpoint.layout",
+                    "checkpoint.digest", "checkpoint.file_write.data",
+                    "checkpoint.file_write.fsync",
+                    "checkpoint.file_write.rename", "checkpoint.link"))):
+            (whole,) = named(parent)        # eval_freq 2: after round 1
+            vm = {"vm_rss_enter", "vm_rss_exit"} \
+                if os.path.exists("/proc/self/status") else set()
+            assert set(whole["args"]) == {"round"} | vm
+            for child in children:
+                assert named(child), child
+                assert all(inside(c, whole) for c in named(child)), child
 
     def test_compile_listeners_leave_with_the_telemetry(self, tmp_path):
         from jax._src import monitoring
