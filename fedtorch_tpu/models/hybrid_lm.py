@@ -44,8 +44,23 @@ layer's gradient is a sum over the passes, which the scan over passes
 accumulates). Products take bfloat16 operands where the launcher's
 ``compute_dtype`` says so and accumulate in float32; the residual
 stream, norms, rotary angles, softmax, decays, the convolution, the
-exit gate and the loss are float32. With ``remat`` each layer runs
-under ``jax.checkpoint``.
+exit gate and the loss are float32.
+
+With ``remat`` each layer runs under ``jax.checkpoint``, which keeps
+the layer's input and, of the layer's matrix products (each result
+carries a name: ``mixer.q`` ... ``mlp.down``, :func:`layer_products`),
+the float32 results that fit in the device's memory; the backward pass
+runs the rest again: norms, activations, gates, the convolution, the
+rotary turn, softmax and the delta rule. A product kept and a product
+run again give the same bits, so the set changes the time and the
+memory and never the gradients. :func:`kept_products` chooses the set
+from shapes alone (the products with the longest inner dimension
+first: a ``[T, K] x [K, N]`` result spares ``K / 2`` FLOPs a byte
+kept) within :func:`residual_budget`, what the device has left when
+the step is first traced; one decision a shape and process
+(:func:`_kept_for`), all of them where the backend reports no memory
+(the CPU). The heads of a looped model (:func:`exit_stats`) and the
+delta rule's scan keep their own checkpoints, which keep nothing.
 
 Scopes for the device trace: ``lm.delta_rule``, ``lm.attention``,
 ``lm.mlp``, ``lm.head``; in a looped model also ``lm.loop`` (the scan
@@ -62,6 +77,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from fedtorch_tpu.ops.attention_dispatch import resolve_attention
 from fedtorch_tpu.ops.delta_rule import chunk_gated_delta_rule
@@ -70,6 +86,15 @@ LAYER_KINDS = ("linear_attention", "full_attention")
 INIT_STD = 0.02
 # ``model_type`` values whose block differs from the Olmo placement
 SANDWICH_BLOCKS = ("ouro",)
+# of a rematerialized step's memory, how many times the widest layer's
+# product results and the head's logits are set aside for the one
+# layer and head at work (:func:`residual_budget`)
+WORKING_SETS = 5
+# the products that close a sublayer: their float32 results feed a norm,
+# so the forward pass writes them whether they are kept or not (an input
+# product's result feeds an epilogue the product fuses): first among
+# products of equal inner dimension (:func:`kept_products`)
+SUBLAYER_OUTPUTS = ("mixer.o", "mlp.down")
 # the file's keys: asked of every file, and of one with a
 # ``linear_attention`` layer
 PLAIN_KEYS = ("vocab_size", "hidden_size", "intermediate_size",
@@ -259,10 +284,13 @@ def _causal_conv(x, w):
     return sum(pad[:, i:i + T] * w[:, i] for i in range(taps))
 
 
-def _dot(x, w, dt):
-    """``x @ w``: operands in the compute dtype, float32 out."""
-    return jnp.matmul(x.astype(dt), w.astype(dt),
-                      preferred_element_type=jnp.float32)
+def _dot(x, w, dt, name: Optional[str] = None):
+    """``x @ w``: operands in the compute dtype, float32 out. ``name``
+    is the result's name for a rematerialized layer's policy
+    (:func:`kept_products`); it changes nothing of the product."""
+    out = jnp.matmul(x.astype(dt), w.astype(dt),
+                     preferred_element_type=jnp.float32)
+    return out if name is None else checkpoint_name(out, name)
 
 
 def rotary_tables(positions, head_dim: int, theta: float):
@@ -288,23 +316,24 @@ def _linear_attention(p, x, s: HybridSpec, dt):
     B, T, _ = x.shape
     h, dk, dv = (s.linear_num_key_heads, s.linear_key_head_dim,
                  s.linear_value_head_dim)
-    qkv = jnp.concatenate([_dot(x, p[n], dt) for n in ("wq", "wk", "wv")],
-                          axis=-1)
+    qkv = jnp.concatenate([_dot(x, p["w" + n], dt, "mixer." + n)
+                           for n in "qkv"], axis=-1)
     qkv = jax.nn.silu(_causal_conv(qkv, p["conv"]))
     q, k, v = jnp.split(qkv, [h * dk, 2 * h * dk], axis=-1)
     q = _l2_normalize(q.reshape(B, T, h, dk)) / math.sqrt(dk)
     k = _l2_normalize(k.reshape(B, T, h, dk))
     v = v.reshape(B, T, h, dv)
     # the factor 2 is the negative eigenvalue the config allows
-    beta = 2.0 * jax.nn.sigmoid(_dot(x, p["wb"], dt))
+    beta = 2.0 * jax.nn.sigmoid(_dot(x, p["wb"], dt, "mixer.b"))
     g = -jnp.exp(p["a_log"]) * jax.nn.softplus(
-        _dot(x, p["wa"], dt) + p["dt_bias"])
+        _dot(x, p["wa"], dt, "mixer.a") + p["dt_bias"])
     with jax.named_scope("lm.delta_rule"):
         o = chunk_gated_delta_rule(q.astype(dt), k.astype(dt),
                                    v.astype(dt), g, beta)
     o = _rms_norm(o, p["o_norm"], s.rms_norm_eps)
-    gate = jax.nn.silu(_dot(x, p["wg"], dt)).reshape(B, T, h, dv)
-    return _dot((o * gate).reshape(B, T, h * dv), p["wo"], dt)
+    gate = jax.nn.silu(_dot(x, p["wg"], dt, "mixer.g")).reshape(
+        B, T, h, dv)
+    return _dot((o * gate).reshape(B, T, h * dv), p["wo"], dt, "mixer.o")
 
 
 def _full_attention(p, x, s: HybridSpec, dt, attention: str, rope=None):
@@ -313,7 +342,7 @@ def _full_attention(p, x, s: HybridSpec, dt, attention: str, rope=None):
     hd = d // h
 
     def project(name, norm):
-        t = _dot(x, p[name], dt)
+        t = _dot(x, p[name], dt, "mixer." + name[1:])
         # lint: disable=FTL005 — the block is a flag of the spec
         if norm and not s.sandwich:
             t = _rms_norm(t, p[norm], s.rms_norm_eps)
@@ -344,13 +373,14 @@ def _full_attention(p, x, s: HybridSpec, dt, attention: str, rope=None):
                 jnp.where(mask[None, None], scores, -jnp.inf), axis=-1)
             out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(dt), v,
                              preferred_element_type=jnp.float32)
-    return _dot(out.reshape(B, T, d), p["wo"], dt)
+    return _dot(out.reshape(B, T, d), p["wo"], dt, "mixer.o")
 
 
 def _mlp(p, x, dt):
     with jax.named_scope("lm.mlp"):
-        return _dot(jax.nn.silu(_dot(x, p["gate"], dt))
-                    * _dot(x, p["up"], dt), p["down"], dt)
+        return _dot(jax.nn.silu(_dot(x, p["gate"], dt, "mlp.gate"))
+                    * _dot(x, p["up"], dt, "mlp.up"), p["down"], dt,
+                    "mlp.down")
 
 
 def _layer(p, x, kind: str, s: HybridSpec, dt, attention: str, rope=None):
@@ -386,14 +416,150 @@ def _rope(s: HybridSpec, T: int):
                          s.rope_theta)
 
 
+# -- what a rematerialized layer keeps ---------------------------------------
+
+def layer_products(s: HybridSpec, kind: str) -> dict:
+    """``{name: (K, N)}`` of a layer's matrix products ``[T, K] x
+    [K, N]``, in the layer's own order: the names their results carry
+    (``_dot``'s ``name``)."""
+    d, f = s.hidden_size, s.intermediate_size
+    # lint: disable=FTL005 — the layer's kind is a string of the spec
+    if kind == "linear_attention":
+        shapes = _linear_shapes(s)
+        mixer = {"mixer." + n: shapes["w" + n] for n in "qkvbago"}
+    else:
+        mixer = {"mixer." + n: (d, d) for n in "qkvo"}
+    return dict(mixer, **{"mlp.gate": (d, f), "mlp.up": (d, f),
+                          "mlp.down": (f, d)})
+
+
+def _product_totals(s: HybridSpec) -> dict:
+    """``{name: (FLOPs, float32 result bytes)}`` a token and pass, summed
+    over the layers that have a product of that name."""
+    totals: dict = {}
+    for kind in s.layer_types:
+        for name, (k, n) in layer_products(s, kind).items():
+            flops, size = totals.get(name, (0, 0))
+            totals[name] = (flops + 2 * k * n, size + 4 * n)
+    return totals
+
+
+def kept_products(s: HybridSpec, rows: int, tokens: int,
+                  budget: Optional[int]) -> Tuple[str, ...]:
+    """The names of the products whose float32 results the
+    rematerialized layers of a ``rows x tokens`` step keep, within
+    ``budget`` bytes (None: no limit known, all of them). A name stands
+    for that product in every layer call of the step (layers x
+    ``total_ut_steps``: the residuals of all of them are alive when
+    the backward pass begins). In order of FLOPs spared a byte kept,
+    which for ``[T, K] x [K, N]`` is ``K / 2``: the longest inner
+    dimension first; of equal ones a sublayer's output before its
+    inputs (``SUBLAYER_OUTPUTS``), then in the layer's own order; each
+    taken if what is left of the budget holds it. From shapes alone:
+    the same answer for the same arguments."""
+    totals = _product_totals(s)
+    order = sorted(totals, key=lambda n: (
+        -totals[n][0] / totals[n][1], n not in SUBLAYER_OUTPUTS))
+    if budget is None:
+        return tuple(order)
+    calls = rows * tokens * s.total_ut_steps
+    kept = []
+    for name in order:
+        size = totals[name][1] * calls
+        # lint: disable=FTL005 — host integers from shapes
+        if size <= budget:
+            kept.append(name)
+            budget -= size
+    return tuple(kept)
+
+
+def kept_counters(s: HybridSpec, tokens: int, kept) -> dict:
+    """The row's two counters of how far the policy engaged:
+    ``lm_kept_product_share``, the share of the stack's forward product
+    FLOPs whose results are kept, and ``lm_kept_residual_bytes``, what
+    they hold a sequence of ``tokens``."""
+    totals = _product_totals(s)
+    flops, size = (sum(totals[n][i] for n in kept) for i in (0, 1))
+    return {"lm_kept_product_share":
+            flops / sum(t[0] for t in totals.values()),
+            "lm_kept_residual_bytes":
+            float(size * tokens * s.total_ut_steps)}
+
+
+def residual_budget(s: HybridSpec, rows: int, tokens: int,
+                    stats: Optional[dict]) -> Optional[int]:
+    """Bytes a ``rows x tokens`` training step can give to kept
+    products, from a device's ``memory_stats()`` as the step is traced
+    (None, as the CPU gives: no limit known). ``bytes_limit`` less
+
+    * ``bytes_in_use``: what the round holds before it starts (the
+      server's tree, the clients' store);
+    * two more parameter trees (the running client and the cohort's
+      running sum) and, in a looped model, six bytes a looped
+      parameter (the float32 accumulator of its gradient over the
+      passes and the bfloat16 copy the scan over passes closes over),
+      from the specification's own shapes;
+    * the reserve: every layer call's kept input, and ``WORKING_SETS``
+      times the widest layer's product results and the head's logits,
+      for the one layer and the one head whose forward and backward
+      pass are alive at a time.
+
+    Read against the chip's allocator (``peak_bytes_reserved``, v5e,
+    PERF.md section 6, PR 38): beside the sets this budget admits 1.1
+    to 2.1 GiB of the device stay free; sets a gibibyte over it failed
+    at compile by 150 MiB or ran a twentieth slower than none."""
+    # lint: disable=FTL005 — the allocator's dict, read on the host
+    if not stats or "bytes_limit" not in stats:
+        return None
+    count = lambda tree: sum(math.prod(shape) for shape in jax.tree.leaves(
+        tree, is_leaf=lambda x: isinstance(x, tuple)))
+    shapes = param_shapes(s)
+    held = 2 * 4 * count(shapes)
+    # lint: disable=FTL005 — a looped model or not, by the spec
+    if s.looped:
+        held += 6 * count([v for k, v in shapes.items()
+                           if k.startswith("layer_")])
+    widest = max(sum(n for _, n in layer_products(s, kind).values())
+                 for kind in set(s.layer_types))
+    reserve = 4 * rows * tokens * (
+        s.hidden_size * len(s.layer_types) * s.total_ut_steps
+        + WORKING_SETS * (widest + s.vocab_size))
+    return max(0, stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+               - held - reserve)
+
+
+@functools.lru_cache(maxsize=None)
+def _kept_for(s: HybridSpec, rows: int, tokens: int) -> Tuple[str, ...]:
+    """:func:`kept_products` within what the fullest local device has
+    left now. One decision a shape and process: every later trace of
+    the step (the cost capture's twin, a retrace) builds the program
+    the first one built, and the row's counters say what it holds.
+    Several processes would each decide from their own devices and
+    must build one program: they keep nothing."""
+    # lint: disable=FTL005 — the process count is a host integer
+    if jax.process_count() > 1:
+        return ()
+    budgets = [residual_budget(s, rows, tokens, d.memory_stats())
+               for d in jax.local_devices()]
+    # lint: disable=FTL005 — host integers or None
+    budget = None if None in budgets else min(budgets)
+    return kept_products(s, rows, tokens, budget)
+
+
 def _stack(params, h, s: HybridSpec, dt, attention: str, remat: bool,
            rope):
     """One pass over the layers: ``[B, T, D] -> [B, T, D]``."""
+    # lint: disable=FTL005 — remat is a static flag of the launcher
+    kept = _kept_for(s, h.shape[0], h.shape[1]) if remat else ()
+    only = jax.checkpoint_policies.save_only_these_names
+    # lint: disable=FTL005 — names or none: then the bare checkpoint
+    policy = only(*kept) if kept else None
     for i, kind in enumerate(s.layer_types):
         fn = lambda p, h, kind=kind: _layer(p, h, kind, s, dt, attention,
                                             rope)
         # lint: disable=FTL005 — remat is a static flag of the launcher
-        h = (jax.checkpoint(fn) if remat else fn)(params[f"layer_{i}"], h)
+        h = (jax.checkpoint(fn, policy=policy) if remat else fn)(
+            params[f"layer_{i}"], h)
     return h
 
 
@@ -513,6 +679,14 @@ class HybridLM(NamedTuple):
 
     def init(self, rng):
         return _jitted_init(self.module)(rng)
+
+    def kept_gauges(self, rows: int, tokens: int) -> dict:
+        """:func:`kept_counters` of a ``rows x tokens`` training step
+        as it was (or will be) traced; nothing without ``remat``."""
+        if not self.remat:
+            return {}
+        return kept_counters(self.module, tokens,
+                             _kept_for(self.module, rows, tokens))
 
     def _states(self, params, x):
         dt = jnp.dtype(self.dtype)

@@ -398,10 +398,10 @@ class FederatedTrainer:
         self.augment = bool(cfg.data.augment) and data.x.ndim == 5
         # a token model trains on rows of token ids ([C, n, T] store):
         # what a round trains, for the row's ``tokens_trained`` counter
-        self.tokens_per_round = (
-            self.k_online * self.local_steps
-            * self.batch_size * int(data.x.shape[2])
-            if is_token_model(model) and data.x.ndim == 3 else 0)
+        self.row_tokens = int(data.x.shape[2]) \
+            if is_token_model(model) and data.x.ndim == 3 else 0
+        self.tokens_per_round = self.k_online * self.local_steps \
+            * self.batch_size * self.row_tokens
         # passes a token makes through a looped model's layers, for
         # the row's ``ut_steps`` counter (1: not looped, no counter)
         self.ut_steps = int(getattr(model, "ut_steps", 1)) \
@@ -1831,6 +1831,13 @@ class FederatedTrainer:
             out["tokens_trained"] = float(self.tokens_per_round)
         if self.ut_steps > 1:
             out["ut_steps"] = float(self.ut_steps)
+        kept = self.model.kept_gauges(self.batch_size, self.row_tokens) \
+            if self.row_tokens and hasattr(self.model, "kept_gauges") \
+            else None
+        if kept:
+            # what the model's rematerialized layers keep of a step
+            out["lm_kept_product_share"] = kept["lm_kept_product_share"]
+            out["lm_kept_residual_bytes"] = kept["lm_kept_residual_bytes"]
         ss = self.stream_stats()
         if ss is not None:
             out.update(ss)

@@ -79,6 +79,17 @@ METRICS_OPTIONAL = {
                       "steps x B rows x the rows' length)",
     "ut_steps": "passes a token makes through a looped model's layers "
                 "(total_ut_steps of the model's file; absent at 1)",
+    "lm_kept_product_share": "token model under remat: share of the "
+                             "layers' forward matrix-product FLOPs "
+                             "whose float32 results the checkpoints "
+                             "keep, so that the backward pass does "
+                             "not run them again (0 to 1; chosen once "
+                             "a shape from the device's free memory, "
+                             "longest inner dimension first: "
+                             "models/hybrid_lm.py kept_products)",
+    "lm_kept_residual_bytes": "token model under remat: bytes those "
+                              "kept results hold a sequence (all "
+                              "layers, all passes)",
     "lm_exit_mass_last": "looped token model: mean mass the exit "
                          "distribution puts on the last pass",
     "lm_exit_entropy": "looped token model: mean entropy of the exit "

@@ -10,6 +10,7 @@ is the one it was."""
 import hashlib
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -389,6 +390,11 @@ def test_launcher_runs_saves_and_resumes(files, tmp_path):
                for r in rows)
     assert all(np.isfinite(r["loss"]) and 0 < r["lm_exit_mass_last"] < 1
                and 0 < r["lm_exit_entropy"] < np.log(3.0) for r in rows)
+    # what the rematerialized layers keep (all, on this backend): two
+    # layers' seven products a token, three passes, 24 tokens, float32
+    assert all(r["lm_kept_product_share"] == 1.0
+               and r["lm_kept_residual_bytes"]
+               == 2 * (5 * 32 + 2 * 48) * 3 * 24 * 4 for r in rows)
     again = run_experiment(lm_cfg(files, "sequential", run_dir=run_dir,
                                   num_comms=3, resume=run_dir))
     assert [r["round"] for r in round_rows(run_dir)] == [0, 1, 2]
@@ -397,19 +403,33 @@ def test_launcher_runs_saves_and_resumes(files, tmp_path):
 
 # -- the single-pass model's program --------------------------------------------
 
-OLMO_ROUND_SHA256 = \
-    "e54e9767a690fcbbe1037b2c3936b9ca84a545151e237032699669eeace23f57"
+# the round's digest (of the text with the counters off its private
+# functions' names, which one more traced equation shifts) with nothing
+# kept: PR 36's program, whose raw text read ``e54e9767...eace23f57``;
+# and with what the chooser keeps where the backend reports no memory
+# (all)
+OLMO_ROUND_SHA256 = {
+    "nothing_kept":
+    "b7202d2bbc1d5918a96b9c7ad491495b1c29c817aaddaff38250d8447a202281",
+    "chosen":
+    "ddbf75df2b943c92d50e73597c419a534ba6d228312fee34b790d41581a341b5",
+}
 
 
-def test_the_olmo_cells_lowered_round_is_unchanged(tmp_path):
+@pytest.mark.parametrize("kept", ["nothing_kept", "chosen"])
+def test_the_olmo_cells_lowered_round_is_unchanged(tmp_path, monkeypatch,
+                                                   kept):
     """``olmo_hybrid_7b_l4.fedavg_k2_e10``'s round program at the
     cell's own flags and widths (nothing allocated: abstract state, a
-    store of 4 rows a client), lowered on the CPU: the text's digest
-    as PR 36 left it (which moved it on purpose: the delta rule's
-    triangular inverse; PR 35 had left its parent's,
-    ``0d16f9ff...da836ce``, in place). A change to the shared model
-    file that moves one operation of the single-pass path moves
-    this."""
+    store of 4 rows a client), lowered on the CPU: the text's digest.
+    With no product kept it is the digest PR 36 left (which moved it
+    on purpose: the delta rule's triangular inverse; PR 35 had left
+    its parent's, ``0d16f9ff...da836ce``, in place): naming the
+    products' results and handing the checkpoints a policy that keeps
+    none moves no operation. PR 38 moved the chosen program's on
+    purpose: its layers' checkpoints keep every named product here. A
+    change to the shared model file that moves one operation of the
+    single-pass path moves both."""
     from benchmark.harness import runner
     from fedtorch_tpu.algorithms import make_algorithm
     from fedtorch_tpu.cli import args_to_config, build_parser
@@ -417,6 +437,8 @@ def test_the_olmo_cells_lowered_round_is_unchanged(tmp_path):
     from fedtorch_tpu.models import define_model
     from fedtorch_tpu.parallel import FederatedTrainer
 
+    if kept == "nothing_kept":
+        monkeypatch.setattr(hybrid_lm, "_kept_for", lambda s, r, t: ())
     cell = runner.load_cell("olmo_hybrid_7b_l4.fedavg_k2_e10")
     sizes = dict(cell["config_file"]["datagen"], rows_per_client=4,
                  test_rows=1)
@@ -429,5 +451,6 @@ def test_the_olmo_cells_lowered_round_is_unchanged(tmp_path):
     server, clients = jax.eval_shape(t.init_state, jax.random.key(0))
     text = jax.jit(t.round_fn, donate_argnums=(0, 1)).lower(
         server, clients, t.data, None).as_text()
+    text = re.sub(r"@([A-Za-z_][\w.]*?)_\d+\b", r"@\1", text)
     digest = hashlib.sha256(text.encode()).hexdigest()
-    assert digest == OLMO_ROUND_SHA256, digest
+    assert digest == OLMO_ROUND_SHA256[kept], digest

@@ -93,7 +93,11 @@ def test_sequential_round_equals_the_vmapped_round(files, algorithm):
     np.testing.assert_allclose(lv, ls, rtol=1e-5)
     for a, b in zip(jax.tree.leaves(pv), jax.tree.leaves(ps)):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-7)
-    assert ts.telemetry_gauges()["tokens_trained"] == 3 * 2 * 1 * 24
+    gauges = ts.telemetry_gauges()
+    assert gauges["tokens_trained"] == 3 * 2 * 1 * 24
+    # remat on and no memory limit known here: every product is kept
+    assert gauges["lm_kept_product_share"] == 1.0
+    assert gauges["lm_kept_residual_bytes"] > 0
 
 
 def test_state_holds_no_parameter_sized_leaf_per_client(files):
