@@ -1254,6 +1254,14 @@ def run_experiment(cfg: ExperimentConfig,
                     # a looped token model's exit gauges — same fetch
                     row["lm_exit_mass_last"] = sc["lm_exit_mass_last"]
                     row["lm_exit_entropy"] = sc["lm_exit_entropy"]
+                if "lm_index_loss" in sc:
+                    # a selected token model's indexer term — same fetch
+                    row["lm_index_loss"] = sc["lm_index_loss"]
+                if "lm_moe_pairs_local" in sc:
+                    # a sparse-expert token model's routing gauges
+                    row["lm_moe_pairs_local"] = sc["lm_moe_pairs_local"]
+                    row["lm_moe_load_max_over_mean"] = \
+                        sc["lm_moe_load_max_over_mean"]
                 if accountant is not None:
                     # host-side accountant read: pure f64 math, no sync
                     row["dp_epsilon_spent"] = accountant.epsilon()

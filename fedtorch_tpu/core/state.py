@@ -109,6 +109,13 @@ class RoundMetrics(NamedTuple):
     # leaves, the round program as it was.
     lm_exit_mass_last: Any = None  # scalar — exit mass on the last pass
     lm_exit_entropy: Any = None    # scalar — entropy of the exit law
+    # a sparse token model's gauges (ops/routed_experts.py,
+    # ops/sparse_attention.py), means over the round's clients, steps
+    # and layers, from the sequential execution; None for every other
+    # model
+    lm_moe_pairs_local: Any = None         # scalar — pairs computed here
+    lm_moe_load_max_over_mean: Any = None  # scalar — fullest held expert
+    lm_index_loss: Any = None              # scalar — the indexers' L_I
 
 
 def tree_where(pred, on_true, on_false):

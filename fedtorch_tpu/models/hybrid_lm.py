@@ -1,27 +1,38 @@
 """A causal language model whose shape is read from a file: the keys
 of a public ``config.json`` (``hidden_size``, ``intermediate_size``,
 ``num_hidden_layers``, ``layer_types``, ``num_attention_heads``,
-``vocab_size``, ``rms_norm_eps``; ``linear_*`` where a layer is a
-``linear_attention`` one), as the Olmo-Hybrid and the Ouro families
-state them. ``layer_types`` names each layer's mixer:
+``num_key_value_heads``, ``head_dim``, ``vocab_size``,
+``rms_norm_eps``; ``linear_*`` where a layer is a ``linear_attention``
+one; ``num_experts`` ... and ``sa_config`` as below), as the
+Olmo-Hybrid, the Ouro and the Keye-VL-2.0 (Qwen3-MoE) families state
+them. ``layer_types`` names each layer's mixer (absent: every layer a
+``full_attention`` one):
 
 * ``linear_attention`` — the gated delta rule (``ops/delta_rule.py``)
   behind a causal depthwise convolution, with an output gate;
 * ``full_attention`` — causal softmax attention through
-  ``ops/attention_dispatch.py`` (flash from 4096 tokens on).
+  ``ops/attention_dispatch.py`` (flash from 4096 tokens on), of
+  ``num_attention_heads`` query heads of ``head_dim`` (default
+  ``hidden_size / num_attention_heads``; the heads together need not be
+  ``hidden_size`` wide) on ``num_key_value_heads`` key and value heads
+  (default: as many; key head ``j`` serves query heads ``j H/KV ..``).
 
-Every layer ends in a SwiGLU MLP, the head is untied, nothing has a
-bias. Three optional keys choose the rest:
+Every layer ends in a SwiGLU MLP or in an expert layer, the head is
+untied, nothing has a bias. Optional keys choose the rest:
 
 * ``model_type`` — the family's block. ``ouro``: sandwich norms (an
   RMSNorm on each sublayer's input and one on its output, four scales
-  a layer) and no QK-norm; anything else: norms on each sublayer's
-  OUTPUT alone and an RMSNorm over the whole projected q and k (the
-  Olmo 2/3 placement);
+  a layer) and no QK-norm; ``KeyeVL2``: pre-norm (a sublayer reads the
+  normed stream and adds to the bare one, two scales a layer) and an
+  RMSNorm over each HEAD of q and k; anything else: norms on each
+  sublayer's OUTPUT alone and an RMSNorm over the whole projected q
+  and k (the Olmo 2/3 placement);
 * ``rope_theta`` (top level, or under ``rope_parameters``) — rotary
   position embedding on q and k of the full-attention layers
   (rotate-half, positions ``0..T-1``, angles in float32); absent or
-  null: none;
+  null: none. ``rope_scaling`` may hold the default embedding's
+  ``mrope_section`` record (for text its three position streams are
+  the same, so the sections fall together) and nothing else;
 * ``total_ut_steps`` (default 1) — the stack of layers, closed by the
   final norm, runs that many times with the same parameters, each
   pass fed the one before. After every pass come the head and an exit
@@ -31,11 +42,47 @@ bias. Three optional keys choose the rest:
   Models", stage I) with the weight ``exit_entropy_beta`` (default
   0.05) on the exit distribution's entropy: :func:`exit_objective`.
   Evaluation reads the last pass. More than one pass is refused for a
-  ``model_type`` whose looped form is not written here.
+  ``model_type`` whose looped form is not written here;
+* ``num_experts`` with ``num_experts_per_tok``,
+  ``moe_intermediate_size``, ``norm_topk_prob`` (pre-norm block only)
+  — every layer's feed-forward is ONE CHIP'S SHARE of a sparse-expert
+  layer (``ops/routed_experts.py``): the router scores
+  ``published.num_experts`` experts (the file's own count where nothing
+  was cut), a token goes to its ``num_experts_per_tok`` best, and the
+  ``num_experts`` experts held here, ``first_expert_held`` (default 0)
+  onwards, give their part of the result; dropless (the grouped
+  products visit the pairs routed here, so a step's seconds follow the
+  routing). A layer's experts are one stacked leaf a matrix.
+  ``decoder_sparse_step`` other than 1 and a non-empty
+  ``mlp_only_layers`` (dense layers among them) are refused;
+* ``sa_config`` (pre-norm block only: ``indexer_num_heads``,
+  ``indexer_head_dim``, ``topk``, ``q_chunk_size``;
+  ``indexer_num_kv_heads`` 1) — every full-attention layer reads a
+  learned selection of ``topk`` keys a query
+  (``ops/sparse_attention.py``, DeepSeek-V3.2-Exp's lightning indexer),
+  in query chunks of ``q_chunk_size`` (which ``topk`` is a multiple
+  of); rows no longer than ``topk`` run the same code and select every
+  key. The training loss is then ``CE + L_I``, ``L_I`` the indexers' KL
+  term summed over the layers, which trains the indexers' three
+  matrices a layer and nothing else; evaluation reads ``CE``;
+* ``embedding_init_std`` (default 0.02, as every other matrix) — the
+  seeded embedding rows' standard deviation. At 0.02 the first
+  attention layer's output (an average of values over the context,
+  some 0.15 a component) is eight times the token's own row, so every
+  token of a row carries nearly the same stream; a router then sends
+  them all to the same ``num_experts_per_tok`` experts, and how many
+  of those a chip holds is the draw of the seed. At 1
+  (``torch.nn.Embedding``'s own default) a token's row leads its
+  stream and the seeded router spreads the tokens as a trained one
+  does.
 
-``benchmark/reference/olmo_hybrid.py`` and ``benchmark/reference/ouro.py``
-write the same equations out in plain float32 and list what the public
-configs leave open.
+Still refused by name: tied embeddings, attention biases, any other
+``rope_scaling``, an odd ``head_dim``, query heads that are no multiple
+of the key heads, grouped VALUE heads in a linear-attention layer.
+
+``benchmark/reference/olmo_hybrid.py``, ``benchmark/reference/ouro.py``
+and ``benchmark/reference/keye_vl2.py`` write the same equations out in
+plain float32 and list what the public configs leave open.
 
 Pure functions over a nested dict of float32 parameters; every layer
 is a subtree of its own (``layer_<i>``: no stacked scan over layers, so
@@ -44,7 +91,8 @@ layer's gradient is a sum over the passes, which the scan over passes
 accumulates). Products take bfloat16 operands where the launcher's
 ``compute_dtype`` says so and accumulate in float32; the residual
 stream, norms, rotary angles, softmax, decays, the convolution, the
-exit gate and the loss are float32.
+exit gate, router probabilities and gates, the indexer's weighted sum
+and the loss are float32.
 
 With ``remat`` each layer runs under ``jax.checkpoint``, which keeps
 the layer's input and, of the layer's matrix products (each result
@@ -60,13 +108,21 @@ kept) within :func:`residual_budget`, what the device has left when
 the step is first traced; one decision a shape and process
 (:func:`_kept_for`), all of them where the backend reports no memory
 (the CPU). The heads of a looped model (:func:`exit_stats`) and the
-delta rule's scan keep their own checkpoints, which keep nothing.
+delta rule's scan keep their own checkpoints, which keep nothing; a
+selected layer also keeps its attention's output, whatever the budget
+(``sparse_attention.KEPT[1]``).
 
 Scopes for the device trace: ``lm.delta_rule``, ``lm.attention``,
 ``lm.mlp``, ``lm.head``; in a looped model also ``lm.loop`` (the scan
 over passes: what it holds outside ``lm.attention`` and ``lm.mlp`` are
 the projections, norms and rotary embedding) and ``lm.exit`` (the
-heads, per-exit losses, gate and mixture; ``lm.head`` inside it).
+heads, per-exit losses, gate and mixture; ``lm.head`` inside it);
+under ``sa_config`` ``lm.indexer`` (the indexer's three products, its
+scores, the selection and the KL term) beside ``lm.attention`` (the
+masked attention of the chunks and the heads' summed probabilities);
+in an expert layer ``lm.router`` (the router's product, softmax and
+top-k, the sort and the buffer's fill) and ``lm.experts`` (the grouped
+products and the combine) in ``lm.mlp``'s place.
 """
 from __future__ import annotations
 
@@ -79,6 +135,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from fedtorch_tpu.ops import routed_experts, sparse_attention
 from fedtorch_tpu.ops.attention_dispatch import resolve_attention
 from fedtorch_tpu.ops.delta_rule import chunk_gated_delta_rule
 
@@ -86,6 +143,10 @@ LAYER_KINDS = ("linear_attention", "full_attention")
 INIT_STD = 0.02
 # ``model_type`` values whose block differs from the Olmo placement
 SANDWICH_BLOCKS = ("ouro",)
+# ``model_type`` values whose block is pre-norm (a sublayer reads the
+# normed stream and adds to the bare one) with an RMSNorm over each
+# head of q and k
+PRENORM_BLOCKS = ("KeyeVL2",)
 # of a rematerialized step's memory, how many times the widest layer's
 # product results and the head's logits are set aside for the one
 # layer and head at work (:func:`residual_budget`)
@@ -99,9 +160,38 @@ SUBLAYER_OUTPUTS = ("mixer.o", "mlp.down")
 # ``linear_attention`` layer
 PLAIN_KEYS = ("vocab_size", "hidden_size", "intermediate_size",
               "layer_types", "num_attention_heads", "rms_norm_eps")
+# ... of one with ``num_experts``, and of its ``sa_config``
+EXPERT_KEYS = ("num_experts_per_tok", "moe_intermediate_size")
+SELECTION_KEYS = ("indexer_num_heads", "indexer_head_dim", "topk",
+                  "q_chunk_size")
+# the keys a ``rope_scaling`` record may hold: the default rotary
+# embedding with its multimodal sections, which fall together for text
+MROPE_KEYS = {"mrope_section", "rope_type", "type"}
 LINEAR_KEYS = ("linear_num_key_heads", "linear_num_value_heads",
                "linear_key_head_dim", "linear_value_head_dim",
                "linear_conv_kernel_dim")
+
+
+class Experts(NamedTuple):
+    """A sparse-expert feed-forward: the router's width, this chip's
+    share of the experts (``first .. first + held - 1``), experts a
+    token, an expert's width, and whether the chosen probabilities are
+    normalised to sum 1."""
+    routed: int
+    held: int
+    first: int
+    per_token: int
+    width: int
+    normalise: bool
+
+
+class Selection(NamedTuple):
+    """``sa_config``: the indexer's query heads and head size (one key
+    head), the keys a query selects, the query rows a chunk."""
+    heads: int
+    head_dim: int
+    topk: int
+    chunk: int
 
 
 class HybridSpec(NamedTuple):
@@ -122,20 +212,98 @@ class HybridSpec(NamedTuple):
     rope_theta: Optional[float] = None
     sandwich: bool = False      # the block: sandwich norms, no QK-norm
     exit_entropy_beta: float = 0.05
+    num_key_value_heads: int = 0    # 0: as many as query heads
+    head_dim: int = 0               # 0: hidden_size / query heads
+    prenorm: bool = False       # the block: pre-norm, per-head QK-norm
+    experts: Optional[Experts] = None       # every layer's feed-forward
+    selection: Optional[Selection] = None   # every full-attention layer
+    embedding_init_std: float = INIT_STD    # the seeded embedding rows'
 
     @property
     def looped(self) -> bool:
         return self.total_ut_steps > 1
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_key_value_heads or self.num_attention_heads
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def loss_parts(self) -> bool:
+        """The training loss reports parts beside its value."""
+        return self.looped or self.experts is not None \
+            or self.selection is not None
+
+
+def _experts_of(path: str, doc: dict) -> Optional[Experts]:
+    """The expert layer of a file with ``num_experts``: the experts
+    held here. The router's width is ``published.num_experts`` (the
+    file's own count where nothing was cut) and the first expert held
+    ``first_expert_held`` (default 0)."""
+    held = int(doc.get("num_experts") or 0)
+    if not held:
+        return None
+    missing = [k for k in EXPERT_KEYS if k not in doc]
+    if missing:
+        raise ValueError(f"model specification {path!r} lacks {missing}")
+    if doc.get("mlp_only_layers") or doc.get("decoder_sparse_step", 1) != 1:
+        raise ValueError(
+            f"model specification {path!r}: mlp_only_layers / "
+            "decoder_sparse_step ask for dense layers among the expert "
+            "layers, which is not written (every layer an expert layer)")
+    routed = int((doc.get("published") or {}).get("num_experts", held))
+    first = int(doc.get("first_expert_held", 0))
+    per_token = int(doc["num_experts_per_tok"])
+    if first < 0 or first + held > routed or not 0 < per_token <= routed:
+        raise ValueError(
+            f"model specification {path!r}: experts {first}.."
+            f"{first + held - 1} held and {per_token} a token do not lie "
+            f"within the router's {routed}")
+    return Experts(routed, held, first, per_token,
+                   int(doc["moe_intermediate_size"]),
+                   bool(doc.get("norm_topk_prob", False)))
+
+
+def _selection_of(path: str, doc: dict) -> Optional[Selection]:
+    sa = doc.get("sa_config")
+    if not sa:
+        return None
+    missing = [k for k in SELECTION_KEYS if k not in sa]
+    if missing:
+        raise ValueError(
+            f"model specification {path!r}: sa_config lacks {missing}")
+    if sa.get("indexer_num_kv_heads", 1) != 1:
+        raise ValueError(
+            f"model specification {path!r}: sa_config: the indexer is "
+            "written with one key head (indexer_num_kv_heads 1)")
+    if sa["topk"] % sa["q_chunk_size"]:
+        raise ValueError(
+            f"model specification {path!r}: sa_config: topk {sa['topk']} "
+            f"is no multiple of q_chunk_size {sa['q_chunk_size']}")
+    return Selection(int(sa["indexer_num_heads"]),
+                     int(sa["indexer_head_dim"]), int(sa["topk"]),
+                     int(sa["q_chunk_size"]))
 
 
 def load_spec(path: str) -> HybridSpec:
     """Read a specification file. Keys beyond the public config's are
     ignored (a benchmark configuration's file carries its launcher
     flags beside them); ``layer_types`` is cut to
-    ``num_hidden_layers``; the ``linear_*`` keys are asked for only
-    where a layer of the cut is a ``linear_attention`` one."""
+    ``num_hidden_layers`` (absent: every layer a ``full_attention``
+    one); the ``linear_*`` keys are asked for only where a layer of the
+    cut is a ``linear_attention`` one, ``intermediate_size`` only where
+    the feed-forward is dense."""
     with open(path) as f:
         doc = json.load(f)
+    experts = _experts_of(path, doc)
+    if "num_hidden_layers" in doc:
+        doc.setdefault("layer_types",
+                       ["full_attention"] * int(doc["num_hidden_layers"]))
+    if experts is not None:
+        doc.setdefault("intermediate_size", 0)
     missing = [k for k in PLAIN_KEYS + ("num_hidden_layers",)
                if k not in doc]
     if missing:
@@ -150,23 +318,27 @@ def load_spec(path: str) -> HybridSpec:
     missing = [k for k in LINEAR_KEYS if linear and k not in doc]
     if missing:
         raise ValueError(f"model specification {path!r} lacks {missing}")
-    if doc.get("num_key_value_heads", doc["num_attention_heads"]) \
-            != doc["num_attention_heads"] \
-            or doc.get("linear_num_value_heads") \
+    heads = int(doc["num_attention_heads"])
+    kv_heads = int(doc.get("num_key_value_heads") or heads)
+    if heads % kv_heads:
+        raise ValueError(
+            f"model specification {path!r}: num_attention_heads {heads} "
+            f"is no multiple of num_key_value_heads {kv_heads}")
+    if doc.get("linear_num_value_heads") \
             != doc.get("linear_num_key_heads"):
         raise ValueError(
-            f"model specification {path!r}: grouped key/value heads are "
-            "not supported (as many key and value heads as query heads)")
+            f"model specification {path!r}: grouped value heads in the "
+            "linear-attention layers are not supported (as many value "
+            "heads as key heads)")
     if doc.get("tie_word_embeddings") or doc.get("attention_bias"):
         raise ValueError(
             f"model specification {path!r}: tied embeddings and "
             "attention biases are not supported")
-    heads = doc["num_attention_heads"]
-    if doc.get("head_dim", doc["hidden_size"] // heads) * heads \
-            != doc["hidden_size"]:
+    head_dim = int(doc.get("head_dim") or doc["hidden_size"] // heads)
+    if head_dim % 2:
         raise ValueError(
-            f"model specification {path!r}: head_dim must be "
-            "hidden_size / num_attention_heads")
+            f"model specification {path!r}: head_dim {head_dim} is odd "
+            "(the rotary embedding turns pairs)")
     steps = int(doc.get("total_ut_steps", 1))
     sandwich = doc.get("model_type") in SANDWICH_BLOCKS
     if steps < 1 or (steps > 1 and not sandwich):
@@ -174,18 +346,40 @@ def load_spec(path: str) -> HybridSpec:
             f"model specification {path!r}: total_ut_steps {steps} with "
             f"model_type {doc.get('model_type')!r}: a looped stack is "
             f"written for {SANDWICH_BLOCKS} only")
-    if doc.get("rope_scaling"):
+    scaling = doc.get("rope_scaling")
+    if scaling and (set(scaling) - MROPE_KEYS or {
+            scaling.get("rope_type", "default"),
+            scaling.get("type", "default")} != {"default"}
+            or sum(scaling.get("mrope_section", [head_dim // 2]))
+            != head_dim // 2):
         raise ValueError(
-            f"model specification {path!r}: rope_scaling is not supported")
+            f"model specification {path!r}: rope_scaling is not supported "
+            "(but for the default embedding's mrope_section record, whose "
+            "sections fill half a head)")
+    selection = _selection_of(path, doc)
+    prenorm = doc.get("model_type") in PRENORM_BLOCKS
+    if (experts or selection) and not prenorm:
+        raise ValueError(
+            f"model specification {path!r}: num_experts and sa_config are "
+            f"written for the pre-norm block ({PRENORM_BLOCKS}), not for "
+            f"model_type {doc.get('model_type')!r}")
     theta = doc.get("rope_theta",
                     (doc.get("rope_parameters") or {}).get("rope_theta"))
+    embed_std = float(doc.get("embedding_init_std", INIT_STD))
+    if not 0.0 < embed_std < math.inf:
+        raise ValueError(
+            f"model specification {path!r}: embedding_init_std "
+            f"{embed_std} is no positive size")
     fields = dict({k: doc[k] for k in PLAIN_KEYS}, layer_types=kinds,
                   **{k: doc.get(k, 0) for k in LINEAR_KEYS})
     return HybridSpec(
         **fields, total_ut_steps=steps,
         rope_theta=None if theta is None else float(theta),
         sandwich=sandwich,
-        exit_entropy_beta=float(doc.get("exit_entropy_beta", 0.05)))
+        exit_entropy_beta=float(doc.get("exit_entropy_beta", 0.05)),
+        num_key_value_heads=kv_heads, head_dim=head_dim,
+        prenorm=prenorm, experts=experts, selection=selection,
+        embedding_init_std=embed_std)
 
 
 def _linear_shapes(s: HybridSpec) -> dict:
@@ -199,14 +393,38 @@ def _linear_shapes(s: HybridSpec) -> dict:
 
 
 def _full_shapes(s: HybridSpec) -> dict:
-    d = s.hidden_size
-    proj = {"wq": (d, d), "wk": (d, d), "wv": (d, d), "wo": (d, d)}
+    d, hd = s.hidden_size, s.head_size
+    q, kv = s.num_attention_heads * hd, s.kv_heads * hd
+    proj = {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d)}
     # lint: disable=FTL005 — the block is a flag of the spec
-    return proj if s.sandwich else dict(proj, q_norm=(d,), k_norm=(d,))
+    if s.sandwich:
+        return proj
+    # lint: disable=FTL005 — the block is a flag of the spec
+    if s.prenorm:
+        proj.update(q_norm=(hd,), k_norm=(hd,))     # over each head
+    else:
+        proj.update(q_norm=(q,), k_norm=(kv,))      # over all of q, of k
+    sel = s.selection
+    # lint: disable=FTL005 — sa_config or none, by the spec
+    if sel is not None:
+        proj.update(index_q=(d, sel.heads * sel.head_dim),
+                    index_k=(d, sel.head_dim), index_w=(d, sel.heads))
+    return proj
+
+
+def _mlp_shapes(s: HybridSpec) -> dict:
+    d, e = s.hidden_size, s.experts
+    # lint: disable=FTL005 — experts or a dense feed-forward, by the spec
+    if e is None:
+        f = s.intermediate_size
+        return {"gate": (d, f), "up": (d, f), "down": (f, d)}
+    # the experts held here are one stacked leaf a matrix
+    return {"router": (d, e.routed), "gate": (e.held, d, e.width),
+            "up": (e.held, d, e.width), "down": (e.held, e.width, d)}
 
 
 def param_shapes(s: HybridSpec) -> dict:
-    d, f = s.hidden_size, s.intermediate_size
+    d = s.hidden_size
     tree = {"embed": (s.vocab_size, d), "final_norm": (d,),
             "head": (d, s.vocab_size)}
     # lint: disable=FTL005 — a looped model or not, by the spec
@@ -217,7 +435,7 @@ def param_shapes(s: HybridSpec) -> dict:
             "mixer": _linear_shapes(s) if kind == "linear_attention"
             else _full_shapes(s),
             "mixer_norm": (d,), "mlp_norm": (d,),
-            "mlp": {"gate": (d, f), "up": (d, f), "down": (f, d)}}
+            "mlp": _mlp_shapes(s)}
         # lint: disable=FTL005 — the block is a flag of the spec
         if s.sandwich:
             tree[f"layer_{i}"].update(mixer_in_norm=(d,), mlp_in_norm=(d,))
@@ -225,8 +443,9 @@ def param_shapes(s: HybridSpec) -> dict:
 
 
 def init_params(spec: HybridSpec, rng) -> Any:
-    """Seeded float32 parameters: matrices normal(0, 0.02), norm
-    scales 1, the exit gate's bias 0, the convolution
+    """Seeded float32 parameters: matrices normal(0, 0.02) (the
+    embedding's rows ``embedding_init_std`` where the file gives it),
+    norm scales 1, the exit gate's bias 0, the convolution
     uniform(+-1/sqrt(taps)), decay rates ``exp(a_log)`` spread over
     1..16 and time steps ``softplus(dt_bias)`` log-spread over
     0.001..0.1 across the heads (the delta-rule family's own
@@ -252,7 +471,8 @@ def init_params(spec: HybridSpec, rng) -> Any:
             leaf = jax.random.uniform(key, shape, jnp.float32, -bound,
                                       bound)
         else:
-            leaf = INIT_STD * jax.random.normal(key, shape, jnp.float32)
+            std = spec.embedding_init_std if name == "embed" else INIT_STD
+            leaf = std * jax.random.normal(key, shape, jnp.float32)
         out.append(leaf.astype(jnp.float32))
     return jax.tree.unflatten(treedef, out)
 
@@ -336,27 +556,44 @@ def _linear_attention(p, x, s: HybridSpec, dt):
     return _dot((o * gate).reshape(B, T, h * dv), p["wo"], dt, "mixer.o")
 
 
-def _full_attention(p, x, s: HybridSpec, dt, attention: str, rope=None):
-    B, T, d = x.shape
-    h = s.num_attention_heads
-    hd = d // h
+def _projected(p, x, s: HybridSpec, dt, rope, out):
+    """q [B, T, H, hd], k and v [B, T, KV, hd] of a full-attention
+    layer, of type ``out``: projected, normed as the block has it, q
+    and k turned by ``rope`` where the model has one."""
+    B, T, _ = x.shape
+    hd = s.head_size
 
-    def project(name, norm):
+    def project(name, norm, n):
         t = _dot(x, p[name], dt, "mixer." + name[1:])
+        # lint: disable=FTL005 — the block is a flag of the spec
+        if norm and s.prenorm:
+            return _rms_norm(t.reshape(B, T, n, hd), p[norm],
+                             s.rms_norm_eps).reshape(B, T, n * hd)
         # lint: disable=FTL005 — the block is a flag of the spec
         if norm and not s.sandwich:
             t = _rms_norm(t, p[norm], s.rms_norm_eps)
         return t
 
-    def heads(t, turn):
+    def heads(t, n, turn):
         # lint: disable=FTL005 — a model with or without rotary embedding
         if turn and rope is not None:
-            return _rotate(t.reshape(B, T, h, hd), rope).astype(dt)
-        return t.astype(dt).reshape(B, T, h, hd)
+            return _rotate(t.reshape(B, T, n, hd), rope).astype(out)
+        return t.astype(out).reshape(B, T, n, hd)
 
-    q, k = project("wq", "q_norm"), project("wk", "k_norm")
-    v = project("wv", None)
-    q, k, v = heads(q, True), heads(k, True), heads(v, False)
+    h, kv = s.num_attention_heads, s.kv_heads
+    q, k = project("wq", "q_norm", h), project("wk", "k_norm", kv)
+    v = project("wv", None, kv)
+    return heads(q, h, True), heads(k, kv, True), heads(v, kv, False)
+
+
+def _full_attention(p, x, s: HybridSpec, dt, attention: str, rope=None):
+    B, T, _ = x.shape
+    h, hd = s.num_attention_heads, s.head_size
+    q, k, v = _projected(p, x, s, dt, rope, dt)
+    # lint: disable=FTL005 — head counts of the spec
+    if s.kv_heads != h:
+        # grouped heads: query head i reads key head i // (h / kv)
+        k, v = (jnp.repeat(t, h // s.kv_heads, axis=2) for t in (k, v))
     with jax.named_scope("lm.attention"):
         # lint: disable=FTL005 — a static mode string and a static length
         if resolve_attention(attention, T) == "flash":
@@ -373,7 +610,54 @@ def _full_attention(p, x, s: HybridSpec, dt, attention: str, rope=None):
                 jnp.where(mask[None, None], scores, -jnp.inf), axis=-1)
             out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(dt), v,
                              preferred_element_type=jnp.float32)
-    return _dot(out.reshape(B, T, d), p["wo"], dt, "mixer.o")
+    return _dot(out.reshape(B, T, h * hd), p["wo"], dt, "mixer.o")
+
+
+def _selected_attention(p, x, s: HybridSpec, dt, rope):
+    """A full-attention layer under ``sa_config``
+    (``ops/sparse_attention.py``): (the layer's output, ``L_I``). The
+    indexer reads ``x`` under ``stop_gradient``, so that the KL term
+    trains its three matrices and nothing else; its queries and key
+    take the rotary embedding over their own head size, and its
+    weights the scale ``(heads x head_dim) ** -0.5``."""
+    B, T, _ = x.shape
+    sel, h, hd = s.selection, s.num_attention_heads, s.head_size
+    # float32 into the chunks, which cast them: the chunks' cotangents
+    # of k and v then add up in float32
+    q, k, v = _projected(p, x, s, dt, rope, jnp.float32)
+    with jax.named_scope("lm.indexer"):
+        u = jax.lax.stop_gradient(x)
+        qi = _dot(u, p["index_q"], dt, "mixer.index_q").reshape(
+            B, T, sel.heads, sel.head_dim)
+        ki = _dot(u, p["index_k"], dt, "mixer.index_k").reshape(
+            B, T, 1, sel.head_dim)
+        wi = _dot(u, p["index_w"], dt, "mixer.index_w") \
+            * (sel.heads * sel.head_dim) ** -0.5
+        # lint: disable=FTL005 — a model with or without rotary embedding
+        if rope is not None:
+            turn = rotary_tables(jnp.arange(T), sel.head_dim, s.rope_theta)
+            qi, ki = _rotate(qi, turn), _rotate(ki, turn)
+    out, index_loss = sparse_attention.selected_attention(
+        q, k, v, qi, ki[:, :, 0], wi, topk=sel.topk, chunk=sel.chunk,
+        dt=dt, scopes=("lm.indexer", "lm.attention"))
+    return _dot(out.reshape(B, T, h * hd), p["wo"], dt, "mixer.o"), \
+        index_loss
+
+
+def _experts(p, x, s: HybridSpec, dt):
+    """The expert layer's share (``ops/routed_experts.py``): (its
+    result [B, T, D], the layer call's routing counters)."""
+    B, T, d = x.shape
+    e = s.experts
+    u = x.reshape(B * T, d)
+    with jax.named_scope("lm.router"):
+        gates, chosen = routed_experts.route(
+            _dot(u, p["router"], dt, "mlp.router"), e.per_token,
+            e.normalise)
+    out, counters = routed_experts.expert_share(
+        p, u, gates, chosen, first=e.first, dt=dt,
+        scopes=("lm.router", "lm.experts"))
+    return out.reshape(B, T, d), counters
 
 
 def _mlp(p, x, dt):
@@ -406,14 +690,37 @@ def _layer(p, x, kind: str, s: HybridSpec, dt, attention: str, rope=None):
     return x + _rms_norm(_mlp(p["mlp"], x, dt), p["mlp_norm"], eps)
 
 
+def _prenorm_layer(p, x, kind: str, s: HybridSpec, dt, attention: str,
+                   rope=None):
+    """A pre-norm layer, ``a = x + Mixer(N_1(x))``, ``y = a +
+    FF(N_2(a))``: (``y``, the layer call's parts: ``index_loss`` under
+    ``sa_config``, the routing counters under experts)."""
+    eps, parts = s.rms_norm_eps, {}
+    u = _rms_norm(x, p["mixer_norm"], eps)
+    # lint: disable=FTL005 — the layer's kind and sa_config, by the spec
+    if kind == "linear_attention":
+        x = x + _linear_attention(p["mixer"], u, s, dt)
+    elif s.selection is not None:
+        o, parts["index_loss"] = _selected_attention(p["mixer"], u, s, dt,
+                                                     rope)
+        x = x + o
+    else:
+        x = x + _full_attention(p["mixer"], u, s, dt, attention, rope)
+    u = _rms_norm(x, p["mlp_norm"], eps)
+    # lint: disable=FTL005 — experts or a dense feed-forward, by the spec
+    if s.experts is not None:
+        o, counters = _experts(p["mlp"], u, s, dt)
+        parts.update(counters)
+        return x + o, parts
+    return x + _mlp(p["mlp"], u, dt), parts
+
+
 def _rope(s: HybridSpec, T: int):
     """The rotary tables of a ``T``-token row, or None without
     ``rope_theta``."""
     if s.rope_theta is None:
         return None
-    return rotary_tables(jnp.arange(T),
-                         s.hidden_size // s.num_attention_heads,
-                         s.rope_theta)
+    return rotary_tables(jnp.arange(T), s.head_size, s.rope_theta)
 
 
 # -- what a rematerialized layer keeps ---------------------------------------
@@ -421,16 +728,39 @@ def _rope(s: HybridSpec, T: int):
 def layer_products(s: HybridSpec, kind: str) -> dict:
     """``{name: (K, N)}`` of a layer's matrix products ``[T, K] x
     [K, N]``, in the layer's own order: the names their results carry
-    (``_dot``'s ``name``)."""
-    d, f = s.hidden_size, s.intermediate_size
+    (``_dot``'s ``name``). An expert layer's ``mlp.gate`` / ``up`` /
+    ``down`` are grouped products over the dispatch buffer, ``[T x
+    per_token, K] x [K, N]`` an expert (:func:`_product_totals`)."""
+    d = s.hidden_size
     # lint: disable=FTL005 — the layer's kind is a string of the spec
     if kind == "linear_attention":
         shapes = _linear_shapes(s)
         mixer = {"mixer." + n: shapes["w" + n] for n in "qkvbago"}
     else:
-        mixer = {"mixer." + n: (d, d) for n in "qkvo"}
+        shapes = _full_shapes(s)
+        mixer = {"mixer." + n: shapes["w" + n] for n in "qkvo"}
+        mixer.update({"mixer." + n: shapes[n]
+                      for n in ("index_q", "index_k", "index_w")
+                      if n in shapes})
+    e = s.experts
+    f = s.intermediate_size if e is None else e.width
+    # lint: disable=FTL005 — experts or a dense feed-forward, by the spec
+    if e is not None:
+        mixer["mlp.router"] = (d, e.routed)
     return dict(mixer, **{"mlp.gate": (d, f), "mlp.up": (d, f),
                           "mlp.down": (f, d)})
+
+
+def _product_cost(s: HybridSpec, name: str, k: int, n: int):
+    """(FLOPs, float32 result elements) a token of one product. An
+    expert layer's grouped products hold ``per_token`` buffer rows a
+    token, whatever is routed here, and run the expected ``per_token x
+    held / routed`` of them."""
+    e = s.experts
+    # lint: disable=FTL005 — host integers of the spec
+    if e is not None and name in ("mlp.gate", "mlp.up", "mlp.down"):
+        return 2 * k * n * e.per_token * e.held / e.routed, n * e.per_token
+    return 2 * k * n, n
 
 
 def _product_totals(s: HybridSpec) -> dict:
@@ -440,7 +770,8 @@ def _product_totals(s: HybridSpec) -> dict:
     for kind in s.layer_types:
         for name, (k, n) in layer_products(s, kind).items():
             flops, size = totals.get(name, (0, 0))
-            totals[name] = (flops + 2 * k * n, size + 4 * n)
+            cost, floats = _product_cost(s, name, k, n)
+            totals[name] = (flops + cost, size + 4 * floats)
     return totals
 
 
@@ -502,7 +833,10 @@ def residual_budget(s: HybridSpec, rows: int, tokens: int,
     * the reserve: every layer call's kept input, and ``WORKING_SETS``
       times the widest layer's product results and the head's logits,
       for the one layer and the one head whose forward and backward
-      pass are alive at a time.
+      pass are alive at a time; under ``sa_config`` as many times a
+      query chunk's float32 scores (the heads' and the indexer's), and
+      every layer's attention output (``sparse_attention.KEPT[1]``,
+      kept whatever the budget).
 
     Read against the chip's allocator (``peak_bytes_reserved``, v5e,
     PERF.md section 6, PR 38): beside the sets this budget admits 1.1
@@ -519,11 +853,22 @@ def residual_budget(s: HybridSpec, rows: int, tokens: int,
     if s.looped:
         held += 6 * count([v for k, v in shapes.items()
                            if k.startswith("layer_")])
-    widest = max(sum(n for _, n in layer_products(s, kind).values())
+    widest = max(sum(_product_cost(s, name, k, n)[1] for name, (k, n)
+                     in layer_products(s, kind).items())
                  for kind in set(s.layer_types))
     reserve = 4 * rows * tokens * (
         s.hidden_size * len(s.layer_types) * s.total_ut_steps
         + WORKING_SETS * (widest + s.vocab_size))
+    sel = s.selection
+    # lint: disable=FTL005 — sa_config or none, by the spec
+    if sel is not None:
+        # a query chunk's float32 scores, of the heads and the indexer's,
+        # and what every selected layer keeps whatever the budget: the
+        # attention's output
+        reserve += 4 * rows * tokens * (
+            WORKING_SETS * min(tokens, sel.chunk)
+            * (s.num_attention_heads + sel.heads)
+            + len(s.layer_types) * s.num_attention_heads * s.head_size)
     return max(0, stats["bytes_limit"] - stats.get("bytes_in_use", 0)
                - held - reserve)
 
@@ -548,25 +893,43 @@ def _kept_for(s: HybridSpec, rows: int, tokens: int) -> Tuple[str, ...]:
 
 def _stack(params, h, s: HybridSpec, dt, attention: str, remat: bool,
            rope):
-    """One pass over the layers: ``[B, T, D] -> [B, T, D]``."""
+    """One pass over the layers: ``[B, T, D] -> [B, T, D]``; of a
+    pre-norm block also the layer calls' parts, each stacked ``[n]``
+    over the layers."""
     # lint: disable=FTL005 — remat is a static flag of the launcher
     kept = _kept_for(s, h.shape[0], h.shape[1]) if remat else ()
+    # lint: disable=FTL005 — remat and sa_config are static
+    if remat and s.selection is not None:
+        # the attention's output: the backward pass then runs the
+        # chunks' attention once, not twice (the thresholds are NOT
+        # kept here: ops/sparse_attention.py says why)
+        kept += sparse_attention.KEPT[1:]
     only = jax.checkpoint_policies.save_only_these_names
     # lint: disable=FTL005 — names or none: then the bare checkpoint
     policy = only(*kept) if kept else None
+    # lint: disable=FTL005 — the block is a flag of the spec
+    layer, parts = (_prenorm_layer if s.prenorm else _layer), []
     for i, kind in enumerate(s.layer_types):
-        fn = lambda p, h, kind=kind: _layer(p, h, kind, s, dt, attention,
-                                            rope)
+        fn = lambda p, h, kind=kind: layer(p, h, kind, s, dt, attention,
+                                           rope)
         # lint: disable=FTL005 — remat is a static flag of the launcher
         h = (jax.checkpoint(fn, policy=policy) if remat else fn)(
             params[f"layer_{i}"], h)
+        # lint: disable=FTL005 — the block is a flag of the spec
+        if s.prenorm:
+            h, part = h
+            parts.append(part)
+    # lint: disable=FTL005 — the block is a flag of the spec
+    if s.prenorm:
+        return h, jax.tree.map(lambda *v: jnp.stack(v), *parts)
     return h
 
 
 def hidden_states(params, x, s: HybridSpec, dt, attention: str,
                   remat: bool):
     """Token ids ``[B, T]`` -> the last layer's output ``[B, T, D]``
-    of a single-pass model (the final norm is ``logits_of``'s)."""
+    of a single-pass model (the final norm is ``logits_of``'s); of a
+    pre-norm block (the output, the layers' parts)."""
     return _stack(params, params["embed"][x], s, dt, attention, remat,
                   _rope(s, x.shape[1]))
 
@@ -677,6 +1040,18 @@ class HybridLM(NamedTuple):
         """Passes a token makes through the stack of layers."""
         return self.module.total_ut_steps
 
+    @property
+    def loss_parts(self) -> bool:
+        """``token_loss_parts`` reports parts for the round's row."""
+        return self.module.loss_parts
+
+    def selected_share(self, tokens: int) -> Optional[float]:
+        """Selected over causal query-key pairs of a ``tokens``-long
+        row, from shapes; None without ``sa_config``."""
+        sel = self.module.selection
+        return None if sel is None else sparse_attention.selected_share(
+            tokens, sel.topk)
+
     def init(self, rng):
         return _jitted_init(self.module)(rng)
 
@@ -689,14 +1064,16 @@ class HybridLM(NamedTuple):
                              _kept_for(self.module, rows, tokens))
 
     def _states(self, params, x):
+        """(compute type, the states the head reads, the layer calls'
+        parts: a pre-norm block's, else none)."""
         dt = jnp.dtype(self.dtype)
         states = looped_states if self.module.looped else hidden_states
-        return dt, states(params, x, self.module, dt, self.attention,
-                          self.remat)
+        h = states(params, x, self.module, dt, self.attention, self.remat)
+        return (dt,) + (h if self.module.prenorm else (h, {}))
 
     def apply(self, params, x, train: bool = False, rng=None, carry=None):
         """Logits ``[B, T, V]``; of the last pass in a looped model."""
-        dt, h = self._states(params, x)
+        dt, h, _ = self._states(params, x)
         # lint: disable=FTL005 — a looped model or not, by the spec
         if self.module.looped:
             with jax.named_scope("lm.exit"), jax.named_scope("lm.head"):
@@ -707,10 +1084,15 @@ class HybridLM(NamedTuple):
     def token_loss_parts(self, params, x, train: bool = False, rng=None):
         """(loss, top-1, parts) over the B x (T - 1) positions of ``x``
         that have a next token. A single-pass model: the mean
-        next-token cross-entropy, no parts. A looped model:
+        next-token cross-entropy ``CE``, no parts. A looped model:
         :func:`exit_objective` over its passes, the top-1 of the last
-        pass, and the objective's parts."""
-        dt, h = self._states(params, x)
+        pass, and the objective's parts. Under ``sa_config``: ``CE +
+        L_I``, ``L_I`` the indexers' KL terms
+        (``ops/sparse_attention.py``) summed over the layers; the parts
+        are ``ce``, ``index_loss`` (``L_I``) and, of an expert layer,
+        ``moe_pairs`` and ``moe_load_max_over_mean``
+        (``ops/routed_experts.py``), means over the layers."""
+        dt, h, layers = self._states(params, x)
         # lint: disable=FTL005 — a looped model or not, by the spec
         if self.module.looped:
             with jax.named_scope("lm.exit"):
@@ -722,7 +1104,21 @@ class HybridLM(NamedTuple):
         with jax.named_scope("lm.head"):
             nll, hit = next_token_stats(
                 logits_of(params, h, self.module, dt), x)
-            return jnp.mean(nll), jnp.mean(hit), {}
+            loss = jnp.mean(nll)
+        # lint: disable=FTL005 — parts or none, by the spec
+        if not layers:
+            return loss, jnp.mean(hit), {}
+        parts = {"ce": loss}
+        # lint: disable=FTL005 — sa_config or none, by the spec
+        if "index_loss" in layers:
+            parts["index_loss"] = jnp.sum(layers["index_loss"])
+            loss = loss + parts["index_loss"]
+        # lint: disable=FTL005 — experts or none, by the spec
+        if "pairs" in layers:
+            parts["moe_pairs"] = jnp.mean(layers["pairs"])
+            parts["moe_load_max_over_mean"] = jnp.mean(
+                layers["load_max_over_mean"])
+        return loss, jnp.mean(hit), parts
 
     def token_loss(self, params, x, train: bool = False, rng=None):
         """(loss, top-1) of :meth:`token_loss_parts`."""

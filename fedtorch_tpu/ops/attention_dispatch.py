@@ -24,7 +24,14 @@ from __future__ import annotations
 # the dense path at T=2048 6.7 ms a call, 7.3 %. Neither length ran
 # both paths, so the constant stays where the capture put it. The
 # looped cell of PR 35 (16 heads of 128, T=1024) reads the dense path
-# 32 times a step: its seconds are in PERF.md section 5.
+# 32 times a step: its seconds are in PERF.md section 5. The selected
+# layers of PR 39's cell (32 query on 4 key heads of 128, 2048 keys a
+# query) take neither path: the flash kernel has no mask argument and
+# returns no probabilities, so ``ops/sparse_attention.py`` runs masked
+# dense query chunks of 512, whatever this constant says. Read there
+# (one chip, forward, recomputation and backward of a layer call):
+# T=4096 19.8 ms a call, 7.9 % of the SELECTED pairs' roofline; T=6144
+# 46.4 ms, 5.6 %; T=8192 91.7 ms, 4.0 %.
 FLASH_MIN_SEQ_LEN = 4096
 
 

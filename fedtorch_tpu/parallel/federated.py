@@ -146,6 +146,18 @@ _SCALAR_FIELDS = (
     # a looped token model's exit gauges; None for every other model
     ("lm_exit_mass_last", "lm_exit_mass_last"),
     ("lm_exit_entropy", "lm_exit_entropy"),
+    # a sparse token model's routing and indexer gauges; None likewise
+    ("lm_moe_pairs_local", "lm_moe_pairs_local"),
+    ("lm_moe_load_max_over_mean", "lm_moe_load_max_over_mean"),
+    ("lm_index_loss", "lm_index_loss"),
+)
+# RoundMetrics field <- the key of a token model's loss parts
+# (``token_loss_parts``) whose mean over clients and steps it holds
+_PART_GAUGES = (
+    ("lm_exit_entropy", "exit_entropy"),
+    ("lm_moe_pairs_local", "moe_pairs"),
+    ("lm_moe_load_max_over_mean", "moe_load_max_over_mean"),
+    ("lm_index_loss", "index_loss"),
 )
 # what the program computes itself, ahead of the table's leaves
 _COMPUTED_SCALARS = ("mean_epoch", "lr", "n_online", "loss_sum",
@@ -406,6 +418,10 @@ class FederatedTrainer:
         # the row's ``ut_steps`` counter (1: not looped, no counter)
         self.ut_steps = int(getattr(model, "ut_steps", 1)) \
             if self.tokens_per_round else 1
+        # the model's loss reports parts for the row (a looped model's
+        # exits, a sparse model's routing and indexer gauges)
+        self.loss_parts = bool(self.tokens_per_round
+                               and getattr(model, "loss_parts", False))
 
         num_epochs = cfg.train.num_epochs or 1
         self.schedule: LRSchedule = compile_schedule(
@@ -1414,9 +1430,9 @@ class FederatedTrainer:
         opt0 = optim.init_opt_state((), cfg.optim, lean=True)
         carry0 = model.init_carry(B)
         budget = jnp.asarray(K, jnp.int32)
-        # a looped model's loss reports its exits' parts: asked for
+        # a looped or a sparse model's loss reports parts: asked for
         # here alone, so that every other model's step is as it was
-        with_parts = {"with_parts": True} if self.ut_steps > 1 else {}
+        with_parts = {"with_parts": True} if self.loss_parts else {}
 
         def one_client(total, member):
             x, y, size, weight, rng_c, epoch0, li0 = member
@@ -1497,12 +1513,16 @@ class FederatedTrainer:
                 byzantine_clients=none, robust_selected=none,
                 robust_trimmed=none)
             if parts:
-                # [k, K, R] exit masses, [k, K] entropies: means over
-                # the round's clients and steps
-                metrics = metrics._replace(
-                    lm_exit_mass_last=jnp.mean(
-                        parts[0]["exit_mass"][..., -1]),
-                    lm_exit_entropy=jnp.mean(parts[0]["exit_entropy"]))
+                # [k, K, R] exit masses, [k, K] entropies and gauges:
+                # means over the round's clients and steps
+                part, means = parts[0], {}
+                if "exit_mass" in part:
+                    means["lm_exit_mass_last"] = jnp.mean(
+                        part["exit_mass"][..., -1])
+                means.update({field: jnp.mean(part[key])
+                              for field, key in _PART_GAUGES
+                              if key in part})
+                metrics = metrics._replace(**means)
         with jax.named_scope("fed.server_step"):
             new_server = ServerState(params=new_params, opt=new_opt,
                                      aux=new_saux, round=server.round + 1,
@@ -1838,6 +1858,11 @@ class FederatedTrainer:
             # what the model's rematerialized layers keep of a step
             out["lm_kept_product_share"] = kept["lm_kept_product_share"]
             out["lm_kept_residual_bytes"] = kept["lm_kept_residual_bytes"]
+        share = self.model.selected_share(self.row_tokens) \
+            if self.row_tokens and hasattr(self.model, "selected_share") \
+            else None
+        if share is not None:
+            out["lm_selected_share"] = share
         ss = self.stream_stats()
         if ss is not None:
             out.update(ss)

@@ -94,6 +94,23 @@ METRICS_OPTIONAL = {
                          "distribution puts on the last pass",
     "lm_exit_entropy": "looped token model: mean entropy of the exit "
                        "distribution over the passes (nats)",
+    "lm_moe_pairs_local": "sparse-expert token model: token-expert "
+                          "pairs routed to the experts held here and "
+                          "computed, a row-step and layer (mean over "
+                          "the round's clients, steps and layers; "
+                          "ops/routed_experts.py)",
+    "lm_moe_load_max_over_mean": "sparse-expert token model: the "
+                                 "fullest held expert's pairs over the "
+                                 "held experts' mean, a row-step and "
+                                 "layer (1: even routing; mean as "
+                                 "above)",
+    "lm_index_loss": "token model under sa_config: L_I, the indexers' "
+                     "KL term of the training loss, summed over the "
+                     "layers (mean over the round's clients and steps; "
+                     "ops/sparse_attention.py)",
+    "lm_selected_share": "token model under sa_config: selected over "
+                         "causal query-key pairs of a row, from shapes "
+                         "(sum_t min(t + 1, topk) over T (T + 1) / 2)",
     # stream plane (trainer.stream_stats)
     "stream_depth": "prefetched feeds ready at fetch time",
     "stream_wait_s": "consumer wall blocked on the feed queue (total)",

@@ -123,7 +123,8 @@ def test_the_benchmarks_configuration_counts_its_parameters():
     ({"layer_types": ["linear_attention", "sliding_attention"],
       "num_hidden_layers": 2}, "layer_types"),
     ({"num_hidden_layers": 5}, "layer_types"),
-    ({"num_key_value_heads": 2}, "grouped"),
+    ({"linear_num_value_heads": 4}, "grouped value heads"),
+    ({"num_key_value_heads": 3}, "no multiple of num_key_value_heads"),
     ({"tie_word_embeddings": True}, "tied"),
     ({"hidden_size": None}, "lacks"),
 ])
@@ -134,6 +135,50 @@ def test_specification_refusals(tmp_path, change, match):
     path.write_text(json.dumps(doc))
     with pytest.raises(ValueError, match=match):
         load_spec(str(path))
+
+
+@pytest.mark.parametrize("change,mixer", [
+    # grouped key/value heads: k and v of 2 heads of 8, a norm over each
+    ({"num_key_value_heads": 2},
+     {"wq": (32, 32), "wk": (32, 16), "wv": (32, 16), "wo": (32, 32),
+      "q_norm": (32,), "k_norm": (16,)}),
+    # a head size of its own: attention 4 x 16 = 64 wide on a stream of 32
+    ({"head_dim": 16},
+     {"wq": (32, 64), "wk": (32, 64), "wv": (32, 64), "wo": (64, 32),
+      "q_norm": (64,), "k_norm": (64,)}),
+])
+def test_grouped_heads_and_a_free_head_size_are_read(tmp_path, change,
+                                                     mixer):
+    """What the loader refused until PR 39: read, and the layer equals
+    attention over key and value heads repeated to the query heads'
+    count."""
+    path = tmp_path / "spec.json"
+    path.write_text(json.dumps(dict(SMALL, **change)))
+    model = model_of(str(path))
+    spec = model.spec
+    assert (spec.kv_heads, spec.head_size) == (
+        change.get("num_key_value_heads", 4), change.get("head_dim", 8))
+    assert param_shapes(spec)["layer_1"]["mixer"] == mixer
+    params = model.init(jax.random.key(3))
+    x = tokens((2, 12), seed=4)
+    loss, _ = jax.jit(model.token_loss)(params, x)
+    # the same weights with every key and value head written out
+    group = 4 // spec.kv_heads
+    wide = dict(dict(SMALL, **change), num_key_value_heads=4)
+    path.write_text(json.dumps(wide))
+    full = model_of(str(path))
+    hd = spec.head_size
+    spread = lambda w: jnp.repeat(
+        w.reshape(w.shape[:-1] + (spec.kv_heads, hd)), group,
+        axis=-2).reshape(w.shape[:-1] + (4 * hd,))
+    mixed = dict(params["layer_1"]["mixer"])
+    mixed.update(wk=spread(mixed["wk"]), wv=spread(mixed["wv"]))
+    # the Olmo block norms all of k at once: over repeated heads the
+    # mean square is the same, and the scale repeats with the heads
+    mixed["k_norm"] = spread(mixed["k_norm"])
+    want, _ = jax.jit(full.token_loss)(
+        dict(params, layer_1=dict(params["layer_1"], mixer=mixed)), x)
+    np.testing.assert_allclose(loss, want, rtol=2e-6)
 
 
 def test_define_model_needs_the_file():

@@ -282,16 +282,36 @@ def test_the_single_pass_files_read_as_they_did(tmp_path):
     assert nested.rope_theta == 5e5
 
 
+@pytest.mark.parametrize("change,mixer", [
+    ({"head_dim": 16}, {"wq": (32, 64), "wk": (32, 64), "wv": (32, 64),
+                        "wo": (64, 32)}),
+    ({"num_key_value_heads": 2}, {"wq": (32, 32), "wk": (32, 16),
+                                  "wv": (32, 16), "wo": (32, 32)}),
+])
+def test_a_looped_file_with_grouped_or_wider_heads_is_read(tmp_path, change,
+                                                            mixer):
+    """Refused until PR 39; the sandwich block has no QK-norm, so the
+    mixer is the four projections at the stated head counts and size,
+    and the looped objective trains through them."""
+    model = model_of(write_spec(tmp_path, **change))
+    assert param_shapes(model.spec)["layer_0"]["mixer"] == mixer
+    params = open_gate(model.init(jax.random.key(2)))
+    (loss, _), grads = loss_and_grads(model, params, tokens((1, 12)))
+    assert np.isfinite(float(loss))
+    assert all(float(jnp.max(jnp.abs(g))) > 0
+               for g in jax.tree.leaves(grads["layer_0"]["mixer"]))
+
+
 @pytest.mark.parametrize("change,match", [
     ({"model_type": "olmo_hybrid"}, r"total_ut_steps 3 with model_type "
                                     r"'olmo_hybrid'"),
     ({"model_type": ...}, "total_ut_steps 3 with model_type None"),
     ({"total_ut_steps": 0}, "total_ut_steps 0"),
-    ({"head_dim": 16}, "head_dim"),
+    ({"head_dim": 7}, "head_dim 7 is odd"),
     ({"rope_scaling": {"factor": 2.0}}, "rope_scaling"),
     ({"layer_types": ["full_attention", "linear_attention"]},
      "lacks.*linear_num_key_heads"),
-    ({"num_key_value_heads": 2}, "grouped"),
+    ({"num_key_value_heads": 3}, "no multiple of num_key_value_heads"),
     ({"intermediate_size": ...}, "lacks.*intermediate_size"),
 ])
 def test_specification_refusals_by_name(tmp_path, change, match):

@@ -111,3 +111,63 @@ def test_delta_rule_gradient_holds_no_triangular_solve(one_chip):
     assert "delta.inverse" in text     # the inverse is there to be judged
     assert "InvertDiagBlocksLowerTriangular" not in text
     assert not re.search(r"\btriangular-solve\(", text)
+
+
+def test_expert_share_compiles_to_grouped_kernels_at_the_cells_widths(
+        one_chip):
+    """The Keye cell's expert layer at its real shapes (4096 tokens of
+    2048, 16 experts of 768 held, 8 a token: a buffer of 32 768 rows),
+    forward and backward: every grouped product is the TPU's own
+    grouped-matmul kernel (``ragged-dot`` custom calls that visit the
+    row tiles in use), none was expanded into a dense product over all
+    the experts (16 times the work), and the temporaries stay near the
+    buffer's worst case."""
+    from fedtorch_tpu.ops import routed_experts
+
+    T, d, f, held = 4096, 2048, 768, 16
+    on_chip = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    p = {"gate": on_chip((held, d, f)), "up": on_chip((held, d, f)),
+         "down": on_chip((held, f, d))}
+
+    def loss(p, u, router):
+        gates, chosen = routed_experts.route(u @ router, 8, True)
+        out, _ = routed_experts.expert_share(
+            p, u, gates, chosen, first=0, dt=jnp.bfloat16)
+        return jnp.sum(out * out)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        p, on_chip((T, d)), on_chip((d, 128))).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r'op_name="[^"]*(ragged-dot[\w-]*)"', text)
+    assert len([k for k in kernels if "metadata" not in k]) >= 9, kernels
+    # no [experts, rows, width] array: the dense expansion's operand
+    assert not re.search(r"\[16,32768,", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+def test_selected_attention_compiles_in_chunks_at_the_cells_length(
+        one_chip):
+    """The Keye cell's attention over selected keys at 4096 tokens (32
+    query on 4 key heads of 128, an indexer of 16 heads of 64, 2048 keys
+    a query, chunks of 512), forward and backward: no array of a whole
+    row's scores lives (``[.., 4096, 4096]``), and the temporaries are
+    a chunk's, under 1 GiB where a row's scores alone are 2.1 GB."""
+    from fedtorch_tpu.ops import sparse_attention
+
+    T = 4096
+    on_chip = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=one_chip)
+    args = (on_chip(1, T, 32, 128), on_chip(1, T, 4, 128),
+            on_chip(1, T, 4, 128), on_chip(1, T, 16, 64),
+            on_chip(1, T, 64), on_chip(1, T, 16))
+
+    def loss(*a):
+        out, index_loss = sparse_attention.selected_attention(
+            *a, topk=2048, chunk=512, dt=jnp.bfloat16)
+        return jnp.sum(out * out) + index_loss
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(6))).lower(
+        *args).compile()
+    assert not re.search(r"4096,4096\]", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
