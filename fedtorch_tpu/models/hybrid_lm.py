@@ -110,7 +110,8 @@ the step is first traced; one decision a shape and process
 (the CPU). The heads of a looped model (:func:`exit_stats`) and the
 delta rule's scan keep their own checkpoints, which keep nothing; a
 selected layer also keeps its attention's output, whatever the budget
-(``sparse_attention.KEPT[1]``).
+(``sparse_attention.KEPT[1]``; of the fused kernels' form the
+log-sum-exp, the thresholds and the indexer's scores besides).
 
 Scopes for the device trace: ``lm.delta_rule``, ``lm.attention``,
 ``lm.mlp``, ``lm.head``; in a looped model also ``lm.loop`` (the scan
@@ -901,8 +902,11 @@ def _stack(params, h, s: HybridSpec, dt, attention: str, remat: bool,
     # lint: disable=FTL005 — remat and sa_config are static
     if remat and s.selection is not None:
         # the attention's output: the backward pass then runs the
-        # chunks' attention once, not twice (the thresholds are NOT
-        # kept here: ops/sparse_attention.py says why)
+        # dense chunks' attention once, not twice (a threshold is
+        # never kept without the scores it is compared with:
+        # ops/sparse_attention.py says why); of the fused form the
+        # name holds all that the backward rule reads of the forward
+        # pass, and no forward sweep runs again
         kept += sparse_attention.KEPT[1:]
     only = jax.checkpoint_policies.save_only_these_names
     # lint: disable=FTL005 — names or none: then the bare checkpoint
@@ -1051,6 +1055,19 @@ class HybridLM(NamedTuple):
         sel = self.module.selection
         return None if sel is None else sparse_attention.selected_share(
             tokens, sel.topk)
+
+    def selected_kernel_share(self, tokens: int) -> Optional[float]:
+        """The share of a step's selected-attention layer calls that
+        run the fused kernels on ``tokens``-long rows as the step is
+        traced here (every call has the same shapes: 0 or 1), from the
+        backend and shapes (``sparse_attention.takes_kernel``); None
+        without ``sa_config``."""
+        s = self.module
+        if s.selection is None:
+            return None
+        return float(sparse_attention.takes_kernel(
+            s.num_attention_heads, s.kv_heads, s.head_size,
+            sparse_attention.chunk_of(tokens, s.selection.chunk), tokens))
 
     def init(self, rng):
         return _jitted_init(self.module)(rng)

@@ -27,12 +27,27 @@ from __future__ import annotations
 # 32 times a step: its seconds are in PERF.md section 5. The selected
 # layers of PR 39's cell (32 query on 4 key heads of 128, 2048 keys a
 # query) take neither path: the flash kernel has no mask argument and
-# returns no probabilities, so ``ops/sparse_attention.py`` runs masked
-# dense query chunks of 512, whatever this constant says. Read there
-# (one chip, forward, recomputation and backward of a layer call):
-# T=4096 19.8 ms a call, 7.9 % of the SELECTED pairs' roofline; T=6144
-# 46.4 ms, 5.6 %; T=8192 91.7 ms, 4.0 %.
+# returns no probabilities, so ``ops/sparse_attention.py`` has its own
+# two forms, whatever this constant says. Since PR 40, on a TPU where
+# the shapes tile, the fused kernels of
+# ``ops/pallas/selected_attention.py`` (mask from the indexer's
+# threshold, online softmax, the heads' summed probabilities, a backward
+# kernel; bfloat16 operands to the MXU): T=4096 6.4 ms a layer call
+# (forward, the target's sweep twice, backward), 24.7 % of the SELECTED
+# pairs' roofline; T=8192 22.1 ms, 16.6 % (builder, PR 40). Elsewhere masked dense query chunks
+# of 512, which read there (one chip, forward, recomputation and
+# backward of a layer call, PR 39): T=4096 19.8 ms a call, 7.9 %;
+# T=6144 46.4 ms, 5.6 %; T=8192 91.7 ms, 4.0 %.
 FLASH_MIN_SEQ_LEN = 4096
+
+
+def on_tpu() -> bool:
+    """Whether the program is being built for a TPU: what decides, with
+    the shapes, that the selected layers run their fused kernels
+    (``ops/sparse_attention.py``: ``takes_kernel``) and that those are
+    compiled, not interpreted."""
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def resolve_attention(mode: str, seq_len: int) -> str:

@@ -1863,6 +1863,8 @@ class FederatedTrainer:
             else None
         if share is not None:
             out["lm_selected_share"] = share
+            out["lm_selected_kernel_share"] = \
+                self.model.selected_kernel_share(self.row_tokens)
         ss = self.stream_stats()
         if ss is not None:
             out.update(ss)

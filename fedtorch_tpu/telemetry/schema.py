@@ -111,6 +111,15 @@ METRICS_OPTIONAL = {
     "lm_selected_share": "token model under sa_config: selected over "
                          "causal query-key pairs of a row, from shapes "
                          "(sum_t min(t + 1, topk) over T (T + 1) / 2)",
+    "lm_selected_kernel_share": "token model under sa_config: share of "
+                                "the round's selected-attention layer "
+                                "calls that run the fused kernels "
+                                "(ops/pallas/selected_attention.py), "
+                                "from the backend and shapes when the "
+                                "round is traced: 1 on a TPU where the "
+                                "shapes tile, 0 for the masked dense "
+                                "form (ops/sparse_attention.py "
+                                "takes_kernel)",
     # stream plane (trainer.stream_stats)
     "stream_depth": "prefetched feeds ready at fetch time",
     "stream_wait_s": "consumer wall blocked on the feed queue (total)",

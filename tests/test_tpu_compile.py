@@ -147,15 +147,22 @@ def test_expert_share_compiles_to_grouped_kernels_at_the_cells_widths(
 
 
 def test_selected_attention_compiles_in_chunks_at_the_cells_length(
-        one_chip):
+        one_chip, monkeypatch):
     """The Keye cell's attention over selected keys at 4096 tokens (32
     query on 4 key heads of 128, an indexer of 16 heads of 64, 2048 keys
-    a query, chunks of 512), forward and backward: no array of a whole
-    row's scores lives (``[.., 4096, 4096]``), and the temporaries are
-    a chunk's, under 1 GiB where a row's scores alone are 2.1 GB."""
-    from fedtorch_tpu.ops import sparse_attention
+    a query, chunks of 512), forward and backward, as a TPU builds it:
+    the three fused kernels (``ops/pallas/selected_attention.py``) are
+    in the compiled program; no array of a whole row's scores lives
+    (``[.., 4096, 4096]``), none of a chunk's heads' float32 scores
+    (``[1, 4, 8, 512, keys]`` or ``[32, 512, keys]``), and the temporaries
+    stay under 1 GiB where a row's scores alone are 2.1 GB."""
+    from fedtorch_tpu.ops import attention_dispatch, sparse_attention
 
+    # the program asks the backend, which is the CPU here: answer for
+    # the chip the program is compiled for
+    monkeypatch.setattr(attention_dispatch, "on_tpu", lambda: True)
     T = 4096
+    assert sparse_attention.takes_kernel(32, 4, 128, 512, T)
     on_chip = lambda *shape: jax.ShapeDtypeStruct(
         shape, jnp.float32, sharding=one_chip)
     args = (on_chip(1, T, 32, 128), on_chip(1, T, 4, 128),
@@ -169,5 +176,11 @@ def test_selected_attention_compiles_in_chunks_at_the_cells_length(
 
     compiled = jax.jit(jax.grad(loss, argnums=range(6))).lower(
         *args).compile()
-    assert not re.search(r"4096,4096\]", compiled.as_text())
+    text = compiled.as_text()
+    kernels = set(re.findall(r"selected_attention_(?:fwd|target|bwd)",
+                             text))
+    assert len(kernels) == 3 and "tpu_custom_call" in text, kernels
+    assert not re.search(r"4096,4096\]", text)
+    # (the rows' statistics are [1,4,8,512,8]: eight lanes, not keys)
+    assert not re.search(r"f32\[(?:1,4,8,512|32,512),\d{4}", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
