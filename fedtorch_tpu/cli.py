@@ -1262,6 +1262,13 @@ def run_experiment(cfg: ExperimentConfig,
                     row["lm_moe_pairs_local"] = sc["lm_moe_pairs_local"]
                     row["lm_moe_load_max_over_mean"] = \
                         sc["lm_moe_load_max_over_mean"]
+                if "lm_balance_loss" in sc:
+                    # a biased router's load, bias and balance part
+                    row["lm_router_load_max_over_mean"] = \
+                        sc["lm_router_load_max_over_mean"]
+                    row["lm_router_bias_abs_max"] = \
+                        sc["lm_router_bias_abs_max"]
+                    row["lm_balance_loss"] = sc["lm_balance_loss"]
                 if accountant is not None:
                     # host-side accountant read: pure f64 math, no sync
                     row["dp_epsilon_spent"] = accountant.epsilon()

@@ -116,6 +116,10 @@ class RoundMetrics(NamedTuple):
     lm_moe_pairs_local: Any = None         # scalar — pairs computed here
     lm_moe_load_max_over_mean: Any = None  # scalar — fullest held expert
     lm_index_loss: Any = None              # scalar — the indexers' L_I
+    # a biased router's gauges (DeepSeek-V3's ``noaux_tc``), likewise
+    lm_router_load_max_over_mean: Any = None   # scalar — over ALL routed
+    lm_router_bias_abs_max: Any = None         # scalar — largest |b|
+    lm_balance_loss: Any = None                # scalar — L_B's value: 0
 
 
 def tree_where(pred, on_true, on_false):

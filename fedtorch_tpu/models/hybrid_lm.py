@@ -3,10 +3,12 @@ of a public ``config.json`` (``hidden_size``, ``intermediate_size``,
 ``num_hidden_layers``, ``layer_types``, ``num_attention_heads``,
 ``num_key_value_heads``, ``head_dim``, ``vocab_size``,
 ``rms_norm_eps``; ``linear_*`` where a layer is a ``linear_attention``
-one; ``num_experts`` ... and ``sa_config`` as below), as the
-Olmo-Hybrid, the Ouro and the Keye-VL-2.0 (Qwen3-MoE) families state
-them. ``layer_types`` names each layer's mixer (absent: every layer a
-``full_attention`` one):
+one; ``num_experts`` ... and ``sa_config``, or ``kv_lora_rank`` ... and
+``n_routed_experts`` ... as below), as the Olmo-Hybrid, the Ouro, the
+Keye-VL-2.0 (Qwen3-MoE) and the kanana-2 (``deepseek_v3``) families
+state them. ``layer_types`` names each layer's mixer (absent: every
+layer a ``full_attention`` one, of a ``deepseek_v3`` file a
+``latent_attention`` one):
 
 * ``linear_attention`` — the gated delta rule (``ops/delta_rule.py``)
   behind a causal depthwise convolution, with an output gate;
@@ -15,18 +17,34 @@ them. ``layer_types`` names each layer's mixer (absent: every layer a
   ``num_attention_heads`` query heads of ``head_dim`` (default
   ``hidden_size / num_attention_heads``; the heads together need not be
   ``hidden_size`` wide) on ``num_key_value_heads`` key and value heads
-  (default: as many; key head ``j`` serves query heads ``j H/KV ..``).
+  (default: as many; key head ``j`` serves query heads ``j H/KV ..``);
+* ``latent_attention`` — DeepSeek-V2/V3's latent attention without the
+  low-rank query path (``model_type`` ``deepseek_v3``; ``kv_lora_rank``,
+  ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+  ``rope_interleave``; ``q_lora_rank`` null): ``q = W_q u`` in heads of
+  ``nope + rope``; ``[c | k_r] = W_a u`` with the ``kv_lora_rank``-wide
+  latent ``c`` under an RMSNorm of its own and ONE rotary key head
+  ``k_r`` for all query heads; ``[k_n | v] = W_b c'`` in heads of
+  ``nope + v_head_dim``; the rotary parts turned (interleaved pairs
+  ``(2i, 2i + 1)`` where the file says so: :func:`pairs_apart` lays
+  them out as rotate-half has them, which no dot product sees); causal
+  softmax of ``q . [k_n | k_r] / sqrt(nope + rope)`` through the same
+  dispatch, the value heads ``v_head_dim`` wide (the flash kernel takes
+  a value head size of its own; nothing is padded); no QK-norm.
 
-Every layer ends in a SwiGLU MLP or in an expert layer, the head is
-untied, nothing has a bias. Optional keys choose the rest:
+Every layer ends in a SwiGLU MLP or in an expert layer (chosen PER
+LAYER in a ``deepseek_v3`` file), the head is untied, nothing has a
+bias. Optional keys choose the rest:
 
 * ``model_type`` — the family's block. ``ouro``: sandwich norms (an
   RMSNorm on each sublayer's input and one on its output, four scales
   a layer) and no QK-norm; ``KeyeVL2``: pre-norm (a sublayer reads the
-  normed stream and adds to the bare one, two scales a layer) and an
-  RMSNorm over each HEAD of q and k; anything else: norms on each
-  sublayer's OUTPUT alone and an RMSNorm over the whole projected q
-  and k (the Olmo 2/3 placement);
+  normed stream and adds to the bare one, two scales a layer:
+  ``PRENORM_BLOCKS``) and an RMSNorm over each HEAD of q and k
+  (``HEAD_NORM_BLOCKS``: two flags of the spec, ``prenorm`` and
+  ``head_norm``); ``deepseek_v3``: pre-norm and no QK-norm; anything
+  else: norms on each sublayer's OUTPUT alone and an RMSNorm over the
+  whole projected q and k (the Olmo 2/3 placement);
 * ``rope_theta`` (top level, or under ``rope_parameters``) — rotary
   position embedding on q and k of the full-attention layers
   (rotate-half, positions ``0..T-1``, angles in float32); absent or
@@ -55,6 +73,31 @@ untied, nothing has a bias. Optional keys choose the rest:
   routing). A layer's experts are one stacked leaf a matrix.
   ``decoder_sparse_step`` other than 1 and a non-empty
   ``mlp_only_layers`` (dense layers among them) are refused;
+* of a ``deepseek_v3`` file ``n_routed_experts`` (the experts HELD;
+  ``published.n_routed_experts`` the router's width,
+  ``first_expert_held`` the first one) with ``num_experts_per_tok``,
+  ``moe_intermediate_size``, ``n_shared_experts``,
+  ``first_k_dense_replace``, ``scoring_func``, ``topk_method``,
+  ``norm_topk_prob``, ``routed_scaling_factor`` — the first
+  ``first_k_dense_replace`` layers' feed-forward is a dense SwiGLU of
+  ``intermediate_size``, every other layer's one chip's share of
+  DeepSeek-V3's expert layer: ``s = sigmoid(W_r u)`` from a float32
+  product, the ``num_experts_per_tok`` largest of ``s + b`` chosen
+  (``topk_method`` ``noaux_tc``: ``b`` one float32 ``[routed]`` leaf a
+  layer that no gradient of ``CE`` reaches), the
+  gates ``routed_scaling_factor x s_e / (sum of the chosen s + 1e-20)``,
+  the held experts' part of the sum, and beside it ONE shared expert, a
+  SwiGLU of ``n_shared_experts x moe_intermediate_size``, which every
+  chip computes whole. ``balance_loss_coef`` (``u``, default 0) scales
+  the balance part of the training loss, ``L_B - stop_gradient(L_B)``
+  with ``L_B = -u sum_layers sum_e b_e stop_gradient(sign(mean(c) -
+  c_e))`` and ``c`` the step's token-expert pairs over ALL routed
+  experts: value zero, gradient DeepSeek-V3's auxiliary-loss-free
+  update of ``b`` (plain SGD at ``lr`` moves ``b_e`` by ``lr x u``
+  towards the mean load) and nothing else. ``n_group`` / ``topk_group``
+  other than 1, ``moe_layer_freq`` other than 1, a ``scoring_func``
+  other than ``sigmoid`` or ``softmax``, another ``topk_method`` and a
+  ``q_lora_rank`` that is not null are refused;
 * ``sa_config`` (pre-norm block only: ``indexer_num_heads``,
   ``indexer_head_dim``, ``topk``, ``q_chunk_size``;
   ``indexer_num_kv_heads`` 1) — every full-attention layer reads a
@@ -77,12 +120,15 @@ untied, nothing has a bias. Optional keys choose the rest:
   does.
 
 Still refused by name: tied embeddings, attention biases, any other
-``rope_scaling``, an odd ``head_dim``, query heads that are no multiple
-of the key heads, grouped VALUE heads in a linear-attention layer.
+``rope_scaling``, an odd ``head_dim`` (or rotary part of a latent head),
+query heads that are no multiple of the key heads, grouped VALUE heads
+in a linear-attention layer, a low-rank query path, grouped routing,
+latent attention outside a ``deepseek_v3`` file.
 
-``benchmark/reference/olmo_hybrid.py``, ``benchmark/reference/ouro.py``
-and ``benchmark/reference/keye_vl2.py`` write the same equations out in
-plain float32 and list what the public configs leave open.
+``benchmark/reference/olmo_hybrid.py``, ``benchmark/reference/ouro.py``,
+``benchmark/reference/keye_vl2.py`` and ``benchmark/reference/kanana2.py``
+write the same equations out in plain float32 and list what the public
+configs leave open.
 
 Pure functions over a nested dict of float32 parameters; every layer
 is a subtree of its own (``layer_<i>``: no stacked scan over layers, so
@@ -91,12 +137,16 @@ layer's gradient is a sum over the passes, which the scan over passes
 accumulates). Products take bfloat16 operands where the launcher's
 ``compute_dtype`` says so and accumulate in float32; the residual
 stream, norms, rotary angles, softmax, decays, the convolution, the
-exit gate, router probabilities and gates, the indexer's weighted sum
-and the loss are float32.
+exit gate, router probabilities, scores and gates (and a sigmoid
+router's own product, at the highest precision), the indexer's weighted
+sum and the loss are float32.
 
 With ``remat`` each layer runs under ``jax.checkpoint``, which keeps
 the layer's input and, of the layer's matrix products (each result
-carries a name: ``mixer.q`` ... ``mlp.down``, :func:`layer_products`),
+carries a name: ``mixer.q`` ... ``mlp.down``; a latent layer's
+``mixer.q`` / ``kv_a`` / ``kv_b`` / ``o``, a shared expert's
+``shared.*``, a dense layer's among expert layers ``dense.*``:
+:func:`layer_products`),
 the float32 results that fit in the device's memory; the backward pass
 runs the rest again: norms, activations, gates, the convolution, the
 rotary turn, softmax and the delta rule. A product kept and a product
@@ -123,7 +173,12 @@ scores, the selection and the KL term) beside ``lm.attention`` (the
 masked attention of the chunks and the heads' summed probabilities);
 in an expert layer ``lm.router`` (the router's product, softmax and
 top-k, the sort and the buffer's fill) and ``lm.experts`` (the grouped
-products and the combine) in ``lm.mlp``'s place.
+products and the combine) in ``lm.mlp``'s place; in a latent-attention
+layer ``lm.latent`` (all of the sublayer but its softmax attention: the
+four products, the latent's norm, the rotary turn) beside
+``lm.attention``; beside a biased router's ``lm.router`` (which also
+holds the load's count and the balance part) ``lm.shared`` (the shared
+expert), and ``lm.mlp`` for the leading dense layers.
 """
 from __future__ import annotations
 
@@ -137,17 +192,19 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from fedtorch_tpu.ops import routed_experts, sparse_attention
-from fedtorch_tpu.ops.attention_dispatch import resolve_attention
+from fedtorch_tpu.ops.attention_dispatch import on_tpu, resolve_attention
 from fedtorch_tpu.ops.delta_rule import chunk_gated_delta_rule
 
-LAYER_KINDS = ("linear_attention", "full_attention")
+LAYER_KINDS = ("linear_attention", "full_attention", "latent_attention")
 INIT_STD = 0.02
 # ``model_type`` values whose block differs from the Olmo placement
 SANDWICH_BLOCKS = ("ouro",)
 # ``model_type`` values whose block is pre-norm (a sublayer reads the
-# normed stream and adds to the bare one) with an RMSNorm over each
+# normed stream and adds to the bare one, two scales a layer) ...
+PRENORM_BLOCKS = ("KeyeVL2", "deepseek_v3")
+# ... and those whose full-attention layers have an RMSNorm over each
 # head of q and k
-PRENORM_BLOCKS = ("KeyeVL2",)
+HEAD_NORM_BLOCKS = ("KeyeVL2",)
 # of a rematerialized step's memory, how many times the widest layer's
 # product results and the head's logits are set aside for the one
 # layer and head at work (:func:`residual_budget`)
@@ -156,13 +213,19 @@ WORKING_SETS = 5
 # so the forward pass writes them whether they are kept or not (an input
 # product's result feeds an epilogue the product fuses): first among
 # products of equal inner dimension (:func:`kept_products`)
-SUBLAYER_OUTPUTS = ("mixer.o", "mlp.down")
+SUBLAYER_OUTPUTS = ("mixer.o", "mlp.down", "dense.down")
 # the file's keys: asked of every file, and of one with a
 # ``linear_attention`` layer
 PLAIN_KEYS = ("vocab_size", "hidden_size", "intermediate_size",
               "layer_types", "num_attention_heads", "rms_norm_eps")
 # ... of one with ``num_experts``, and of its ``sa_config``
 EXPERT_KEYS = ("num_experts_per_tok", "moe_intermediate_size")
+# ... of a ``deepseek_v3`` file: its latent attention and its experts
+LATENT_KEYS = ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+               "v_head_dim")
+DEEPSEEK_EXPERT_KEYS = EXPERT_KEYS + (
+    "n_routed_experts", "n_shared_experts", "first_k_dense_replace",
+    "scoring_func", "topk_method", "routed_scaling_factor")
 SELECTION_KEYS = ("indexer_num_heads", "indexer_head_dim", "topk",
                   "q_chunk_size")
 # the keys a ``rope_scaling`` record may hold: the default rotary
@@ -184,6 +247,27 @@ class Experts(NamedTuple):
     per_token: int
     width: int
     normalise: bool
+    # DeepSeek-V3's router and layer (``_deepseek_experts_of``); the
+    # defaults are the softmax router with nothing beside it
+    scoring: str = "softmax"    # 'softmax' | 'sigmoid' (float32 product)
+    scale: float = 1.0          # ``routed_scaling_factor`` on the gates
+    biased: bool = False        # ``noaux_tc``: the choice by score + bias
+    balance: float = 0.0        # ``u`` of the balance part ``L_B``
+    shared: int = 0             # the shared experts' width, together
+    dense_first: int = 0        # leading layers whose feed-forward is dense
+
+
+class Latent(NamedTuple):
+    """Latent attention (``kv_lora_rank``): the key/value path's rank,
+    the head's sizes without and with rotary embedding (a query/key
+    head is both, side by side), the value head's size, and whether
+    the rotary pairs are interleaved (``(2i, 2i + 1)``) in the
+    weights' own layout."""
+    rank: int
+    nope: int
+    rope: int
+    value: int
+    interleave: bool
 
 
 class Selection(NamedTuple):
@@ -215,10 +299,12 @@ class HybridSpec(NamedTuple):
     exit_entropy_beta: float = 0.05
     num_key_value_heads: int = 0    # 0: as many as query heads
     head_dim: int = 0               # 0: hidden_size / query heads
-    prenorm: bool = False       # the block: pre-norm, per-head QK-norm
-    experts: Optional[Experts] = None       # every layer's feed-forward
+    prenorm: bool = False       # the block: pre-norm
+    experts: Optional[Experts] = None       # the layers' feed-forward
     selection: Optional[Selection] = None   # every full-attention layer
     embedding_init_std: float = INIT_STD    # the seeded embedding rows'
+    head_norm: bool = False     # an RMSNorm over each head of q and k
+    latent: Optional[Latent] = None     # every latent-attention layer
 
     @property
     def looped(self) -> bool:
@@ -268,6 +354,78 @@ def _experts_of(path: str, doc: dict) -> Optional[Experts]:
                    bool(doc.get("norm_topk_prob", False)))
 
 
+def _deepseek_experts_of(path: str, doc: dict) -> Experts:
+    """The expert layers of a ``deepseek_v3`` file: ``n_routed_experts``
+    the experts held here, ``published.n_routed_experts`` the router's
+    width (the file's own count where nothing was cut),
+    ``first_expert_held`` the first one held; the first
+    ``first_k_dense_replace`` layers dense. ``balance_loss_coef`` is
+    ``u`` of the balance part (default 0: the bias then stays where it
+    is)."""
+    missing = [k for k in DEEPSEEK_EXPERT_KEYS + ("num_hidden_layers",)
+               if k not in doc]
+    if missing:
+        raise ValueError(f"model specification {path!r} lacks {missing}")
+    refusals = (
+        ("moe_layer_freq", doc.get("moe_layer_freq", 1) != 1,
+         "dense layers among the expert layers are not written "
+         "(moe_layer_freq 1)"),
+        ("n_group / topk_group",
+         (doc.get("n_group", 1), doc.get("topk_group", 1)) != (1, 1),
+         "grouped routing is not written (n_group 1, topk_group 1: the "
+         "group step then selects everything)"),
+        ("scoring_func", doc["scoring_func"] not in ("sigmoid", "softmax"),
+         f"{doc['scoring_func']!r} is not written (sigmoid, softmax)"),
+        ("topk_method", doc["topk_method"] != "noaux_tc",
+         f"{doc['topk_method']!r} is not written (noaux_tc: the choice "
+         "by score plus a per-expert bias)"),
+    )
+    for key, refused, why in refusals:
+        if refused:
+            raise ValueError(f"model specification {path!r}: {key}: {why}")
+    held = int(doc["n_routed_experts"])
+    routed = int((doc.get("published") or {}).get("n_routed_experts", held))
+    first = int(doc.get("first_expert_held", 0))
+    per_token = int(doc["num_experts_per_tok"])
+    dense = int(doc["first_k_dense_replace"])
+    if first < 0 or first + held > routed or not 0 < per_token <= routed \
+            or not 0 <= dense <= int(doc["num_hidden_layers"]):
+        raise ValueError(
+            f"model specification {path!r}: experts {first}.."
+            f"{first + held - 1} held and {per_token} a token do not lie "
+            f"within the router's {routed}, or first_k_dense_replace "
+            f"{dense} not within the layers")
+    return Experts(
+        routed, held, first, per_token, int(doc["moe_intermediate_size"]),
+        bool(doc.get("norm_topk_prob", False)),
+        scoring=doc["scoring_func"],
+        scale=float(doc["routed_scaling_factor"]),
+        biased=True,
+        balance=float(doc.get("balance_loss_coef", 0.0)),
+        shared=int(doc["n_shared_experts"])
+        * int(doc["moe_intermediate_size"]),
+        dense_first=dense)
+
+
+def _latent_of(path: str, doc: dict) -> Latent:
+    missing = [k for k in LATENT_KEYS if k not in doc]
+    if missing:
+        raise ValueError(f"model specification {path!r} lacks {missing}")
+    if doc.get("q_lora_rank") is not None:
+        raise ValueError(
+            f"model specification {path!r}: q_lora_rank "
+            f"{doc['q_lora_rank']}: a low-rank query path is not written "
+            "(q_lora_rank null: one full query projection)")
+    rope = int(doc["qk_rope_head_dim"])
+    if rope % 2:
+        raise ValueError(
+            f"model specification {path!r}: qk_rope_head_dim {rope} is "
+            "odd (the rotary embedding turns pairs)")
+    return Latent(int(doc["kv_lora_rank"]), int(doc["qk_nope_head_dim"]),
+                  rope, int(doc["v_head_dim"]),
+                  bool(doc.get("rope_interleave", False)))
+
+
 def _selection_of(path: str, doc: dict) -> Optional[Selection]:
     sa = doc.get("sa_config")
     if not sa:
@@ -299,11 +457,15 @@ def load_spec(path: str) -> HybridSpec:
     the feed-forward is dense."""
     with open(path) as f:
         doc = json.load(f)
-    experts = _experts_of(path, doc)
+    deepseek = doc.get("model_type") == "deepseek_v3"
+    latent = _latent_of(path, doc) if deepseek else None
+    experts = _deepseek_experts_of(path, doc) if deepseek \
+        else _experts_of(path, doc)
     if "num_hidden_layers" in doc:
-        doc.setdefault("layer_types",
-                       ["full_attention"] * int(doc["num_hidden_layers"]))
-    if experts is not None:
+        doc.setdefault("layer_types", [
+            "latent_attention" if deepseek else "full_attention"]
+            * int(doc["num_hidden_layers"]))
+    if experts is not None and not experts.dense_first:
         doc.setdefault("intermediate_size", 0)
     missing = [k for k in PLAIN_KEYS + ("num_hidden_layers",)
                if k not in doc]
@@ -315,6 +477,12 @@ def load_spec(path: str) -> HybridSpec:
         raise ValueError(
             f"model specification {path!r}: layer_types must name "
             f"num_hidden_layers layers, each one of {LAYER_KINDS}")
+    if ("latent_attention" in kinds) != (
+            latent is not None and len(set(kinds)) == 1):
+        raise ValueError(
+            f"model specification {path!r}: latent_attention layers are "
+            "written for model_type 'deepseek_v3' (kv_lora_rank ...), "
+            "whose every layer is one")
     linear = "linear_attention" in kinds
     missing = [k for k in LINEAR_KEYS if linear and k not in doc]
     if missing:
@@ -380,7 +548,9 @@ def load_spec(path: str) -> HybridSpec:
         exit_entropy_beta=float(doc.get("exit_entropy_beta", 0.05)),
         num_key_value_heads=kv_heads, head_dim=head_dim,
         prenorm=prenorm, experts=experts, selection=selection,
-        embedding_init_std=embed_std)
+        embedding_init_std=embed_std,
+        head_norm=doc.get("model_type") in HEAD_NORM_BLOCKS,
+        latent=latent)
 
 
 def _linear_shapes(s: HybridSpec) -> dict:
@@ -401,7 +571,7 @@ def _full_shapes(s: HybridSpec) -> dict:
     if s.sandwich:
         return proj
     # lint: disable=FTL005 — the block is a flag of the spec
-    if s.prenorm:
+    if s.head_norm:
         proj.update(q_norm=(hd,), k_norm=(hd,))     # over each head
     else:
         proj.update(q_norm=(q,), k_norm=(kv,))      # over all of q, of k
@@ -413,15 +583,45 @@ def _full_shapes(s: HybridSpec) -> dict:
     return proj
 
 
-def _mlp_shapes(s: HybridSpec) -> dict:
+def _latent_shapes(s: HybridSpec) -> dict:
+    d, h, l = s.hidden_size, s.num_attention_heads, s.latent
+    return {"wq": (d, h * (l.nope + l.rope)),
+            "wkv_a": (d, l.rank + l.rope), "kv_a_norm": (l.rank,),
+            "wkv_b": (l.rank, h * (l.nope + l.value)),
+            "wo": (h * l.value, d)}
+
+
+def _mixer_shapes(s: HybridSpec, kind: str) -> dict:
+    return {"linear_attention": _linear_shapes,
+            "latent_attention": _latent_shapes,
+            "full_attention": _full_shapes}[kind](s)
+
+
+def dense_layer(s: HybridSpec, i: int) -> bool:
+    """Layer ``i``'s feed-forward is a dense SwiGLU (every layer's
+    without experts; the first ``first_k_dense_replace`` with)."""
+    return s.experts is None or i < s.experts.dense_first
+
+
+def _swiglu_shapes(d: int, f: int) -> dict:
+    return {"gate": (d, f), "up": (d, f), "down": (f, d)}
+
+
+def _mlp_shapes(s: HybridSpec, dense: bool = True) -> dict:
     d, e = s.hidden_size, s.experts
     # lint: disable=FTL005 — experts or a dense feed-forward, by the spec
-    if e is None:
-        f = s.intermediate_size
-        return {"gate": (d, f), "up": (d, f), "down": (f, d)}
+    if e is None or dense:
+        return _swiglu_shapes(d, s.intermediate_size)
     # the experts held here are one stacked leaf a matrix
-    return {"router": (d, e.routed), "gate": (e.held, d, e.width),
+    tree = {"router": (d, e.routed), "gate": (e.held, d, e.width),
             "up": (e.held, d, e.width), "down": (e.held, e.width, d)}
+    # lint: disable=FTL005 — flags of the spec
+    if e.biased:
+        tree["router_bias"] = (e.routed,)
+    # lint: disable=FTL005 — a width of the spec
+    if e.shared:
+        tree["shared"] = _swiglu_shapes(d, e.shared)
+    return tree
 
 
 def param_shapes(s: HybridSpec) -> dict:
@@ -433,10 +633,9 @@ def param_shapes(s: HybridSpec) -> dict:
         tree["exit_gate"] = {"w": (d,), "b": (1,)}
     for i, kind in enumerate(s.layer_types):
         tree[f"layer_{i}"] = {
-            "mixer": _linear_shapes(s) if kind == "linear_attention"
-            else _full_shapes(s),
+            "mixer": _mixer_shapes(s, kind),
             "mixer_norm": (d,), "mlp_norm": (d,),
-            "mlp": _mlp_shapes(s)}
+            "mlp": _mlp_shapes(s, dense_layer(s, i))}
         # lint: disable=FTL005 — the block is a flag of the spec
         if s.sandwich:
             tree[f"layer_{i}"].update(mixer_in_norm=(d,), mlp_in_norm=(d,))
@@ -446,7 +645,8 @@ def param_shapes(s: HybridSpec) -> dict:
 def init_params(spec: HybridSpec, rng) -> Any:
     """Seeded float32 parameters: matrices normal(0, 0.02) (the
     embedding's rows ``embedding_init_std`` where the file gives it),
-    norm scales 1, the exit gate's bias 0, the convolution
+    norm scales 1, the exit gate's and the routers' biases 0, the
+    convolution
     uniform(+-1/sqrt(taps)), decay rates ``exp(a_log)`` spread over
     1..16 and time steps ``softplus(dt_bias)`` log-spread over
     0.001..0.1 across the heads (the delta-rule family's own
@@ -459,7 +659,7 @@ def init_params(spec: HybridSpec, rng) -> Any:
         key = jax.random.fold_in(rng, i)
         if name.endswith("norm"):
             leaf = jnp.ones(shape, jnp.float32)
-        elif name == "b":
+        elif name in ("b", "router_bias"):
             leaf = jnp.zeros(shape, jnp.float32)
         elif name == "a_log":
             leaf = jnp.log(jnp.linspace(1.0, 16.0, shape[0]))
@@ -514,6 +714,16 @@ def _dot(x, w, dt, name: Optional[str] = None):
     return out if name is None else checkpoint_name(out, name)
 
 
+def _dot32(x, w, name: str):
+    """``x @ w`` in float32 at the highest precision (DeepSeek-V3's
+    router: the published code runs that product in float32), the
+    result named as :func:`_dot`'s."""
+    out = jnp.matmul(x.astype(jnp.float32), w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    return checkpoint_name(out, name)
+
+
 def rotary_tables(positions, head_dim: int, theta: float):
     """(cos, sin), each ``[T, head_dim]`` float32, of the rotate-half
     rotary embedding at ``positions`` [T]: pair ``i`` of a head
@@ -531,6 +741,16 @@ def _rotate(x, rope):
     cos, sin = (t[None, :, None, :] for t in rope)
     a, b = jnp.split(x, 2, axis=-1)
     return x * cos + jnp.concatenate([-b, a], axis=-1) * sin
+
+
+def pairs_apart(x):
+    """The last axis' interleaved pairs ``(2i, 2i + 1)`` laid out as
+    rotate-half has them, ``(i, i + n/2)``: the even elements, then the
+    odd ones. Applied to a query's and to a key's rotary part alike it
+    leaves every dot product where it was, so the interleaved rotary
+    embedding (``rope_interleave``) of the published weights is
+    ``_rotate`` of this layout."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
 def _linear_attention(p, x, s: HybridSpec, dt):
@@ -567,7 +787,7 @@ def _projected(p, x, s: HybridSpec, dt, rope, out):
     def project(name, norm, n):
         t = _dot(x, p[name], dt, "mixer." + name[1:])
         # lint: disable=FTL005 — the block is a flag of the spec
-        if norm and s.prenorm:
+        if norm and s.head_norm:
             return _rms_norm(t.reshape(B, T, n, hd), p[norm],
                              s.rms_norm_eps).reshape(B, T, n * hd)
         # lint: disable=FTL005 — the block is a flag of the spec
@@ -587,6 +807,29 @@ def _projected(p, x, s: HybridSpec, dt, rope, out):
     return heads(q, h, True), heads(k, kv, True), heads(v, kv, False)
 
 
+def _softmax_attention(q, k, v, dt, attention: str):
+    """Causal softmax attention of ``q``, ``k`` [B, T, H, hd] and ``v``
+    [B, T, H, vd] (``vd`` the value head's own size) -> float32 or
+    ``dt`` [B, T, H, vd], scores scaled by ``hd ** -0.5``: the flash
+    kernel or the dense form, by ``attention`` and ``T``."""
+    T, hd = q.shape[1], q.shape[-1]
+    with jax.named_scope("lm.attention"):
+        # lint: disable=FTL005 — a static mode string and a static length
+        if resolve_attention(attention, T) == "flash":
+            from fedtorch_tpu.ops.pallas.flash_attention import (
+                flash_attention,
+            )
+            return flash_attention(q, k, v, causal=True)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32) \
+            / math.sqrt(hd)
+        mask = jnp.tril(jnp.ones((T, T), bool))
+        probs = jax.nn.softmax(
+            jnp.where(mask[None, None], scores, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(dt), v,
+                          preferred_element_type=jnp.float32)
+
+
 def _full_attention(p, x, s: HybridSpec, dt, attention: str, rope=None):
     B, T, _ = x.shape
     h, hd = s.num_attention_heads, s.head_size
@@ -595,23 +838,42 @@ def _full_attention(p, x, s: HybridSpec, dt, attention: str, rope=None):
     if s.kv_heads != h:
         # grouped heads: query head i reads key head i // (h / kv)
         k, v = (jnp.repeat(t, h // s.kv_heads, axis=2) for t in (k, v))
-    with jax.named_scope("lm.attention"):
-        # lint: disable=FTL005 — a static mode string and a static length
-        if resolve_attention(attention, T) == "flash":
-            from fedtorch_tpu.ops.pallas.flash_attention import (
-                flash_attention,
-            )
-            out = flash_attention(q, k, v, causal=True)
-        else:
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                                preferred_element_type=jnp.float32) \
-                / math.sqrt(hd)
-            mask = jnp.tril(jnp.ones((T, T), bool))
-            probs = jax.nn.softmax(
-                jnp.where(mask[None, None], scores, -jnp.inf), axis=-1)
-            out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(dt), v,
-                             preferred_element_type=jnp.float32)
+    out = _softmax_attention(q, k, v, dt, attention)
     return _dot(out.reshape(B, T, h * hd), p["wo"], dt, "mixer.o")
+
+
+def _latent_attention(p, x, s: HybridSpec, dt, attention: str, rope):
+    """A latent-attention layer (DeepSeek-V2/V3's, without the low-rank
+    query path): ``q = W_q u`` in H heads of ``nope + rope``;
+    ``[c | k_r] = W_a u``, ``c`` the ``rank``-wide latent, which gets an
+    RMSNorm of its own, and ``k_r`` ONE rotary key head shared by all
+    query heads; ``[k_n | v] = W_b c'`` in H heads of ``nope + value``;
+    the rotary parts turned (interleaved pairs where the file says so),
+    ``k = [k_n | k_r]``; causal softmax of ``q . k / sqrt(nope + rope)``
+    over value heads of ``value``; ``W_o``. All of it but the softmax
+    attention runs under the scope ``lm.latent``."""
+    B, T, _ = x.shape
+    h, l = s.num_attention_heads, s.latent
+    with jax.named_scope("lm.latent"):
+        q = _dot(x, p["wq"], dt, "mixer.q").reshape(B, T, h,
+                                                    l.nope + l.rope)
+        a = _dot(x, p["wkv_a"], dt, "mixer.kv_a")
+        c = _rms_norm(a[..., :l.rank], p["kv_a_norm"], s.rms_norm_eps)
+        kv = _dot(c, p["wkv_b"], dt, "mixer.kv_b").reshape(
+            B, T, h, l.nope + l.value)
+        q_r, k_r = q[..., l.nope:], a[..., l.rank:].reshape(B, T, 1, l.rope)
+        # lint: disable=FTL005 — a flag of the spec
+        if l.interleave:
+            q_r, k_r = pairs_apart(q_r), pairs_apart(k_r)
+        q_r, k_r = _rotate(q_r, rope), _rotate(k_r, rope)
+        q = jnp.concatenate([q[..., :l.nope], q_r], axis=-1).astype(dt)
+        k = jnp.concatenate(
+            [kv[..., :l.nope], jnp.broadcast_to(k_r, (B, T, h, l.rope))],
+            axis=-1).astype(dt)
+        v = kv[..., l.nope:].astype(dt)
+    out = _softmax_attention(q, k, v, dt, attention)
+    with jax.named_scope("lm.latent"):
+        return _dot(out.reshape(B, T, h * l.value), p["wo"], dt, "mixer.o")
 
 
 def _selected_attention(p, x, s: HybridSpec, dt, rope):
@@ -647,25 +909,54 @@ def _selected_attention(p, x, s: HybridSpec, dt, rope):
 
 def _experts(p, x, s: HybridSpec, dt):
     """The expert layer's share (``ops/routed_experts.py``): (its
-    result [B, T, D], the layer call's routing counters)."""
+    result [B, T, D], the layer call's routing counters). DeepSeek-V3's
+    layer (sigmoid scores from a float32 product, the choice by score
+    plus ``router_bias``) also counts the step's pairs over ALL routed
+    experts, whose largest over their mean and the balance part of the
+    loss (``routed_experts.balance_step``) join the counters, and adds
+    the shared expert's SwiGLU, which every chip computes alike."""
     B, T, d = x.shape
     e = s.experts
     u = x.reshape(B * T, d)
     with jax.named_scope("lm.router"):
-        gates, chosen = routed_experts.route(
-            _dot(u, p["router"], dt, "mlp.router"), e.per_token,
-            e.normalise)
+        # lint: disable=FTL005 — the router's kind, by the spec
+        if e.biased:
+            bias = p["router_bias"]
+            gates, chosen = routed_experts.route(
+                _dot32(u, p["router"], "mlp.router"), e.per_token,
+                e.normalise, scoring=e.scoring, bias=bias, scale=e.scale)
+        else:
+            gates, chosen = routed_experts.route(
+                _dot(u, p["router"], dt, "mlp.router"), e.per_token,
+                e.normalise)
     out, counters = routed_experts.expert_share(
         p, u, gates, chosen, first=e.first, dt=dt,
         scopes=("lm.router", "lm.experts"))
+    # lint: disable=FTL005 — flags of the spec
+    if e.biased:
+        with jax.named_scope("lm.router"):
+            load = routed_experts.load_over_all(chosen, e.routed)
+            counters.update(
+                router_load_max_over_mean=jnp.max(load) / jnp.mean(load),
+                router_bias_abs_max=jnp.max(jnp.abs(bias)),
+                balance=routed_experts.balance_step(bias, load))
+    # lint: disable=FTL005 — a width of the spec
+    if e.shared:
+        out = out + _mlp(p["shared"], u, dt, "shared")
     return out.reshape(B, T, d), counters
 
 
-def _mlp(p, x, dt):
-    with jax.named_scope("lm.mlp"):
-        return _dot(jax.nn.silu(_dot(x, p["gate"], dt, "mlp.gate"))
-                    * _dot(x, p["up"], dt, "mlp.up"), p["down"], dt,
-                    "mlp.down")
+def _mlp(p, x, dt, name: str = "mlp"):
+    """A SwiGLU under the scope ``lm.<name>``, its products' results
+    named ``<name>.gate`` / ``up`` / ``down`` (``dense``: a dense layer
+    of a model whose other layers hold experts, whose grouped products
+    carry the ``mlp.`` names; its scope is ``lm.mlp``)."""
+    # lint: disable=FTL005 — a name the caller writes out
+    scope = "lm.mlp" if name == "dense" else "lm." + name
+    with jax.named_scope(scope):
+        return _dot(jax.nn.silu(_dot(x, p["gate"], dt, name + ".gate"))
+                    * _dot(x, p["up"], dt, name + ".up"), p["down"], dt,
+                    name + ".down")
 
 
 def _layer(p, x, kind: str, s: HybridSpec, dt, attention: str, rope=None):
@@ -692,15 +983,21 @@ def _layer(p, x, kind: str, s: HybridSpec, dt, attention: str, rope=None):
 
 
 def _prenorm_layer(p, x, kind: str, s: HybridSpec, dt, attention: str,
-                   rope=None):
+                   rope, dense: bool):
     """A pre-norm layer, ``a = x + Mixer(N_1(x))``, ``y = a +
     FF(N_2(a))``: (``y``, the layer call's parts: ``index_loss`` under
-    ``sa_config``, the routing counters under experts)."""
+    ``sa_config``, the routing counters of an expert layer). ``dense``
+    (:func:`dense_layer`): this layer's feed-forward is a dense
+    SwiGLU."""
     eps, parts = s.rms_norm_eps, {}
     u = _rms_norm(x, p["mixer_norm"], eps)
     # lint: disable=FTL005 — the layer's kind and sa_config, by the spec
     if kind == "linear_attention":
         x = x + _linear_attention(p["mixer"], u, s, dt)
+    # lint: disable=FTL005 — the layer's kind is a string of the spec
+    elif kind == "latent_attention":
+        x = x + _latent_attention(p["mixer"], u, s, dt, attention, rope)
+    # lint: disable=FTL005 — sa_config or none, by the spec
     elif s.selection is not None:
         o, parts["index_loss"] = _selected_attention(p["mixer"], u, s, dt,
                                                      rope)
@@ -709,11 +1006,12 @@ def _prenorm_layer(p, x, kind: str, s: HybridSpec, dt, attention: str,
         x = x + _full_attention(p["mixer"], u, s, dt, attention, rope)
     u = _rms_norm(x, p["mlp_norm"], eps)
     # lint: disable=FTL005 — experts or a dense feed-forward, by the spec
-    if s.experts is not None:
+    if not dense:
         o, counters = _experts(p["mlp"], u, s, dt)
         parts.update(counters)
         return x + o, parts
-    return x + _mlp(p["mlp"], u, dt), parts
+    return x + _mlp(p["mlp"], u, dt,
+                    "mlp" if s.experts is None else "dense"), parts
 
 
 def _rope(s: HybridSpec, T: int):
@@ -721,35 +1019,51 @@ def _rope(s: HybridSpec, T: int):
     ``rope_theta``."""
     if s.rope_theta is None:
         return None
-    return rotary_tables(jnp.arange(T), s.head_size, s.rope_theta)
+    turned = s.head_size if s.latent is None else s.latent.rope
+    return rotary_tables(jnp.arange(T), turned, s.rope_theta)
 
 
 # -- what a rematerialized layer keeps ---------------------------------------
 
-def layer_products(s: HybridSpec, kind: str) -> dict:
+def layer_products(s: HybridSpec, kind: str,
+                   dense: Optional[bool] = None) -> dict:
     """``{name: (K, N)}`` of a layer's matrix products ``[T, K] x
     [K, N]``, in the layer's own order: the names their results carry
-    (``_dot``'s ``name``). An expert layer's ``mlp.gate`` / ``up`` /
-    ``down`` are grouped products over the dispatch buffer, ``[T x
-    per_token, K] x [K, N]`` an expert (:func:`_product_totals`)."""
-    d = s.hidden_size
+    (``_dot``'s ``name``). ``dense`` (default: the model has no
+    experts): the layer's feed-forward is a dense SwiGLU, named
+    ``dense.*`` in a model whose other layers hold experts. An expert
+    layer's ``mlp.gate`` / ``up`` / ``down`` are grouped products over
+    the dispatch buffer, ``[T x per_token, K] x [K, N]`` an expert
+    (:func:`_product_totals`); its shared expert's are ``shared.*``."""
+    d, e = s.hidden_size, s.experts
+    dense = e is None if dense is None else dense
     # lint: disable=FTL005 — the layer's kind is a string of the spec
     if kind == "linear_attention":
         shapes = _linear_shapes(s)
         mixer = {"mixer." + n: shapes["w" + n] for n in "qkvbago"}
+    # lint: disable=FTL005 — the layer's kind is a string of the spec
+    elif kind == "latent_attention":
+        shapes = _latent_shapes(s)
+        mixer = {"mixer." + n: shapes["w" + n]
+                 for n in ("q", "kv_a", "kv_b", "o")}
     else:
         shapes = _full_shapes(s)
         mixer = {"mixer." + n: shapes["w" + n] for n in "qkvo"}
         mixer.update({"mixer." + n: shapes[n]
                       for n in ("index_q", "index_k", "index_w")
                       if n in shapes})
-    e = s.experts
-    f = s.intermediate_size if e is None else e.width
+    swiglu = lambda name, f: {name + ".gate": (d, f), name + ".up": (d, f),
+                              name + ".down": (f, d)}
     # lint: disable=FTL005 — experts or a dense feed-forward, by the spec
-    if e is not None:
-        mixer["mlp.router"] = (d, e.routed)
-    return dict(mixer, **{"mlp.gate": (d, f), "mlp.up": (d, f),
-                          "mlp.down": (f, d)})
+    if dense:
+        return dict(mixer, **swiglu("mlp" if e is None else "dense",
+                                    s.intermediate_size))
+    mixer["mlp.router"] = (d, e.routed)
+    mixer.update(swiglu("mlp", e.width))
+    # lint: disable=FTL005 — a width of the spec
+    if e.shared:
+        mixer.update(swiglu("shared", e.shared))
+    return mixer
 
 
 def _product_cost(s: HybridSpec, name: str, k: int, n: int):
@@ -764,12 +1078,18 @@ def _product_cost(s: HybridSpec, name: str, k: int, n: int):
     return 2 * k * n, n
 
 
+def _layers_products(s: HybridSpec):
+    """Each layer's :func:`layer_products`, in the stack's order."""
+    return [layer_products(s, kind, dense_layer(s, i))
+            for i, kind in enumerate(s.layer_types)]
+
+
 def _product_totals(s: HybridSpec) -> dict:
     """``{name: (FLOPs, float32 result bytes)}`` a token and pass, summed
     over the layers that have a product of that name."""
     totals: dict = {}
-    for kind in s.layer_types:
-        for name, (k, n) in layer_products(s, kind).items():
+    for products in _layers_products(s):
+        for name, (k, n) in products.items():
             flops, size = totals.get(name, (0, 0))
             cost, floats = _product_cost(s, name, k, n)
             totals[name] = (flops + cost, size + 4 * floats)
@@ -855,8 +1175,8 @@ def residual_budget(s: HybridSpec, rows: int, tokens: int,
         held += 6 * count([v for k, v in shapes.items()
                            if k.startswith("layer_")])
     widest = max(sum(_product_cost(s, name, k, n)[1] for name, (k, n)
-                     in layer_products(s, kind).items())
-                 for kind in set(s.layer_types))
+                     in products.items())
+                 for products in _layers_products(s))
     reserve = 4 * rows * tokens * (
         s.hidden_size * len(s.layer_types) * s.total_ut_steps
         + WORKING_SETS * (widest + s.vocab_size))
@@ -911,11 +1231,15 @@ def _stack(params, h, s: HybridSpec, dt, attention: str, remat: bool,
     only = jax.checkpoint_policies.save_only_these_names
     # lint: disable=FTL005 — names or none: then the bare checkpoint
     policy = only(*kept) if kept else None
-    # lint: disable=FTL005 — the block is a flag of the spec
-    layer, parts = (_prenorm_layer if s.prenorm else _layer), []
+    parts = []
     for i, kind in enumerate(s.layer_types):
-        fn = lambda p, h, kind=kind: layer(p, h, kind, s, dt, attention,
-                                           rope)
+        # lint: disable=FTL005 — the block is a flag of the spec
+        if s.prenorm:
+            fn = lambda p, h, kind=kind, dense=dense_layer(s, i): \
+                _prenorm_layer(p, h, kind, s, dt, attention, rope, dense)
+        else:
+            fn = lambda p, h, kind=kind: _layer(p, h, kind, s, dt,
+                                                attention, rope)
         # lint: disable=FTL005 — remat is a static flag of the launcher
         h = (jax.checkpoint(fn, policy=policy) if remat else fn)(
             params[f"layer_{i}"], h)
@@ -925,7 +1249,11 @@ def _stack(params, h, s: HybridSpec, dt, attention: str, remat: bool,
             parts.append(part)
     # lint: disable=FTL005 — the block is a flag of the spec
     if s.prenorm:
-        return h, jax.tree.map(lambda *v: jnp.stack(v), *parts)
+        # each part stacked over the layers that report it (a dense
+        # layer among expert layers reports no routing)
+        return h, {key: jnp.stack([part[key] for part in parts
+                                   if key in part])
+                   for key in sorted(set().union(*parts))}
     return h
 
 
@@ -1069,6 +1397,19 @@ class HybridLM(NamedTuple):
             s.num_attention_heads, s.kv_heads, s.head_size,
             sparse_attention.chunk_of(tokens, s.selection.chunk), tokens))
 
+    def attention_kernel_share(self, tokens: int) -> Optional[float]:
+        """The share of a step's latent-attention layer calls whose
+        softmax attention runs the flash kernel on ``tokens``-long rows
+        as the step is traced here (every call has the same shapes: 0
+        or 1), from the launcher's ``attention`` mode, the length
+        (``ops/attention_dispatch.py``) and the backend (off a TPU the
+        flash path is its dense oracle); None without latent
+        attention."""
+        if self.module.latent is None:
+            return None
+        return float(resolve_attention(self.attention, tokens) == "flash"
+                     and on_tpu())
+
     def init(self, rng):
         return _jitted_init(self.module)(rng)
 
@@ -1108,7 +1449,15 @@ class HybridLM(NamedTuple):
         (``ops/sparse_attention.py``) summed over the layers; the parts
         are ``ce``, ``index_loss`` (``L_I``) and, of an expert layer,
         ``moe_pairs`` and ``moe_load_max_over_mean``
-        (``ops/routed_experts.py``), means over the layers."""
+        (``ops/routed_experts.py``), means over the layers. Under a
+        biased router (``topk_method`` ``noaux_tc``): ``CE + L_B -
+        stop_gradient(L_B)``, ``L_B`` = ``balance_loss_coef`` x the
+        layers' ``routed_experts.balance_step`` summed: value zero, its
+        gradient the published update of the routers' biases and of
+        nothing else; the parts gain ``balance_loss`` (zero: its
+        presence says the part ran), ``router_load_max_over_mean``
+        (over ALL routed experts, mean over the layers) and
+        ``router_bias_abs_max`` (the largest ``|b|`` of any layer)."""
         dt, h, layers = self._states(params, x)
         # lint: disable=FTL005 — a looped model or not, by the spec
         if self.module.looped:
@@ -1135,6 +1484,15 @@ class HybridLM(NamedTuple):
             parts["moe_pairs"] = jnp.mean(layers["pairs"])
             parts["moe_load_max_over_mean"] = jnp.mean(
                 layers["load_max_over_mean"])
+        # lint: disable=FTL005 — a biased router or none, by the spec
+        if "balance" in layers:
+            step = self.module.experts.balance * jnp.sum(layers["balance"])
+            parts["balance_loss"] = step - jax.lax.stop_gradient(step)
+            loss = loss + parts["balance_loss"]
+            parts["router_load_max_over_mean"] = jnp.mean(
+                layers["router_load_max_over_mean"])
+            parts["router_bias_abs_max"] = jnp.max(
+                layers["router_bias_abs_max"])
         return loss, jnp.mean(hit), parts
 
     def token_loss(self, params, x, train: bool = False, rng=None):

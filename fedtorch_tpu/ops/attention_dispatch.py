@@ -38,6 +38,15 @@ from __future__ import annotations
 # of 512, which read there (one chip, forward, recomputation and
 # backward of a layer call, PR 39): T=4096 19.8 ms a call, 7.9 %;
 # T=6144 46.4 ms, 5.6 %; T=8192 91.7 ms, 4.0 %.
+# The latent-attention layers of PR 41's cell (32 heads, 192 wide for
+# queries and keys and 128 for values) take this dispatch as any
+# full-attention layer does: the flash kernel has a value head size of
+# its own since then (forward and chunked backward, nothing padded),
+# and the dense form never had one. Both paths at T=4096 at those
+# heads, one chip, one row, by hand (builder, PR 41): forward and
+# backward 25.1 ms flash against 29.6 dense, forward alone 3.7
+# against 16.1; the cell's traced round reads 27.9 ms a layer call
+# with the recomputed forward, 9.4 % of causal attention's roofline.
 FLASH_MIN_SEQ_LEN = 4096
 
 

@@ -137,8 +137,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 def _fwd_pallas(q3, k3, v3, scale: float, causal: bool, block_q: int,
                 block_k: int, interpret: bool):
-    """[BH, T, D] forward -> (o [BH, T, D], lse [BH, T] f32)."""
+    """q3, k3 [BH, T, D], v3 [BH, T, Dv] forward -> (o [BH, T, Dv],
+    lse [BH, T] f32). ``Dv`` may differ from ``D`` (latent attention:
+    query/key heads of 192 beside value heads of 128): the value tile,
+    the accumulator and the output are ``Dv`` wide and nothing is
+    padded. A head size that is no multiple of 128 lanes is a block's
+    whole last dimension, which Mosaic takes."""
     BH, T, D = q3.shape
+    Dv = v3.shape[-1]
     grid = (BH, T // block_q, T // block_k)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal)
     # Under shard_map (ring/ulysses call this per shard), jax's vma
@@ -154,25 +160,25 @@ def _fwd_pallas(q3, k3, v3, scale: float, causal: bool, block_q: int,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_q, _LSE_LANES),
                          lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), q3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((BH, T, Dv), q3.dtype, vma=vma),
             jax.ShapeDtypeStruct((BH, T, _LSE_LANES), jnp.float32,
                                  vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, D), jnp.float32),       # accumulator
+            pltpu.VMEM((block_q, Dv), jnp.float32),      # accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -358,22 +364,25 @@ def _prep(q, k, v, scale, block_q, block_k, force):
         dq, dk = _default_blocks(T)
         block_q = dq if block_q is None else block_q
         block_k = dk if block_k is None else block_k
-    if k.shape != q.shape or v.shape != q.shape:
+    if k.shape != q.shape or v.shape[:3] != q.shape[:3]:
         # The kernel grid and chunked VJP tile Q and K/V with one shared
         # T; unequal q/kv lengths (e.g. cross-attention or uneven K/V
         # partitions) are not supported — fail with the shapes rather
         # than an opaque reshape error downstream. Ring/Ulysses always
-        # pass equal-size blocks.
+        # pass equal-size blocks. The VALUE head size is v's own (the
+        # output's too): latent attention has query/key heads of 192
+        # beside value heads of 128.
         raise ValueError(
-            "flash attention requires q, k, v of identical shape "
-            f"[B, T, H, D]; got q={q.shape}, k={k.shape}, v={v.shape}. "
+            "flash attention requires q and k of identical shape "
+            "[B, T, H, D] and v [B, T, H, Dv]; got "
+            f"q={q.shape}, k={k.shape}, v={v.shape}. "
             "For disjoint K/V partitions, run the kernel per equal-size "
             "block and merge with the returned logsumexp.")
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     block_q = _divisor_block(T, block_q)
     block_k = _divisor_block(T, block_k)
-    q3, k3, v3 = (t.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+    q3, k3, v3 = (t.transpose(0, 2, 1, 3).reshape(B * H, T, t.shape[-1])
                   for t in (q, k, v))
     if force not in (None, "interpret", "xla"):
         raise ValueError(
@@ -390,8 +399,8 @@ def _prep(q, k, v, scale, block_q, block_k, force):
         # a [block_q, block_k] f32 score tile would blow VMEM on the
         # real lowering — the XLA oracle is the correct backend there
         use_pallas = False
-    return (q3, k3, v3), (B, T, H, D), scale, block_q, block_k, \
-        use_pallas
+    return (q3, k3, v3), (B, T, H, v.shape[-1]), scale, block_q, \
+        block_k, use_pallas
 
 
 def flash_attention(q, k, v, causal: bool = False,
@@ -399,7 +408,9 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     force: Optional[str] = None) -> jnp.ndarray:
-    """Exact attention, [B, T, H, D] in/out, differentiable.
+    """Exact attention, q and k [B, T, H, D], v and the result
+    [B, T, H, Dv] (``Dv`` = ``D`` but for latent attention),
+    differentiable.
 
     Backend selection: the Pallas kernel on TPU; its interpreter when
     ``force='interpret'`` (CPU kernel tests); the dense-oracle math
@@ -408,10 +419,10 @@ def flash_attention(q, k, v, causal: bool = False,
     (``_default_blocks``) and are adjusted to divisors of T (static
     shapes: decided once at trace time), so both the kernel grid and
     the chunked VJP always tile the sequence exactly."""
-    (q3, k3, v3), (B, T, H, D), scale, bq, bk, use_pallas = _prep(
+    (q3, k3, v3), (B, T, H, Dv), scale, bq, bk, use_pallas = _prep(
         q, k, v, scale, block_q, block_k, force)
     out3 = _flash3(q3, k3, v3, scale, causal, bq, bk, use_pallas)
-    return out3.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    return out3.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = False,
@@ -424,9 +435,9 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
     disjoint K/V blocks: pieces (o_i, lse_i) over K-partitions combine
     exactly via lse-weighted averaging (ring attention's per-step
     blocks, parallel/sequence.py). Differentiable in both outputs."""
-    (q3, k3, v3), (B, T, H, D), scale, bq, bk, use_pallas = _prep(
+    (q3, k3, v3), (B, T, H, Dv), scale, bq, bk, use_pallas = _prep(
         q, k, v, scale, block_q, block_k, force)
     o3, lse3 = _flash3_lse(q3, k3, v3, scale, causal, bq, bk,
                            use_pallas)
-    o = o3.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    o = o3.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
     return o, lse3.reshape(B, H, T).transpose(0, 2, 1)

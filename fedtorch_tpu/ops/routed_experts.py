@@ -10,6 +10,18 @@ computes the part of the result that the experts it HOLDS give.
 all chips add up to the whole layer; what absent experts would add is
 left out, and nothing stands in for the other chips or their exchange.
 
+:func:`route` also writes DeepSeek-V3's router (``scoring='sigmoid'``):
+``s = sigmoid(W_r u)``, ``T`` the ``per_token`` largest of ``s + b``
+with ``b`` a per-expert bias that no gradient reaches, ``g_e = scale x
+s_e / (sum_{e' in T} s_e' + 1e-20)`` from the unbiased scores.
+:func:`load_over_all` counts a step's pairs over ALL routed experts and
+:func:`balance_step` is the loss part whose gradient is the published
+auxiliary-loss-free update of ``b`` (``topk_method`` ``noaux_tc``).
+:func:`plan`, :func:`dispatch`, the grouped products and
+:func:`combine` serve both routers as they are; a shared expert beside
+the routed ones is the model's (``models/hybrid_lm.py``), not this
+file's: every chip computes it alike.
+
 Dropless under any imbalance: the token-expert pairs routed here are
 sorted by expert into ONE buffer of ``tokens x per_token`` rows, the
 worst case (every pair held here), and the three grouped products
@@ -54,16 +66,65 @@ class Plan(NamedTuple):
     here: jax.Array        # [N, k] pair is routed to an expert held
 
 
-def route(logits, per_token: int, normalise: bool):
+def route(logits, per_token: int, normalise: bool, *,
+          scoring: str = "softmax", bias=None, scale: float = 1.0):
     """Router ``logits`` [N, routed] float32 -> (gates [N, k] float32,
-    experts [N, k] int32): the ``k`` most probable experts a token and
-    their probabilities, which ``normalise`` makes sum to 1."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, experts = jax.lax.top_k(probs, per_token)
+    experts [N, k] int32): the ``k`` best experts a token and their
+    gates. ``scoring`` 'softmax' (the default: the scores are the
+    probabilities over all experts) or 'sigmoid' (each expert's own,
+    DeepSeek-V3's). The choice is by score plus ``bias`` [routed] where
+    one is given (no gradient reaches it: the choice has none), the
+    gates are the chosen experts' UNBIASED scores, which ``normalise``
+    makes sum to 1 (over ``sum + 1e-20`` where the scores are sigmoids,
+    as published) and ``scale`` (``routed_scaling_factor``) multiplies.
+    With the defaults the program is the softmax router's as it was."""
+    logits = logits.astype(jnp.float32)
+    # lint: disable=FTL005 — a string of the model's file
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got "
+                         f"{scoring!r}")
+    # lint: disable=FTL005 — a bias leaf or none, by the model's file
+    if bias is None:
+        gates, experts = jax.lax.top_k(scores, per_token)
+    else:
+        _, experts = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)),
+            per_token)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
     # lint: disable=FTL005 — a flag of the model's file
     if normalise:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        # lint: disable=FTL005 — a string of the model's file
+        gates = gates / (total + 1e-20 if scoring == "sigmoid" else total)
+    # lint: disable=FTL005 — a host float of the model's file
+    if scale != 1.0:
+        gates = gates * scale
     return gates, experts
+
+
+def load_over_all(experts, routed: int):
+    """``c`` [routed] float32: the pairs of ``experts`` [N, k] that
+    chose each of the ``routed`` experts, held here or not: this chip's
+    tokens over the whole router (in a deployment the group's chips
+    would sum their counts)."""
+    hot = experts.reshape(-1)[:, None] == jnp.arange(routed)[None, :]
+    return jnp.sum(hot.astype(jnp.float32), axis=0)
+
+
+def balance_step(bias, load):
+    """DeepSeek-V3's auxiliary-loss-free balance as a loss part: ``-sum_e
+    b_e sign(mean(c) - c_e)`` with the sign under ``stop_gradient``, so
+    that its gradient with respect to ``bias`` [routed] is minus the
+    published step's direction (a plain SGD step of size ``lr x u``
+    then moves ``b_e`` by ``gamma sign(mean(c) - c_e)``, ``gamma = lr x
+    u``) and no other leaf receives anything. The caller adds it as
+    ``u (L - stop_gradient(L))``: value zero."""
+    direction = jax.lax.stop_gradient(jnp.sign(jnp.mean(load) - load))
+    return -jnp.sum(bias.astype(jnp.float32) * direction)
 
 
 def plan(experts, first: int, held: int) -> Plan:

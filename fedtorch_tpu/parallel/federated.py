@@ -150,6 +150,10 @@ _SCALAR_FIELDS = (
     ("lm_moe_pairs_local", "lm_moe_pairs_local"),
     ("lm_moe_load_max_over_mean", "lm_moe_load_max_over_mean"),
     ("lm_index_loss", "lm_index_loss"),
+    # a biased router's load, bias and balance part; None likewise
+    ("lm_router_load_max_over_mean", "lm_router_load_max_over_mean"),
+    ("lm_router_bias_abs_max", "lm_router_bias_abs_max"),
+    ("lm_balance_loss", "lm_balance_loss"),
 )
 # RoundMetrics field <- the key of a token model's loss parts
 # (``token_loss_parts``) whose mean over clients and steps it holds
@@ -158,6 +162,9 @@ _PART_GAUGES = (
     ("lm_moe_pairs_local", "moe_pairs"),
     ("lm_moe_load_max_over_mean", "moe_load_max_over_mean"),
     ("lm_index_loss", "index_loss"),
+    ("lm_router_load_max_over_mean", "router_load_max_over_mean"),
+    ("lm_router_bias_abs_max", "router_bias_abs_max"),
+    ("lm_balance_loss", "balance_loss"),
 )
 # what the program computes itself, ahead of the table's leaves
 _COMPUTED_SCALARS = ("mean_epoch", "lr", "n_online", "loss_sum",
@@ -1865,6 +1872,11 @@ class FederatedTrainer:
             out["lm_selected_share"] = share
             out["lm_selected_kernel_share"] = \
                 self.model.selected_kernel_share(self.row_tokens)
+        share = self.model.attention_kernel_share(self.row_tokens) \
+            if self.row_tokens \
+            and hasattr(self.model, "attention_kernel_share") else None
+        if share is not None:
+            out["lm_attention_kernel_share"] = share
         ss = self.stream_stats()
         if ss is not None:
             out.update(ss)

@@ -120,6 +120,30 @@ METRICS_OPTIONAL = {
                                 "shapes tile, 0 for the masked dense "
                                 "form (ops/sparse_attention.py "
                                 "takes_kernel)",
+    "lm_router_load_max_over_mean": "token model with a biased router "
+                                    "(topk_method noaux_tc): the "
+                                    "fullest expert's pairs over the "
+                                    "mean, over ALL routed experts "
+                                    "(this chip's tokens; a row-step "
+                                    "and layer, mean over the round's "
+                                    "clients, steps and layers)",
+    "lm_router_bias_abs_max": "token model with a biased router: the "
+                              "largest |b| of any layer's router bias "
+                              "(mean over the round's clients and "
+                              "steps)",
+    "lm_balance_loss": "token model with a biased router: the value of "
+                       "the balance part L_B - stop_gradient(L_B) of "
+                       "the training loss, zero by construction (its "
+                       "gradient is the published bias update): its "
+                       "presence says the part ran",
+    "lm_attention_kernel_share": "token model with latent attention: "
+                                 "share of the round's layer calls "
+                                 "whose softmax attention runs the "
+                                 "flash kernel (ops/pallas/"
+                                 "flash_attention.py), from the "
+                                 "attention mode, the rows' length and "
+                                 "the backend when the round is "
+                                 "traced: 0 for the dense form",
     # stream plane (trainer.stream_stats)
     "stream_depth": "prefetched feeds ready at fetch time",
     "stream_wait_s": "consumer wall blocked on the feed queue (total)",

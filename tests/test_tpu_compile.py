@@ -184,3 +184,33 @@ def test_selected_attention_compiles_in_chunks_at_the_cells_length(
     # (the rows' statistics are [1,4,8,512,8]: eight lanes, not keys)
     assert not re.search(r"f32\[(?:1,4,8,512|32,512),\d{4}", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_flash_attention_compiles_at_the_latent_cells_heads(one_chip,
+                                                            monkeypatch):
+    """The kanana cell's softmax attention at 4096 tokens (32 heads,
+    192 wide for queries and keys and 128 for values), forward and the
+    chunked backward, as a TPU builds it: Mosaic takes blocks whose last
+    dimension is the head's whole 192 (one and a half lane tiles) with a
+    value tile and an accumulator of 128 beside them, no operand is
+    padded to the other's size, and no array of a row's scores lives."""
+    import fedtorch_tpu.ops.pallas.flash_attention as fa
+
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    T = 4096
+    q = jax.ShapeDtypeStruct((1, T, 32, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, T, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"4096,4096\]", text)
+    assert not re.search(r"\[32,4096,192\][^\n]* pad\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
