@@ -1410,6 +1410,24 @@ class HybridLM(NamedTuple):
         return float(resolve_attention(self.attention, tokens) == "flash"
                      and on_tpu())
 
+    def attention_backward_kernel_share(self, tokens: int
+                                        ) -> Optional[float]:
+        """The share of those layer calls whose backward pass runs the
+        flash path's backward kernel, not its chunked scan, as the step
+        is traced here (0 or 1): the decision of the flash path's
+        backward rule (``ops/pallas/flash_attention.py``:
+        ``backward_kernel_taken``) where the call takes that path; None
+        without latent attention."""
+        l = self.module.latent
+        if l is None:
+            return None
+        if resolve_attention(self.attention, tokens) != "flash":
+            return 0.0
+        from fedtorch_tpu.ops.pallas.flash_attention import (
+            backward_kernel_taken,
+        )
+        return float(backward_kernel_taken(tokens, l.nope + l.rope))
+
     def init(self, rng):
         return _jitted_init(self.module)(rng)
 

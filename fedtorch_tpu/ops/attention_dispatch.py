@@ -41,12 +41,19 @@ from __future__ import annotations
 # The latent-attention layers of PR 41's cell (32 heads, 192 wide for
 # queries and keys and 128 for values) take this dispatch as any
 # full-attention layer does: the flash kernel has a value head size of
-# its own since then (forward and chunked backward, nothing padded),
-# and the dense form never had one. Both paths at T=4096 at those
-# heads, one chip, one row, by hand (builder, PR 41): forward and
-# backward 25.1 ms flash against 29.6 dense, forward alone 3.7
-# against 16.1; the cell's traced round reads 27.9 ms a layer call
-# with the recomputed forward, 9.4 % of causal attention's roofline.
+# its own since then (nothing padded), and the dense form never had
+# one. Since PR 42 the flash path is a kernel in both directions
+# (causal tiles only, the arrays' own type to the MXU, no row of scores
+# in HBM). At T=4096 at those heads, one chip, one row, bfloat16, by
+# hand (builder, PR 42, 2026-10-03): forward 2.38 ms (36.7 % of the
+# MXU's peak on the causal pairs' 0.172 TFLOP), backward 4.56 ms
+# (49.8 % on 0.447 TFLOP; the chunked float32 XLA scan it replaced
+# 21.8), forward and backward with the layout copies 6.85 (PR 41:
+# 25.1, against 29.6 dense; forward alone 3.7 against 16.1 dense); the
+# cell's traced round reads 8.4 ms a layer call with the recomputed
+# forward (PR 41: 27.9), 31.3 % of causal attention's roofline (9.4).
+# At 30 heads of 128 (the olmo file's, T=4096): forward 1.42, backward
+# 2.52 (the scan 15.3). The dense path below 4096 was not read again.
 FLASH_MIN_SEQ_LEN = 4096
 
 

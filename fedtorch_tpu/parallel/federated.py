@@ -1877,6 +1877,9 @@ class FederatedTrainer:
             and hasattr(self.model, "attention_kernel_share") else None
         if share is not None:
             out["lm_attention_kernel_share"] = share
+            out["lm_attention_backward_kernel_share"] = \
+                self.model.attention_backward_kernel_share(
+                    self.row_tokens)
         ss = self.stream_stats()
         if ss is not None:
             out.update(ss)

@@ -144,6 +144,16 @@ METRICS_OPTIONAL = {
                                  "attention mode, the rows' length and "
                                  "the backend when the round is "
                                  "traced: 0 for the dense form",
+    "lm_attention_backward_kernel_share": "token model with latent "
+                                          "attention: share of those "
+                                          "layer calls whose backward "
+                                          "pass runs the flash path's "
+                                          "backward kernel, not its "
+                                          "chunked scan (the backward "
+                                          "rule's own decision when "
+                                          "the round is traced: "
+                                          "flash_attention.py "
+                                          "backward_kernel_taken)",
     # stream plane (trainer.stream_stats)
     "stream_depth": "prefetched feeds ready at fetch time",
     "stream_wait_s": "consumer wall blocked on the feed queue (total)",

@@ -510,6 +510,54 @@ def test_the_model_runs_the_same_function_on_both_attention_paths(
     assert worst_gap(keep(ga), keep(gb))[0] < 1e-4
     assert model_of(spec_file).attention_kernel_share(24) == 0.0
     assert model_of(spec_file).attention_kernel_share(4096) == 0.0  # CPU
+    assert model_of(spec_file).attention_backward_kernel_share(24) == 0.0
+    assert model_of(spec_file).attention_backward_kernel_share(4096) == 0.0
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_model_takes_the_kernels_in_both_directions(
+        spec_file, monkeypatch, remat):
+    """'flash' with the kernels in the interpreter, forward and backward
+    (what a TPU compiles), against the dense model on a 256-token row:
+    two query and two key tiles a head, under the layer's checkpoint
+    and without it; one backward kernel a layer."""
+    import fedtorch_tpu.ops.pallas.flash_attention as fa
+    built = []
+    bwd_pallas = fa._bwd_pallas
+
+    def counted(res, *a, **kw):
+        built.append(res[0].shape)
+        return bwd_pallas(res, *a, **kw)
+
+    monkeypatch.setattr(fa, "_backend", lambda bq, bk, force: None)
+    monkeypatch.setattr(fa, "_bwd_pallas", counted)
+    x = tokens((1, 256), seed=11)
+    params = model_of(spec_file).init(jax.random.key(8))
+    with jax.default_matmul_precision("highest"):
+        (a, _), ga = loss_and_grads(
+            model_of(spec_file, attention="dense", remat=remat), params, x)
+        (b, _), gb = loss_and_grads(
+            model_of(spec_file, attention="flash", remat=remat), params, x)
+    assert built == [(4, 256, 24)] * SMALL["num_hidden_layers"]
+    np.testing.assert_allclose(a, b, rtol=1e-6)
+    keep = lambda g: [v for p, v in jax.tree_util.tree_leaves_with_path(g)
+                      if not is_bias(p)]
+    assert worst_gap(keep(ga), keep(gb))[0] < 1e-4
+
+
+def test_the_backward_counter_follows_the_backward_rule(spec_file,
+                                                        monkeypatch):
+    """On a TPU both counters read 1 from 4096 tokens on ('auto'), and
+    0 below, where the layer takes the dense form."""
+    import fedtorch_tpu.ops.pallas.flash_attention as fa
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    monkeypatch.setattr(hybrid_lm, "on_tpu", lambda: True)
+    model = model_of(spec_file)
+    assert model.attention_kernel_share(4096) == 1.0
+    assert model.attention_backward_kernel_share(4096) == 1.0
+    assert model.attention_backward_kernel_share(2048) == 0.0
+    dense = model_of(spec_file, attention="dense")
+    assert dense.attention_backward_kernel_share(4096) == 0.0
 
 
 # -- the specification ------------------------------------------------------------
@@ -636,6 +684,7 @@ def test_sequential_round_reports_the_routers_gauges(files):
     assert m.lm_index_loss is None and m.lm_exit_entropy is None
     gauges = t.telemetry_gauges()
     assert gauges["lm_attention_kernel_share"] == 0.0
+    assert gauges["lm_attention_backward_kernel_share"] == 0.0
     assert "lm_selected_share" not in gauges
     scalars = t.round_host_scalars(clients, m)
     assert scalars["lm_router_bias_abs_max"] == float(
@@ -658,6 +707,7 @@ def test_launcher_rounds_evaluation_save_and_resume(files, tmp_path):
                and r["lm_router_load_max_over_mean"] >= 1.0
                and 0.0 < r["lm_router_bias_abs_max"] < 1e-3
                and r["lm_attention_kernel_share"] == 0.0
+               and r["lm_attention_backward_kernel_share"] == 0.0
                and 0 < r["lm_moe_pairs_local"] < 72
                and r["dropped"] == 0 for r in rows)
     again = run_experiment(lm_cfg(files, "sequential", run_dir=run_dir,

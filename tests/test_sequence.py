@@ -122,6 +122,45 @@ class TestRingFlashBlocks:
             q, k, v, mesh, causal=True,
             block_impl="flash")).trace(q, k, v)
 
+    @pytest.mark.parametrize("strategy", ["ring", "ulysses"])
+    def test_backward_kernel_traces_under_shard_map_vma(self, strategy,
+                                                        monkeypatch):
+        """The same check on the backward rule's ``pallas_call`` (its
+        outputs declare the inputs' vma as the forward's do): the
+        gradient traced on the real pallas path."""
+        import fedtorch_tpu.ops.pallas.flash_attention as fa
+
+        monkeypatch.setattr(fa, "on_tpu", lambda: True)
+        q, k, v = _qkv(s=64, seed=13)
+        mesh = _mesh(4)
+        fn = (ring_attention if strategy == "ring"
+              else ulysses_attention)
+        text = str(jax.jit(jax.grad(lambda q, k, v: jnp.sum(fn(
+            q, k, v, mesh, causal=True, block_impl="flash") ** 2),
+            argnums=(0, 1, 2))).trace(q, k, v).jaxpr)
+        assert "flash_attention_bwd" in text
+
+    def test_interpreted_kernels_gradients_match_oracle(self,
+                                                        monkeypatch):
+        """Both kernels in the interpreter under ``shard_map``
+        (ulysses' local attention between its all-to-alls) against
+        dense attention's gradients. The ring's blocks are not run this
+        way: the interpreter's own slicing fails shard_map's vma check;
+        their log-sum-exp cotangent is in test_flash_attention.py."""
+        import fedtorch_tpu.ops.pallas.flash_attention as fa
+
+        monkeypatch.setattr(fa, "_backend", lambda bq, bk, force: None)
+        q, k, v = _qkv(s=64, seed=17)
+        mesh = _mesh(2)
+        gf = jax.grad(lambda q, k, v: jnp.sum(ulysses_attention(
+            q, k, v, mesh, causal=True, block_impl="flash") ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(lambda q, k, v: jnp.sum(reference_attention(
+            q, k, v, causal=True) ** 2), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gf, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-5, rtol=5e-4)
+
 
 class TestUlysses:
     """All-to-all (head-parallel) strategy: must agree with dense AND

@@ -189,11 +189,14 @@ def test_selected_attention_compiles_in_chunks_at_the_cells_length(
 def test_flash_attention_compiles_at_the_latent_cells_heads(one_chip,
                                                             monkeypatch):
     """The kanana cell's softmax attention at 4096 tokens (32 heads,
-    192 wide for queries and keys and 128 for values), forward and the
-    chunked backward, as a TPU builds it: Mosaic takes blocks whose last
+    192 wide for queries and keys and 128 for values), forward and
+    backward, as a TPU builds it: Mosaic takes blocks whose last
     dimension is the head's whole 192 (one and a half lane tiles) with a
     value tile and an accumulator of 128 beside them, no operand is
-    padded to the other's size, and no array of a row's scores lives."""
+    padded to the other's size; both directions are kernels, so no
+    array of a query block's scores lives (the chunked scan's were
+    ``f32[32,512,4096]``, 256 MiB each) and no float32 copy of ``q`` or
+    ``k``."""
     import fedtorch_tpu.ops.pallas.flash_attention as fa
 
     monkeypatch.setattr(fa, "on_tpu", lambda: True)
@@ -211,6 +214,37 @@ def test_flash_attention_compiles_at_the_latent_cells_heads(one_chip,
         q, q, v).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
     assert not re.search(r"4096,4096\]", text)
     assert not re.search(r"\[32,4096,192\][^\n]* pad\(", text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    assert "f32[32,512,4096]" not in text
+    assert "f32[32,4096,192]" not in text
+    assert "while(" not in text
+    # read 353 MiB (the layout copies between [B, T, H, D] and
+    # [BH, T, D] and the float32 cotangent of this test's loss); the
+    # scan's program read 682 MiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+
+
+def test_flash_kernels_take_bfloat16_operands_under_highest_precision(
+        one_chip, monkeypatch):
+    """A caller's ``default_matmul_precision('highest')`` (the tests',
+    chip_smoke's) reaches a kernel's products when it is traced; Mosaic
+    refuses it on bfloat16 operands ("Bad lhs type"), whose product is
+    exact in one pass anyway: the kernels ask for it on float32
+    operands alone."""
+    import fedtorch_tpu.ops.pallas.flash_attention as fa
+
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    x = jax.ShapeDtypeStruct((1, 1024, 2, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32) ** 2)
+
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile().as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+
