@@ -1262,6 +1262,7 @@ def run_experiment(cfg: ExperimentConfig,
                     row["lm_moe_pairs_local"] = sc["lm_moe_pairs_local"]
                     row["lm_moe_load_max_over_mean"] = \
                         sc["lm_moe_load_max_over_mean"]
+                    row["lm_moe_rows_visited"] = sc["lm_moe_rows_visited"]
                 if "lm_balance_loss" in sc:
                     # a biased router's load, bias and balance part
                     row["lm_router_load_max_over_mean"] = \

@@ -115,6 +115,7 @@ class RoundMetrics(NamedTuple):
     # model
     lm_moe_pairs_local: Any = None         # scalar — pairs computed here
     lm_moe_load_max_over_mean: Any = None  # scalar — fullest held expert
+    lm_moe_rows_visited: Any = None        # scalar — buffer rows worked over
     lm_index_loss: Any = None              # scalar — the indexers' L_I
     # a biased router's gauges (DeepSeek-V3's ``noaux_tc``), likewise
     lm_router_load_max_over_mean: Any = None   # scalar — over ALL routed

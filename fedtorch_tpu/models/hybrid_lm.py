@@ -172,8 +172,9 @@ under ``sa_config`` ``lm.indexer`` (the indexer's three products, its
 scores, the selection and the KL term) beside ``lm.attention`` (the
 masked attention of the chunks and the heads' summed probabilities);
 in an expert layer ``lm.router`` (the router's product, softmax and
-top-k, the sort and the buffer's fill) and ``lm.experts`` (the grouped
-products and the combine) in ``lm.mlp``'s place; in a latent-attention
+top-k, the sort and the row blocks' fill) and ``lm.experts`` (the
+grouped products over the blocks in use and the combine) in
+``lm.mlp``'s place; in a latent-attention
 layer ``lm.latent`` (all of the sublayer but its softmax attention: the
 four products, the latent's norm, the rotary turn) beside
 ``lm.attention``; beside a biased router's ``lm.router`` (which also
@@ -931,6 +932,8 @@ def _experts(p, x, s: HybridSpec, dt):
                 e.normalise)
     out, counters = routed_experts.expert_share(
         p, u, gates, chosen, first=e.first, dt=dt,
+        block=routed_experts.block_rows(B * T, e.per_token, e.held,
+                                        e.routed),
         scopes=("lm.router", "lm.experts"))
     # lint: disable=FTL005 — flags of the spec
     if e.biased:
@@ -1033,8 +1036,9 @@ def layer_products(s: HybridSpec, kind: str,
     experts): the layer's feed-forward is a dense SwiGLU, named
     ``dense.*`` in a model whose other layers hold experts. An expert
     layer's ``mlp.gate`` / ``up`` / ``down`` are grouped products over
-    the dispatch buffer, ``[T x per_token, K] x [K, N]`` an expert
-    (:func:`_product_totals`); its shared expert's are ``shared.*``."""
+    the dispatch buffer's row blocks in use, ``[block, K] x [K, N]`` an
+    expert, the names on the first block's results
+    (:func:`_product_cost`); its shared expert's are ``shared.*``."""
     d, e = s.hidden_size, s.experts
     dense = e is None if dense is None else dense
     # lint: disable=FTL005 — the layer's kind is a string of the spec
@@ -1066,15 +1070,21 @@ def layer_products(s: HybridSpec, kind: str,
     return mixer
 
 
-def _product_cost(s: HybridSpec, name: str, k: int, n: int):
-    """(FLOPs, float32 result elements) a token of one product. An
-    expert layer's grouped products hold ``per_token`` buffer rows a
-    token, whatever is routed here, and run the expected ``per_token x
-    held / routed`` of them."""
+def _product_cost(s: HybridSpec, name: str, k: int, n: int, step: int):
+    """(FLOPs, float32 result elements) a token of one product, in a
+    step of ``step`` tokens. An expert layer's grouped products run the
+    expected ``per_token x held / routed`` buffer rows a token, and
+    what carries their names is the first row block's results
+    (``routed_experts.block_rows`` of the step's tokens: further
+    blocks, where more is routed here, are computed again in the
+    backward pass), whatever is routed here."""
     e = s.experts
     # lint: disable=FTL005 — host integers of the spec
     if e is not None and name in ("mlp.gate", "mlp.up", "mlp.down"):
-        return 2 * k * n * e.per_token * e.held / e.routed, n * e.per_token
+        block = routed_experts.block_rows(step, e.per_token, e.held,
+                                          e.routed)
+        return (2 * k * n * e.per_token * e.held / e.routed,
+                n * block / step)
     return 2 * k * n, n
 
 
@@ -1084,14 +1094,15 @@ def _layers_products(s: HybridSpec):
             for i, kind in enumerate(s.layer_types)]
 
 
-def _product_totals(s: HybridSpec) -> dict:
-    """``{name: (FLOPs, float32 result bytes)}`` a token and pass, summed
-    over the layers that have a product of that name."""
+def _product_totals(s: HybridSpec, step: int) -> dict:
+    """``{name: (FLOPs, float32 result bytes)}`` a token and pass of a
+    step of ``step`` tokens, summed over the layers that have a product
+    of that name."""
     totals: dict = {}
     for products in _layers_products(s):
         for name, (k, n) in products.items():
             flops, size = totals.get(name, (0, 0))
-            cost, floats = _product_cost(s, name, k, n)
+            cost, floats = _product_cost(s, name, k, n, step)
             totals[name] = (flops + cost, size + 4 * floats)
     return totals
 
@@ -1109,7 +1120,7 @@ def kept_products(s: HybridSpec, rows: int, tokens: int,
     inputs (``SUBLAYER_OUTPUTS``), then in the layer's own order; each
     taken if what is left of the budget holds it. From shapes alone:
     the same answer for the same arguments."""
-    totals = _product_totals(s)
+    totals = _product_totals(s, rows * tokens)
     order = sorted(totals, key=lambda n: (
         -totals[n][0] / totals[n][1], n not in SUBLAYER_OUTPUTS))
     if budget is None:
@@ -1125,12 +1136,12 @@ def kept_products(s: HybridSpec, rows: int, tokens: int,
     return tuple(kept)
 
 
-def kept_counters(s: HybridSpec, tokens: int, kept) -> dict:
+def kept_counters(s: HybridSpec, tokens: int, kept, rows: int = 1) -> dict:
     """The row's two counters of how far the policy engaged:
     ``lm_kept_product_share``, the share of the stack's forward product
     FLOPs whose results are kept, and ``lm_kept_residual_bytes``, what
-    they hold a sequence of ``tokens``."""
-    totals = _product_totals(s)
+    they hold a sequence of ``tokens`` (of a step of ``rows``)."""
+    totals = _product_totals(s, rows * tokens)
     flops, size = (sum(totals[n][i] for n in kept) for i in (0, 1))
     return {"lm_kept_product_share":
             flops / sum(t[0] for t in totals.values()),
@@ -1174,8 +1185,8 @@ def residual_budget(s: HybridSpec, rows: int, tokens: int,
     if s.looped:
         held += 6 * count([v for k, v in shapes.items()
                            if k.startswith("layer_")])
-    widest = max(sum(_product_cost(s, name, k, n)[1] for name, (k, n)
-                     in products.items())
+    widest = max(sum(_product_cost(s, name, k, n, rows * tokens)[1]
+                     for name, (k, n) in products.items())
                  for products in _layers_products(s))
     reserve = 4 * rows * tokens * (
         s.hidden_size * len(s.layer_types) * s.total_ut_steps
@@ -1437,7 +1448,7 @@ class HybridLM(NamedTuple):
         if not self.remat:
             return {}
         return kept_counters(self.module, tokens,
-                             _kept_for(self.module, rows, tokens))
+                             _kept_for(self.module, rows, tokens), rows)
 
     def _states(self, params, x):
         """(compute type, the states the head reads, the layer calls'
@@ -1500,6 +1511,7 @@ class HybridLM(NamedTuple):
         # lint: disable=FTL005 — experts or none, by the spec
         if "pairs" in layers:
             parts["moe_pairs"] = jnp.mean(layers["pairs"])
+            parts["moe_rows_visited"] = jnp.mean(layers["rows_visited"])
             parts["moe_load_max_over_mean"] = jnp.mean(
                 layers["load_max_over_mean"])
         # lint: disable=FTL005 — a biased router or none, by the spec

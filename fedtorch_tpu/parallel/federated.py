@@ -149,6 +149,7 @@ _SCALAR_FIELDS = (
     # a sparse token model's routing and indexer gauges; None likewise
     ("lm_moe_pairs_local", "lm_moe_pairs_local"),
     ("lm_moe_load_max_over_mean", "lm_moe_load_max_over_mean"),
+    ("lm_moe_rows_visited", "lm_moe_rows_visited"),
     ("lm_index_loss", "lm_index_loss"),
     # a biased router's load, bias and balance part; None likewise
     ("lm_router_load_max_over_mean", "lm_router_load_max_over_mean"),
@@ -161,6 +162,7 @@ _PART_GAUGES = (
     ("lm_exit_entropy", "exit_entropy"),
     ("lm_moe_pairs_local", "moe_pairs"),
     ("lm_moe_load_max_over_mean", "moe_load_max_over_mean"),
+    ("lm_moe_rows_visited", "moe_rows_visited"),
     ("lm_index_loss", "index_loss"),
     ("lm_router_load_max_over_mean", "router_load_max_over_mean"),
     ("lm_router_bias_abs_max", "router_bias_abs_max"),

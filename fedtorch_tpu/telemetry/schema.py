@@ -99,6 +99,13 @@ METRICS_OPTIONAL = {
                           "computed, a row-step and layer (mean over "
                           "the round's clients, steps and layers; "
                           "ops/routed_experts.py)",
+    "lm_moe_rows_visited": "sparse-expert token model: rows of the "
+                           "dispatch buffer a layer call's work ran "
+                           "over, the row block times its trips "
+                           "(ops/routed_experts.py:block_rows; the "
+                           "buffer holds tokens x per_token; mean as "
+                           "lm_moe_pairs_local, which it is read "
+                           "beside)",
     "lm_moe_load_max_over_mean": "sparse-expert token model: the "
                                  "fullest held expert's pairs over the "
                                  "held experts' mean, a row-step and "

@@ -566,6 +566,8 @@ def test_launcher_rounds_evaluation_save_and_resume(files, tmp_path):
                and r["tokens_trained"] == 3 * 2 * 24
                and r["lm_selected_share"] == (36 + 16 * 8) / 300
                and 0 < r["lm_moe_pairs_local"] < 48
+               # under a row tile the block is the buffer: one trip
+               and r["lm_moe_rows_visited"] == 48
                and r["lm_moe_load_max_over_mean"] >= 1.0
                and 0 < r["lm_index_loss"] < 2.0
                and r["dropped"] == 0 for r in rows)

@@ -13,6 +13,7 @@ Keep TPU compiles in THIS file only, behind the fixture: one process at
 a time may load the TPU's library, and the workers of a parallel run
 each import every test file.
 """
+import functools
 import re
 
 import jax
@@ -113,37 +114,102 @@ def test_delta_rule_gradient_holds_no_triangular_solve(one_chip):
     assert not re.search(r"\btriangular-solve\(", text)
 
 
+def _computations(text):
+    """``{name: lines}`` of a compiled module's computations, the names
+    of those a ``fusion`` calls, and of those inside a ``while`` body
+    (the body and what it calls, to any depth)."""
+    lines, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if m:
+            name = m.group(1)
+            lines[name] = []
+        elif name is not None:
+            lines[name].append(line)
+    called = {n: set(re.findall(
+        r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", "\n".join(ls)))
+        for n, ls in lines.items()}
+    fused = {c for ls in lines.values() for line in ls if " fusion(" in line
+             for c in re.findall(r"calls=%([\w.\-]+)", line)}
+    looped = {c for ls in lines.values() for line in ls
+              for c in re.findall(r"body=%([\w.\-]+)", line)}
+    grown = True
+    while grown:
+        more = set().union(*(called[n] for n in looped)) - looped
+        grown = bool(more)
+        looped |= more
+    return lines, fused, looped
+
+
+@pytest.mark.parametrize("per_token,block", [(8, 8192), (6, 6144)])
 def test_expert_share_compiles_to_grouped_kernels_at_the_cells_widths(
-        one_chip):
-    """The Keye cell's expert layer at its real shapes (4096 tokens of
-    2048, 16 experts of 768 held, 8 a token: a buffer of 32 768 rows),
-    forward and backward: every grouped product is the TPU's own
-    grouped-matmul kernel (``ragged-dot`` custom calls that visit the
-    row tiles in use), none was expanded into a dense product over all
-    the experts (16 times the work), and the temporaries stay near the
-    buffer's worst case."""
+        one_chip, per_token, block):
+    """The Keye cell's expert layer (8 experts a token) and the kanana
+    cell's (6) at their real shapes (4096 tokens of 2048, 16 experts of
+    768 held of 128: a buffer of 32 768 / 24 576 rows, a row block of
+    8192 / 6144), forward and backward under the layer's checkpoint:
+    every grouped product is the TPU's own grouped-matmul kernel
+    (``ragged-dot`` custom calls that visit the row tiles in use), none
+    was expanded into a dense product over all the experts (16 times
+    the work). Outside the loop over the further blocks NO array of the
+    buffer's rows is written but the two sums that bring a block's rows
+    back to their tokens (XLA:TPU does not fuse a gather into the sum
+    that reads it: ``[per_token, tokens, 2048]``, float32 for the
+    result and the compute type for the tokens' cotangent): no fill,
+    mask, activation, cast or product result of ``tokens x per_token``
+    rows; the temporaries are a sixth of what the whole buffer took
+    (under 3 GiB then)."""
     from fedtorch_tpu.ops import routed_experts
 
-    T, d, f, held = 4096, 2048, 768, 16
+    T, d, f, held, routed = 4096, 2048, 768, 16, 128
+    assert routed_experts.block_rows(T, per_token, held, routed) == block
     on_chip = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip)
     p = {"gate": on_chip((held, d, f)), "up": on_chip((held, d, f)),
          "down": on_chip((held, f, d))}
 
+    @functools.partial(
+        jax.checkpoint, policy=jax.checkpoint_policies.save_only_these_names(
+            "mlp.gate", "mlp.up"))
+    def layer(p, u, router):
+        gates, chosen = routed_experts.route(u @ router, per_token, True)
+        return routed_experts.expert_share(
+            p, u, gates, chosen, first=0, dt=jnp.bfloat16, block=block,
+            scopes=("lm.router", "lm.experts"))[0]
+
     def loss(p, u, router):
-        gates, chosen = routed_experts.route(u @ router, 8, True)
-        out, _ = routed_experts.expert_share(
-            p, u, gates, chosen, first=0, dt=jnp.bfloat16)
+        out = layer(p, u, router)
         return jnp.sum(out * out)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        p, on_chip((T, d)), on_chip((d, 128))).compile()
+        p, on_chip((T, d)), on_chip((d, routed))).compile()
     text = compiled.as_text()
     kernels = re.findall(r'op_name="[^"]*(ragged-dot[\w-]*)"', text)
     assert len([k for k in kernels if "metadata" not in k]) >= 9, kernels
     # no [experts, rows, width] array: the dense expansion's operand
-    assert not re.search(r"\[16,32768,", text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+    rows = T * per_token
+    assert not re.search(rf"\[16,(?:{rows}|{block}),", text)
+    lines, fused, looped = _computations(text)
+    assert looped, "the further blocks' loop is there to be judged"
+    made = re.compile(
+        rf"%[\w.\-]+ = (\w+)\[(?:{rows}|{per_token},{T}),(?:{d}|{f})\]"
+        r"\S* ([\w\-]+)\(")
+    passes_through = {"parameter", "get-tuple-element", "bitcast"}
+    written = [(m.group(1), line.strip())
+               for name, ls in lines.items()
+               if name not in fused and name not in looped
+               for line in ls for m in [made.search(line)]
+               if m and m.group(2) not in passes_through]
+    assert sorted(t for t, _ in written) == ["bf16", "f32"], \
+        [line[:200] for _, line in written]
+    assert all("/gather" in line for _, line in written)
+    # every operation of the loop's body and of the backward rule
+    # carries a scope the device trace's reader sums
+    ops = re.findall(r'op_name="([^"]*)"', text)
+    body = [o for o in ops if "/while/body/" in o]
+    assert body and all("lm.experts" in o or "lm.router" in o for o in body)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 640 * 2 ** 20, temp
 
 
 def test_selected_attention_compiles_in_chunks_at_the_cells_length(
