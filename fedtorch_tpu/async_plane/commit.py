@@ -134,7 +134,7 @@ class AsyncFederatedTrainer(FederatedTrainer):
     supports_async = True
     # run_round serves the COMMIT dispatch: the base constructor
     # validates the (source x commit x execution) cell — algorithm,
-    # val-stream, fused and shard-gather refusals all ride the one
+    # val-stream and shard-gather refusals all ride the one
     # validator in parallel/round_program.py
     construction_dispatch = "commit"
 

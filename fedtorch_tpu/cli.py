@@ -441,14 +441,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "transformer: ~1.33x FLOPs for depth-independent "
                         "activation memory")
     p.add_argument("--client_fusion", default="auto",
-                   choices=("auto", "vmap", "fused", "sequential"),
-                   help="client-axis execution strategy for the round "
-                        "program's model compute: 'fused' packs the k "
-                        "online clients into one feature_group_count=k "
-                        "grouped conv per layer (k x the MXU lanes; "
-                        "resnet-cifar/cnn + norm=bn, 1-device mesh); "
-                        "'auto' currently keeps 'vmap' pending the "
-                        "on-chip A/B (docs/performance.md)")
+                   choices=("auto", "vmap", "sequential"),
+                   help="how the round program runs the cohort's k "
+                        "clients: 'vmap' stacks them; 'sequential' "
+                        "runs one after another into a running sum, "
+                        "for a model too large to stack k times; "
+                        "'auto' is 'vmap' (docs/performance.md)")
     p.add_argument("--client_shards", type=int, default=0,
                    help="pod-scale client-axis sharding: shard the k "
                         "online clients over this many device groups "
@@ -1250,26 +1248,10 @@ def run_experiment(cfg: ExperimentConfig,
                     # privacy-plane gauges (DP armed) — same batched fetch
                     row["dp_clipped_frac"] = sc["dp_clipped_frac"]
                     row["dp_noise_sigma"] = sc["dp_noise_sigma"]
-                if "lm_exit_mass_last" in sc:
-                    # a looped token model's exit gauges — same fetch
-                    row["lm_exit_mass_last"] = sc["lm_exit_mass_last"]
-                    row["lm_exit_entropy"] = sc["lm_exit_entropy"]
-                if "lm_index_loss" in sc:
-                    # a selected token model's indexer term — same fetch
-                    row["lm_index_loss"] = sc["lm_index_loss"]
-                if "lm_moe_pairs_local" in sc:
-                    # a sparse-expert token model's routing gauges
-                    row["lm_moe_pairs_local"] = sc["lm_moe_pairs_local"]
-                    row["lm_moe_load_max_over_mean"] = \
-                        sc["lm_moe_load_max_over_mean"]
-                    row["lm_moe_rows_visited"] = sc["lm_moe_rows_visited"]
-                if "lm_balance_loss" in sc:
-                    # a biased router's load, bias and balance part
-                    row["lm_router_load_max_over_mean"] = \
-                        sc["lm_router_load_max_over_mean"]
-                    row["lm_router_bias_abs_max"] = \
-                        sc["lm_router_bias_abs_max"]
-                    row["lm_balance_loss"] = sc["lm_balance_loss"]
+                # the model's own gauges (models/common.py
+                # ``is_token_model``) — same batched fetch
+                row.update((name, sc[name])
+                           for name in trainer.gauge_names)
                 if accountant is not None:
                     # host-side accountant read: pure f64 math, no sync
                     row["dp_epsilon_spent"] = accountant.epsilon()

@@ -140,7 +140,7 @@ class DataConfig:
     # device compute (population capped by host RAM;
     # bitwise-identical trajectories). Both values compose with every
     # dispatch (per-round | scan | async commit) and execution
-    # (vmap | fused) the cell validator allows
+    # (vmap | sequential) the cell validator allows
     # (parallel/round_program.py).
     data_plane: str = "device"
     # Host client-store implementation behind the stream plane's feed
@@ -679,22 +679,17 @@ class MeshConfig:
     # one block instead of the depth — the standard TPU HBM lever for
     # deep models / long sequences. Same values, same gradients.
     remat: bool = False
-    # Client-axis execution strategy for the per-client model compute
-    # inside the jitted round program (docs/performance.md
-    # "Client-fused MXU execution"):
-    #   'vmap'  — vmap model.apply over the k online clients (each
-    #             client's 16-64-channel conv tiles the MXU separately;
-    #             the certified round-5 program identity);
-    #   'fused' — pack the k clients into the channel axis and run ONE
-    #             feature_group_count=k grouped conv per layer (k x the
-    #             MXU lanes per pass; numerics-equivalent, pinned by
-    #             tests/test_client_fusion.py). Supported for the
-    #             resnet-cifar family + cnn with norm='bn' on a
-    #             single-device mesh and base-local-step algorithms;
-    #             requesting it elsewhere raises with the reason;
-    #   'auto'  — resolves to 'vmap'. PERF.md section 6 (PR 29) holds
-    #             the chip's one reading of 'fused'; ROADMAP Design 3
-    #             says what follows from it.
+    # How the round program runs the cohort's k clients
+    # (docs/performance.md "The round-program builder"):
+    #   'vmap'       — the k clients stacked, model.apply under vmap;
+    #   'sequential' — one client after another into a running weighted
+    #                  sum, no per-client copy of the parameters at
+    #                  rest: a model too large to stack k times
+    #                  (parallel/round_program.py names what it
+    #                  refuses);
+    #   'auto'       — 'vmap' (ROADMAP Design 5: it should read from
+    #                  shapes and the device's memory whether k copies
+    #                  fit).
     client_fusion: str = "auto"
     # Pod-scale client-axis sharding (docs/performance.md "Pod-scale
     # round programs"): shard the k online clients of a round over
@@ -706,8 +701,8 @@ class MeshConfig:
     # bitwise twin every sharded run is pinned against); S > 1 builds
     # an (S x devices/S) mesh and cuts per-host feed bytes/RAM by S.
     # Must be a power of two <= 64 that divides both the device count
-    # and the cohort width; illegal compositions (fused execution,
-    # robust rules, cohort stats, ...) are refused by name in
+    # and the cohort width; illegal compositions (robust rules,
+    # cohort stats, ...) are refused by name in
     # `round_program.validate_cell`.
     client_shards: int = 0
 
@@ -839,12 +834,10 @@ class ExperimentConfig:
             raise ValueError(
                 f"model.attention must be 'auto', 'dense' or 'flash', "
                 f"got {self.model.attention!r}")
-        if self.mesh.client_fusion not in ("auto", "vmap", "fused",
-                                           "sequential"):
+        if self.mesh.client_fusion not in ("auto", "vmap", "sequential"):
             raise ValueError(
-                f"mesh.client_fusion must be 'auto', 'vmap', 'fused' or "
-                f"'sequential', "
-                f"got {self.mesh.client_fusion!r}")
+                f"mesh.client_fusion must be 'auto', 'vmap' or "
+                f"'sequential', got {self.mesh.client_fusion!r}")
         cs = self.mesh.client_shards
         if cs < 0 or cs > 64 or (cs > 0 and cs & (cs - 1)):
             raise ValueError(

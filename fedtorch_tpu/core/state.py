@@ -103,24 +103,12 @@ class RoundMetrics(NamedTuple):
     dp_clipped_frac: Any = None    # scalar [0,1] — accepted clients clipped
     dp_noise_sigma: Any = None     # scalar — applied noise stddev
     #                                (sigma * noise_scale; 0 after degrade)
-    # a looped token model's exit gauges (models/hybrid_lm.py
-    # ``exit_objective``), means over the round's clients and steps,
-    # from the sequential execution. None for every other model: zero
+    # a token model's own gauges (models/common.py ``is_token_model``):
+    # the tuple of float32 scalars its ``round_gauges`` makes of the
+    # loss parts, in its ``gauge_names``' order, from the sequential
+    # execution. None for every other model and execution: zero
     # leaves, the round program as it was.
-    lm_exit_mass_last: Any = None  # scalar — exit mass on the last pass
-    lm_exit_entropy: Any = None    # scalar — entropy of the exit law
-    # a sparse token model's gauges (ops/routed_experts.py,
-    # ops/sparse_attention.py), means over the round's clients, steps
-    # and layers, from the sequential execution; None for every other
-    # model
-    lm_moe_pairs_local: Any = None         # scalar — pairs computed here
-    lm_moe_load_max_over_mean: Any = None  # scalar — fullest held expert
-    lm_moe_rows_visited: Any = None        # scalar — buffer rows worked over
-    lm_index_loss: Any = None              # scalar — the indexers' L_I
-    # a biased router's gauges (DeepSeek-V3's ``noaux_tc``), likewise
-    lm_router_load_max_over_mean: Any = None   # scalar — over ALL routed
-    lm_router_bias_abs_max: Any = None         # scalar — largest |b|
-    lm_balance_loss: Any = None                # scalar — L_B's value: 0
+    model_gauges: Any = None
 
 
 def tree_where(pred, on_true, on_false):

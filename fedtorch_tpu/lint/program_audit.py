@@ -296,24 +296,6 @@ def _audit_config(source: str, dispatch: str, execution: str,
 
     facts = cell_build_facts(source, dispatch, execution,
                              client_shards=client_shards)
-    if execution == "fused":
-        # the fused execution needs a fused-capable module (cnn/bn on
-        # 32x32 inputs) and a single-device mesh
-        return ExperimentConfig(
-            data=DataConfig(dataset="cifar10", batch_size=4,
-                            augment=False,
-                            data_plane=facts["data_plane"]),
-            federated=FederatedConfig(
-                federated=True, num_clients=4, online_client_rate=0.5,
-                algorithm="fedavg", sync_type="local_step",
-                sync_mode=facts["sync_mode"]),
-            model=ModelConfig(arch="cnn", norm="bn"),
-            optim=OptimConfig(lr=0.05, in_momentum=True),
-            train=TrainConfig(local_step=2),
-            mesh=MeshConfig(num_devices=1,
-                            client_fusion=facts["client_fusion"],
-                            compute_dtype=compute_dtype),
-        ).finalize()
     return ExperimentConfig(
         data=DataConfig(dataset="synthetic", synthetic_dim=16,
                         batch_size=8, synthetic_alpha=0.5,
@@ -338,27 +320,14 @@ def _audit_config(source: str, dispatch: str, execution: str,
 def _build_cell_trainer(source: str, dispatch: str, execution: str,
                         compute_dtype: str = "float32",
                         client_shards: int = 0):
-    import numpy as np
-
     from fedtorch_tpu.algorithms import make_algorithm
     from fedtorch_tpu.data import build_federated_data
-    from fedtorch_tpu.data.batching import stack_partitions
     from fedtorch_tpu.models import define_model
     from fedtorch_tpu.parallel import FederatedTrainer
 
     cfg = _audit_config(source, dispatch, execution, compute_dtype,
                         client_shards)
-    if execution == "fused":
-        sizes = (16, 9, 12, 16)
-        rng = np.random.RandomState(0)
-        feats = rng.randn(sum(sizes), 32, 32, 3).astype(np.float32)
-        labels = rng.randint(0, 10, sum(sizes))
-        off = np.concatenate([[0], np.cumsum(sizes)])
-        parts = [np.arange(off[i], off[i + 1])
-                 for i in range(len(sizes))]
-        data = stack_partitions(feats, labels, parts)
-    else:
-        data = build_federated_data(cfg).train
+    data = build_federated_data(cfg).train
     model = define_model(cfg, batch_size=cfg.data.batch_size)
     if cfg.federated.sync_mode == "async":
         from fedtorch_tpu.async_plane import AsyncFederatedTrainer
@@ -485,8 +454,7 @@ def audit_cell_evidence(ev: Dict, *, compute_dtype: str = "float32",
 
 
 # bf16 twins: the vmap round/scan cells re-lower bf16-configured so the
-# f32-in-bf16 half of FTP001 has a live program to check (the fused
-# execution pins its own lowering contract in test_client_fusion)
+# f32-in-bf16 half of FTP001 has a live program to check
 BF16_CELLS = (("resident", "round", "vmap"), ("feed", "round", "vmap"),
               ("resident", "scan", "vmap"), ("feed", "scan", "vmap"))
 
@@ -540,8 +508,7 @@ def audit_programs(*, baseline_path: str = PROGRAM_BASELINE,
             variants.append(("bfloat16", 0))
         if (execution == "vmap"
                 and len(jax.devices()) >= PODSCALE_SHARDS):
-            # the mesh'd twin of every legal vmap cell — fused cells
-            # refuse multi-shard by name and are not lowered here
+            # the mesh'd twin of every legal vmap cell
             variants.append(("float32", PODSCALE_SHARDS))
         for compute_dtype, shards in variants:
             ev = lower_cell(source, dispatch, execution,

@@ -53,7 +53,13 @@ RESERVED_METRIC_FIELDS: Tuple[str, ...] = ()
 NON_CONFIG_DESTS: Tuple[str, ...] = ("download",)
 
 # functions whose returned dict keys ride the metrics row
-_GAUGE_FN_NAMES = {"stats", "telemetry_gauges", "round_gauges"}
+_GAUGE_FN_NAMES = {"stats", "telemetry_gauges", "round_gauges",
+                   # a token model's host floats (models/common.py
+                   # ``is_token_model``) and the counters under them
+                   "trace_gauges", "kept_counters"}
+# module-level dict literals whose keys ride the metrics row: a token
+# model's table of its ``gauge_names``
+_GAUGE_TABLE_NAMES = {"_PART_GAUGES"}
 
 
 def _finding(path: str, line: int, rule: str, message: str,
@@ -86,9 +92,11 @@ def emitted_row_fields_from_source(src: str) -> Set[str]:
     * keys of the round loop's ``row = {...}`` literal,
       ``row["x"] = ...`` assignments, and ``row.update(x=..., {...})``;
     * keys of dict literals built/returned inside functions named
-      ``stats`` / ``telemetry_gauges`` / ``round_gauges`` (the gauge
-      providers the loop merges in), including ``out["x"] = ...`` and
-      ``out.update({...}, x=...)`` inside them.
+      in ``_GAUGE_FN_NAMES`` (the gauge providers the loop merges in),
+      including ``out["x"] = ...`` and ``out.update({...}, x=...)``
+      inside them;
+    * keys of the module-level dict literals named in
+      ``_GAUGE_TABLE_NAMES``.
     """
     tree = ast.parse(src)
     fields: Set[str] = set()
@@ -102,11 +110,12 @@ def emitted_row_fields_from_source(src: str) -> Set[str]:
         for a in call.args:
             fields.update(_str_keys(a))
 
-    # the row loop's direct writes
+    # the row loop's direct writes, and the gauge tables
     for node in ast.walk(tree):
         if isinstance(node, ast.Assign):
             for tgt in node.targets:
-                if isinstance(tgt, ast.Name) and tgt.id == "row":
+                if isinstance(tgt, ast.Name) and (
+                        tgt.id == "row" or tgt.id in _GAUGE_TABLE_NAMES):
                     fields.update(_str_keys(node.value))
                 if isinstance(tgt, ast.Subscript) and \
                         isinstance(tgt.value, ast.Name) and \
@@ -146,6 +155,8 @@ _EMIT_SITE_FILES = (
     "fedtorch_tpu/cli.py",
     "fedtorch_tpu/parallel/federated.py",
     "fedtorch_tpu/async_plane/commit.py",
+    # a token model's own gauges (models/common.py ``is_token_model``)
+    "fedtorch_tpu/models/hybrid_lm.py",
     "fedtorch_tpu/data/streaming.py",
     "fedtorch_tpu/utils/checkpoint.py",
     "fedtorch_tpu/robustness/host_recovery.py",

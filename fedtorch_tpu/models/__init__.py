@@ -11,7 +11,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from fedtorch_tpu.config import ExperimentConfig
-from fedtorch_tpu.models.cnn import CNN, FusedCNN
+from fedtorch_tpu.models.cnn import CNN
 from fedtorch_tpu.models.common import (
     CONVEX_DIMS, REGRESSION_DIMS, ModelDef, flat_input_size, image_shape,
     num_classes_of,
@@ -22,8 +22,7 @@ from fedtorch_tpu.models.linear import (
 )
 from fedtorch_tpu.models.mlp import MLP
 from fedtorch_tpu.models.resnet import (
-    FusedResNetCifar, ResNetCifar, ResNetImageNet, build_fused_resnet,
-    build_resnet,
+    ResNetCifar, ResNetImageNet, build_resnet,
 )
 from fedtorch_tpu.models.rnn import CharGRU
 from fedtorch_tpu.models.wideresnet import WideResNet, build_wideresnet
@@ -51,33 +50,6 @@ def _sample_regression(dataset: str, batch: int, synthetic_dim: int):
     dim = synthetic_dim if dataset == "synthetic" \
         else REGRESSION_DIMS[dataset]
     return jnp.zeros((batch, dim), jnp.float32)
-
-
-def define_fused_model(cfg: ExperimentConfig,
-                       num_clients: int) -> "object | None":
-    """Client-fused module for ``cfg.mesh.client_fusion='fused'``.
-
-    Returns a flax module whose parameter tree is the vmap path's
-    per-client tree stacked on a leading ``[num_clients]`` axis and
-    whose ``apply`` maps stacked ``[k, B, ...]`` inputs to
-    ``[k, B, classes]`` logits through ``feature_group_count=k``
-    grouped convolutions (models/common.py "client-fused layers"), or
-    ``None`` when the (arch, dataset, norm) triple has no fused form —
-    the engine's fusion gate (parallel/fusion.py) then keeps the vmap
-    strategy."""
-    arch, dataset, m = cfg.model.arch, cfg.data.dataset, cfg.model
-    if arch.startswith("resnet"):
-        return build_fused_resnet(arch, dataset, num_clients, m.norm,
-                                  dtype=cfg.mesh.compute_dtype,
-                                  remat=cfg.mesh.remat)
-    if arch == "cnn":
-        try:
-            image_shape(dataset)
-        except NotImplementedError:
-            return None
-        return FusedCNN(dataset=dataset, num_clients=num_clients,
-                        dtype=cfg.mesh.compute_dtype)
-    return None
 
 
 def define_model(cfg: ExperimentConfig, batch_size: int = 2) -> ModelDef:

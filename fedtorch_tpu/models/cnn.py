@@ -10,10 +10,7 @@ from __future__ import annotations
 import flax.linen as nn
 import jax.numpy as jnp
 
-from fedtorch_tpu.models.common import (
-    FusedConv, FusedDense, fused_max_pool, num_classes_of,
-    pack_clients,
-)
+from fedtorch_tpu.models.common import num_classes_of
 
 
 class CNN(nn.Module):
@@ -37,36 +34,3 @@ class CNN(nn.Module):
         x = nn.relu(nn.Dense(512, dtype=dt)(x))
         return nn.Dense(num_classes_of(self.dataset))(
             x.astype(jnp.float32))
-
-
-class FusedCNN(nn.Module):
-    """Client-fused :class:`CNN` (cfg.mesh.client_fusion='fused'):
-    ``[k, B, H, W, C]`` stacked inputs -> ``[k, B, classes]`` logits
-    with each conv one ``feature_group_count=k`` grouped convolution
-    (models/common.py "client-fused layers"). Parameter tree == the
-    stacked CNN tree (explicit names mirror CNN's auto-names)."""
-    dataset: str
-    num_clients: int = 1
-    dtype: str = "float32"
-
-    @nn.compact
-    def __call__(self, x, train: bool = False):
-        dt = jnp.dtype(self.dtype)
-        k = self.num_clients
-        x = pack_clients(x.astype(dt))  # [B, H, W, k, C]
-        x = FusedConv(20, (5, 5), num_clients=k, padding="VALID",
-                      dtype=dt, use_bias=True, name="Conv_0")(x)
-        x = nn.relu(x)
-        x = fused_max_pool(x, (2, 2), strides=(2, 2))
-        x = FusedConv(50, (5, 5), num_clients=k, padding="VALID",
-                      dtype=dt, use_bias=True, name="Conv_1")(x)
-        x = nn.relu(x)
-        x = fused_max_pool(x, (2, 2), strides=(2, 2))
-        # per-client flatten in the vmap path's (H, W, C) order
-        B = x.shape[0]
-        x = jnp.moveaxis(x, 3, 1).reshape((B, k, -1))
-        x = nn.relu(FusedDense(512, num_clients=k, dtype=dt,
-                               name="Dense_0")(x))
-        x = FusedDense(num_classes_of(self.dataset), num_clients=k,
-                       name="Dense_1")(x.astype(jnp.float32))
-        return x.transpose(1, 0, 2)  # [k, B, classes]

@@ -114,157 +114,6 @@ def norm_f32(kind: str, x, dtype):
     return make_norm(kind)(x.astype(jnp.float32)).astype(dtype)
 
 
-# -- client-fused layers (cfg.mesh.client_fusion='fused') -------------------
-#
-# The federated engine's per-client weights make the vmapped conv lower
-# to a ``batch_group_count=k`` grouped convolution: each online client's
-# 16-64-channel conv tiles the 128-lane MXU separately, leaving most
-# lanes idle (docs/performance.md "MFU roofline" — the round-5 verdict's
-# 3.37% vs ~29% gap). The fused layers below pack the k online clients
-# into the CHANNEL axis instead: activations travel as
-# ``[B, H, W, k, C]`` and every conv is ONE
-# ``lax.conv_general_dilated(feature_group_count=k)`` over ``k*C``
-# channels — k x more output lanes per MXU pass, same per-client math.
-#
-# Contract shared by every Fused* layer: parameters are the vmap path's
-# per-client parameters STACKED on a leading [k] axis, with the SAME
-# names — so ``fused_module.apply({'params': stacked_params}, x)``
-# consumes the exact pytree the engine's ClientState already holds, and
-# the two execution strategies are checkpoint- and state-compatible
-# (tests/test_client_fusion.py pins the numerics A/B).
-
-
-class FusedConv(nn.Module):
-    """k per-client convolutions as one grouped convolution.
-
-    Input/output are client-packed ``[B, H, W, k, C]``; the kernel
-    parameter is the stacked ``[k, kh, kw, cin, features]`` tree the
-    vmap path holds. Group g of the ``feature_group_count=k`` conv sees
-    exactly client g's channels and filters, so the math per client is
-    identical to ``nn.Conv`` — only the MXU tiling changes."""
-    features: int
-    kernel_size: tuple
-    num_clients: int = 1
-    strides: tuple = (1, 1)
-    padding: "int | str | tuple" = 0
-    use_bias: bool = False
-    dtype: "str | jnp.dtype" = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        kh, kw = self.kernel_size
-        k = self.num_clients
-        B, H, W, kx, cin = x.shape
-        assert kx == k, (kx, k)
-        kernel = self.param(
-            "kernel",
-            nn.initializers.lecun_normal(in_axis=(1, 2, 3), out_axis=4,
-                                         batch_axis=(0,)),
-            (k, kh, kw, cin, self.features))
-        dt = jnp.dtype(self.dtype)
-        pad = self.padding
-        if isinstance(pad, int):
-            pad = ((pad, pad), (pad, pad))
-        # channel packing: lhs channel (g, c) -> g*cin + c, rhs output
-        # column (g, f) -> g*features + f; feature_group_count=k then
-        # routes input group g through kernel block g only.
-        lhs = x.astype(dt).reshape(B, H, W, k * cin)
-        rhs = kernel.astype(dt).transpose(1, 2, 3, 0, 4).reshape(
-            kh, kw, cin, k * self.features)
-        y = jax.lax.conv_general_dilated(
-            lhs, rhs, window_strides=tuple(self.strides), padding=pad,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=k)
-        y = y.reshape(y.shape[:3] + (k, self.features))
-        if self.use_bias:
-            bias = self.param("bias", nn.initializers.zeros,
-                              (k, self.features))
-            y = y + bias.astype(dt)
-        return y
-
-
-class FusedDense(nn.Module):
-    """k per-client Dense layers as one batched matmul.
-
-    Input ``[B, k, in]``, output ``[B, k, features]``; parameters are
-    the stacked ``kernel [k, in, features]`` / ``bias [k, features]``."""
-    features: int
-    num_clients: int = 1
-    use_bias: bool = True
-    dtype: "str | jnp.dtype | None" = None
-
-    @nn.compact
-    def __call__(self, x):
-        k = self.num_clients
-        kernel = self.param(
-            "kernel",
-            nn.initializers.lecun_normal(in_axis=(1,), out_axis=2,
-                                         batch_axis=(0,)),
-            (k, x.shape[-1], self.features))
-        if self.dtype is not None:
-            dt = jnp.dtype(self.dtype)
-            x, kernel = x.astype(dt), kernel.astype(dt)
-        y = jnp.einsum("bki,kio->bko", x, kernel)
-        if self.use_bias:
-            bias = self.param("bias", nn.initializers.zeros,
-                              (k, self.features))
-            y = y + bias.astype(y.dtype)
-        return y
-
-
-class FusedBatchStatsNorm(nn.Module):
-    """Per-client :class:`BatchStatsNorm` on client-packed activations.
-
-    Input ``[B, H, W, k, C]`` (or ``[B, k, C]``): statistics reduce
-    over every axis except the trailing ``(k, C)`` pair — the same
-    element set per (client, channel) as the vmap path — with stacked
-    ``scale``/``bias`` parameters of shape ``[k, C]``."""
-    num_clients: int = 1
-    epsilon: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        reduce_axes = tuple(range(x.ndim - 2))
-        mean = jnp.mean(x, axis=reduce_axes, keepdims=True)
-        var = jnp.var(x, axis=reduce_axes, keepdims=True)
-        y = (x - mean) * jax.lax.rsqrt(var + self.epsilon)
-        shape = (self.num_clients, x.shape[-1])
-        scale = self.param("scale", nn.initializers.ones, shape)
-        bias = self.param("bias", nn.initializers.zeros, shape)
-        return y * scale + bias
-
-
-def fused_norm_f32(kind: str, x, dtype, k: int, *, name: str):
-    """Client-packed counterpart of :func:`norm_f32` (f32 statistics,
-    compute-dtype output). Only 'bn' has a fused form — the engine's
-    fusion gate falls back to the vmap path for other norms."""
-    if kind != "bn":
-        raise ValueError(
-            f"client fusion supports norm='bn' only, got {kind!r}")
-    y = FusedBatchStatsNorm(num_clients=k, name=name)(
-        x.astype(jnp.float32))
-    return y.astype(dtype)
-
-
-def fused_max_pool(x, window: tuple, strides: tuple):
-    """Per-client max pool on ``[B, H, W, k, C]`` (``nn.max_pool``
-    would pool over the packed client axis for 5-D inputs)."""
-    wh, ww = window
-    sh, sw = strides
-    # init must be a PYTHON scalar (as in flax's max_pool): an array
-    # constant here breaks reduce_window's linearization under lax.scan
-    return jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max,
-        window_dimensions=(1, wh, ww, 1, 1),
-        window_strides=(1, sh, sw, 1, 1), padding="VALID")
-
-
-def pack_clients(x):
-    """``[k, B, H, W, C]`` stacked batches -> ``[B, H, W, k, C]``
-    client-packed activations (the fused layers' layout)."""
-    return jnp.moveaxis(x, 0, -2)
-
-
 def make_norm(kind: str):
     """Norm factory: 'bn' -> batch-stats norm, 'gn' -> GroupNorm."""
     if kind == "bn":
@@ -286,7 +135,30 @@ class _GN(nn.Module):
 def is_token_model(model) -> bool:
     """A model that makes its target from the batch itself (the next
     token, models/hybrid_lm.py): its loss is ``model.token_loss(params,
-    x)`` and a row's label takes no part in it."""
+    x)`` and a row's label takes no part in it.
+
+    What such a model reports on the round's row (``metrics.jsonl``) is
+    its own business: the engine (``parallel/federated.py``, ``cli.py``)
+    names none of the keys. A token model MAY give, and the engine
+    reads with one ``getattr`` each:
+
+    * ``gauge_names`` — a static tuple of row keys, known from the
+      model's specification before anything is traced; empty where its
+      loss reports no parts. Where it is not, the sequential round asks
+      ``local_step`` for the parts (``with_parts``).
+    * ``round_gauges(parts)`` — from ``token_loss_parts``' parts
+      stacked over the round's clients and steps (``[k, K, ...]``) a
+      tuple of float32 scalars in ``gauge_names``' order. It runs
+      inside the round program; the tuple is
+      ``RoundMetrics.model_gauges`` and rides the round's one scalar
+      fetch under those keys.
+    * ``trace_gauges(rows, tokens)`` — one dict of the host floats that
+      are known when a ``rows x tokens`` training step is traced (what
+      the step keeps, which kernels it takes), merged into the row as
+      it is.
+
+    A new key is written in the model's file and documented in
+    ``telemetry/schema.py`` and docs/observability.md, nowhere else."""
     return getattr(model, "token_loss", None) is not None
 
 

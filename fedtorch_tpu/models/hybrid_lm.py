@@ -319,12 +319,6 @@ class HybridSpec(NamedTuple):
     def head_size(self) -> int:
         return self.head_dim or self.hidden_size // self.num_attention_heads
 
-    @property
-    def loss_parts(self) -> bool:
-        """The training loss reports parts beside its value."""
-        return self.looped or self.experts is not None \
-            or self.selection is not None
-
 
 def _experts_of(path: str, doc: dict) -> Optional[Experts]:
     """The expert layer of a file with ``num_experts``: the experts
@@ -1359,6 +1353,45 @@ def exit_gate(params, hs):
     return jnp.sum(hs * gate["w"], axis=-1) + gate["b"]
 
 
+# the round's row key <- the part of ``token_loss_parts`` whose mean
+# over the round's clients and steps it holds (``exit_mass``: the last
+# pass's), in the order the round's metrics carry them
+_PART_GAUGES = {
+    # a looped model's exit law (``exit_objective``)
+    "lm_exit_mass_last": "exit_mass",
+    "lm_exit_entropy": "exit_entropy",
+    # the expert layers' routing (``ops/routed_experts.py``) and the
+    # indexers' ``L_I`` (``ops/sparse_attention.py``), over the layers
+    "lm_moe_pairs_local": "moe_pairs",
+    "lm_moe_load_max_over_mean": "moe_load_max_over_mean",
+    "lm_moe_rows_visited": "moe_rows_visited",
+    "lm_index_loss": "index_loss",
+    # a biased router's load over ALL routed experts, largest ``|b|``
+    # and the balance part's value (zero)
+    "lm_router_load_max_over_mean": "router_load_max_over_mean",
+    "lm_router_bias_abs_max": "router_bias_abs_max",
+    "lm_balance_loss": "balance_loss",
+}
+
+
+def gauge_parts(s: HybridSpec) -> frozenset:
+    """The keys of ``token_loss_parts``' parts that feed a gauge, from
+    the specification alone: which layers run says which parts come."""
+    if s.looped:
+        return frozenset({"exit_mass", "exit_entropy"})
+    if not s.prenorm:       # the other blocks' layers report no parts
+        return frozenset()
+    keys = set()
+    if s.selection is not None and "full_attention" in s.layer_types:
+        keys.add("index_loss")
+    if not all(dense_layer(s, i) for i in range(len(s.layer_types))):
+        keys |= {"moe_pairs", "moe_load_max_over_mean", "moe_rows_visited"}
+        if s.experts.biased:
+            keys |= {"router_load_max_over_mean", "router_bias_abs_max",
+                     "balance_loss"}
+    return frozenset(keys)
+
+
 class HybridLM(NamedTuple):
     """The model as the engine sees it: :class:`models.common.ModelDef`'s
     surface, with the loss made from ``x`` (``token_loss``) because a
@@ -1379,76 +1412,63 @@ class HybridLM(NamedTuple):
         return self.module
 
     @property
-    def ut_steps(self) -> int:
-        """Passes a token makes through the stack of layers."""
-        return self.module.total_ut_steps
+    def gauge_names(self) -> Tuple[str, ...]:
+        """The row keys of :meth:`round_gauges`, in its order, from the
+        specification (``models/common.py``: a token model's gauges);
+        empty where the loss reports no such part."""
+        parts = gauge_parts(self.module)
+        return tuple(name for name, key in _PART_GAUGES.items()
+                     if key in parts)
 
-    @property
-    def loss_parts(self) -> bool:
-        """``token_loss_parts`` reports parts for the round's row."""
-        return self.module.loss_parts
+    def round_gauges(self, parts) -> tuple:
+        """:attr:`gauge_names`' values, float32 scalars, from
+        ``token_loss_parts``' parts stacked over the round's clients
+        and steps (``[k, K, ...]``): the means; of the exit masses
+        ``[k, K, R]``, the last pass's."""
+        keys = gauge_parts(self.module)
+        return tuple(
+            jnp.mean(parts[key][..., -1] if key == "exit_mass"
+                     else parts[key])
+            for key in _PART_GAUGES.values() if key in keys)
 
-    def selected_share(self, tokens: int) -> Optional[float]:
-        """Selected over causal query-key pairs of a ``tokens``-long
-        row, from shapes; None without ``sa_config``."""
-        sel = self.module.selection
-        return None if sel is None else sparse_attention.selected_share(
-            tokens, sel.topk)
-
-    def selected_kernel_share(self, tokens: int) -> Optional[float]:
-        """The share of a step's selected-attention layer calls that
-        run the fused kernels on ``tokens``-long rows as the step is
-        traced here (every call has the same shapes: 0 or 1), from the
-        backend and shapes (``sparse_attention.takes_kernel``); None
-        without ``sa_config``."""
-        s = self.module
-        if s.selection is None:
-            return None
-        return float(sparse_attention.takes_kernel(
-            s.num_attention_heads, s.kv_heads, s.head_size,
-            sparse_attention.chunk_of(tokens, s.selection.chunk), tokens))
-
-    def attention_kernel_share(self, tokens: int) -> Optional[float]:
-        """The share of a step's latent-attention layer calls whose
-        softmax attention runs the flash kernel on ``tokens``-long rows
-        as the step is traced here (every call has the same shapes: 0
-        or 1), from the launcher's ``attention`` mode, the length
-        (``ops/attention_dispatch.py``) and the backend (off a TPU the
-        flash path is its dense oracle); None without latent
-        attention."""
-        if self.module.latent is None:
-            return None
-        return float(resolve_attention(self.attention, tokens) == "flash"
-                     and on_tpu())
-
-    def attention_backward_kernel_share(self, tokens: int
-                                        ) -> Optional[float]:
-        """The share of those layer calls whose backward pass runs the
-        flash path's backward kernel, not its chunked scan, as the step
-        is traced here (0 or 1): the decision of the flash path's
-        backward rule (``ops/pallas/flash_attention.py``:
-        ``backward_kernel_taken``) where the call takes that path; None
-        without latent attention."""
-        l = self.module.latent
-        if l is None:
-            return None
-        if resolve_attention(self.attention, tokens) != "flash":
-            return 0.0
-        from fedtorch_tpu.ops.pallas.flash_attention import (
-            backward_kernel_taken,
-        )
-        return float(backward_kernel_taken(tokens, l.nope + l.rope))
+    def trace_gauges(self, rows: int, tokens: int) -> dict:
+        """The row's host floats that are known when a ``rows x
+        tokens`` training step is traced here, from the specification,
+        the launcher's flags, shapes and the backend
+        (``telemetry/schema.py`` describes each key): a looped stack's
+        passes; under ``remat`` :func:`kept_counters` of the step as it
+        was (or will be) traced; under ``sa_config`` the selected share
+        of the causal pairs and whether the layer calls take the fused
+        kernels; with latent attention whether they take the flash
+        kernel and its backward kernel (each call has the same shapes:
+        0 or 1; off a TPU the flash path is its dense oracle)."""
+        s, out = self.module, {}
+        if s.looped:
+            out["ut_steps"] = float(s.total_ut_steps)
+        if self.remat:
+            out.update(kept_counters(s, tokens, _kept_for(s, rows, tokens),
+                                     rows))
+        if s.selection is not None:
+            out["lm_selected_share"] = sparse_attention.selected_share(
+                tokens, s.selection.topk)
+            out["lm_selected_kernel_share"] = float(
+                sparse_attention.takes_kernel(
+                    s.num_attention_heads, s.kv_heads, s.head_size,
+                    sparse_attention.chunk_of(tokens, s.selection.chunk),
+                    tokens))
+        if s.latent is not None:
+            from fedtorch_tpu.ops.pallas.flash_attention import (
+                backward_kernel_taken,
+            )
+            flash = resolve_attention(self.attention, tokens) == "flash"
+            out["lm_attention_kernel_share"] = float(flash and on_tpu())
+            out["lm_attention_backward_kernel_share"] = float(
+                flash and backward_kernel_taken(
+                    tokens, s.latent.nope + s.latent.rope))
+        return out
 
     def init(self, rng):
         return _jitted_init(self.module)(rng)
-
-    def kept_gauges(self, rows: int, tokens: int) -> dict:
-        """:func:`kept_counters` of a ``rows x tokens`` training step
-        as it was (or will be) traced; nothing without ``remat``."""
-        if not self.remat:
-            return {}
-        return kept_counters(self.module, tokens,
-                             _kept_for(self.module, rows, tokens), rows)
 
     def _states(self, params, x):
         """(compute type, the states the head reads, the layer calls'

@@ -19,10 +19,7 @@ from typing import Type
 import flax.linen as nn
 import jax.numpy as jnp
 
-from fedtorch_tpu.models.common import (
-    FusedConv, FusedDense, fused_norm_f32, norm_f32 as _norm32,
-    num_classes_of, pack_clients,
-)
+from fedtorch_tpu.models.common import norm_f32 as _norm32, num_classes_of
 
 
 class BasicBlock(nn.Module):
@@ -168,148 +165,6 @@ class ResNetImageNet(nn.Module):
         x = x.mean(axis=(1, 2))
         return nn.Dense(num_classes_of(self.dataset))(
             x.astype(jnp.float32))
-
-
-# -- client-fused variants (cfg.mesh.client_fusion='fused') -----------------
-#
-# Structural mirrors of the modules above on client-packed activations
-# ([B, H, W, k, C]; see models/common.py "client-fused layers"): every
-# submodule carries the SAME explicit name as its vmap-path counterpart,
-# so the parameter tree of FusedResNetCifar(k=k) is exactly the vmap
-# path's per-client tree stacked on a leading [k] axis — the engine
-# feeds it the gathered ClientState params unchanged.
-
-
-class FusedBasicBlock(nn.Module):
-    planes: int
-    num_clients: int = 1
-    stride: int = 1
-    norm: str = "bn"
-    dtype: str = "float32"
-    expansion = 1
-
-    @nn.compact
-    def __call__(self, x, train: bool = False):
-        dt = jnp.dtype(self.dtype)
-        k = self.num_clients
-        nrm = lambda v, i: fused_norm_f32(self.norm, v, dt, k,
-                                          name=f"BatchStatsNorm_{i}")
-        residual = x
-        y = FusedConv(self.planes, (3, 3), num_clients=k,
-                      strides=(self.stride, self.stride), padding=1,
-                      use_bias=False, dtype=dt, name="Conv_0")(x)
-        y = nrm(y, 0)
-        y = nn.relu(y)
-        y = FusedConv(self.planes, (3, 3), num_clients=k, padding=1,
-                      use_bias=False, dtype=dt, name="Conv_1")(y)
-        y = nrm(y, 1)
-        if self.stride != 1 or x.shape[-1] != self.planes:
-            residual = FusedConv(self.planes, (1, 1), num_clients=k,
-                                 strides=(self.stride, self.stride),
-                                 use_bias=False, dtype=dt,
-                                 name="Conv_2")(x)
-            residual = nrm(residual, 2)
-        return nn.relu(y + residual)
-
-
-class FusedBottleneck(nn.Module):
-    planes: int
-    num_clients: int = 1
-    stride: int = 1
-    norm: str = "bn"
-    dtype: str = "float32"
-    expansion = 4
-
-    @nn.compact
-    def __call__(self, x, train: bool = False):
-        dt = jnp.dtype(self.dtype)
-        k = self.num_clients
-        nrm = lambda v, i: fused_norm_f32(self.norm, v, dt, k,
-                                          name=f"BatchStatsNorm_{i}")
-        residual = x
-        out_planes = self.planes * self.expansion
-        y = FusedConv(self.planes, (1, 1), num_clients=k, use_bias=False,
-                      dtype=dt, name="Conv_0")(x)
-        y = nrm(y, 0)
-        y = nn.relu(y)
-        y = FusedConv(self.planes, (3, 3), num_clients=k,
-                      strides=(self.stride, self.stride), padding=1,
-                      use_bias=False, dtype=dt, name="Conv_1")(y)
-        y = nrm(y, 1)
-        y = nn.relu(y)
-        y = FusedConv(out_planes, (1, 1), num_clients=k, use_bias=False,
-                      dtype=dt, name="Conv_2")(y)
-        y = nrm(y, 2)
-        if self.stride != 1 or x.shape[-1] != out_planes:
-            residual = FusedConv(out_planes, (1, 1), num_clients=k,
-                                 strides=(self.stride, self.stride),
-                                 use_bias=False, dtype=dt,
-                                 name="Conv_3")(x)
-            residual = nrm(residual, 3)
-        return nn.relu(y + residual)
-
-
-class FusedResNetCifar(nn.Module):
-    """Client-fused :class:`ResNetCifar`: ``[k, B, H, W, C]`` stacked
-    inputs -> ``[k, B, num_classes]`` logits, every conv a
-    ``feature_group_count=k`` grouped convolution over k x the
-    channels. Parameter tree == stacked ResNetCifar tree (the
-    block/norm/head names below replicate the vmap path's
-    auto-names)."""
-    dataset: str
-    size: int
-    num_clients: int = 1
-    norm: str = "bn"
-    dtype: str = "float32"
-    remat: bool = False  # see ResNetCifar.remat
-
-    @nn.compact
-    def __call__(self, x, train: bool = False):
-        if self.size % 6 != 2:
-            raise ValueError(f"resnet_size must be 6n+2, got {self.size}")
-        dt = jnp.dtype(self.dtype)
-        k = self.num_clients
-        x = pack_clients(x.astype(dt))
-        n_blocks = (self.size - 2) // 6
-        base: Type = FusedBottleneck if self.size >= 44 else FusedBasicBlock
-        block = nn.remat(base, static_argnums=(2,)) if self.remat \
-            else base
-        # vmap-path names: base names exclude the Fused prefix so the
-        # tree matches BasicBlock_i / Bottleneck_i exactly
-        base_name = base.__name__.replace("Fused", "")
-        x = FusedConv(16, (3, 3), num_clients=k, padding=1,
-                      use_bias=False, dtype=dt, name="Conv_0")(x)
-        x = fused_norm_f32(self.norm, x, dt, k, name="BatchStatsNorm_0")
-        x = nn.relu(x)
-        bi = 0
-        for stage, planes in enumerate((16, 32, 64)):
-            for i in range(n_blocks):
-                stride = 2 if (stage > 0 and i == 0) else 1
-                x = block(planes=planes, num_clients=k, stride=stride,
-                          norm=self.norm, dtype=self.dtype,
-                          name=f"{base_name}_{bi}")(x, train)
-                bi += 1
-        x = x.mean(axis=(1, 2))  # [B, k, C]
-        x = FusedDense(num_classes_of(self.dataset), num_clients=k,
-                       name="Dense_0")(x.astype(jnp.float32))
-        return x.transpose(1, 0, 2)  # [k, B, classes]
-
-
-def build_fused_resnet(arch: str, dataset: str, num_clients: int,
-                       norm: str = "bn", dtype: str = "float32",
-                       remat: bool = False) -> "nn.Module | None":
-    """Client-fused counterpart of :func:`build_resnet`. Returns None
-    when no fused form exists (ImageNet-family variant, non-'bn' norm)
-    — the engine's fusion gate then keeps the vmap path."""
-    if norm != "bn":
-        return None
-    size = int(arch.replace("resnet", ""))
-    if "cifar" in dataset or "svhn" in dataset \
-            or "downsampled_imagenet" in dataset or dataset == "stl10":
-        return FusedResNetCifar(dataset=dataset, size=size,
-                                num_clients=num_clients, norm=norm,
-                                dtype=dtype, remat=remat)
-    return None
 
 
 def build_resnet(arch: str, dataset: str, norm: str = "bn",

@@ -34,7 +34,7 @@ Design (SURVEY.md §7):
   host-packed feed built ahead by ``data/streaming.py``) x dispatch
   (per-round | ``lax.scan``-of-R, incl. the scanned streamed program
   over an [R, ...] feed window | async one-step commit) x client
-  execution (vmap | fused) compose orthogonally; illegal cells are
+  execution (vmap | sequential) compose orthogonally; illegal cells are
   refused by ONE named ValueError from ``validate_cell``. Every cell
   funnels into ``_round_core`` and shares ``round_row_plan``, so
   trajectories are bitwise-identical across sources and dispatches
@@ -67,13 +67,11 @@ from jax.sharding import NamedSharding, PartitionSpec
 from fedtorch_tpu.algorithms.base import (FedAlgorithm, num_online_effective)
 from fedtorch_tpu.config import ExperimentConfig
 from fedtorch_tpu.core import optim
-from fedtorch_tpu.core.losses import (
-    accuracy, make_criterion, per_sample_loss,
-)
+from fedtorch_tpu.core.losses import make_criterion, per_sample_loss
 from fedtorch_tpu.core.schedule import LRSchedule, compile_schedule, lr_at
 from fedtorch_tpu.core.state import (
-    ClientState, RoundMetrics, ServerState, tree_broadcast_clients,
-    tree_bytes, tree_sub, tree_where, tree_zeros_like,
+    ClientState, RoundMetrics, ServerState, tree_bytes, tree_sub,
+    tree_where, tree_zeros_like,
 )
 from fedtorch_tpu.data.batching import (
     VAL_FOLD, ClientData, epoch_permutation, gather_client_rows,
@@ -84,7 +82,6 @@ from fedtorch_tpu.data.streaming import (
 )
 from fedtorch_tpu.models.common import ModelDef, is_token_model
 from fedtorch_tpu.ops.augment import augment_image_batch
-from fedtorch_tpu.parallel.fusion import resolve_client_fusion
 from fedtorch_tpu.parallel.round_program import (
     RoundProgramBuilder, resolve_gather_mode,
 )
@@ -143,30 +140,6 @@ _SCALAR_FIELDS = (
     # saturation + applied noise stddev; None when DP is off
     ("dp_clipped_frac", "dp_clipped_frac"),
     ("dp_noise_sigma", "dp_noise_sigma"),
-    # a looped token model's exit gauges; None for every other model
-    ("lm_exit_mass_last", "lm_exit_mass_last"),
-    ("lm_exit_entropy", "lm_exit_entropy"),
-    # a sparse token model's routing and indexer gauges; None likewise
-    ("lm_moe_pairs_local", "lm_moe_pairs_local"),
-    ("lm_moe_load_max_over_mean", "lm_moe_load_max_over_mean"),
-    ("lm_moe_rows_visited", "lm_moe_rows_visited"),
-    ("lm_index_loss", "lm_index_loss"),
-    # a biased router's load, bias and balance part; None likewise
-    ("lm_router_load_max_over_mean", "lm_router_load_max_over_mean"),
-    ("lm_router_bias_abs_max", "lm_router_bias_abs_max"),
-    ("lm_balance_loss", "lm_balance_loss"),
-)
-# RoundMetrics field <- the key of a token model's loss parts
-# (``token_loss_parts``) whose mean over clients and steps it holds
-_PART_GAUGES = (
-    ("lm_exit_entropy", "exit_entropy"),
-    ("lm_moe_pairs_local", "moe_pairs"),
-    ("lm_moe_load_max_over_mean", "moe_load_max_over_mean"),
-    ("lm_moe_rows_visited", "moe_rows_visited"),
-    ("lm_index_loss", "index_loss"),
-    ("lm_router_load_max_over_mean", "router_load_max_over_mean"),
-    ("lm_router_bias_abs_max", "router_bias_abs_max"),
-    ("lm_balance_loss", "balance_loss"),
 )
 # what the program computes itself, ahead of the table's leaves
 _COMPUTED_SCALARS = ("mean_epoch", "lr", "n_online", "loss_sum",
@@ -180,12 +153,15 @@ _NO_COHORT_VECTORS = dict.fromkeys(
      "cohort_suspicion", "cohort_staleness", "cohort_norm_q"))
 
 
-def _scalar_leaves(metrics: RoundMetrics) -> dict:
+def _scalar_leaves(metrics: RoundMetrics, gauge_names) -> dict:
     """key -> leaf of the table's fields that are present, in the
-    table's order."""
+    table's order, then the model's own gauges under ``gauge_names``
+    (``metrics.model_gauges``, where the round made them)."""
     leaves = ((key, getattr(metrics, field))
               for key, field in _SCALAR_FIELDS)
-    return {key: leaf for key, leaf in leaves if leaf is not None}
+    out = {key: leaf for key, leaf in leaves if leaf is not None}
+    out.update(zip(gauge_names, metrics.model_gauges or ()))
+    return out
 
 
 def _sparse_participation(rng: jax.Array, num_clients: int,
@@ -423,15 +399,6 @@ class FederatedTrainer:
             if is_token_model(model) and data.x.ndim == 3 else 0
         self.tokens_per_round = self.k_online * self.local_steps \
             * self.batch_size * self.row_tokens
-        # passes a token makes through a looped model's layers, for
-        # the row's ``ut_steps`` counter (1: not looped, no counter)
-        self.ut_steps = int(getattr(model, "ut_steps", 1)) \
-            if self.tokens_per_round else 1
-        # the model's loss reports parts for the row (a looped model's
-        # exits, a sparse model's routing and indexer gauges)
-        self.loss_parts = bool(self.tokens_per_round
-                               and getattr(model, "loss_parts", False))
-
         num_epochs = cfg.train.num_epochs or 1
         self.schedule: LRSchedule = compile_schedule(
             cfg.lr_schedule, cfg.optim, num_epochs,
@@ -460,15 +427,19 @@ class FederatedTrainer:
         # round — stashed at first trace (podscale only), emitted via
         # telemetry_gauges
         self._allreduce_bytes: Optional[float] = None
-        # client-axis execution strategy (parallel/fusion.py): 'fused'
-        # swaps the vmapped per-client model compute for ONE
-        # feature_group_count=k grouped conv per layer — k x the MXU
-        # lanes on the 16-64-channel north-star convs. The fused module
-        # consumes the stacked per-client params unchanged;
-        # _fused_client_round keeps every [k] state semantic.
-        self.client_fusion, self.fused_module = resolve_client_fusion(
-            cfg, model, algorithm, int(self.mesh.devices.size),
-            self.k_dispatch)
+        # how the cohort's clients run (``mesh.client_fusion``):
+        # stacked under ``vmap`` ('auto' is 'vmap' today, ROADMAP
+        # Design 5), or 'sequential', one after another into a running
+        # fold (_round_core_sequential); what that cannot serve is
+        # refused by round_program.validate_cell
+        self.client_fusion = "sequential" \
+            if cfg.mesh.client_fusion == "sequential" else "vmap"
+        # the keys of a token model's own gauges on the round's row
+        # (models/common.py ``is_token_model``): the sequential round
+        # asks the loss for its parts and hands them to the model
+        self.gauge_names = tuple(getattr(model, "gauge_names", ())) \
+            if self.tokens_per_round \
+            and self.client_fusion == "sequential" else ()
         # the round-program builder (parallel/round_program.py): the
         # ONE place programs are composed and cells are refused. The
         # construction-time dispatch ('round' here, 'commit' on the
@@ -1042,31 +1013,20 @@ class FederatedTrainer:
                     jnp.sum(losses * act) / n_act,
                     jnp.sum(accs * act) / n_act)
 
-        if self.client_fusion == "fused":
-            # same per-client math, one grouped conv per layer — the
-            # fusion gate guarantees the features the fused step does
-            # not thread (val batches, full loss, rnn carry) are off;
-            # the async plane forces 'vmap', so per-client bases never
-            # reach this branch
-            payloads, deltas, new_on_clients, (losses, accs) = \
-                self._fused_client_round(server, on_clients, on_x, on_y,
-                                         on_sizes, weights, rngs,
-                                         plan.budget_scale, batch_mode)
-        else:
-            # the per-client server snapshot: stacked [k] trees on the
-            # async commit plane, the live server state broadcast
-            # (in_axes=None — vmap treats it exactly like the previous
-            # closure capture, so the sync program is unchanged)
-            stacked_base = base_params is not None
-            base_p_in = base_params if stacked_base else server.params
-            base_a_in = base_aux if stacked_base else server.aux
-            base_ax = 0 if stacked_base else None
-            payloads, deltas, new_on_clients, (losses, accs) = jax.vmap(
-                client_round,
-                in_axes=(0,) * 10 + (base_ax, base_ax)
-            )(on_clients, on_x, on_y, on_vx, on_vy,
-              on_sizes, on_vsizes, weights, rngs,
-              plan.budget_scale, base_p_in, base_a_in)
+        # the per-client server snapshot: stacked [k] trees on the
+        # async commit plane, the live server state broadcast
+        # (in_axes=None — vmap treats it exactly like the previous
+        # closure capture, so the sync program is unchanged)
+        stacked_base = base_params is not None
+        base_p_in = base_params if stacked_base else server.params
+        base_a_in = base_aux if stacked_base else server.aux
+        base_ax = 0 if stacked_base else None
+        payloads, deltas, new_on_clients, (losses, accs) = jax.vmap(
+            client_round,
+            in_axes=(0,) * 10 + (base_ax, base_ax)
+        )(on_clients, on_x, on_y, on_vx, on_vy,
+          on_sizes, on_vsizes, weights, rngs,
+          plan.budget_scale, base_p_in, base_a_in)
         # pod-scale: each shard group leaves the client loops holding
         # its k/S clients' payloads/state; per-client scalars replicate
         # so downstream metric sums stay shard-count invariant
@@ -1439,9 +1399,10 @@ class FederatedTrainer:
         opt0 = optim.init_opt_state((), cfg.optim, lean=True)
         carry0 = model.init_carry(B)
         budget = jnp.asarray(K, jnp.int32)
-        # a looped or a sparse model's loss reports parts: asked for
-        # here alone, so that every other model's step is as it was
-        with_parts = {"with_parts": True} if self.loss_parts else {}
+        # a model with gauges of its own makes them of its loss's
+        # parts: asked for here alone, so that every other model's
+        # step is as it was
+        with_parts = {"with_parts": True} if self.gauge_names else {}
 
         def one_client(total, member):
             x, y, size, weight, rng_c, epoch0, li0 = member
@@ -1522,16 +1483,10 @@ class FederatedTrainer:
                 byzantine_clients=none, robust_selected=none,
                 robust_trimmed=none)
             if parts:
-                # [k, K, R] exit masses, [k, K] entropies and gauges:
-                # means over the round's clients and steps
-                part, means = parts[0], {}
-                if "exit_mass" in part:
-                    means["lm_exit_mass_last"] = jnp.mean(
-                        part["exit_mass"][..., -1])
-                means.update({field: jnp.mean(part[key])
-                              for field, key in _PART_GAUGES
-                              if key in part})
-                metrics = metrics._replace(**means)
+                # the loss's parts, each stacked [k, K, ...] over the
+                # round's clients and steps
+                metrics = metrics._replace(
+                    model_gauges=model.round_gauges(parts[0]))
         with jax.named_scope("fed.server_step"):
             new_server = ServerState(params=new_params, opt=new_opt,
                                      aux=new_saux, round=server.round + 1,
@@ -1543,134 +1498,6 @@ class FederatedTrainer:
                 new_server = alg.post_round_global(
                     new_server, data, jax.random.fold_in(rng_round, 99))
         return new_server, new_clients, metrics
-
-    # -- fused client round (cfg.mesh.client_fusion='fused') --------------
-    def _fused_client_round(self, server, on_clients, x, y, sizes,
-                            weights, rngs, budget_scale, batch_mode):
-        """``client_round`` for the fused client-axis strategy: one
-        scan whose body computes ALL k online clients' forward/backward
-        through the client-fused module (``feature_group_count=k``
-        grouped convs, models/common.py "client-fused layers") while
-        every per-client algorithm hook — extra_loss, transform_grads,
-        the optimizer step, client_payload — still runs under ``vmap``
-        on the stacked [k] state, so hook numerics stay per-client
-        exact for arbitrary hook code. Freeze masks (epoch-sync early
-        exit, straggler cuts), PRNG folds, masked metrics and payload
-        semantics mirror ``client_round`` line for line;
-        tests/test_client_fusion.py pins the A/B against the vmap
-        path."""
-        cfg, model, alg = self.cfg, self.model, self.algorithm
-        K, B, k = self.local_steps, self.batch_size, self.k_dispatch
-        flt = self.fault
-        server_params = server.params
-        with jax.named_scope("fed.local_steps"):
-            nb = jnp.ceil(sizes / B)  # [k] batches per local epoch
-
-            # lint: disable=FTL005 — batch_mode is a static Python bool
-            if not batch_mode:
-                perms = jax.vmap(
-                    lambda r, s: epoch_permutation(
-                        jax.random.fold_in(r, 0), s, x.shape[1])
-                )(rngs, sizes)
-
-            # per-client effective step counts (see client_round)
-            step_budget = (nb.astype(jnp.int32)
-                           * cfg.federated.num_epochs_per_comm) \
-                if self.epoch_sync else jnp.full((k,), K, jnp.int32)
-            if flt.straggler_rate > 0.0:
-                step_budget = jnp.maximum(jnp.ceil(
-                    step_budget.astype(jnp.float32) * budget_scale),
-                    1.0).astype(jnp.int32)
-
-        fused = self.fused_module
-        lrs_of = jax.vmap(lambda e: lr_at(self.schedule, e))
-
-        def step(carry, kk):
-            params, opt, aux, epoch, li = carry
-            active = (kk < step_budget) if self.mask_steps \
-                else jnp.ones((k,), bool)
-            lr = lrs_of(epoch)  # [k]
-            if batch_mode:
-                bx = jax.lax.dynamic_slice_in_dim(x, kk * B, B, axis=1)
-                by = jax.lax.dynamic_slice_in_dim(y, kk * B, B, axis=1)
-            else:
-                bx, by = jax.vmap(
-                    lambda xc, yc, p, s: take_batch(xc, yc, p, s, kk, B)
-                )(x, y, perms, sizes)
-            if self.augment:
-                # client_round's exact fold chain: disjoint parent
-                # 0x7FFFFFFF, then the step index
-                with jax.named_scope("fed.augment"):
-                    aug = jax.vmap(lambda r: jax.random.fold_in(
-                        jax.random.fold_in(r, 0x7FFFFFFF), kk))(rngs)
-                    bx = jax.vmap(augment_image_batch)(aug, bx)
-
-            def loss_fn(p):
-                logits = fused.apply({"params": p}, bx, train=True)
-                # [k, B] per-sample NLL spelled out (per_sample_loss's
-                # 2-D branch is the rnn time-mean, not a client axis)
-                logp = jax.nn.log_softmax(logits)
-                per = -jnp.take_along_axis(
-                    logp, by[..., None].astype(jnp.int32),
-                    axis=-1)[..., 0]
-                # criterion per client (mean over the batch axis) +
-                # the per-client extra loss (FedProx-style terms)
-                loss_k = jnp.mean(per, axis=1) + jax.vmap(
-                    lambda pc, ac: alg.extra_loss(pc, server_params, ac)
-                )(p, aux)
-                # clients are independent, so the grad of the SUM is
-                # each client's own grad — the stacked [k] twin of the
-                # vmapped value_and_grad
-                return jnp.sum(loss_k), (loss_k, logits)
-
-            with jax.named_scope("fed.forward_backward"):
-                (_, (loss_k, logits)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params)
-                grads = jax.vmap(
-                    lambda g, pc, ac, l: alg.transform_grads(
-                        g, params=pc, server_params=server_params,
-                        client_aux=ac, server_aux=server.aux, lr=l)
-                )(grads, params, aux, lr)
-            with jax.named_scope("fed.opt_step"):
-                n_params, n_opt = jax.vmap(
-                    lambda pc, g, o, l: optim.local_step(pc, g, o, l,
-                                                         cfg.optim)
-                )(params, grads, opt, lr)
-                if self.mask_steps:
-                    n_params = tree_where(active, n_params, params)
-                    n_opt = tree_where(active, n_opt, opt)
-            af = active.astype(jnp.float32)
-            acc_k = jax.vmap(accuracy)(logits, by)
-            return (n_params, n_opt, aux, epoch + af / nb,
-                    li + active.astype(li.dtype)), (loss_k, acc_k, af)
-
-        with jax.named_scope("fed.local_steps"):
-            init = (tree_broadcast_clients(server_params, k),
-                    on_clients.opt, on_clients.aux, on_clients.epoch,
-                    on_clients.local_index)
-            (params, opt, aux, epoch, li), (losses, accs, act) = \
-                jax.lax.scan(step, init, jnp.arange(K))
-
-            # delta = server - params, leaf-broadcast over the stacked
-            # [k] axis (same helper as the vmap path so the convention
-            # cannot drift between the two strategies)
-            deltas = tree_sub(server_params, params)
-            lr_end = lrs_of(epoch)
-        with jax.named_scope("fed.wire"):
-            payloads, aux = jax.vmap(
-                lambda d, a, pc, l, sb, w: alg.client_payload(
-                    delta=d, client_aux=a, params=pc,
-                    server_params=server_params, server_aux=server.aux,
-                    lr=l, local_steps=sb, weight=w, full_loss=None)
-            )(deltas, aux, params, lr_end, step_budget, weights)
-        new_states = ClientState(params=params, opt=opt, aux=aux,
-                                 epoch=epoch, local_index=li)
-        # metrics over the steps each client actually took
-        with jax.named_scope("fed.metrics"):
-            n_act = jnp.maximum(jnp.sum(act, axis=0), 1.0)
-            return payloads, deltas, new_states, (
-                jnp.sum(losses * act, axis=0) / n_act,
-                jnp.sum(accs * act, axis=0) / n_act)
 
     def _mean_epoch_dev(self, clients) -> jnp.ndarray:
         """Device-side mean training epoch over the REAL clients — the
@@ -1757,11 +1584,11 @@ class FederatedTrainer:
         """Body of the round's scalar program
         (``self.scalars_trace_name``; under ``jax.jit`` only, which
         specialises on the leaves ``metrics`` holds):
-        ``_COMPUTED_SCALARS`` then ``_scalar_leaves(metrics)``, as ONE
+        ``_COMPUTED_SCALARS`` then ``_scalar_leaves``' values, as ONE
         float32 array, so one copy brings them to the host. Nothing is
         cast: a leaf of another dtype is refused when the program is
         traced."""
-        leaves = _scalar_leaves(metrics)
+        leaves = _scalar_leaves(metrics, self.gauge_names)
         if self.padded_clients != self.num_clients:
             # a padded axis is cut on a replicated copy and summed in
             # index order on every device: what eager _mean_epoch_dev
@@ -1806,7 +1633,8 @@ class FederatedTrainer:
             self.stop_flag_dev(bool(self._stop_signal()))
         values, stop, extra = jax.device_get((packed, stop, extra))
         scalars = dict(zip(
-            _COMPUTED_SCALARS + tuple(_scalar_leaves(metrics)),
+            _COMPUTED_SCALARS
+            + tuple(_scalar_leaves(metrics, self.gauge_names)),
             values.tolist()))
         if stop is not None:
             scalars["stop"] = float(stop)
@@ -1858,30 +1686,11 @@ class FederatedTrainer:
         out = {}
         if self.tokens_per_round:
             out["tokens_trained"] = float(self.tokens_per_round)
-        if self.ut_steps > 1:
-            out["ut_steps"] = float(self.ut_steps)
-        kept = self.model.kept_gauges(self.batch_size, self.row_tokens) \
-            if self.row_tokens and hasattr(self.model, "kept_gauges") \
-            else None
-        if kept:
-            # what the model's rematerialized layers keep of a step
-            out["lm_kept_product_share"] = kept["lm_kept_product_share"]
-            out["lm_kept_residual_bytes"] = kept["lm_kept_residual_bytes"]
-        share = self.model.selected_share(self.row_tokens) \
-            if self.row_tokens and hasattr(self.model, "selected_share") \
-            else None
-        if share is not None:
-            out["lm_selected_share"] = share
-            out["lm_selected_kernel_share"] = \
-                self.model.selected_kernel_share(self.row_tokens)
-        share = self.model.attention_kernel_share(self.row_tokens) \
-            if self.row_tokens \
-            and hasattr(self.model, "attention_kernel_share") else None
-        if share is not None:
-            out["lm_attention_kernel_share"] = share
-            out["lm_attention_backward_kernel_share"] = \
-                self.model.attention_backward_kernel_share(
-                    self.row_tokens)
+            # what the model knows of the step as it is traced here
+            # (models/common.py ``is_token_model``)
+            trace_gauges = getattr(self.model, "trace_gauges", None)
+            if trace_gauges is not None:
+                out.update(trace_gauges(self.batch_size, self.row_tokens))
         ss = self.stream_stats()
         if ss is not None:
             out.update(ss)

@@ -4,8 +4,8 @@ axes (ROADMAP item 2 — "one round-program compiler").
 What used to be four hand-maintained dispatch paths in
 ``parallel/federated.py`` (per-round device, ``run_rounds`` scan,
 streamed per-round, async commit) plus a pairwise gate matrix (stream
-refused ``run_rounds``, async refused fused/scan/shard-gather, fused
-refused multi-device) is composed here from three independent choices:
+refused ``run_rounds``, async refused scan/shard-gather) is composed
+here from three independent choices:
 
 * **data source** — ``'resident'`` (the full ``[C, n_max, ...]`` client
   store lives in HBM and the round gathers its rows in-program) or
@@ -18,8 +18,7 @@ refused multi-device) is composed here from three independent choices:
   scan family, with per-job stale bases threaded through the commit
   seam of ``_round_core``);
 * **client execution** — ``'vmap'`` (per-client model compute under
-  ``vmap``), ``'fused'`` (one ``feature_group_count=k`` grouped conv
-  per layer — ``parallel/fusion.py``) or ``'sequential'`` (the cohort
+  ``vmap``) or ``'sequential'`` (the cohort
   one client after another into a running weighted sum, no per-client
   copy of the parameters at rest —
   ``FederatedTrainer._round_core_sequential``; what needs the stacked
@@ -37,25 +36,14 @@ impossible, each refused by ONE named ``ValueError`` from
 :func:`validate_cell` — there are no per-path gate checks left in
 ``parallel/federated.py`` or ``async_plane/commit.py``:
 
-* ``commit × fused`` — the fused step packs all k clients into one
-  grouped conv against ONE shared server snapshot; buffered commits
-  train each client against its own dispatch-time version;
 * ``scan`` under ``sync_mode='async'`` — commits are host-scheduled
   events (the event scheduler decides each commit's jobs), so there is
   no R-commit program for one trace to scan;
 * algorithm/feature preconditions of an axis value (a ``feed`` source
   cannot replay server-state-dependent participation; ``commit`` needs
-  a stale-snapshot-safe algorithm; ``fused`` packs the clients into
-  one device's channel axis, so it refuses any multi-device mesh —
-  that rule is authored HERE, not in ``parallel/fusion.py``, because
-  this validator owns the whole composition matrix) — named with the
-  same reasons the old per-path gates carried. The remaining
-  fused-execution preconditions (architecture/normalization/optimizer
-  shape) stay authored in ``parallel/fusion.py``
-  (``fusion_supported``): at trainer construction
-  ``resolve_client_fusion`` raises them directly while resolving the
-  execution axis, and :func:`illegal_reason` consults the same
-  function for matrix enumeration — one rule set, two entry points.
+  a stale-snapshot-safe algorithm; ``sequential`` serves what needs no
+  stacked cohort) — named with the same reasons the old per-path gates
+  carried.
 
 The pod-scale **client-shard fact** (``mesh.client_shards``,
 docs/performance.md "Pod-scale round programs") composes with every
@@ -67,7 +55,7 @@ round/commit program, certified by the FTP004 budget. Compositions
 whose cross-client float reductions live OUTSIDE that seam (robust
 rules, cohort statistics, cohort-global-loss algorithms, per-client
 val streams) are refused by name here rather than silently losing
-bitwise parity, and fused × multi-shard stays refused until measured.
+bitwise parity.
 """
 from __future__ import annotations
 
@@ -78,13 +66,12 @@ import jax.numpy as jnp
 
 from fedtorch_tpu.algorithms.base import FedAlgorithm
 from fedtorch_tpu.data.batching import gather_client_rows, round_row_plan
-from fedtorch_tpu.parallel.fusion import fusion_supported
 
 # the three axes; tests and the chaos-suite matrix enumerate these so a
 # new axis value can never be silently absent from the coverage matrix
 SOURCES = ("resident", "feed")
 DISPATCHES = ("round", "scan", "commit")
-EXECUTIONS = ("vmap", "fused", "sequential")
+EXECUTIONS = ("vmap", "sequential")
 
 # algorithms the sequential execution serves: their client hooks are
 # the base's (a weighted delta as payload, no per-client aux read
@@ -181,16 +168,12 @@ def collective_budget(source: str, dispatch: str, execution: str, *,
 def illegal_reason(source: str, dispatch: str, execution: str, *, cfg,
                    algorithm: FedAlgorithm, model, mesh_devices: int,
                    k_online: int, gather_mode: str = "auto",
-                   has_val: bool = False, fused_resolved: bool = False):
+                   has_val: bool = False):
     """The reason a cell is unsupported, or None when it is legal.
 
     ``gather_mode`` is the EXPLICIT (pre-resolution) mode: an
     auto-resolved ``'shard'`` on the resident source is legal; an
-    explicitly pinned one on a packed-row program is not.
-    ``fused_resolved=True`` skips the fused-execution precondition
-    re-check (``fusion.fusion_supported`` builds a throwaway fused
-    module): a trainer whose ``resolve_client_fusion`` already
-    resolved 'fused' has proven it, with the same named reasons."""
+    explicitly pinned one on a packed-row program is not."""
     if source not in SOURCES or dispatch not in DISPATCHES \
             or execution not in EXECUTIONS:
         raise ValueError(
@@ -221,12 +204,6 @@ def illegal_reason(source: str, dispatch: str, execution: str, *, cfg,
             return ("per-client validation splits "
                     "(cfg.federated.personal) are not buffered — "
                     "sync_mode='async' commits carry no val stream")
-        if execution == "fused":
-            return ("client_fusion='fused' packs clients into one "
-                    "grouped conv against ONE shared server snapshot; "
-                    "buffered commits train each client against its "
-                    "own dispatch-time version — use the vmap "
-                    "execution or --sync_mode sync")
         if gather_mode == "shard":
             return ("gather_mode='shard' moves whole client shards; "
                     "the commit program packs each buffered job's rows "
@@ -257,14 +234,6 @@ def illegal_reason(source: str, dispatch: str, execution: str, *, cfg,
     # -- client-shard fact (pod-scale cohort sharding) -------------------
     shards = int(getattr(cfg.mesh, "client_shards", 0) or 0)
     if shards > 1:
-        if execution == "fused":
-            return ("client_fusion='fused' packs all k clients into "
-                    "one grouped conv on one device, while "
-                    f"mesh.client_shards={shards} splits the cohort "
-                    "across device groups — fused x multi-shard stays "
-                    "refused until a sharded grouped-conv lowering is "
-                    "measured (use the vmap execution, which shards "
-                    "the client axis)")
         if k_online % shards:
             return (f"mesh.client_shards={shards} does not divide the "
                     f"dispatch cohort width k={k_online} — contiguous "
@@ -321,21 +290,6 @@ def illegal_reason(source: str, dispatch: str, execution: str, *, cfg,
                         "shard count)")
 
     # -- execution axis --------------------------------------------------
-    if execution == "fused" and mesh_devices > 1:
-        # the one multi-device rule of the fused execution, owned by
-        # this validator (not fusion.py) so the whole composition
-        # matrix refuses from a single site
-        return ("mesh.client_fusion='fused' is unsupported: mesh has "
-                f"{mesh_devices} devices — the packed client/channel "
-                "axis must not be sharded (use the vmap path's "
-                "client-axis sharding)")
-    if execution == "fused" and dispatch != "commit" \
-            and not fused_resolved:
-        fused, why = fusion_supported(cfg, model, algorithm,
-                                      mesh_devices, k_online)
-        if fused is None:
-            return f"mesh.client_fusion='fused' is unsupported: {why}"
-
     if execution == "sequential":
         why = _sequential_refusal(cfg, algorithm, model, dispatch,
                                   mesh_devices, gather_mode, has_val)
@@ -460,15 +414,11 @@ class RoundProgramBuilder:
             self.source, dispatch, self.execution, cfg=t.cfg,
             algorithm=t.algorithm, model=t.model,
             # over-selection widens the cohort the program actually
-            # vmaps/fuses over — validate the dispatch width, not the
+            # runs over — validate the dispatch width, not the
             # close-quorum k_online
             mesh_devices=int(t.mesh.devices.size),
             k_online=getattr(t, "k_dispatch", t.k_online),
-            gather_mode=t.explicit_gather_mode, has_val=t.has_val,
-            # resolve_client_fusion already proved the fused-execution
-            # preconditions (same named reasons) — don't rebuild the
-            # fused module per validate call
-            fused_resolved=t.fused_module is not None)
+            gather_mode=t.explicit_gather_mode, has_val=t.has_val)
 
     def build(self, dispatch: str, *, scan_length: int = 1):
         """Validate the cell, then return its program function."""

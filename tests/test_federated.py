@@ -280,8 +280,9 @@ class TestAsyncGateMatrix:
         cfg = self._async_cfg(algorithm=algorithm)
         self._build(cfg)  # must not raise
 
-    def test_fused_client_fusion_gated(self):
-        cfg = self._async_cfg(mesh_kw={"client_fusion": "fused"})
+    def test_sequential_client_fusion_gated(self):
+        cfg = self._async_cfg(mesh_kw={"client_fusion": "sequential",
+                                       "num_devices": 1})
         with pytest.raises(ValueError, match="client_fusion"):
             self._build(cfg)
 

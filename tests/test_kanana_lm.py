@@ -26,7 +26,9 @@ from fedtorch_tpu.models import hybrid_lm
 from fedtorch_tpu.models.hybrid_lm import HybridLM, load_spec, param_shapes
 from fedtorch_tpu.ops import routed_experts
 from fedtorch_tpu.ops.pallas.flash_attention import flash_attention
-from test_sequential_round import lm_cfg, round_rows, trainer_of
+from test_sequential_round import (
+    gauges_of, lm_cfg, round_rows, trainer_of, with_field_names,
+)
 
 SMALL = {
     "model_type": "deepseek_v3", "vocab_size": 64, "hidden_size": 32,
@@ -508,10 +510,10 @@ def test_the_model_runs_the_same_function_on_both_attention_paths(
     keep = lambda g: [v for p, v in jax.tree_util.tree_leaves_with_path(g)
                       if not is_bias(p)]
     assert worst_gap(keep(ga), keep(gb))[0] < 1e-4
-    assert model_of(spec_file).attention_kernel_share(24) == 0.0
-    assert model_of(spec_file).attention_kernel_share(4096) == 0.0  # CPU
-    assert model_of(spec_file).attention_backward_kernel_share(24) == 0.0
-    assert model_of(spec_file).attention_backward_kernel_share(4096) == 0.0
+    for tokens_ in (24, 4096):      # on the CPU
+        gauges = model_of(spec_file).trace_gauges(1, tokens_)
+        assert gauges["lm_attention_kernel_share"] == 0.0
+        assert gauges["lm_attention_backward_kernel_share"] == 0.0
 
 
 @pytest.mark.parametrize("remat", [True, False])
@@ -553,11 +555,13 @@ def test_the_backward_counter_follows_the_backward_rule(spec_file,
     monkeypatch.setattr(fa, "on_tpu", lambda: True)
     monkeypatch.setattr(hybrid_lm, "on_tpu", lambda: True)
     model = model_of(spec_file)
-    assert model.attention_kernel_share(4096) == 1.0
-    assert model.attention_backward_kernel_share(4096) == 1.0
-    assert model.attention_backward_kernel_share(2048) == 0.0
+    share, backward = "lm_attention_kernel_share", \
+        "lm_attention_backward_kernel_share"
+    assert model.trace_gauges(1, 4096)[share] == 1.0
+    assert model.trace_gauges(1, 4096)[backward] == 1.0
+    assert model.trace_gauges(1, 2048)[backward] == 0.0
     dense = model_of(spec_file, attention="dense")
-    assert dense.attention_backward_kernel_share(4096) == 0.0
+    assert dense.trace_gauges(1, 4096)[backward] == 0.0
 
 
 # -- the specification ------------------------------------------------------------
@@ -682,18 +686,19 @@ def test_sequential_round_reports_the_routers_gauges(files):
     start = jax.device_get(server.params)
     for _ in range(2):
         server, clients, m = t.run_round(server, clients)
-    assert float(m.lm_balance_loss) == 0.0
-    assert 1.0 <= float(m.lm_router_load_max_over_mean) <= 8.0
-    assert 0.0 < float(m.lm_router_bias_abs_max) < 1e-3
-    assert 0 < float(m.lm_moe_pairs_local) < 72
-    assert m.lm_index_loss is None and m.lm_exit_entropy is None
+    g = gauges_of(t, m)
+    assert float(g["lm_balance_loss"]) == 0.0
+    assert 1.0 <= float(g["lm_router_load_max_over_mean"]) <= 8.0
+    assert 0.0 < float(g["lm_router_bias_abs_max"]) < 1e-3
+    assert 0 < float(g["lm_moe_pairs_local"]) < 72
+    assert "lm_index_loss" not in g and "lm_exit_entropy" not in g
     gauges = t.telemetry_gauges()
     assert gauges["lm_attention_kernel_share"] == 0.0
     assert gauges["lm_attention_backward_kernel_share"] == 0.0
     assert "lm_selected_share" not in gauges
     scalars = t.round_host_scalars(clients, m)
     assert scalars["lm_router_bias_abs_max"] == float(
-        m.lm_router_bias_abs_max)
+        g["lm_router_bias_abs_max"])
     moved = jax.tree.map(lambda a, b: bool(np.any(np.asarray(a) != b)),
                          jax.device_get(server.params), start)
     assert all(jax.tree.leaves(moved)), moved
@@ -760,5 +765,6 @@ def test_the_keye_cells_lowered_round_is_unchanged(tmp_path):
     server, clients = jax.eval_shape(t.init_state, jax.random.key(0))
     text = jax.jit(t.round_fn, donate_argnums=(0, 1)).lower(
         server, clients, t.data, None).as_text()
-    text = re.sub(r"@([A-Za-z_][\w.]*?)_\d+\b", r"@\1", text)
+    text = with_field_names(
+        re.sub(r"@([A-Za-z_][\w.]*?)_\d+\b", r"@\1", text), t)
     assert hashlib.sha256(text.encode()).hexdigest() == KEYE_ROUND_SHA256

@@ -138,7 +138,7 @@ def test_without_remat_nothing_is_chosen_or_run_again(tmp_path,
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: model.token_loss(p, x)[0]))(model.init(jax.random.key(0)))
     assert rematted_products(jaxpr.jaxpr) == 0
-    assert model.kept_gauges(1, 16) == {}
+    assert model.trace_gauges(1, 16) == {}
 
 
 # -- the chooser -------------------------------------------------------------
@@ -195,7 +195,8 @@ def test_all_is_kept_where_the_backend_reports_no_memory(tmp_path, name):
     assert hybrid_lm._kept_for(spec, 1, 128) == full
     model = HybridLM("hybrid_lm", spec, dtype="bfloat16",
                      attention="auto", remat=True)
-    assert model.kept_gauges(1, 128) == kept_counters(spec, 128, full)
+    assert kept_counters(spec, 128, full).items() \
+        <= model.trace_gauges(1, 128).items()
     # a device that is full keeps nothing
     assert residual_budget(spec, 1, 128, {
         "bytes_limit": GIB, "bytes_in_use": GIB}) == 0

@@ -21,7 +21,9 @@ from benchmark.reference import _ops, keye_vl2 as reference
 from fedtorch_tpu.models import hybrid_lm
 from fedtorch_tpu.models.hybrid_lm import HybridLM, load_spec, param_shapes
 from fedtorch_tpu.ops import routed_experts, sparse_attention
-from test_sequential_round import lm_cfg, round_rows, trainer_of
+from test_sequential_round import (
+    gauges_of, lm_cfg, round_rows, trainer_of, with_field_names,
+)
 
 SMALL = {
     "model_type": "KeyeVL2", "vocab_size": 64, "hidden_size": 32,
@@ -443,7 +445,8 @@ def test_a_file_without_the_mechanisms_runs_the_same_block(tmp_path):
     plain = model_of(write_spec(tmp_path, "p.json", sa_config=...,
                                 num_experts=0))
     assert plain.spec.experts is None and plain.spec.selection is None
-    assert not plain.loss_parts and plain.selected_share(24) is None
+    assert not plain.gauge_names
+    assert "lm_selected_share" not in plain.trace_gauges(1, 24)
     shapes = param_shapes(plain.spec)["layer_0"]
     assert shapes["mlp"] == {"gate": (32, 96), "up": (32, 96),
                              "down": (96, 32)}
@@ -457,7 +460,7 @@ def test_a_file_without_the_mechanisms_runs_the_same_block(tmp_path):
     wide = model_of(write_spec(tmp_path, "w.json", sa_config=dict(
         SMALL["sa_config"], topk=32)))
     params = wide.init(jax.random.key(0))
-    assert wide.selected_share(24) == 1.0
+    assert wide.trace_gauges(1, 24)["lm_selected_share"] == 1.0
     with jax.default_matmul_precision("highest"):
         got = jax.jit(wide.apply)(params, x)
         # the same weights without the indexer: plain attention
@@ -540,19 +543,20 @@ def test_sequential_round_equals_the_vmapped_round(files):
     np.testing.assert_allclose(lv, ls, rtol=1e-5)
     for a, b in zip(jax.tree.leaves(pv), jax.tree.leaves(ps)):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-7)
-    assert mv.lm_index_loss is None and mv.lm_moe_pairs_local is None
-    assert ms.lm_exit_entropy is None
+    assert mv.model_gauges is None
+    g = gauges_of(ts, ms)
+    assert "lm_exit_entropy" not in g
     # 24 tokens x 2 a token x 4 of 8 experts held: 24 pairs expected
-    assert 10 < float(ms.lm_moe_pairs_local) < 40
-    assert 1.0 <= float(ms.lm_moe_load_max_over_mean) <= 4.0
-    assert 0.0 < float(ms.lm_index_loss) < 2.0
+    assert 10 < float(g["lm_moe_pairs_local"]) < 40
+    assert 1.0 <= float(g["lm_moe_load_max_over_mean"]) <= 4.0
+    assert 0.0 < float(g["lm_index_loss"]) < 2.0
     gauges = ts.telemetry_gauges()
     assert gauges["tokens_trained"] == 3 * 2 * 1 * 24
     assert gauges["lm_selected_share"] == (36 + 16 * 8) / 300
     assert "ut_steps" not in gauges
     scalars = ts.round_host_scalars(clients, ms)
-    assert scalars["lm_index_loss"] == float(ms.lm_index_loss)
-    assert scalars["lm_moe_pairs_local"] == float(ms.lm_moe_pairs_local)
+    assert scalars["lm_index_loss"] == float(g["lm_index_loss"])
+    assert scalars["lm_moe_pairs_local"] == float(g["lm_moe_pairs_local"])
 
 
 def test_launcher_rounds_evaluation_save_and_resume(files, tmp_path):
@@ -613,7 +617,8 @@ def test_the_ouro_cells_lowered_round_is_unchanged(tmp_path):
     server, clients = jax.eval_shape(t.init_state, jax.random.key(0))
     text = jax.jit(t.round_fn, donate_argnums=(0, 1)).lower(
         server, clients, t.data, None).as_text()
-    text = re.sub(r"@([A-Za-z_][\w.]*?)_\d+\b", r"@\1", text)
+    text = with_field_names(
+        re.sub(r"@([A-Za-z_][\w.]*?)_\d+\b", r"@\1", text), t)
     assert hashlib.sha256(text.encode()).hexdigest() == OURO_ROUND_SHA256
 
 

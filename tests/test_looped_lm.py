@@ -23,7 +23,9 @@ from fedtorch_tpu.models import hybrid_lm
 from fedtorch_tpu.models.hybrid_lm import (
     HybridLM, exit_objective, load_spec, param_shapes, rotary_tables,
 )
-from test_sequential_round import lm_cfg, round_rows, trainer_of
+from test_sequential_round import (
+    gauges_of, lm_cfg, round_rows, trainer_of,
+)
 
 SMALL = {
     "model_type": "ouro", "vocab_size": 64, "hidden_size": 32,
@@ -347,14 +349,15 @@ def test_sequential_round_equals_the_vmapped_round(files):
     np.testing.assert_allclose(lv, ls, rtol=1e-5)
     for a, b in zip(jax.tree.leaves(pv), jax.tree.leaves(ps)):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-7)
-    assert mv.lm_exit_entropy is None
-    assert 0.0 < float(ms.lm_exit_mass_last) < 1.0
-    assert 0.0 < float(ms.lm_exit_entropy) < np.log(3.0)
+    assert mv.model_gauges is None
+    g = gauges_of(ts, ms)
+    assert 0.0 < float(g["lm_exit_mass_last"]) < 1.0
+    assert 0.0 < float(g["lm_exit_entropy"]) < np.log(3.0)
     gauges = ts.telemetry_gauges()
     assert gauges["ut_steps"] == 3.0
     assert gauges["tokens_trained"] == 3 * 2 * 1 * 24
     scalars = ts.round_host_scalars(clients, ms)
-    assert scalars["lm_exit_entropy"] == float(ms.lm_exit_entropy)
+    assert scalars["lm_exit_entropy"] == float(g["lm_exit_entropy"])
 
 
 DEEP = dict(SMALL, hidden_size=256, intermediate_size=704,

@@ -10,12 +10,12 @@ run carries:
 * **degraded-pod resume** — a checkpoint taken at S=4 restored onto
   S=2 continues the S=1 trajectory bitwise (the hierarchical sum's
   association is a function of k alone, never of S);
-* **named refusals** — each illegal sharded composition (fused
-  execution, non-dividing cohort, robust rules, cohort stats,
-  uncertified algorithms, shard gather mode, non-dividing commit
+* **named refusals** — each illegal sharded composition (the
+  sequential execution, non-dividing cohort, robust rules, cohort
+  stats, uncertified algorithms, shard gather mode, non-dividing commit
   buffer) raises ONE ValueError naming the cell from validate_cell,
-  including the relocated fused-x-multi-device refusal with its exact
-  message (ISSUE 20 satellite: fusion.py no longer owns it);
+  including the sequential execution's multi-device refusal with its
+  exact message;
 * **torn-shard recovery** — under per-host sharded packing a torn
   ``MmapClientStore`` shard escalates through the
   'stream.gather' -> 'stream.producer' chain NAMING the owning
@@ -237,11 +237,11 @@ def _reason(cfg, source="resident", dispatch="round",
 
 
 class TestShardedRefusals:
-    def test_fused_execution_refused_under_sharding(self):
+    def test_sequential_execution_refused_under_sharding(self):
         reason = _reason(make_cfg("resident", "round", 2),
-                         execution="fused")
-        assert "until a sharded grouped-conv lowering is measured" \
-            in reason
+                         execution="sequential")
+        assert "the fold is one device's (mesh has 8 devices; " \
+            "client_shards must be 0)" in reason
 
     def test_non_dividing_cohort_refused(self):
         with pytest.raises(ValueError, match="does not divide the "
@@ -297,37 +297,21 @@ class TestShardedRefusals:
             make_mesh(MeshConfig(client_shards=3))
 
 
-# -- the relocated fused-cell multi-device refusal (satellite) --------------
-def test_fused_multi_device_refusal_exact_message():
-    """The fused execution's one multi-device rule now lives in
-    validate_cell (not fusion.py): the EXACT message, raised at
-    trainer construction on a multi-device mesh."""
-    from fedtorch_tpu.data.batching import stack_partitions
-    cfg = ExperimentConfig(
-        data=DataConfig(dataset="cifar10", batch_size=6,
-                        augment=False, data_plane="device"),
-        federated=FederatedConfig(
-            federated=True, num_clients=4, online_client_rate=0.5,
-            algorithm="fedavg", sync_type="local_step"),
-        model=ModelConfig(arch="cnn", norm="bn"),
-        optim=OptimConfig(lr=0.05, in_momentum=True),
-        train=TrainConfig(local_step=2),
-        mesh=MeshConfig(client_fusion="fused"),  # all 8 devices
-    ).finalize()
-    sizes = (24, 9, 17, 24)
-    rng = np.random.RandomState(0)
-    feats = rng.randn(sum(sizes), 32, 32, 3).astype(np.float32)
-    labels = rng.randint(0, 10, sum(sizes))
-    off = np.concatenate([[0], np.cumsum(sizes)])
-    parts = [np.arange(off[i], off[i + 1]) for i in range(len(sizes))]
-    data = stack_partitions(feats, labels, parts)
+# -- the sequential cell's multi-device refusal ------------------------------
+def test_sequential_multi_device_refusal_exact_message():
+    """The execution axis's one multi-device rule lives in
+    validate_cell: the EXACT message, raised at trainer construction
+    on a multi-device mesh."""
     n = len(jax.devices())
     expected = (
-        "mesh.client_fusion='fused' is unsupported: mesh has "
-        f"{n} devices — the packed client/channel axis must not be "
-        "sharded (use the vmap path's client-axis sharding)")
+        "round-program cell (resident x round x sequential) is "
+        "unsupported here: mesh.client_fusion='sequential' runs the "
+        "cohort one client after another into a running weighted sum "
+        "and keeps no per-client copy of the parameters: the fold is "
+        f"one device's (mesh has {n} devices; client_shards must be 0)")
     with pytest.raises(ValueError, match=re.escape(expected)):
-        build_trainer(cfg, data)
+        build_trainer(make_cfg("resident", "round", 0,
+                               fusion="sequential"))  # all 8 devices
 
 
 # -- torn-shard recovery under per-host sharded packing ---------------------

@@ -153,8 +153,8 @@ class TestSeededLowerings:
 class TestFullMatrix:
     def test_every_cell_lowers_clean_with_empty_baseline(self, tmp_path):
         """The acceptance bar: all legal cells lower and pass with an
-        empty FTP baseline, the fused and the sequential commit cells
-        refuse, and the whole audit stays far inside the 120 s tier-1
+        empty FTP baseline, the sequential commit cells refuse, and
+        the whole audit stays far inside the 120 s tier-1
         budget."""
         t0 = time.time()
         new, report = audit_programs(log=lambda *_: None)
@@ -163,16 +163,14 @@ class TestFullMatrix:
         legal = {c: r for c, r in report["cells"].items() if r["legal"]}
         refused = {c: r for c, r in report["cells"].items()
                    if not r["legal"]}
-        # 14 legal cells (4 of them the sequential execution's) + the
+        # 10 legal cells (4 of them the sequential execution's) + the
         # 6 [shards=2] pod-scale twins of the vmap cells (+ bf16 twins
         # of the vmap round/scan cells)
         assert len([c for c in legal if "[bfloat16]" not in c
-                    and "[shards=" not in c]) == 14
+                    and "[shards=" not in c]) == 10
         assert len([c for c in legal if "[shards=" in c]) == 6
         assert len([c for c in legal if "[bfloat16]" in c]) == 4
-        assert set(refused) == {"(resident x commit x fused)",
-                                "(feed x commit x fused)",
-                                "(resident x commit x sequential)",
+        assert set(refused) == {"(resident x commit x sequential)",
                                 "(feed x commit x sequential)"}
         for cell, rec in refused.items():
             assert cell in rec["refusal"]

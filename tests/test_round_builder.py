@@ -33,7 +33,6 @@ from fedtorch_tpu.config import (
     MeshConfig, ModelConfig, OptimConfig, TrainConfig,
 )
 from fedtorch_tpu.data import build_federated_data
-from fedtorch_tpu.data.batching import stack_partitions
 from fedtorch_tpu.models import define_model
 from fedtorch_tpu.parallel import FederatedTrainer
 from fedtorch_tpu.parallel.round_program import (
@@ -46,8 +45,6 @@ CELLS = list(iter_cells())
 # the genuinely impossible cells of the base (fedavg) matrix — every
 # other combination must run and hold the parity bars
 ILLEGAL = {
-    ("resident", "commit", "fused"),
-    ("feed", "commit", "fused"),
     ("resident", "commit", "sequential"),
     ("feed", "commit", "sequential"),
 }
@@ -59,22 +56,6 @@ CHAOS = {"client_drop_rate": 0.3, "straggler_rate": 0.3,
 def make_cfg(source, *, execution="vmap", sync_mode="sync",
              algorithm="fedavg", fault_kw=None, **fed_kw):
     plane = "stream" if source == "feed" else "device"
-    if execution == "fused":
-        # the fused execution needs a fused module (cnn/bn) and a
-        # single-device mesh
-        return ExperimentConfig(
-            data=DataConfig(dataset="cifar10", batch_size=6,
-                            augment=False, data_plane=plane),
-            federated=FederatedConfig(
-                federated=True, num_clients=4, online_client_rate=0.5,
-                algorithm=algorithm, sync_type="local_step",
-                sync_mode=sync_mode, **fed_kw),
-            model=ModelConfig(arch="cnn", norm="bn"),
-            optim=OptimConfig(lr=0.05, in_momentum=True),
-            train=TrainConfig(local_step=2),
-            mesh=MeshConfig(num_devices=1, client_fusion=execution),
-            fault=FaultConfig(**(fault_kw or {})),
-        ).finalize()
     return ExperimentConfig(
         data=DataConfig(dataset="synthetic", synthetic_dim=20,
                         batch_size=16, synthetic_alpha=0.5,
@@ -98,17 +79,7 @@ def build_trainer(source, *, execution="vmap", dispatch="round",
     sync_mode = "async" if dispatch == "commit" else "sync"
     cfg = make_cfg(source, execution=execution, sync_mode=sync_mode,
                    algorithm=algorithm, fault_kw=fault_kw, **fed_kw)
-    if execution == "fused":
-        sizes = (24, 9, 17, 24)
-        rng = np.random.RandomState(0)
-        feats = rng.randn(sum(sizes), 32, 32, 3).astype(np.float32)
-        labels = rng.randint(0, 10, sum(sizes))
-        off = np.concatenate([[0], np.cumsum(sizes)])
-        parts = [np.arange(off[i], off[i + 1])
-                 for i in range(len(sizes))]
-        data = stack_partitions(feats, labels, parts)
-    else:
-        data = build_federated_data(cfg).train
+    data = build_federated_data(cfg).train
     model = define_model(cfg, batch_size=cfg.data.batch_size)
     if sync_mode == "async":
         from fedtorch_tpu.async_plane import AsyncFederatedTrainer
@@ -288,29 +259,6 @@ def _validate(source, dispatch, execution, sync_mode):
                   gather_mode="auto", has_val=False)
 
 
-_COMMIT_FUSED_REASON = (
-    "client_fusion='fused' packs clients into one grouped conv "
-    "against ONE shared server snapshot; buffered commits train each "
-    "client against its own dispatch-time version — use the vmap "
-    "execution or --sync_mode sync")
-
-
-def test_refusal_snapshot_resident_commit_fused():
-    with pytest.raises(ValueError) as err:
-        _validate("resident", "commit", "fused", "async")
-    assert str(err.value) == (
-        "round-program cell (resident x commit x fused) is "
-        "unsupported here: " + _COMMIT_FUSED_REASON)
-
-
-def test_refusal_snapshot_feed_commit_fused():
-    with pytest.raises(ValueError) as err:
-        _validate("feed", "commit", "fused", "async")
-    assert str(err.value) == (
-        "round-program cell (feed x commit x fused) is "
-        "unsupported here: " + _COMMIT_FUSED_REASON)
-
-
 _COMMIT_SEQUENTIAL_REASON = (
     "mesh.client_fusion='sequential' runs the cohort one client after "
     "another into a running weighted sum and keeps no per-client copy "
@@ -336,7 +284,7 @@ def test_refusal_snapshot_feed_commit_sequential():
 
 def test_refusal_snapshot_scan_under_async():
     """The deferred scan gate's exact text (run_rounds on the async
-    plane) — structurally impossible like the fused commits, but
+    plane) — structurally impossible like the sequential commits, but
     refused at call time rather than construction."""
     with pytest.raises(ValueError) as err:
         _validate("resident", "scan", "vmap", "async")

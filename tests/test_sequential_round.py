@@ -5,6 +5,7 @@ round hold; ``validate_cell``'s refusals by name; the launcher with a
 specification file through rounds, evaluation, save and resume."""
 import json
 import os
+import re
 
 import jax
 import numpy as np
@@ -69,6 +70,22 @@ def trainer_of(cfg):
     data = build_federated_data(cfg).train
     model = define_model(cfg, batch_size=cfg.data.batch_size)
     return FederatedTrainer(cfg, model, make_algorithm(cfg), data)
+
+
+def gauges_of(trainer, metrics) -> dict:
+    """The model's own gauges of a round's metrics, by row key."""
+    return dict(zip(trainer.gauge_names, metrics.model_gauges or ()))
+
+
+def with_field_names(text: str, trainer) -> str:
+    """A lowered round's text with the model's gauges named as
+    ``RoundMetrics``' fields named them until PR 44 made them one tuple
+    under the model's own names (``jax.result_info``: a result's name,
+    no operation), so that the digests taken before it stand as they
+    were and still pin every operation."""
+    return re.sub(
+        r"(result\[2\])\.model_gauges\[(\d+)\]",
+        lambda m: f"{m[1]}.{trainer.gauge_names[int(m[2])]}", text)
 
 
 @pytest.mark.parametrize("algorithm", ["fedavg", "fedprox"])
